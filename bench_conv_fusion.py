@@ -12,8 +12,8 @@ batch 256 bf16:
   once). XLA's schedule is write-y, read-y-for-stats, read-y-normalize —
   the kernel removes one full activation pass.
 
-Protocol as bench_resnet_profile.py: N queued calls + one value sync,
-min of 3, null round-trip subtracted.
+Protocol as bench_resnet_profile.py: N queued calls + one
+``block_until_ready``, min of 3. Runs on the chip only.
 """
 
 import functools
@@ -32,20 +32,12 @@ def main():
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    def _sync(x):
-        return float(jnp.asarray(x).astype(jnp.float32).reshape(-1)[0])
+    from deeplearning4j_tpu.util.device import banner, require_tpu
 
-    null = jax.jit(lambda v: v + 1.0)
-    _sync(null(jnp.float32(0.0)))
-    rts = []
-    for _ in range(5):
-        t0 = time.perf_counter()
-        out = jnp.float32(0.0)
-        for _ in range(10):
-            out = null(out)
-        _sync(out)
-        rts.append((time.perf_counter() - t0) * 1000.0)
-    rt = min(rts)
+    dev = require_tpu("bench_conv_fusion.py")
+    print("# " + banner(dev), flush=True)
+
+    _sync = jax.block_until_ready
 
     def timed(fn, *args):
         out = fn(*args)
@@ -56,7 +48,7 @@ def main():
             for _ in range(N):
                 out = fn(*args)
             _sync(out)
-            best = min(best, ((time.perf_counter() - t0) * 1000.0 - rt) / N)
+            best = min(best, (time.perf_counter() - t0) * 1000.0 / N)
         return best
 
     rng = np.random.default_rng(0)
@@ -71,7 +63,7 @@ def main():
         ("s1_3x3", (B, 56, 56, 64), (3, 3, 64, 64), (1, 1), "SAME"),
         ("s1_1x1x4", (B, 56, 56, 64), (1, 1, 64, 256), (1, 1), "VALID"),
     ]
-    results = {"null_roundtrip_ms": round(rt, 1)}
+    results = {"device": dev}
     dn = ("NHWC", "HWIO", "NHWC")
 
     def conv(x, w, s, p):

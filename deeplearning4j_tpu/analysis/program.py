@@ -30,6 +30,8 @@ import re
 import threading
 from typing import Dict, List, Optional, Tuple
 
+import numpy as np
+
 from deeplearning4j_tpu.analysis.findings import (
     ERROR,
     WARN,
@@ -72,8 +74,11 @@ TRAIN_KIND_PREFIXES = ("train_step", "fused_scan", "tbptt_scan", "pw_",
 # audit and compile finding-free).
 RESHARD_KIND_PREFIXES = ("pod_recut", "reshard_commit")
 
-ALL_REDUCE_PRIMS = frozenset({"psum", "psum2", "all_reduce"})
-REDUCE_SCATTER_PRIMS = frozenset({"psum_scatter", "reduce_scatter"})
+# jax 0.9.0 primitive names: lax.psum under check_vma binds
+# psum_invariant (plain psum only with the check off), psum_scatter
+# binds reduce_scatter
+ALL_REDUCE_PRIMS = frozenset({"psum", "psum_invariant"})
+REDUCE_SCATTER_PRIMS = frozenset({"reduce_scatter"})
 CALLBACK_PRIMS = frozenset({
     "pure_callback", "io_callback", "debug_callback", "callback",
     "outside_call", "infeed", "outfeed",
@@ -181,6 +186,17 @@ def iter_eqns(closed_jaxpr):
     yield from walk(closed_jaxpr.jaxpr)
 
 
+def pallas_interpret_flags(fn, *args) -> List[bool]:
+    """The ``interpret`` param of every ``pallas_call`` that ``fn(*args)``
+    traces (custom-VJP branches included). Tracing lowers nothing, so a
+    TPU-keyed kernel build can be inspected on any backend."""
+    import jax
+
+    return [bool(e.params["interpret"])
+            for e in iter_eqns(jax.make_jaxpr(fn)(*args))
+            if e.primitive.name == "pallas_call"]
+
+
 def _prim_counts(closed_jaxpr) -> Dict[str, int]:
     counts: Dict[str, int] = {}
     for eqn in iter_eqns(closed_jaxpr):
@@ -245,11 +261,15 @@ def _rule_baked_constants(art: ProgramArtifact, out: List[Finding]) -> None:
     if art.jaxpr is None:
         return
     for c in getattr(art.jaxpr, "consts", ()):
-        nbytes = getattr(c, "nbytes", 0) or 0
+        shape = getattr(c, "shape", None)
+        dtype = getattr(c, "dtype", None)
+        if shape is None or dtype is None:
+            continue
+        # sized from shape/dtype: jax wraps captured numpy arrays in a
+        # TypedNdArray, which has no ``nbytes``
+        nbytes = int(np.prod(shape)) * np.dtype(dtype).itemsize
         if nbytes >= CONST_WARN_BYTES:
             sev = ERROR if nbytes >= CONST_ERROR_BYTES else WARN
-            shape = getattr(c, "shape", ())
-            dtype = getattr(c, "dtype", "?")
             out.append(Finding(
                 rule="PRG202", severity=sev, location=art.location,
                 message=f"closure-captured constant {shape} {dtype} "
@@ -355,7 +375,9 @@ def _audit_scheduler_plans(art: ProgramArtifact, counts, n_allreduce,
         return  # keys minted elsewhere / comms unavailable: nothing to say
     if not plans:
         return
-    n_gather = counts.get("all_gather", 0)
+    # the scheduler emits the Varying -> Invariant gather primitive
+    n_gather = (counts.get("all_gather_invariant", 0)
+                + counts.get("all_gather", 0))
     exp_barriers = sum(max(0, p.launches() - 1) for p in plans)
     for p in plans:
         if (p.intent == "reduce_scatter" and n_scatter == 0
@@ -365,13 +387,12 @@ def _audit_scheduler_plans(art: ProgramArtifact, counts, n_allreduce,
                 message=f"plan {p.digest} promised reduce-scatter but "
                         f"the module compiled all-reduce collectives "
                         f"only — the gradient exchange is not sharded"))
-        if (p.intent == "all_gather" and "all_gather" in p.choices
-                and n_gather == 0 and n_allreduce == 0):
+        if p.intent == "all_gather" and n_gather == 0:
             out.append(Finding(
                 rule="PRG205", severity=WARN, location=art.location,
                 message=f"plan {p.digest} promised a native all-gather "
-                        f"but the module contains no gather (or masked-"
-                        f"psum) collective"))
+                        f"but the module contains no gather "
+                        f"collective"))
     # expected scatter launches: >= one psum_scatter eqn per leaf, so at
     # least one per bucket — fewer means buckets merged despite the pins
     exp_scatter = sum(p.launches() for p in plans

@@ -27,17 +27,10 @@ owner of all three decisions:
                   per-leaf sparse exchange loses to one dense buffer when
                   leaves are tiny); ``psum`` is elementwise, so the result
                   is bitwise the per-leaf exchange
-  ``all_gather``  native ``lax.all_gather`` — chosen when
-                  :data:`NATIVE_ALL_GATHER` shows a vma-capable jax whose
-                  type system can express the gathered output's
-                  replication (probe-gated like
-                  ``mesh.EFFICIENT_PSUM_TRANSPOSE``); moves the ring
-                  all-gather's (n-1)/n payload
-  ``masked_psum`` the pre-vma fallback: each shard deposits its slice
-                  into a zeros vector and a ``psum`` reassembles —
-                  bitwise-exact and statically-replicated for check_rep
-                  jax (this container's 0.4.37), at ~2x native all-gather
-                  bandwidth on the wire
+  ``all_gather``  the native all-gather (the ring all-gather's (n-1)/n
+                  payload), in its Varying -> Invariant form so the
+                  gathered result type-checks against replicated
+                  ``P()`` out_specs under ``shard_map``'s ``check_vma``
   =============== ==========================================================
 
 Every plan is content-addressed: :attr:`CollectivePlan.digest` hashes the
@@ -59,27 +52,9 @@ import numpy as np
 
 # this module sits BELOW parallel/ in the import graph (parallel.
 # compression re-exports from here), so it cannot import parallel.mesh
-# at module scope; the axis-name constant and the capability probe are
-# restated with their authorities cross-referenced
+# at module scope; the axis-name constant is restated with its authority
+# cross-referenced
 DATA_AXIS = "data"   # parallel.mesh.DATA_AXIS
-
-
-def _probe_vma() -> bool:
-    import jax
-
-    # the SAME feature probe as parallel.mesh.EFFICIENT_PSUM_TRANSPOSE
-    # (jax.typeof + lax.pcast = the vma type system), restated here to
-    # keep comms importable without the parallel package
-    return hasattr(jax, "typeof") and hasattr(jax.lax, "pcast")
-
-
-# capability probe: a native lax.all_gather's output is replicated in
-# VALUE but only the vma type system can SAY so — pre-vma check_rep
-# shard_map rejects out_specs claiming replication of a gathered result,
-# so the masked-psum fallback stays active on this container's 0.4.37.
-# Tests exercise the native branch through this seam (monkeypatch +
-# varying out_specs).
-NATIVE_ALL_GATHER = _probe_vma()
 
 INTENTS = ("all_reduce", "reduce_scatter", "all_gather")
 
@@ -151,9 +126,7 @@ class CollectivePlan:
     digest: str = ""
 
     def bytes_moved(self) -> int:
-        """Logical per-shard payload of one exchange (the masked-psum
-        gather fallback costs ~2x this on the wire — the counters record
-        the logical payload either way)."""
+        """Logical per-shard payload of one exchange."""
         return int(sum(self.leaf_sizes))
 
     def launches(self) -> int:
@@ -185,8 +158,8 @@ class CollectivePlan:
 
 def _leaf_meta(leaves, intent, full_sizes):
     """-> (payload bytes per leaf, dtype strs). For ``all_gather`` the
-    payload is the GATHERED vector (``full_sizes``), matching the layout
-    the masked-psum contributions actually bucket on."""
+    payload is the GATHERED vector (``full_sizes``) — the same layout
+    the matching reduce-scatter buckets on."""
     dtypes = [str(np.dtype(l.dtype)) for l in leaves]
     if intent == "all_gather":
         if full_sizes is None:
@@ -213,7 +186,7 @@ def _choose(intent, idxs, sizes, dtypes):
         # reduce-scatter always exchanges per-leaf
         return "variadic"
     if intent == "all_gather":
-        return "all_gather" if NATIVE_ALL_GATHER else "masked_psum"
+        return "all_gather"
     raise ValueError(f"unknown intent {intent!r}; expected one of "
                      f"{INTENTS}")
 
@@ -276,8 +249,7 @@ class CollectiveScheduler:
         leaves = jax.tree_util.tree_leaves(tree)
         sizes, dtypes = _leaf_meta(leaves, intent, full_sizes)
         key = (intent, self.axis_name, self.bucket_bytes, tuple(sizes),
-               tuple(dtypes),
-               NATIVE_ALL_GATHER if intent == "all_gather" else None)
+               tuple(dtypes))
         with _LOCK:
             cached = _PLAN_CACHE.get(key)
             if cached is not None:
@@ -316,19 +288,15 @@ class CollectiveScheduler:
         return plan
 
     # --- execution (traced: runs inside jitted steps) ----------------------
-    def execute(self, plan: CollectivePlan, tree, index=None,
-                full_sizes=None):
+    def execute(self, plan: CollectivePlan, tree):
         """Run one exchange under ``plan``. ``all_gather`` plans take the
-        per-shard slice tree plus ``index`` (this shard's ``axis_index``,
-        masked-psum fallback only) and ``full_sizes`` (per-leaf gathered
-        lengths)."""
+        per-shard slice tree and return the gathered vectors, replicated
+        on every shard."""
         import jax
 
         leaves, treedef = jax.tree_util.tree_flatten(tree)
         if not leaves:
             return tree
-        if plan.intent == "all_gather":
-            leaves = _gather_operands(plan, leaves, index, full_sizes)
         out = [None] * len(leaves)
         pin = None
         for bucket, choice in zip(plan.buckets, plan.choices):
@@ -343,29 +311,6 @@ class CollectiveScheduler:
             for i, r in zip(bucket, red):
                 out[i] = r
         return jax.tree_util.tree_unflatten(treedef, out)
-
-
-def _gather_operands(plan, slices, index, full_sizes):
-    """The all-gather operand transform. Masked-psum fallback: each
-    shard deposits its slice at ``[index*m, (index+1)*m)`` of a zeros
-    vector — adding zeros is float-exact AND the psum output is
-    statically replicated for check_rep jax. Native path: the raw
-    slices feed ``lax.all_gather`` directly."""
-    import jax
-    import jax.numpy as jnp
-
-    if full_sizes is None:
-        raise ValueError("all_gather execution needs full_sizes")
-    if all(c == "all_gather" for c in plan.choices):
-        return list(slices)
-    if index is None:
-        raise ValueError("masked-psum all_gather needs the shard index")
-    out = []
-    for sl, full in zip(slices, full_sizes):
-        m = sl.shape[0]
-        out.append(jax.lax.dynamic_update_slice(
-            jnp.zeros((int(full),), sl.dtype), sl, (index * m,)))
-    return out
 
 
 def _run_bucket(plan, choice, vals):
@@ -391,11 +336,14 @@ def _run_bucket(plan, choice, vals):
                 jax.lax.slice_in_dim(red, off, off + n), shape))
             off += n
         return tuple(out)
-    if choice == "masked_psum":
-        # operands are the position-masked full-size contributions
-        return jax.lax.psum(vals, axis)
     if choice == "all_gather":
-        return tuple(jax.lax.all_gather(v, axis, axis=0, tiled=True)
+        # Varying -> Invariant: the public lax.all_gather types its
+        # result as still varying over the axis, which check_vma rejects
+        # against the P() out_specs every ZeRO step returns its params
+        # under; jax 0.9.0 has the invariant form only under jax._src
+        from jax._src.lax.parallel import all_gather_invariant
+
+        return tuple(all_gather_invariant(v, axis, axis=0, tiled=True)
                      for v in vals)
     raise ValueError(f"unknown collective choice {choice!r}")
 
@@ -413,12 +361,12 @@ def plan_for(tree, intent: str, axis_name: str = DATA_AXIS,
 
 
 def exchange(tree, intent: str, axis_name: str = DATA_AXIS,
-             bucket_bytes=None, index=None, full_sizes=None):
+             bucket_bytes=None, full_sizes=None):
     """Plan + execute one exchange (the ``bucketed_*`` primitives'
     engine). Traced: call from inside jitted/shard_mapped steps."""
     sched = CollectiveScheduler(axis_name, bucket_bytes)
     plan = sched.plan(tree, intent, full_sizes=full_sizes)
-    return sched.execute(plan, tree, index=index, full_sizes=full_sizes)
+    return sched.execute(plan, tree)
 
 
 def _record_plan(plan: CollectivePlan) -> None:

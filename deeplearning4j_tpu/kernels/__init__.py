@@ -15,10 +15,10 @@ compiler-integration argument, arXiv:2503.19779):
   digest-verified on-disk tuning cache (temp+rename; corruption is a
   named refusal + stock-XLA fallback);
 - ``routing``: the forward-pass dispatch behind ``conf.use_kernels``
-  (default OFF — bit-identical to no subsystem at all) plus the
-  capability probe (real Mosaic lowering on TPU, the Pallas
-  interpreter everywhere else so CPU containers validate the same
-  kernel bodies end to end).
+  (default OFF — bit-identical to no subsystem at all); ``backend()``
+  picks real Mosaic lowering on a TPU backend and the Pallas
+  interpreter everywhere else, so CPU containers validate the same
+  kernel bodies end to end.
 
 Selection is keyed into ``optimize/aot_cache`` via
 ``cache_tag(conf)``'s ``kern:<id>:<digest>`` tokens: a retuned kernel
@@ -48,7 +48,6 @@ from deeplearning4j_tpu.kernels.routing import (  # noqa: F401
     autotune_decoder,
     autotune_model,
     backend,
-    capability,
     decoder_envelopes,
     maybe_decode_attention,
     maybe_flash_attention,

@@ -23,8 +23,10 @@ Both kernels follow the ``ops/conv_fused`` discipline:
 
 Tiling validity: a candidate ``(bm, bn, bk)`` is clamped per-dimension
 to the problem size (``ebm = min(bm, m)`` ...) and is legal when every
-clamped block divides its dimension exactly — the registry's envelope
-check; shapes with no legal candidate fall back to stock XLA.
+clamped block divides its dimension exactly and, for a TPU-keyed
+envelope, passes Mosaic's block-shape rule (:func:`mosaic_block_ok`) —
+the registry's envelope check; shapes with no legal candidate fall back
+to stock XLA.
 """
 
 from __future__ import annotations
@@ -36,20 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pltpu imports can fail on CPU-only installs; interpret mode is
-    # still available without the TPU lowering itself
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
-
-
-def has_pallas() -> bool:
-    """Whether the Pallas TPU dialect is importable at all (its VMEM
-    scratch types are needed even in interpret mode)."""
-    return _HAS_PLTPU
+from jax.experimental.pallas import tpu as pltpu
 
 
 def effective_tiling(m: int, k: int, n: int,
@@ -68,8 +57,20 @@ def tiling_valid(m: int, k: int, n: int,
             and m % ebm == 0 and n % ebn == 0 and k % ebk == 0)
 
 
+def mosaic_block_ok(m: int, k: int, n: int,
+                    tiling: Tuple[int, int, int]) -> bool:
+    """Mosaic's block-shape rule for the ``[bm, bk] x [bk, bn]`` blocks
+    these kernels declare: the last two dims of a block are multiples of
+    (8, 128) or span the whole array dim. The interpreter takes any
+    divisor; the TPU lowering refuses the rest by name."""
+    ebm, ebn, ebk = effective_tiling(m, k, n, tiling)
+    return ((ebm % 8 == 0 or ebm == m)
+            and (ebn % 128 == 0 or ebn == n)
+            and (ebk % 128 == 0 or ebk == k))
+
+
 def _compiler_params(interpret: bool):
-    if interpret or not _HAS_PLTPU:
+    if interpret:
         return None
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
@@ -100,8 +101,6 @@ def _mm_bias_act_impl(x2, w2, b, act, tiling, interpret):
     n = w2.shape[-1]
     ebm, ebn, ebk = effective_tiling(m, k, n, tiling)
     assert tiling_valid(m, k, n, tiling), (m, k, n, tiling)
-    if not _HAS_PLTPU:  # pragma: no cover - interpret-only environments
-        raise NotImplementedError("pallas tpu dialect unavailable")
     nbm, nbn, nbk = m // ebm, n // ebn, k // ebk
     return pl.pallas_call(
         functools.partial(_mm_bias_act_kernel, nk=nbk, act_fn=act.apply),
@@ -186,8 +185,6 @@ def _mm_stats_impl(x2, w2, tiling, interpret):
     n = w2.shape[-1]
     ebm, ebn, ebk = effective_tiling(m, k, n, tiling)
     assert tiling_valid(m, k, n, tiling), (m, k, n, tiling)
-    if not _HAS_PLTPU:  # pragma: no cover - interpret-only environments
-        raise NotImplementedError("pallas tpu dialect unavailable")
     nbm, nbn, nbk = m // ebm, n // ebn, k // ebk
     y, ssum, sq = pl.pallas_call(
         functools.partial(_mm_stats_kernel, nk=nbk),
@@ -282,8 +279,6 @@ def matmul_bias_act_int8(xq, wq, scale, b, act, tiling, interpret):
     n = wq.shape[-1]
     ebm, ebn, ebk = effective_tiling(m, k, n, tiling)
     assert tiling_valid(m, k, n, tiling), (m, k, n, tiling)
-    if not _HAS_PLTPU:  # pragma: no cover - interpret-only environments
-        raise NotImplementedError("pallas tpu dialect unavailable")
     nbm, nbn, nbk = m // ebm, n // ebn, k // ebk
     return pl.pallas_call(
         functools.partial(_mm_bias_act_q8_kernel, nk=nbk, act_fn=act.apply),

@@ -106,6 +106,14 @@ class AttentionEnvelope:
         return f"b{self.b}_h{self.h}_tq{self.tq}_tk{self.tk}_d{self.d}"
 
 
+def _tiling_legal(env: MatmulEnvelope, tiling) -> bool:
+    """The clamped blocks divide the problem exactly and, on the TPU
+    backend, are blocks Mosaic will lower."""
+    return (impls.tiling_valid(env.m, env.k, env.n, tiling)
+            and (env.backend != "tpu"
+                 or impls.mosaic_block_ok(env.m, env.k, env.n, tiling)))
+
+
 def _sweep_candidates(env: MatmulEnvelope,
                       limit: Optional[int]) -> List[Tuple[int, int, int]]:
     seen, out = set(), []
@@ -114,8 +122,7 @@ def _sweep_candidates(env: MatmulEnvelope,
             for bk in _BK_SWEEP:
                 t = (bm, bn, bk)
                 eff = impls.effective_tiling(env.m, env.k, env.n, t)
-                if eff in seen or not impls.tiling_valid(
-                        env.m, env.k, env.n, t):
+                if eff in seen or not _tiling_legal(env, t):
                     continue
                 seen.add(eff)
                 out.append(eff)
@@ -126,8 +133,7 @@ def _sweep_candidates(env: MatmulEnvelope,
 
 
 def _matmul_supports(env) -> bool:
-    return (impls.has_pallas()
-            and env.dtype in _SUPPORTED_DTYPES
+    return (env.dtype in _SUPPORTED_DTYPES
             and env.m > 0 and env.k > 0 and env.n > 0
             and bool(_sweep_candidates(env, limit=1)))
 
@@ -188,12 +194,11 @@ class Kernel:
 
 
 class _MatmulKernel(Kernel):
-    """Shared matmul-class winner validation: a 3-tuple whose clamped
-    blocks divide the problem exactly (``impls.tiling_valid``)."""
+    """Shared matmul-class winner validation: a 3-tuple that is a legal
+    tiling of the envelope (:func:`_tiling_legal`)."""
 
     def tiling_ok(self, env, tiling) -> bool:
-        return len(tiling) == 3 and impls.tiling_valid(
-            env.m, env.k, env.n, tiling)
+        return len(tiling) == 3 and _tiling_legal(env, tiling)
 
 
 class MatmulBiasActKernel(_MatmulKernel):
@@ -243,7 +248,7 @@ class Int8MatmulBiasActKernel(_MatmulKernel):
     version = 1
 
     def supports(self, env) -> bool:
-        return (impls.has_pallas() and env.dtype == "int8"
+        return (env.dtype == "int8"
                 and env.m > 0 and env.k > 0 and env.n > 0
                 and bool(_sweep_candidates(env, limit=1)))
 
@@ -326,8 +331,7 @@ class ConvBnActKernel(_MatmulKernel):
 
 
 def _attention_supports(env) -> bool:
-    return (impls.has_pallas()
-            and isinstance(env, AttentionEnvelope)
+    return (isinstance(env, AttentionEnvelope)
             and env.dtype in _SUPPORTED_DTYPES
             and env.b > 0 and env.h > 0 and env.d > 0
             and env.tq > 0 and env.tk > 0)

@@ -1,4 +1,4 @@
-"""Layer-to-kernel routing + envelope planning + capability probe.
+"""Layer-to-kernel routing + envelope planning.
 
 ``maybe_forward(layer, ...)`` is the single dispatch point the model
 forward passes call when ``conf.use_kernels`` is on: it inspects the
@@ -40,7 +40,6 @@ from __future__ import annotations
 
 from typing import List, Optional, Tuple
 
-from deeplearning4j_tpu.kernels import impls
 from deeplearning4j_tpu.kernels.registry import (
     REGISTRY,
     AttentionEnvelope,
@@ -49,47 +48,14 @@ from deeplearning4j_tpu.kernels.registry import (
 
 
 def backend() -> str:
-    """The Pallas execution mode for this process: ``"tpu"`` (real
-    Mosaic lowering), ``"interpret"`` (the Pallas interpreter — CPU
-    containers), or ``"none"`` (pallas-tpu unimportable: routing is
-    disabled entirely)."""
-    if not impls.has_pallas():
-        return "none"
+    """The Pallas execution mode for this process, decided from
+    ``jax.default_backend()`` alone: ``"tpu"`` (real Mosaic lowering) or
+    ``"interpret"`` (the Pallas interpreter — CPU containers). On a TPU
+    backend a kernel Mosaic refuses is a compile error carrying the
+    compiler's message, never a quiet run in the interpreter."""
     import jax
 
     return "tpu" if jax.default_backend() == "tpu" else "interpret"
-
-
-_CAPABILITY = None
-
-
-def capability() -> str:
-    """Probe-once capability: like :func:`backend`, but ``"tpu"`` is
-    only reported after a trivial ``pallas_call`` actually COMPILES
-    without ``interpret`` (the PR-7 probe-and-skip shape — a TPU
-    backend whose Mosaic pipeline is broken degrades to interpret
-    rather than failing every routed trace)."""
-    global _CAPABILITY
-    if _CAPABILITY is not None:
-        return _CAPABILITY
-    mode = backend()
-    if mode == "tpu":
-        try:
-            import jax
-            import jax.numpy as jnp
-            from jax.experimental import pallas as pl
-
-            def _probe(x_ref, o_ref):
-                o_ref[...] = x_ref[...] + 1.0
-
-            x = jnp.zeros((8, 128), jnp.float32)
-            jax.jit(lambda a: pl.pallas_call(
-                _probe, out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-            )(a)).lower(x).compile()
-        except Exception:
-            mode = "interpret"
-    _CAPABILITY = mode
-    return _CAPABILITY
 
 
 # every Activation is elementwise except softmax (normalizes over the
@@ -109,11 +75,8 @@ def _pair(v) -> Tuple[int, int]:
 
 def _env(m: int, k: int, n: int, dtype, act: str = "identity",
          mode: Optional[str] = None) -> MatmulEnvelope:
-    # capability(), not backend(): a TPU whose Mosaic pipeline fails the
-    # probe keys (and builds) its envelopes as "interpret" instead of
-    # failing every routed trace at compile time
     return MatmulEnvelope(m=int(m), k=int(k), n=int(n), dtype=str(dtype),
-                          backend=mode or capability(), act=act)
+                          backend=mode or backend(), act=act)
 
 
 def _attn_env(b: int, h: int, tq: int, tk: int, d: int, dtype,
@@ -121,7 +84,7 @@ def _attn_env(b: int, h: int, tq: int, tk: int, d: int, dtype,
               mode: Optional[str] = None) -> AttentionEnvelope:
     return AttentionEnvelope(b=int(b), h=int(h), tq=int(tq), tk=int(tk),
                              d=int(d), dtype=str(dtype),
-                             backend=mode or capability(),
+                             backend=mode or backend(),
                              causal=bool(causal), masked=bool(masked))
 
 
@@ -297,11 +260,9 @@ def _route_fused_conv_bn(layer, params, state, x, train, rng):
 def maybe_flash_attention(q, k, v, key_mask=None, causal=False):
     """Route head-split ``[B, H, T, D]`` attention through the tuned
     flash kernel, or return ``None`` for the stock tier (untuned
-    envelope, unsupported shape, pallas unavailable). Selection happens
+    envelope, unsupported shape). Selection happens
     at trace time, so the caller's executable bakes one tuned
     ``(block_q, block_k)`` layout."""
-    if capability() == "none":
-        return None
     b, h, tq, d = q.shape
     env = _attn_env(b, h, tq, k.shape[2], d, q.dtype, causal=causal,
                     masked=key_mask is not None)
@@ -318,8 +279,6 @@ def maybe_decode_attention(q, k_cache, v_cache, positions):
     ``[B, S, H, D]`` caches valid through ``positions``) through the
     tuned paged-gather kernel, or return ``None`` for the stock masked
     full-cache read."""
-    if capability() == "none":
-        return None
     b, h, d = q.shape
     env = _attn_env(b, h, 1, k_cache.shape[1], d, q.dtype, causal=True,
                     masked=False)
@@ -356,8 +315,6 @@ def maybe_forward(layer, params, state, x, train=False, rng=None, **kw):
     """Run ``layer`` through a tuned registry kernel, or return ``None``
     for the stock path. ``kw`` beyond SelfAttentionLayer's ``mask``
     never routes."""
-    if capability() == "none":
-        return None
     from deeplearning4j_tpu.conf.layers import DenseLayer
     from deeplearning4j_tpu.conf.layers_attention import SelfAttentionLayer
     from deeplearning4j_tpu.conf.layers_cnn import (

@@ -216,10 +216,17 @@ class AutotuneResult:
         self.ms = ms
         self.trials = trials
 
+    @property
+    def refused(self) -> List[dict]:
+        """The candidates the compiler (or the run) rejected, in sweep
+        order: ``{"tiling": [...], "error": repr(exception)}``."""
+        return [t for t in self.trials if "error" in t]
+
     def __repr__(self):
         return (f"AutotuneResult({self.kernel_id}, {self.env_key}, "
                 f"tiling={self.tiling}, ms={self.ms:.3f}, "
-                f"{len(self.trials)} candidates)")
+                f"{len(self.trials)} candidates, "
+                f"{len(self.refused)} refused)")
 
 
 def autotune(kernel, env, cache: Optional[TuningCache] = None,
@@ -262,8 +269,9 @@ def autotune(kernel, env, cache: Optional[TuningCache] = None,
                 jax.block_until_ready(fn(*args))
                 best = min(best, time.perf_counter() - t0)
         except Exception as e:
-            # a candidate the compiler rejects is a silent non-winner,
-            # not an autotune failure (Mosaic tile limits vary by chip)
+            # a candidate the compiler rejects does not end the sweep
+            # (Mosaic tile limits vary by chip); it stays in the result
+            # with the compiler's message (AutotuneResult.refused)
             results.append({"tiling": list(tiling), "error": repr(e)})
             telemetry.record_autotune_trial(kernel.kernel_id)
             continue

@@ -7,13 +7,16 @@ presets over the libnd4j C ABI (SURVEY.md §2.1); here the host-side kernels
 matters since these are coarse host calls.
 
 The library is compiled on first use with the baked-in g++ (``-O3 -fopenmp``)
-and cached next to the source. Everything degrades to numpy fallbacks when
-compilation is unavailable (``DL4J_TPU_DISABLE_NATIVE=1`` forces that).
+into ``native/build/`` under a name that carries the digest of the source
+it was built from, so a ``.so`` is only ever loaded for exactly the source
+in this checkout. Everything degrades to numpy fallbacks when compilation
+is unavailable (``DL4J_TPU_DISABLE_NATIVE=1`` forces that).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -24,25 +27,34 @@ import numpy as np
 
 _ABI_VERSION = 2  # must match dl4j_native_version() in dl4j_native.cpp
 _SRC = Path(__file__).resolve().parents[2] / "native" / "src" / "dl4j_native.cpp"
-# the ABI version is part of the artifact name: an incompatible cached .so
-# from an older source tree can never be picked up by a newer wrapper
-# (mtime staleness alone can miss restored/copied build dirs)
-_OUT = (Path(__file__).resolve().parents[2] / "native" / "build"
-        / f"libdl4j_native_v{_ABI_VERSION}.so")
+_BUILD_DIR = Path(__file__).resolve().parents[2] / "native" / "build"
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
-def _build() -> bool:
-    _OUT.parent.mkdir(parents=True, exist_ok=True)
+def _artifact() -> Path:
+    """The ``.so`` for THIS source: named by the source's content digest
+    (a build dir copied or restored from another tree, whatever its
+    mtimes, can never supply a library for a different source)."""
+    digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:12]
+    return _BUILD_DIR / f"libdl4j_native_v{_ABI_VERSION}_{digest}.so"
+
+
+def _build(out: Path) -> bool:
+    out.parent.mkdir(parents=True, exist_ok=True)
+    # pid-suffixed temp + rename: concurrent first users (xdist workers)
+    # never load a half-written library
+    tmp = out.with_suffix(f".tmp.{os.getpid()}")
     cmd = ["g++", "-O3", "-fopenmp", "-shared", "-fPIC", "-std=c++17",
-           str(_SRC), "-o", str(_OUT)]
+           str(_SRC), "-o", str(tmp)]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp, out)
         return True
-    except Exception:
+    except (OSError, subprocess.SubprocessError):
+        tmp.unlink(missing_ok=True)
         return False
 
 
@@ -90,27 +102,16 @@ def get_lib() -> Optional[ctypes.CDLL]:
             return _lib
         _tried = True
         try:
-            stale = (not _OUT.exists()
-                     or _OUT.stat().st_mtime < _SRC.stat().st_mtime)
-            if stale and not _build():
+            out = _artifact()
+            if not out.exists() and not _build(out):
                 return None
-            lib = _bind(ctypes.CDLL(str(_OUT)))
+            lib = _bind(ctypes.CDLL(str(out)))
+            # the wrapper's ABI constant vs the source's own: a skew no
+            # rebuild can fix
             if lib.dl4j_native_version() != _ABI_VERSION:
-                if stale:
-                    # we JUST built from current source and it still
-                    # mismatches: wrapper/source version skew — a rebuild
-                    # cannot help, fail fast (cached via _tried)
-                    return None
-                # old artifact under the right filename: delete and
-                # rebuild ONCE from current source
-                _OUT.unlink(missing_ok=True)
-                if not _build():
-                    return None
-                lib = _bind(ctypes.CDLL(str(_OUT)))
-                if lib.dl4j_native_version() != _ABI_VERSION:
-                    return None
+                return None
             _lib = lib
-        except Exception:
+        except OSError:
             _lib = None
     return _lib
 

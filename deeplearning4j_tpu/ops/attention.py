@@ -34,12 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pallas TPU backend is absent on some CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -183,8 +178,6 @@ def paged_decode_attention(q, k_cache, v_cache, positions, scale=None,
     ``page`` must divide ``max_len`` (the pow2 bucket ladder guarantees
     a divisor exists; the autotuner only proposes legal pages).
     ``interpret=None`` auto-enables the Pallas interpreter off-TPU."""
-    if not _HAS_PLTPU:
-        raise NotImplementedError("pallas tpu dialect unavailable")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, s, h, d = k_cache.shape
@@ -214,7 +207,7 @@ def paged_decode_attention(q, k_cache, v_cache, positions, scale=None,
                         pltpu.VMEM((h, d), jnp.float32)],
     )
     params = None
-    if not interpret and _HAS_PLTPU:
+    if not interpret:
         params = pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"))
     return pl.pallas_call(
@@ -484,7 +477,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, km_ref, do_ref, o_ref, l_ref, m_ref,
 def _tpu_compiler_params(interpret: bool):
     """Batch/head/query grid dims are parallel; the innermost streamed
     (scratch-accumulating) dim is sequential."""
-    if interpret or not _HAS_PLTPU:
+    if interpret:
         return None
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))

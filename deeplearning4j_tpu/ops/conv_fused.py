@@ -32,13 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pltpu imports fail on CPU-only installs; interpret mode covers CI
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 _BM_CANDIDATES = (512, 256, 128)
 _BN = 128
@@ -46,7 +40,7 @@ _BK = 128
 
 
 def _tpu_compiler_params(interpret: bool):
-    if interpret or not _HAS_PLTPU:
+    if interpret:
         return None
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
@@ -62,13 +56,11 @@ def pick_block_m(m: int) -> Optional[int]:
 
 
 def fusable(m: int, cin: int, cout: int) -> bool:
-    """True when the kernel can run here: pallas-tpu importable (its VMEM
-    scratch type is needed even in interpret mode) and the grid covers
-    these shapes exactly — row count divisible by a supported block,
-    channel counts either below the 128-lane block or a multiple of it.
-    False -> callers (FusedConvBN1x1) take the plain XLA path."""
-    return (_HAS_PLTPU
-            and pick_block_m(m) is not None
+    """True when the grid covers these shapes exactly — row count
+    divisible by a supported block, channel counts either below the
+    128-lane block or a multiple of it. False -> callers
+    (FusedConvBN1x1) take the plain XLA path."""
+    return (pick_block_m(m) is not None
             and (cin <= _BK or cin % _BK == 0)
             and (cout <= _BN or cout % _BN == 0))
 
@@ -102,8 +94,6 @@ def _fwd_impl(x2, w2, interpret):
     bn = min(_BN, cout)
     bk = min(_BK, cin)
     nbm, nbn, nbk = m // bm, cout // bn, cin // bk
-    if not _HAS_PLTPU:  # pragma: no cover - interpret-only environments
-        raise NotImplementedError("pallas tpu backend unavailable")
     scratch = [pltpu.VMEM((bm, bn), jnp.float32)]
     y, ssum, sq = pl.pallas_call(
         functools.partial(_kernel, nk=nbk),

@@ -27,11 +27,48 @@ from __future__ import annotations
 
 import contextlib
 import hashlib
+import os
 import threading
 import time
+from pathlib import Path
 from typing import Callable, Optional
 
 import numpy as np
+
+
+# --------------------------------------------------------------------------
+# jax's persistent compilation cache: where compiled programs outlive the
+# process. The rule, in this one place: a directory given from outside
+# (JAX_COMPILATION_CACHE_DIR, which jax reads itself) is left alone and
+# no directory is set in code; otherwise the cache lives at ONE fixed
+# path in the checkout — the path is part of the cache key, so a
+# directory that moves (tempfile, pid, time) never hits.
+# --------------------------------------------------------------------------
+
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_COMPILE_CACHE_DIR = str(
+    Path(__file__).resolve().parents[2] / ".jax_cache")
+_COMPILE_CACHE_PLACED = False
+
+
+def place_compile_cache() -> str:
+    """Apply the rule above (idempotent; every miss runs it, entry
+    points may call it earlier so eager and init-time compiles are kept
+    too). Returns the directory in effect. jax's 1 s minimum compile
+    time for an entry is dropped to 0 unless the environment sets it:
+    the decoder ladder is tens of sub-second executables."""
+    global _COMPILE_CACHE_PLACED
+    import jax
+
+    if not _COMPILE_CACHE_PLACED:
+        _COMPILE_CACHE_PLACED = True
+        if not os.environ.get(COMPILE_CACHE_ENV):
+            jax.config.update("jax_compilation_cache_dir",
+                              DEFAULT_COMPILE_CACHE_DIR)
+        if "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in os.environ:
+            jax.config.update(
+                "jax_persistent_cache_min_compile_time_secs", 0.0)
+    return jax.config.jax_compilation_cache_dir
 
 
 class AotCacheStats:
@@ -282,8 +319,6 @@ def _program_lint(key, traced, exe) -> None:
     global _LINT_HOOK, _LINT_INIT
     if not _LINT_INIT:
         _LINT_INIT = True
-        import os
-
         if os.environ.get("DL4J_TPU_PROGRAM_LINT", "1") != "0":
             try:
                 from deeplearning4j_tpu.analysis import program
@@ -342,19 +377,13 @@ class AotStep:
                 f"({budget.compiles} compiles, "
                 f"{budget.compile_seconds:.2f}s) — refusing to compile "
                 f"{key[1]}")
+        place_compile_cache()
         t0 = time.perf_counter()
-        # trace and lower as separate stages when this jax supports it:
-        # .lower() runs the same trace internally, but splitting keeps
-        # the jaxpr available for the program linter at zero extra cost
-        traced = None
-        trace = getattr(self._jit, "trace", None)
-        if trace is not None:
-            try:
-                traced = trace(*args)
-            except Exception:
-                traced = None
-        lowered = (traced.lower() if traced is not None
-                   else self._jit.lower(*args))
+        # trace and lower as separate stages: .lower() runs the same
+        # trace internally, but splitting keeps the jaxpr available for
+        # the program linter at zero extra cost
+        traced = self._jit.trace(*args)
+        lowered = traced.lower()
         exe = lowered.compile()
         seconds = time.perf_counter() - t0
         STATS.record_miss(key, seconds)
@@ -412,8 +441,6 @@ def wrap(jit_fn: Callable, graph_key: str, fn_key: str,
          enabled: Optional[bool] = None) -> Callable:
     """Wrap a jitted step in the AOT cache. ``enabled=False`` returns the
     jit untouched (env kill-switch honored when ``enabled`` is None)."""
-    import os
-
     if enabled is None:
         enabled = os.environ.get("DL4J_TPU_AOT_CACHE", "1") != "0"
     return AotStep(jit_fn, graph_key, fn_key) if enabled else jit_fn

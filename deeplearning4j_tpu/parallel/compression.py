@@ -117,8 +117,8 @@ def encode_tree(grads, residuals, tau):
 # bucket is reduced by its own collective under an ``optimization_barrier``
 # issue chain. Since the comms round these three primitives no longer own
 # that machinery: ``comms.scheduler`` plans layout, order, AND the per-
-# bucket collective choice (variadic / densified / native-vs-masked
-# gather), and each function here is one ``scheduler.exchange`` call.
+# bucket collective choice (variadic / densified / all-gather), and each
+# function here is one ``scheduler.exchange`` call.
 # ``bucket_partition`` / ``bucket_layout`` are re-exported from the
 # scheduler (the single shared implementation).
 
@@ -146,34 +146,20 @@ def bucketed_psum_scatter(tree, axis_name, bucket_bytes=None):
                               bucket_bytes)
 
 
-def bucketed_all_gather(tree, axis_name, index, full_sizes,
-                        bucket_bytes=None):
+def bucketed_all_gather(tree, axis_name, full_sizes, bucket_bytes=None):
     """All-gather a pytree of per-shard 1-D slices back into full flat
     vectors (the ZeRO exchange's second half) on the scheduler's
     ``all_gather`` plan — bucketed on the SAME layout as
-    :func:`bucketed_psum`, with the collective CHOICE probe-gated:
+    :func:`bucketed_psum`, one native all-gather per leaf (the ring
+    all-gather's (n-1)/n payload) whose result is typed replicated, so
+    it passes ``P()`` out_specs under ``check_vma``.
 
-    - **vma-capable jax** (``comms.scheduler.NATIVE_ALL_GATHER``): a
-      native ``lax.all_gather`` per leaf — the ring all-gather's
-      (n-1)/n payload, with the output's replication expressed by the
-      vma type system;
-    - **this container's 0.4.37 (check_rep)**: the masked-psum fallback
-      — each shard deposits its slice at ``[index*m, (index+1)*m)`` of
-      a zeros vector and the cross-shard sum reassembles the full
-      array. Adding zeros is exact in floating point, so the result is
-      bitwise the concatenation of the shards' slices, and the psum
-      output's replication is statically known to the pre-vma shard_map
-      checker — at the cost of all-reduce bandwidth on the wire (~2x
-      the native path's payload; the telemetry counters record the
-      LOGICAL gathered payload under either choice).
-
-    docs/collectives.md has the full choice/probe table.
     ``full_sizes``: per-leaf gathered lengths (``n_shards *
     slice_len``), in tree-leaf order."""
     from deeplearning4j_tpu.comms import scheduler
 
     return scheduler.exchange(tree, "all_gather", axis_name, bucket_bytes,
-                              index=index, full_sizes=full_sizes)
+                              full_sizes=full_sizes)
 
 
 def bucketed_psum(tree, axis_name, bucket_bytes=None):

@@ -42,11 +42,6 @@ import contextlib as _contextlib
 
 _ACTIVE_EXPERT_AXIS: list = [None]
 
-# vma-era jax transposes collectives replication-correctly inside
-# shard_map bodies; older check_rep jax needs manual scale corrections
-# in differentiated regions (see moe_train_step / pipeline.psum_replicate)
-_EFFICIENT_PSUM_TRANSPOSE = mesh_mod.EFFICIENT_PSUM_TRANSPOSE
-
 
 @_contextlib.contextmanager
 def active_expert_axis(name: str):
@@ -224,13 +219,9 @@ def moe_train_step(n_experts: int, capacity: int, mesh: Mesh,
     transpose of the psum collective inside pmean performs the reduction
     — so ``g["router"]`` arrives as the full logical gradient, identical
     on every shard (verified elementwise against the 1-device mesh).
-    ``pmean`` over identical replicas is an identity in both shard_map
-    semantics modes (varying-manual-axes tracking on or off); ``psum``
-    would over-scale the router gradient by n_shards when vma tracking
-    is off. The test pins one full train step against the 1-device mesh
-    elementwise, so any regression in either direction is caught."""
-    n_shards = mesh.shape[EXPERT_AXIS]
-
+    ``pmean`` over identical replicas is an identity. The test pins one
+    full train step against the 1-device mesh elementwise, so any
+    regression in either direction is caught."""
     def spmd(params, x, target):
         def loss_fn(p):
             y, aux = _moe_local(p, x, n_experts, capacity, top_k=top_k)
@@ -240,15 +231,6 @@ def moe_train_step(n_experts: int, capacity: int, mesh: Mesh,
 
         loss, g = jax.value_and_grad(loss_fn)(params)
         g = dict(g)
-        if not _EFFICIENT_PSUM_TRANSPOSE and n_shards > 1:
-            # check_rep jax: the per-shard AD of the pmean'd loss arrives
-            # with unit cotangent (the old psum transpose cancels the
-            # 1/n), so the expert-sharded grads accumulate the SUM over
-            # shards' loss terms through the all_to_all transpose — scale
-            # back to the mean the loss actually is. vma jax needs no
-            # correction (its pmean transpose carries the 1/n).
-            g = {k: (v if k == "router" else v / n_shards)
-                 for k, v in g.items()}
         g["router"] = jax.lax.pmean(g["router"], EXPERT_AXIS)
         new = {k: params[k] - lr * g[k] for k in params}
         return new, loss

@@ -177,49 +177,19 @@ def shard_valid_counts(rows: int, workers: int) -> np.ndarray:
     return np.clip(rows - np.arange(workers) * s, 0, s).astype(np.float32)
 
 
-# vma-era jax (jax.typeof / lax.pcast) tracks varying-manual-axes types
-# and transposes collectives replication-correctly inside shard_map
-# bodies; older check_rep jax needs explicit anchors and manual scale
-# corrections in differentiated regions (pipeline.psum_replicate,
-# expert/wrapper grad rescales). ONE feature probe, shared by every
-# parallel module so a future jax-version fix lands in one place.
-EFFICIENT_PSUM_TRANSPOSE = (hasattr(jax, "typeof")
-                            and hasattr(jax.lax, "pcast"))
-
-
 def ensure_varying(x, axes):
-    """Mark ``x`` device-varying over the given mesh axes, version-
-    adaptively:
-
-    - vma jax: pcast to varying only on the axes ``x`` does not already
-      vary on (pcast errors on varying->varying; shard-mapped inputs
-      arrive already varying on their sharded axes).
-    - check_rep jax: add a zero anchor derived from ``axis_index`` so the
-      replication tracker drops the axes from the value's rep set — a
-      free elementwise add under XLA, and a no-op on axes the value
-      already varies on."""
-    if EFFICIENT_PSUM_TRANSPOSE:
-        have = set(getattr(jax.typeof(x), "vma", ()) or ())
-        need = tuple(a for a in axes if a not in have)
-        return jax.lax.pcast(x, need, to="varying") if need else x
-    if not axes:
-        return x
-    z = sum(jax.lax.axis_index(a) for a in axes) * 0
-    return x + z.astype(x.dtype)
+    """Mark ``x`` device-varying over the given mesh axes: pcast to
+    varying only on the axes ``x`` does not already vary on (pcast
+    errors on varying->varying; shard-mapped inputs arrive already
+    varying on their sharded axes)."""
+    have = set(jax.typeof(x).vma)
+    need = tuple(a for a in axes if a not in have)
+    return jax.lax.pcast(x, need, to="varying") if need else x
 
 
-try:  # jax >= 0.4.35
-    from jax import shard_map as _shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs)
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs)
+def shard_map(f, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs)
 
 
 def initialize_distributed(coordinator_address: str | None = None,

@@ -35,8 +35,6 @@ each stage owns its parameters, exactly pipeline parallelism's point).
 
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -78,7 +76,7 @@ def _gpipe_forward(stage_fn, my_params, x_micro, n_stages, n_micro):
 
     _, ys = jax.lax.scan(step, buf, jnp.arange(total))
     outs = ys[n_stages - 1:]
-    return psum_replicate(
+    return jax.lax.psum(
         jnp.where(sid == n_stages - 1, outs, jnp.zeros_like(outs)),
         STAGE_AXIS)
 
@@ -275,7 +273,7 @@ class HeteroPipeline:
 
         _, ys = jax.lax.scan(step, buf, jnp.arange(total))
         outs = ys[S - 1:]
-        outs = psum_replicate(
+        outs = jax.lax.psum(
             jnp.where(sid == S - 1, outs, jnp.zeros_like(outs)),
             STAGE_AXIS)
         out_size = int(np.prod(self.out_shape))
@@ -382,47 +380,7 @@ def hetero_serial_reference(stage_fns, per_stage_params, x):
 
 
 
-# shared version-adaptive vma probe + anchor (see parallel/mesh.py)
-_HAS_VMA = mesh_mod.EFFICIENT_PSUM_TRANSPOSE
 _ensure_varying = mesh_mod.ensure_varying
-
-
-# --- transpose-correct replication collectives --------------------------
-#
-# ``jax.grad`` INSIDE a shard_map body differentiates per shard. Under the
-# varying-manual-axes type system psum's transpose is replication-aware,
-# but under older check_rep jax the raw transpose psums the (already
-# replicated) cotangent — every psum inside a differentiated region
-# multiplies its gradient contribution by the axis size (measured: the
-# GPipe collect produced exactly S x the serial gradients). The fix is the
-# math the pattern actually means: ``out = sum_s x_s`` replicated, so
-# d out / d x_s = 1 per shard — the transpose is the IDENTITY on each
-# shard's cotangent. ``_psum_id_t`` pins that with a custom_vjp; new-vma
-# jax keeps the native psum (its transpose is already correct).
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(1,))
-def _psum_id_t(x, axis_name):
-    return jax.lax.psum(x, axis_name)
-
-
-def _psum_id_t_fwd(x, axis_name):
-    return jax.lax.psum(x, axis_name), None
-
-
-def _psum_id_t_bwd(axis_name, _res, ct):
-    return (ct,)
-
-
-_psum_id_t.defvjp(_psum_id_t_fwd, _psum_id_t_bwd)
-
-
-def psum_replicate(x, axis_name):
-    """psum usable inside a differentiated shard_map region: the forward
-    is a plain psum; the backward is per-shard identity (see above)."""
-    if _HAS_VMA:
-        return jax.lax.psum(x, axis_name)
-    return _psum_id_t(x, axis_name)
 
 
 def _flatten_f32(tree):
@@ -1076,10 +1034,7 @@ class PipelineParallelWrapper:
                 (_, final_state), ys = jax.lax.scan(
                     step, (buf0, st0), jnp.arange(total))
                 outs = ys[S - 1:]
-                # transpose-correct collect: inside this differentiated
-                # region every replication psum must backprop as the
-                # per-shard identity (see psum_replicate)
-                outs = psum_replicate(
+                outs = jax.lax.psum(
                     jnp.where(sid == S - 1, outs, jnp.zeros_like(outs)),
                     STAGE_AXIS)
                 losses = [head_score(out_p, outs[m], y_micro[m])
@@ -1088,17 +1043,14 @@ class PipelineParallelWrapper:
                 reg_branches = [
                     (lambda fp, f=f: _ensure_varying(f(fp), axes_all))
                     for f in self._regs]
-                loss = loss + psum_replicate(
+                loss = loss + jax.lax.psum(
                     jax.lax.switch(sid, reg_branches, my_flat),
                     STAGE_AXIS)
                 loss = loss + self._head_reg(out_p)
-                if has_data and _HAS_VMA:
-                    # vma jax: pmean inside the differentiated region and
-                    # the AD machinery psums the replicated-param
-                    # cotangents itself. check_rep jax differentiates the
-                    # PER-SHARD loss instead; _common_post's forward
-                    # pmean of the per-shard grads is the data mean
-                    # (classic pmap calculus — same numbers)
+                if has_data:
+                    # pmean inside the differentiated region: the AD
+                    # machinery psums the replicated-param cotangents
+                    # itself
                     loss = jax.lax.pmean(loss, mesh_mod.DATA_AXIS)
                 return loss, final_state
 

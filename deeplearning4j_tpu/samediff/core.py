@@ -18,7 +18,7 @@ TPU-first design — deliberately NOT the reference architecture:
   / ``lax.scan`` behind ``sd.cond`` / ``sd.while_loop``, compiler-friendly
   by construction.
 
-Variable taxonomy mirrors the reference exactly (``VariableType``):
+Variable kinds mirror the reference exactly (``VariableType``):
 VARIABLE (trainable, persisted), CONSTANT (persisted, not trained),
 PLACEHOLDER (fed per call), ARRAY (op output, recomputed).
 """

@@ -59,13 +59,7 @@ def validate(case: TestCase) -> None:
     graph."""
     import jax
 
-    if hasattr(jax, "enable_x64"):
-        ctx = jax.enable_x64(True)
-    else:  # older jax spells it jax.experimental.enable_x64
-        from jax.experimental import enable_x64
-
-        ctx = enable_x64(True)
-    with ctx:
+    with jax.enable_x64(True):
         _validate_x64(case)
 
 

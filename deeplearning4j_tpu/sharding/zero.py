@@ -182,7 +182,7 @@ class ZeroSpec:
                for f, m in zip(flat, self.slice_sizes)]
         return jax.tree_util.tree_unflatten(self.treedef, out)
 
-    def assemble(self, slices, index, axis: str, bucket_bytes=None):
+    def assemble(self, slices, axis: str, bucket_bytes=None):
         """Per-shard slice tree -> full-shape tree replicated on every
         shard (the ZeRO all-gather), via
         ``compression.bucketed_all_gather`` on this layout's bucket
@@ -194,8 +194,8 @@ class ZeroSpec:
             bucketed_all_gather,
         )
 
-        full_flat = bucketed_all_gather(slices, axis, index,
-                                        self.padded_sizes, bucket_bytes)
+        full_flat = bucketed_all_gather(slices, axis, self.padded_sizes,
+                                        bucket_bytes)
         leaves = jax.tree_util.tree_flatten(full_flat)[0]
         out = [jnp.reshape(f[:size], shape)
                for f, size, shape in zip(leaves, self.sizes, self.shapes)]

@@ -130,7 +130,7 @@ def record_collective_plan(intent: str, choice: str, nbytes: float,
     (``comms.scheduler``): the ``dl4j_collective_plan_total{intent,
     choice}`` counter plus per-plan bytes/launches gauges feeding the UI
     System tab collective panel — the scheduler's CHOICES (variadic /
-    densify / native all-gather vs masked psum) made observable per fit.
+    densify / all-gather) made observable per fit.
     Unconditional like the control-plane events below: plans resolve at
     trace time (once per unique layout per process), never per step."""
     REGISTRY.counter("dl4j_collective_plan_total",

@@ -21,18 +21,6 @@ import numpy as np
 from deeplearning4j_tpu.util import params as params_util
 
 
-def _enable_x64():
-    """``jax.enable_x64`` (new jax) / ``jax.experimental.enable_x64``
-    (older jax) — same context-manager contract."""
-    import jax
-
-    if hasattr(jax, "enable_x64"):
-        return jax.enable_x64(True)
-    from jax.experimental import enable_x64
-
-    return enable_x64(True)
-
-
 @dataclasses.dataclass
 class GradCheckResult:
     n_params: int
@@ -139,7 +127,7 @@ def gradient_check(conf, ds, epsilon: float = 1e-6,
     """
     import jax
 
-    with _enable_x64():
+    with jax.enable_x64(True):
         import jax.numpy as jnp
 
         from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
@@ -168,7 +156,7 @@ def check_layer_input_gradient(layer, input_type, x, epsilon: float = 1e-6,
     d(sum(layer(x)))/dx vs central differences, f64."""
     import jax
 
-    with _enable_x64():
+    with jax.enable_x64(True):
         import jax.numpy as jnp
 
         key = jax.random.PRNGKey(seed)
@@ -201,7 +189,7 @@ def gradient_check_graph(conf, mds, epsilon: float = 1e-6,
     overload; same f64 protocol as :func:`gradient_check`)."""
     import jax
 
-    with _enable_x64():
+    with jax.enable_x64(True):
         import jax.numpy as jnp
 
         from deeplearning4j_tpu.nn.graph import ComputationGraph, _as_multi

@@ -5,9 +5,8 @@ Pins the PR-12 contracts:
 - plan determinism: same tree + intent -> identical CollectivePlan
   digest, in-process (cache hit) and across processes;
 - choice rules: variadic single-exchange for sub-threshold trees,
-  densified accumulation for many-tiny-leaf buckets, masked-psum gather
-  on this container's check_rep jax with the native-all-gather branch
-  behind the probe seam;
+  densified accumulation for many-tiny-leaf buckets, the native
+  all-gather (typed replicated) for every gather;
 - bit-identity: scheduler-routed exchanges == the pre-scheduler
   primitives (inline legacy copies below) on the simulated 8-device
   mesh, and every scheduler-routed ParallelWrapper mode == its legacy
@@ -122,11 +121,14 @@ def _legacy_psum_scatter(tree, axis_name, bucket_bytes=None):
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
-def _legacy_all_gather(tree, axis_name, index, full_sizes,
-                       bucket_bytes=None):
+def _legacy_all_gather(tree, axis_name, full_sizes, bucket_bytes=None):
+    """Masked-psum gather: each shard deposits its slice into a zeros
+    vector and a psum reassembles — the bitwise oracle for the native
+    all-gather."""
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     if not leaves:
         return tree
+    index = jax.lax.axis_index(axis_name)
     contribs = []
     for sl, full in zip(leaves, full_sizes):
         m = sl.shape[0]
@@ -202,24 +204,10 @@ def test_plan_choice_rules():
     flat = [jnp.zeros((16,), jnp.float32) for _ in range(12)]
     p = scheduler.plan_for(flat, "reduce_scatter", DATA_AXIS, 10 ** 9)
     assert set(p.choices) == {"variadic"}
-    # gather: masked psum on this check_rep jax, native behind the probe
+    # gather: always the native all-gather
     p = scheduler.plan_for([jnp.zeros((4,))], "all_gather", DATA_AXIS,
                            full_sizes=[16])
-    assert p.choices == (
-        ("all_gather",) if scheduler.NATIVE_ALL_GATHER
-        else ("masked_psum",))
-
-
-def test_native_probe_seam_changes_choice_and_digest(monkeypatch):
-    sl = [jnp.zeros((4,), jnp.float32)]
-    fallback = scheduler.plan_for(sl, "all_gather", DATA_AXIS,
-                                  full_sizes=[16])
-    monkeypatch.setattr(scheduler, "NATIVE_ALL_GATHER", True)
-    native = scheduler.plan_for(sl, "all_gather", DATA_AXIS,
-                                full_sizes=[16])
-    assert native.choices == ("all_gather",)
-    assert fallback.choices == ("masked_psum",)
-    assert native.digest != fallback.digest  # never aliases an executable
+    assert p.choices == ("all_gather",)
 
 
 def test_unknown_intent_raises():
@@ -285,13 +273,11 @@ def test_scheduler_zero_exchange_bitwise_vs_legacy(bucket_bytes):
 
     def routed(t):
         sl = bucketed_psum_scatter(t, DATA_AXIS, bucket_bytes)
-        idx = jax.lax.axis_index(DATA_AXIS)
-        return bucketed_all_gather(sl, DATA_AXIS, idx, full, bucket_bytes)
+        return bucketed_all_gather(sl, DATA_AXIS, full, bucket_bytes)
 
     def legacy(t):
         sl = _legacy_psum_scatter(t, DATA_AXIS, bucket_bytes)
-        idx = jax.lax.axis_index(DATA_AXIS)
-        return _legacy_all_gather(sl, DATA_AXIS, idx, full, bucket_bytes)
+        return _legacy_all_gather(sl, DATA_AXIS, full, bucket_bytes)
 
     in_specs = (tuple(P() for _ in flat),)
     out_specs = tuple(P() for _ in flat)
@@ -302,35 +288,34 @@ def test_scheduler_zero_exchange_bitwise_vs_legacy(bucket_bytes):
     _bit_identical(got, want)
 
 
-def test_native_all_gather_branch_executes(monkeypatch):
-    """The fallback seam, exercised for real: with the probe forced on,
-    the plan chooses the native lax.all_gather and its execution
-    (observed per shard under varying out_specs — the pre-vma checker
-    cannot see the output's replication, which is exactly why the probe
-    gates the product path) gathers bitwise what the masked psum
-    gathers."""
+def test_all_gather_is_native_and_typed_replicated():
+    """The one gather route: a native all-gather (no all-reduce in the
+    traced step) whose result passes replicated ``P()`` out_specs under
+    check_vma and is bitwise what the masked psum gathers."""
+    from deeplearning4j_tpu.analysis import program
+
     mesh = _mesh()
     rng = np.random.default_rng(5)
     sl = jnp.asarray(rng.normal(size=(16,)).astype(np.float32))
 
     def masked(s):
-        idx = jax.lax.axis_index(DATA_AXIS)
-        (out,) = bucketed_all_gather((s,), DATA_AXIS, idx, [16])
+        (out,) = _legacy_all_gather((s,), DATA_AXIS, [16])
+        return out
+
+    def native(s):
+        (out,) = bucketed_all_gather((s,), DATA_AXIS, [16])
         return out
 
     want = jax.jit(shard_map(masked, mesh, in_specs=(P(DATA_AXIS),),
                              out_specs=P()))(sl)
-    monkeypatch.setattr(scheduler, "NATIVE_ALL_GATHER", True)
-
-    def native(s):
-        (out,) = bucketed_all_gather((s,), DATA_AXIS, None, [16])
-        return out
-
-    per_shard = jax.jit(shard_map(native, mesh, in_specs=(P(DATA_AXIS),),
-                                  out_specs=P(DATA_AXIS)))(sl)
-    stacked = np.asarray(per_shard).reshape(4, 16)
-    for row in stacked:
-        np.testing.assert_array_equal(row, np.asarray(want))
+    native_jit = jax.jit(shard_map(native, mesh, in_specs=(P(DATA_AXIS),),
+                                   out_specs=P()))
+    np.testing.assert_array_equal(np.asarray(native_jit(sl)),
+                                  np.asarray(want))
+    prims = {e.primitive.name
+             for e in program.iter_eqns(native_jit.trace(sl).jaxpr)}
+    assert "all_gather_invariant" in prims
+    assert not prims & program.ALL_REDUCE_PRIMS
 
 
 # --------------------------------------------------------------------------
@@ -509,8 +494,7 @@ def test_prg205_scheduler_routed_zero_step_passes():
 
     def step(t):
         sl = bucketed_psum_scatter(z.flat_padded(t), DATA_AXIS, 64)
-        idx = jax.lax.axis_index(DATA_AXIS)
-        return z.assemble(sl, idx, DATA_AXIS, 64)
+        return z.assemble(sl, DATA_AXIS, 64)
 
     jit_fn = jax.jit(shard_map(step, mesh, in_specs=(P(),),
                                out_specs=P()))

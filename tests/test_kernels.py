@@ -1,12 +1,13 @@
 """Pallas kernel subsystem tests (kernels/): registry parity against the
 XLA references, per-shape autotuner + persistent digest-verified tuning
 cache, cache-keyed selection through the model fit paths, PRG207, and
-the capability probe-and-skip discipline.
+the backend decision (a TPU-keyed envelope never builds an interpreted
+kernel).
 
-Every kernel here executes through the Pallas INTERPRETER (no TPU in
-CI) — the same kernel bodies a TPU run lowers through Mosaic, so the
-numerics and the selection/fallback/re-key machinery are validated end
-to end; only the real-lowering leg probes and skips.
+Every kernel here executes through the Pallas INTERPRETER (tier-1 runs
+on the CPU) — the same kernel bodies a TPU run lowers through Mosaic, so
+the numerics and the selection/fallback/re-key machinery are validated
+end to end; the real lowering is chip_smoke.py's kernel phase.
 """
 
 import json
@@ -91,27 +92,68 @@ def _fit(net, X, Y, steps=3):
 
 
 # --------------------------------------------------------------------------
-# capability probe + skip discipline
+# the backend decision
 # --------------------------------------------------------------------------
 
-def test_capability_probe():
-    cap = kernels.capability()
-    assert cap in ("tpu", "interpret", "none")
-    # this container has pallas importable -> at least interpret mode
-    assert cap != "none"
-    assert kernels.backend() in ("tpu", "interpret")
+def test_backend_follows_default_backend(monkeypatch):
+    assert kernels.backend() == "interpret"     # tier-1 is pinned to cpu
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # the only decision: no probe compile, no downgrade to interpret
+    assert kernels.backend() == "tpu"
+    assert not hasattr(kernels, "capability")
 
 
-@pytest.mark.skipif(kernels.capability() != "tpu",
-                    reason="no real Pallas TPU lowering in this container "
-                           "(interpret mode covers the kernel bodies)")
-def test_real_tpu_lowering_compiles():
-    env = _env(128, 128, 128)
-    env = MatmulEnvelope(m=env.m, k=env.k, n=env.n, dtype=env.dtype,
-                         backend="tpu", act="relu")
+def _kernel_envelopes(backend):
+    mm = dict(m=32, k=128, n=128, dtype="float32", backend=backend)
+    attn = dict(b=1, h=2, d=16, dtype="float32", backend=backend)
+    return [
+        ("matmul_bias_act", MatmulEnvelope(act="relu", **mm)),
+        ("conv_bn_act", MatmulEnvelope(**mm)),
+        ("matmul_bias_act_int8",
+         MatmulEnvelope(act="relu", **{**mm, "dtype": "int8"})),
+        ("flash_attention",
+         AttentionEnvelope(tq=128, tk=128, masked=True, **attn)),
+        ("paged_decode_attention", AttentionEnvelope(tq=1, tk=64, **attn)),
+    ]
+
+
+@pytest.mark.parametrize("backend", ["tpu", "interpret"])
+def test_envelope_backend_decides_interpret(backend):
+    """A TPU-keyed envelope is never built with ``interpret=True`` (and
+    an interpret-keyed one always is) — for every registry kernel,
+    forward and, where there is one, the custom-VJP backward."""
+    from deeplearning4j_tpu.analysis import program
+
+    envs = _kernel_envelopes(backend)
+    assert sorted(kid for kid, _ in envs) == kernels.REGISTRY.ids()
+    for kid, env in envs:
+        k = kernels.REGISTRY.get(kid)
+        fn = k.build(env, k.candidates(env, limit=1)[0])
+        args = k.make_inputs(env)
+        flags = program.pallas_interpret_flags(fn, *args)
+        if kid == "flash_attention":
+            flags += program.pallas_interpret_flags(
+                jax.grad(lambda q, *r: jnp.sum(fn(q, *r))), *args)
+        assert flags and set(flags) == {backend != "tpu"}, (kid, flags)
+
+
+def test_tpu_candidates_obey_mosaic_block_rule():
+    """TPU-keyed matmul sweeps only propose blocks Mosaic lowers (last
+    two dims multiples of (8, 128) or the whole dim); the interpreter
+    keeps every exact divisor."""
+    from deeplearning4j_tpu.kernels import impls
+
     k = kernels.REGISTRY.get("matmul_bias_act")
-    fn = jax.jit(k.build(env, (128, 128, 128)))
-    jax.block_until_ready(fn(*k.make_inputs(env)))
+    tpu = MatmulEnvelope(m=64, k=256, n=256, dtype="float32",
+                         backend="tpu")
+    cpu = MatmulEnvelope(m=64, k=256, n=256, dtype="float32",
+                         backend="interpret")
+    tc, cc = k.candidates(tpu), k.candidates(cpu)
+    assert tc and set(tc) < set(cc)
+    assert all(impls.mosaic_block_ok(64, 256, 256, t) for t in tc)
+    assert (64, 64, 64) in cc and (64, 64, 64) not in tc
+    assert not k.tiling_ok(tpu, (64, 64, 64))    # nor as a cached winner
+    assert k.tiling_ok(cpu, (64, 64, 64))
 
 
 # --------------------------------------------------------------------------
@@ -196,6 +238,28 @@ def test_autotune_records_winner_and_counters():
         'dl4j_kernel_autotune_winners_total{kernel="matmul_bias_act"}',
         0) >= 1
     assert snap.get("dl4j_kernel_tuning_cache_entries", 0) >= 1
+
+
+def test_autotune_keeps_refused_candidates_with_their_message():
+    """A candidate the compiler rejects does not end the sweep, and is
+    not silent: it stays in the result with the error (what
+    chip_smoke.py prints per kernel)."""
+    env = _env(32, 16, 8)
+    real = kernels.REGISTRY.get("matmul_bias_act")
+    bad = real.candidates(env, limit=2)[0]
+
+    class Refusing(type(real)):
+        def build(self, env, tiling):
+            if tuple(tiling) == tuple(bad):
+                def fn(*args):
+                    raise ValueError("Mosaic says no")
+                return fn
+            return super().build(env, tiling)
+
+    res = kernels.autotune(Refusing(), env, max_candidates=2, record=False)
+    assert [tuple(r["tiling"]) for r in res.refused] == [tuple(bad)]
+    assert "Mosaic says no" in res.refused[0]["error"]
+    assert res.tiling != tuple(bad)
 
 
 def test_tuning_digest_tracks_winner_set():

@@ -218,11 +218,28 @@ def test_zoo_rule_tables_resolve_on_real_nets():
 # ---------------------------------------------------------------------------
 # ZeRO numerics: bit-identity with the all-reduce DP path
 # ---------------------------------------------------------------------------
+#
+# The bitwise reference is the EXPLICIT all-reduce exchange
+# (``gradient_bucket_mb=0``: the same per-shard gradients, psum where
+# ZeRO reduce-scatters). The default SPMD step is a different program —
+# XLA's partitioner picks its own reduction order — so it agrees to the
+# last ulp or two, not bitwise.
+
+_ALLREDUCE = {"gradient_bucket_mb": 0}
+
+
+def _ulp_close(a, b):
+    for u, v in zip(jax.tree_util.tree_leaves((a.params, a.opt_state)),
+                    jax.tree_util.tree_leaves((b.params, b.opt_state))):
+        np.testing.assert_allclose(np.asarray(u), np.asarray(v),
+                                   rtol=1e-5, atol=1e-6)
+
 
 def test_zero_bit_identical_to_allreduce_dp():
-    ref, _ = _train()
+    ref, _ = _train(**_ALLREDUCE)
     zero, pw = _train(zero_optimizer=True)
     _bit_identical(ref, zero)
+    _ulp_close(_train()[0], zero)
     # and the optimizer state REALLY lives scattered on device: each
     # leaf of the live tree is a flat padded vector sharded over 'data'
     leaf = jax.tree_util.tree_leaves(pw._opt)[0]
@@ -232,7 +249,7 @@ def test_zero_bit_identical_to_allreduce_dp():
 
 
 def test_zero_bit_identical_with_ragged_tail_and_buckets():
-    ref, _ = _train(n=61)                          # ragged final batch
+    ref, _ = _train(n=61, **_ALLREDUCE)            # ragged final batch
     zero, _ = _train(n=61, zero_optimizer=True)
     _bit_identical(ref, zero)
     bucketed, _ = _train(n=61, zero_optimizer=True,
@@ -243,7 +260,7 @@ def test_zero_bit_identical_with_ragged_tail_and_buckets():
 def test_zero_bit_identical_momentum_and_stateless_updaters():
     for upd in (Nesterovs(learning_rate=0.02, momentum=0.9),
                 Sgd(learning_rate=0.05)):
-        ref, _ = _train(updater=upd)
+        ref, _ = _train(updater=upd, **_ALLREDUCE)
         zero, _ = _train(updater=upd, zero_optimizer=True)
         _bit_identical(ref, zero)
 
@@ -273,7 +290,9 @@ def test_zero_bit_identical_computation_graph():
             ArrayDataSetIterator(x, y, batch=16), epochs=2)
         return net
 
-    _bit_identical(train(), train(zero_optimizer=True))
+    zero = train(zero_optimizer=True)
+    _bit_identical(train(**_ALLREDUCE), zero)
+    _ulp_close(train(), zero)
 
 
 def test_zero_mode_refusals():
