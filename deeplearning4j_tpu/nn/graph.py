@@ -413,12 +413,13 @@ class ComputationGraph(nn_io.LazyScoreMixin):
 
         telemetry.host_gap_reset()
         try:
-            with flightrec.flight_recorder(model=self):
+            with telemetry.span("fit", epochs=epochs), \
+                    flightrec.flight_recorder(model=self):
                 for _ in range(epochs):
                     for lst in self.listeners:
                         lst.on_epoch_start(self, self.epoch)
                     pending = []
-                    for ds in batches:
+                    for ds in nn_io.timed_batches(batches):
                         pending.append(self._fit_batch_async(ds))
                         nn_io.drain(pending)
                     nn_io.drain(pending, force=True)
@@ -598,8 +599,10 @@ class ComputationGraph(nn_io.LazyScoreMixin):
                 self, "graph", cur, self.epoch, loss, gvec,
                 self._guard_keys, batch=(features, labels),
                 rng_seed=int(getattr(self.conf, "seed", 0) or 0))
-        for lst in self.listeners:
-            lst.iteration_done(self, cur, self.epoch, loss)
+        if self.listeners:
+            with telemetry.span("listeners"):
+                for lst in self.listeners:
+                    lst.iteration_done(self, cur, self.epoch, loss)
         return loss
 
     # --- truncated BPTT (reference ComputationGraph#doTruncatedBPTT) -------
@@ -890,10 +893,12 @@ class ComputationGraph(nn_io.LazyScoreMixin):
                 self._guard_keys, k, batch=(features, labels),
                 rng_seed=int(getattr(self.conf, "seed", 0) or 0))
         if self.listeners:
-            for j in range(k):
-                loss_j = losses[j]
-                for lst in self.listeners:
-                    lst.iteration_done(self, cur + j, self.epoch, loss_j)
+            with telemetry.span("listeners"):
+                for j in range(k):
+                    loss_j = losses[j]
+                    for lst in self.listeners:
+                        lst.iteration_done(self, cur + j, self.epoch,
+                                           loss_j)
         return losses[-1]  # device scalar: the async fit pipeline queues it
 
     def tbptt_batch_arrays(self, ds):
@@ -995,10 +1000,13 @@ class ComputationGraph(nn_io.LazyScoreMixin):
                 self, "graph", self.iteration - 1, self.epoch, mean_loss,
                 gvec, self._guard_keys, batch=(features, labels),
                 rng_seed=int(getattr(self.conf, "seed", 0) or 0))
-        for lst in self.listeners:
-            # one batch-level call, arg = last segment's iteration index
-            lst.iteration_done(self, self.iteration - 1, self.epoch,
-                               mean_loss)
+        if self.listeners:
+            with telemetry.span("listeners"):
+                for lst in self.listeners:
+                    # one batch-level call, arg = last segment's
+                    # iteration index
+                    lst.iteration_done(self, self.iteration - 1,
+                                       self.epoch, mean_loss)
         return mean_loss  # device scalar: the async fit pipeline queues it
 
     # --- stateful RNN inference (reference CG#rnnTimeStep) ------------------

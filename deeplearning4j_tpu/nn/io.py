@@ -121,17 +121,35 @@ def drain(pending, force: bool = False):
     """Block on queued step results when the pipeline is full (or at epoch
     end with ``force``); returns the (possibly emptied) list. The block is
     an intentional device wait, so the telemetry host-gap clock pauses
-    around it (device time must never read as host dispatch gap)."""
+    around it (device time must never read as host dispatch gap). It
+    waits for ALL of them: the device's queue is empty when it returns.
+    The ``drain`` span says how many steps it blocked on."""
     if pending and (force or len(pending) >= DISPATCH_DEPTH):
         from deeplearning4j_tpu.telemetry import spans
 
-        spans.host_gap_pause()
-        try:
-            jax.block_until_ready(pending)
-        finally:
-            spans.host_gap_resume()
+        with spans.span("drain", sync=True, steps=len(pending)):
+            spans.host_gap_pause()
+            try:
+                jax.block_until_ready(pending)
+            finally:
+                spans.host_gap_resume()
         pending.clear()
     return pending
+
+
+def timed_batches(batches):
+    """``batches``, with each ``next()`` of its iterator under a
+    ``fit.next_batch`` span: the time a fit loop waited for its input."""
+    from deeplearning4j_tpu.telemetry import spans
+
+    it = iter(batches)
+    while True:
+        with spans.span("fit.next_batch"):
+            try:
+                ds = next(it)
+            except StopIteration:
+                return
+        yield ds
 
 
 class LazyScoreMixin:

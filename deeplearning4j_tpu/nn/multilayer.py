@@ -464,12 +464,13 @@ class MultiLayerNetwork(nn_io.LazyScoreMixin):
         iterator = _wrap_fused(iterator, fused_steps, self.conf)
         telemetry.host_gap_reset()
         try:
-            with flightrec.flight_recorder(model=self):
+            with telemetry.span("fit", epochs=epochs), \
+                    flightrec.flight_recorder(model=self):
                 for _ in range(epochs):
                     for lst in self.listeners:
                         lst.on_epoch_start(self, self.epoch)
                     pending = []
-                    for ds in iterator:
+                    for ds in nn_io.timed_batches(iterator):
                         pending.append(self._fit_batch_async(ds))
                         nn_io.drain(pending)
                     nn_io.drain(pending, force=True)
@@ -585,8 +586,10 @@ class MultiLayerNetwork(nn_io.LazyScoreMixin):
                 self, "multilayer", cur, self.epoch, loss, gvec,
                 self._guard_keys, batch=(features, labels),
                 rng_seed=int(getattr(self.conf, "seed", 0) or 0))
-        for lst in self.listeners:
-            lst.iteration_done(self, cur, self.epoch, loss)
+        if self.listeners:
+            with telemetry.span("listeners"):
+                for lst in self.listeners:
+                    lst.iteration_done(self, cur, self.epoch, loss)
         return loss
 
     def fit_batch(self, ds: DataSet) -> float:
@@ -669,10 +672,12 @@ class MultiLayerNetwork(nn_io.LazyScoreMixin):
         if self.listeners:
             # K per-step losses from the scan's ys — each a lazy device
             # slice, so listeners that never read a score never sync
-            for j in range(k):
-                loss_j = losses[j]
-                for lst in self.listeners:
-                    lst.iteration_done(self, cur + j, self.epoch, loss_j)
+            with telemetry.span("listeners"):
+                for j in range(k):
+                    loss_j = losses[j]
+                    for lst in self.listeners:
+                        lst.iteration_done(self, cur + j, self.epoch,
+                                           loss_j)
         return losses[-1]  # device scalar: the async fit pipeline queues it
 
     def _tbptt_prepad(self, ds: DataSet) -> DataSet:
@@ -983,11 +988,14 @@ class MultiLayerNetwork(nn_io.LazyScoreMixin):
                 mean_loss, gvec, self._guard_keys,
                 batch=(features, labels),
                 rng_seed=int(getattr(self.conf, "seed", 0) or 0))
-        for lst in self.listeners:
-            # one batch-level call, arg = last segment's iteration index
-            # (same contract as the segment-loop path)
-            lst.iteration_done(self, self.iteration - 1, self.epoch,
-                               mean_loss)
+        if self.listeners:
+            with telemetry.span("listeners"):
+                for lst in self.listeners:
+                    # one batch-level call, arg = last segment's
+                    # iteration index (same contract as the
+                    # segment-loop path)
+                    lst.iteration_done(self, self.iteration - 1,
+                                       self.epoch, mean_loss)
         return mean_loss  # device scalar: the async fit pipeline queues it
 
     def _fit_tbptt(self, features, labels, fmask, lmask) -> float:

@@ -102,6 +102,13 @@ from deeplearning4j_tpu.resilience.retry import SERVING_RETRY
 _ENGINE_SEQ = itertools.count(1)
 
 
+def _lock_wait(sp, asked_ns: int):
+    """Called first thing under the engine's lock: put the time from
+    asking for it (``asked_ns``) to holding it on ``sp`` as
+    ``lock_wait_us``."""
+    sp.annotate(lock_wait_us=(time.monotonic_ns() - asked_ns) / 1e3)
+
+
 @dataclasses.dataclass
 class GenerationConfig:
     """Scheduler policy knobs (the generation twin of
@@ -133,8 +140,8 @@ class GenerationConfig:
 
 class _GenRequest:
     __slots__ = ("tokens", "n", "max_new", "eos", "temp", "rng", "deadline",
-                 "event", "out", "error", "t0", "t_first", "row",
-                 "prefix_len", "prefix_nodes", "trace")
+                 "event", "out", "error", "t0", "t_join", "t_first",
+                 "t_done", "row", "prefix_len", "prefix_nodes", "trace")
 
     def __init__(self, tokens, max_new, eos, temp, rng, deadline, t0,
                  trace=None):
@@ -148,12 +155,22 @@ class _GenRequest:
         self.event = threading.Event()
         self.out: List[int] = []
         self.error: Optional[BaseException] = None
+        # time.monotonic() seconds: submitted, picked from the queue
+        # (t_join - t0 is the queue wait), first token, completion (or
+        # failure: whenever ``event`` was set)
         self.t0 = t0
-        self.t_first: Optional[float] = None  # first-token wall clock
+        self.t_join: Optional[float] = None
+        self.t_first: Optional[float] = None
+        self.t_done: Optional[float] = None
         self.row: Optional[int] = None
         self.prefix_len = 0          # tokens served from the prefix cache
         self.prefix_nodes: list = []  # pinned trie nodes (one pin each)
         self.trace = trace           # request trace (None when disabled)
+
+    def complete(self, now: float):
+        """Stamp ``t_done`` and wake the waiter."""
+        self.t_done = now
+        self.event.set()
 
 
 class GenerationEngine:
@@ -235,8 +252,13 @@ class GenerationEngine:
         self._joined_total = 0
         self._retired_total = 0
         self._tokens_total = 0
+        # host wall time of the prefill launches and of the decode
+        # windows, each INCLUDING the wait for the device's result
         self._prefill_seconds = 0.0
         self._decode_seconds = 0.0
+        # sequence number of the loop's iteration: every span of one
+        # iteration (admit, prefills, decode window) carries it as ``n``
+        self._window = 0
         # optional SLOMonitor (parallel.platform wires it): TTFT + error
         # outcomes observed synchronously at the same points telemetry
         # records them
@@ -293,18 +315,26 @@ class GenerationEngine:
         Admission order matches the batcher: malformed → 400, queue full
         → 503, breaker open → shed (503) — breaker LAST so a rejected
         request never burns a half-open probe ticket."""
+        with telemetry.span("gen.submit"):
+            return self._submit(tokens, max_new_tokens, eos_id,
+                                temperature, seed, timeout_ms, traceparent)
+
+    def _submit(self, tokens, max_new_tokens, eos_id, temperature, seed,
+                timeout_ms, traceparent) -> _GenRequest:
         trace = tracing.start_trace(
             "generate", traceparent=traceparent,
             attrs={"model": self.name} if self.name else None)
         if max_new_tokens is None:
             max_new_tokens = self.config.max_new_default
         try:
-            toks = self._dec.validate_request(tokens, int(max_new_tokens))
-            if temperature < 0:
-                raise ValueError("temperature must be >= 0")
-            if eos_id is not None and not (
-                    0 <= int(eos_id) < self._dec.vocab_size):
-                raise ValueError("eos_id outside the vocabulary")
+            with telemetry.span("gen.submit.validate"):
+                toks = self._dec.validate_request(tokens,
+                                                  int(max_new_tokens))
+                if temperature < 0:
+                    raise ValueError("temperature must be >= 0")
+                if eos_id is not None and not (
+                        0 <= int(eos_id) < self._dec.vocab_size):
+                    raise ValueError("eos_id outside the vocabulary")
         except ValueError as e:
             telemetry.record_decode_request("bad_request", model=self.name)
             tracing.finish_trace(trace, "bad_request")
@@ -313,7 +343,9 @@ class GenerationEngine:
             timeout_ms = self.config.timeout_ms
         t0 = time.monotonic()
         deadline = t0 + timeout_ms / 1000.0 if timeout_ms else None
-        rng = np.asarray(jax.random.PRNGKey(int(seed)), np.uint32)
+        # a device program and its read-back, on the caller's thread
+        with telemetry.span("gen.submit.key", sync=True):
+            rng = np.asarray(jax.random.PRNGKey(int(seed)), np.uint32)
         req = _GenRequest(toks, int(max_new_tokens),
                           -1 if eos_id is None else int(eos_id),
                           float(temperature), rng, deadline, t0,
@@ -325,36 +357,43 @@ class GenerationEngine:
             # push the row past max_len (the suffix join writes a
             # ts-wide block at offset m, so m + bucket(n - m) must fit).
             ladder = self._dec.prompt_ladder
-            m, nodes = self._prefix.match(
-                req.tokens, limit=req.n - 1,
-                fits=lambda mm: mm + bucket_for(
-                    req.n - mm, ladder) <= self._dec.max_len)
+            with telemetry.span("gen.submit.prefix") as sp:
+                m, nodes = self._prefix.match(
+                    req.tokens, limit=req.n - 1,
+                    fits=lambda mm: mm + bucket_for(
+                        req.n - mm, ladder) <= self._dec.max_len)
+                sp.annotate(prefix_len=m)
             req.prefix_len = m
             req.prefix_nodes = list(nodes)
         try:
-            with self._cond:
-                if self._stop:
-                    tracing.finish_trace(trace, "shutdown")
-                    raise RuntimeError("generation engine is closed")
-                if len(self._queue) >= self.config.max_queue:
-                    telemetry.record_decode_request("rejected",
-                                                    model=self.name)
-                    tracing.finish_trace(trace, "rejected")
-                    raise ServerOverloadedError(
-                        f"generation queue full "
-                        f"({self.config.max_queue} waiting)")
-                if self._breaker is not None and not self._breaker.allow():
-                    telemetry.record_decode_request("shed", model=self.name)
-                    tracing.finish_trace(trace, "shed")
-                    raise CircuitOpenError(
-                        f"circuit breaker {self._breaker.name!r} is "
-                        f"{self._breaker.state}; request shed")
-                self._queue.append(req)
-                tracing.trace_event(
-                    trace, "queued",
-                    {"prefix_len": req.prefix_len} if req.prefix_len
-                    else None)
-                self._cond.notify_all()
+            with telemetry.span("gen.submit.enqueue") as sp:
+                asked = time.monotonic_ns()
+                with self._cond:
+                    _lock_wait(sp, asked)
+                    if self._stop:
+                        tracing.finish_trace(trace, "shutdown")
+                        raise RuntimeError("generation engine is closed")
+                    if len(self._queue) >= self.config.max_queue:
+                        telemetry.record_decode_request("rejected",
+                                                        model=self.name)
+                        tracing.finish_trace(trace, "rejected")
+                        raise ServerOverloadedError(
+                            f"generation queue full "
+                            f"({self.config.max_queue} waiting)")
+                    if (self._breaker is not None
+                            and not self._breaker.allow()):
+                        telemetry.record_decode_request("shed",
+                                                        model=self.name)
+                        tracing.finish_trace(trace, "shed")
+                        raise CircuitOpenError(
+                            f"circuit breaker {self._breaker.name!r} is "
+                            f"{self._breaker.state}; request shed")
+                    self._queue.append(req)
+                    tracing.trace_event(
+                        trace, "queued",
+                        {"prefix_len": req.prefix_len} if req.prefix_len
+                        else None)
+                    self._cond.notify_all()
         except BaseException:
             self._release_prefix(req)
             raise
@@ -424,7 +463,10 @@ class GenerationEngine:
     def stats(self) -> dict:
         """Scheduler + cache counters: running-batch occupancy, rows in
         use, retire/join/token totals, current KV bucket, the AOT cache
-        (zero-recompile invariant reads off ``misses``), breaker state."""
+        (zero-recompile invariant reads off ``misses``), breaker state.
+        ``prefill_seconds`` and ``decode_seconds`` are HOST wall time of
+        the prefill launches and the decode windows, the wait for the
+        device's result (the read-back) included: not device time."""
         with self._cond:
             out = {
                 "rows": self.config.max_batch,
@@ -479,16 +521,28 @@ class GenerationEngine:
                     target=self._loop, name="dl4j-decode-loop", daemon=True)
                 self._thread.start()
 
+    def _span(self, name: str, **attrs):
+        """A span of the loop's current iteration."""
+        return telemetry.span(name, n=self._window, **attrs)
+
     def _loop(self):
         while True:
-            with self._cond:
-                while (not self._stop and not self._queue
-                       and self._n_active == 0):
-                    self._cond.wait(0.1)
-                if self._stop:
-                    return
-                self._expire_queued_locked(time.monotonic())
-                joins = self._pick_joins_locked()
+            self._window += 1
+            with self._span("gen.admit") as sp:
+                asked = time.monotonic_ns()
+                with self._cond:
+                    _lock_wait(sp, asked)
+                    if (not self._stop and not self._queue
+                            and self._n_active == 0):
+                        with self._span("gen.wait"):
+                            while (not self._stop and not self._queue
+                                   and self._n_active == 0):
+                                self._cond.wait(0.1)
+                    if self._stop:
+                        return
+                    self._expire_queued_locked(time.monotonic())
+                    joins = self._pick_joins_locked()
+                    sp.annotate(joins=len(joins), queued=len(self._queue))
             try:
                 if joins:
                     self._do_prefill(joins)
@@ -509,7 +563,7 @@ class GenerationEngine:
                 telemetry.record_decode_request("expired", now - req.t0, model=self.name)
                 tracing.finish_trace(req.trace, "expired")
                 self._release_prefix(req)
-                req.event.set()
+                req.complete(now)
             else:
                 live.append(req)
         if len(live) != len(self._queue):
@@ -522,8 +576,10 @@ class GenerationEngine:
         free = [i for i, r in enumerate(self._rows) if r is None]
         n = min(len(free), len(self._queue))
         joins = []
+        now = time.monotonic() if n else None
         for _ in range(n):
             req = self._queue.popleft()
+            req.t_join = now
             req.row = free[len(joins)]
             self._rows[req.row] = req
             if req.trace is not None:
@@ -531,20 +587,28 @@ class GenerationEngine:
             joins.append(req)
         return joins
 
-    def _grow_to(self, target: int):
+    def _grow_to(self, target: int) -> bool:
+        """Make the cache hold ``target`` positions; whether a program
+        was dispatched for it (a new state, or the hop to a wider KV
+        bucket), which then has a ``gen.grow`` span of its own."""
         s2 = bucket_for(target, self._dec.kv_ladder)
-        if self._state is None:
-            self._S = max(self._S, s2)
-            self._state = self._dec.new_state(self._S)
-            if self._draft_dec is not None:
-                self._draft_state = self._draft_dec.new_state(self._S)
-            return
-        if s2 > self._S:
-            self._state = self._dec.grow_fn(self._S, s2)(self._state)
-            if self._draft_dec is not None:
-                self._draft_state = self._draft_dec.grow_fn(
-                    self._S, s2)(self._draft_state)
-            self._S = s2
+        if self._state is not None and s2 <= self._S:
+            return False
+        with self._span("gen.grow",
+                        kv_from=self._S if self._state is not None else 0,
+                        kv_to=max(self._S, s2)):
+            if self._state is None:
+                self._S = max(self._S, s2)
+                self._state = self._dec.new_state(self._S)
+                if self._draft_dec is not None:
+                    self._draft_state = self._draft_dec.new_state(self._S)
+            else:
+                self._state = self._dec.grow_fn(self._S, s2)(self._state)
+                if self._draft_dec is not None:
+                    self._draft_state = self._draft_dec.grow_fn(
+                        self._S, s2)(self._draft_state)
+                self._S = s2
+        return True
 
     def _do_prefill(self, joins: List[_GenRequest]):
         """Prompt ingestion for this iteration's joins: cold prompts
@@ -574,45 +638,54 @@ class GenerationEngine:
         t0 = time.monotonic()
         tp = bucket_for(max(r.n for r in joins), self._dec.prompt_ladder)
         bp = bucket_for(len(joins), self._dec.join_ladder)
-        self._grow_to(max(tp, self._S))
-        prompts = np.full((bp, tp), self._dec.pad_id, np.int32)
-        lengths = np.zeros((bp,), np.int32)
-        rows = np.full((bp,), cfg.max_batch, np.int32)  # OOB = dropped
-        max_new = np.ones((bp,), np.int32)
-        eos = np.full((bp,), -1, np.int32)
-        temps = np.zeros((bp,), np.float32)
-        rng = np.zeros((bp, 2), np.uint32)
-        for i, r in enumerate(joins):
-            prompts[i, :r.n] = r.tokens
-            lengths[i] = r.n
-            rows[i] = r.row
-            max_new[i] = r.max_new
-            eos[i] = r.eos
-            temps[i] = r.temp
-            rng[i] = r.rng
+        with self._span("gen.prefill", kind="cold", joins=len(joins),
+                        prompt_bucket=tp, rows=bp):
+            with self._span("gen.prefill.stage"):
+                self._grow_to(max(tp, self._S))
+                prompts = np.full((bp, tp), self._dec.pad_id, np.int32)
+                lengths = np.zeros((bp,), np.int32)
+                rows = np.full((bp,), cfg.max_batch, np.int32)  # OOB = dropped
+                max_new = np.ones((bp,), np.int32)
+                eos = np.full((bp,), -1, np.int32)
+                temps = np.zeros((bp,), np.float32)
+                rng = np.zeros((bp, 2), np.uint32)
+                for i, r in enumerate(joins):
+                    prompts[i, :r.n] = r.tokens
+                    lengths[i] = r.n
+                    rows[i] = r.row
+                    max_new[i] = r.max_new
+                    eos[i] = r.eos
+                    temps[i] = r.temp
+                    rng[i] = r.rng
 
-        def once():
-            faults.fault_point(self._fault_site)
-            return self._dec.prompt_fn(tp, bp)(
-                self._net_params(), prompts, lengths, max_new, eos, temps,
-                rng)
+            def once():
+                faults.fault_point(self._fault_site)
+                return self._dec.prompt_fn(tp, bp)(
+                    self._net_params(), prompts, lengths, max_new, eos,
+                    temps, rng)
 
+            with self._span("gen.prefill.launch"):
+                kv, tok, active, rng2 = self._call_prefill(once, joins)
+                self._state = self._dec.join_fn(self._S, tp, bp)(
+                    self._state, kv, rows, tok, lengths, max_new, eos, temps,
+                    rng2, active)
+                if self._prefix is not None:
+                    self._insert_pages(joins, kv, offset=0)
+            for r in joins:
+                if r.trace is not None:
+                    r.trace.event("prefill",
+                                  {"prompt_bucket": tp, "rows": bp})
+            self._account_prefill(joins, tok, active, bp, t0)
+
+    def _call_prefill(self, once, joins):
+        """One prefill launch, retried (never past the joins' earliest
+        deadline) when the engine has a retry policy."""
         if self._retry is None:
-            kv, tok, active, rng2 = once()
-        else:
-            deadlines = [r.deadline for r in joins if r.deadline is not None]
-            kv, tok, active, rng2 = self._retry.call(
-                once, deadline=min(deadlines) if deadlines else None,
-                op=self._fault_site)
-        self._state = self._dec.join_fn(self._S, tp, bp)(
-            self._state, kv, rows, tok, lengths, max_new, eos, temps,
-            rng2, active)
-        if self._prefix is not None:
-            self._insert_pages(joins, kv, offset=0)
-        for r in joins:
-            if r.trace is not None:
-                r.trace.event("prefill", {"prompt_bucket": tp, "rows": bp})
-        self._account_prefill(joins, tok, active, bp, t0)
+            return once()
+        deadlines = [r.deadline for r in joins if r.deadline is not None]
+        return self._retry.call(
+            once, deadline=min(deadlines) if deadlines else None,
+            op=self._fault_site)
 
     def _prefill_suffix_group(self, joins: List[_GenRequest], ts: int):
         """One prefix-HIT join group (shared suffix bucket ``ts``): the
@@ -631,64 +704,64 @@ class GenerationEngine:
         # width per (ts, tpre, s) keeps the prefix warm set small, and
         # padding rows scatter out of bounds (dropped)
         bp = cfg.max_batch
-        self._grow_to(max(max_m + ts, self._S))
-        suffix = np.full((bp, ts), self._dec.pad_id, np.int32)
-        suf_lens = np.zeros((bp,), np.int32)
-        plens = np.zeros((bp,), np.int32)
-        lengths = np.zeros((bp,), np.int32)
-        rows = np.full((bp,), cfg.max_batch, np.int32)  # OOB = dropped
-        max_new = np.ones((bp,), np.int32)
-        eos = np.full((bp,), -1, np.int32)
-        temps = np.zeros((bp,), np.float32)
-        rng = np.zeros((bp, 2), np.uint32)
-        pkv = None
-        for i, r in enumerate(joins):
-            blk = self._prefix.assemble(r.prefix_nodes, tpre)
-            if pkv is None:
-                pkv = {name: {
-                    "k": np.zeros((bp,) + b["k"].shape, b["k"].dtype),
-                    "v": np.zeros((bp,) + b["v"].shape, b["v"].dtype)}
-                    for name, b in blk.items()}
-            for name, b in blk.items():
-                pkv[name]["k"][i] = b["k"]
-                pkv[name]["v"][i] = b["v"]
-            suffix[i, :r.n - r.prefix_len] = r.tokens[r.prefix_len:]
-            suf_lens[i] = r.n - r.prefix_len
-            plens[i] = r.prefix_len
-            lengths[i] = r.n
-            rows[i] = r.row
-            max_new[i] = r.max_new
-            eos[i] = r.eos
-            temps[i] = r.temp
-            rng[i] = r.rng
+        with self._span("gen.prefill", kind="suffix", joins=len(joins),
+                        prompt_bucket=ts, rows=bp):
+            with self._span("gen.prefill.stage"):
+                self._grow_to(max(max_m + ts, self._S))
+                suffix = np.full((bp, ts), self._dec.pad_id, np.int32)
+                suf_lens = np.zeros((bp,), np.int32)
+                plens = np.zeros((bp,), np.int32)
+                lengths = np.zeros((bp,), np.int32)
+                rows = np.full((bp,), cfg.max_batch, np.int32)  # OOB = dropped
+                max_new = np.ones((bp,), np.int32)
+                eos = np.full((bp,), -1, np.int32)
+                temps = np.zeros((bp,), np.float32)
+                rng = np.zeros((bp, 2), np.uint32)
+                pkv = None
+                for i, r in enumerate(joins):
+                    blk = self._prefix.assemble(r.prefix_nodes, tpre)
+                    if pkv is None:
+                        pkv = {name: {
+                            "k": np.zeros((bp,) + b["k"].shape,
+                                          b["k"].dtype),
+                            "v": np.zeros((bp,) + b["v"].shape,
+                                          b["v"].dtype)}
+                            for name, b in blk.items()}
+                    for name, b in blk.items():
+                        pkv[name]["k"][i] = b["k"]
+                        pkv[name]["v"][i] = b["v"]
+                    suffix[i, :r.n - r.prefix_len] = r.tokens[r.prefix_len:]
+                    suf_lens[i] = r.n - r.prefix_len
+                    plens[i] = r.prefix_len
+                    lengths[i] = r.n
+                    rows[i] = r.row
+                    max_new[i] = r.max_new
+                    eos[i] = r.eos
+                    temps[i] = r.temp
+                    rng[i] = r.rng
 
-        def once():
-            faults.fault_point(self._fault_site)
-            return self._dec.suffix_prompt_fn(ts, tpre, bp)(
-                self._net_params(), suffix, suf_lens, pkv, plens,
-                max_new, eos, temps, rng)
+            def once():
+                faults.fault_point(self._fault_site)
+                return self._dec.suffix_prompt_fn(ts, tpre, bp)(
+                    self._net_params(), suffix, suf_lens, pkv, plens,
+                    max_new, eos, temps, rng)
 
-        if self._retry is None:
-            kv, tok, active, rng2 = once()
-        else:
-            deadlines = [r.deadline for r in joins if r.deadline is not None]
-            kv, tok, active, rng2 = self._retry.call(
-                once, deadline=min(deadlines) if deadlines else None,
-                op=self._fault_site)
-        self._state = self._dec.prefix_attach_fn(self._S, tpre, bp)(
-            self._state, pkv, rows, plens)
-        self._state = self._dec.suffix_join_fn(self._S, ts, bp)(
-            self._state, kv, rows, tok, plens, lengths, max_new, eos,
-            temps, rng2, active)
-        # extend the trie with the hit requests' own suffix pages (page
-        # extension: next time a LONGER shared prefix hits)
-        self._insert_pages(joins, kv, offset="prefix")
-        for r in joins:
-            if r.trace is not None:
-                r.trace.event("prefix_attach",
-                              {"prefix_len": r.prefix_len,
-                               "suffix_bucket": ts})
-        self._account_prefill(joins, tok, active, bp, t0)
+            with self._span("gen.prefill.launch"):
+                kv, tok, active, rng2 = self._call_prefill(once, joins)
+                self._state = self._dec.prefix_attach_fn(self._S, tpre, bp)(
+                    self._state, pkv, rows, plens)
+                self._state = self._dec.suffix_join_fn(self._S, ts, bp)(
+                    self._state, kv, rows, tok, plens, lengths, max_new,
+                    eos, temps, rng2, active)
+                # extend the trie with the hit requests' own suffix pages
+                # (page extension: next time a LONGER shared prefix hits)
+                self._insert_pages(joins, kv, offset="prefix")
+            for r in joins:
+                if r.trace is not None:
+                    r.trace.event("prefix_attach",
+                                  {"prefix_len": r.prefix_len,
+                                   "suffix_bucket": ts})
+            self._account_prefill(joins, tok, active, bp, t0)
 
     def _insert_pages(self, joins, kv, offset):
         """Donate a prefill launch's KV to the prefix cache: full pages
@@ -733,88 +806,100 @@ class GenerationEngine:
         cfg = self.config
         tp = bucket_for(max(r.n for r in joins), d.prompt_ladder)
         bp = bucket_for(len(joins), d.join_ladder)
-        prompts = np.full((bp, tp), d.pad_id, np.int32)
-        lengths = np.zeros((bp,), np.int32)
-        rows = np.full((bp,), cfg.max_batch, np.int32)
-        max_new = np.full((bp,), d.max_len, np.int32)
-        eos = np.full((bp,), -1, np.int32)
-        temps = np.zeros((bp,), np.float32)
-        rng = np.zeros((bp, 2), np.uint32)
-        tok = np.zeros((bp,), np.int32)
-        active = np.zeros((bp,), bool)
-        with self._cond:
-            for i, r in enumerate(joins):
-                prompts[i, :r.n] = r.tokens
-                lengths[i] = r.n
-                rows[i] = r.row
-                rng[i] = r.rng
-                tok[i] = r.out[0]
-                active[i] = self._rows[r.row] is r
+        with self._span("gen.prefill", kind="draft", joins=len(joins),
+                        prompt_bucket=tp, rows=bp):
+            with self._span("gen.prefill.stage") as sp:
+                prompts = np.full((bp, tp), d.pad_id, np.int32)
+                lengths = np.zeros((bp,), np.int32)
+                rows = np.full((bp,), cfg.max_batch, np.int32)
+                max_new = np.full((bp,), d.max_len, np.int32)
+                eos = np.full((bp,), -1, np.int32)
+                temps = np.zeros((bp,), np.float32)
+                rng = np.zeros((bp, 2), np.uint32)
+                tok = np.zeros((bp,), np.int32)
+                active = np.zeros((bp,), bool)
+                asked = time.monotonic_ns()
+                with self._cond:
+                    _lock_wait(sp, asked)
+                    for i, r in enumerate(joins):
+                        prompts[i, :r.n] = r.tokens
+                        lengths[i] = r.n
+                        rows[i] = r.row
+                        rng[i] = r.rng
+                        tok[i] = r.out[0]
+                        active[i] = self._rows[r.row] is r
 
-        def once():
-            faults.fault_point(self._fault_site)
-            return d.prompt_fn(tp, bp)(
-                d.params, prompts, lengths, max_new, eos, temps, rng)
+            def once():
+                faults.fault_point(self._fault_site)
+                return d.prompt_fn(tp, bp)(
+                    d.params, prompts, lengths, max_new, eos, temps, rng)
 
-        if self._retry is None:
-            kv, _tok, _act, rng2 = once()
-        else:
-            deadlines = [r.deadline for r in joins if r.deadline is not None]
-            kv, _tok, _act, rng2 = self._retry.call(
-                once, deadline=min(deadlines) if deadlines else None,
-                op=self._fault_site)
-        self._draft_state = d.join_fn(self._S, tp, bp)(
-            self._draft_state, kv, rows, tok, lengths, max_new, eos,
-            temps, rng2, active)
+            with self._span("gen.prefill.launch"):
+                kv, _tok, _act, rng2 = self._call_prefill(once, joins)
+                self._draft_state = d.join_fn(self._S, tp, bp)(
+                    self._draft_state, kv, rows, tok, lengths, max_new, eos,
+                    temps, rng2, active)
 
     def _account_prefill(self, joins, tok, active, bp, t0):
-        tok = np.asarray(tok)
-        active = np.asarray(active)
+        with self._span("gen.prefill.readback", sync=True):
+            tok = np.asarray(tok)
+            active = np.asarray(active)
         now = time.monotonic()
         n_live = 0
-        with self._cond:
-            for i, r in enumerate(joins):
-                r.out.append(int(tok[i]))
-                self._positions[r.row] = r.n
-                r.t_first = now
-                telemetry.record_decode_first_token(now - r.t0)
-                if r.trace is not None:
-                    r.trace.event("first_token")
-                if self._slo is not None:
-                    self._slo.observe(self.name or "default",
-                                      ttft=now - r.t0)
-                if active[i]:
-                    n_live += 1
-                else:
-                    self._finish_locked(r, now)
-            self._n_active += n_live
-            self._joined_total += len(joins)
-            self._tokens_total += len(joins)
-            self._prefill_seconds += now - t0
+        with self._span("gen.prefill.account") as sp:
+            asked = time.monotonic_ns()
+            with self._cond:
+                _lock_wait(sp, asked)
+                for i, r in enumerate(joins):
+                    r.out.append(int(tok[i]))
+                    self._positions[r.row] = r.n
+                    r.t_first = now
+                    telemetry.record_decode_first_token(now - r.t0)
+                    if r.trace is not None:
+                        r.trace.event("first_token")
+                    if self._slo is not None:
+                        self._slo.observe(self.name or "default",
+                                          ttft=now - r.t0)
+                    if active[i]:
+                        n_live += 1
+                    else:
+                        self._finish_locked(r, now)
+                self._n_active += n_live
+                self._joined_total += len(joins)
+                self._tokens_total += len(joins)
+                self._prefill_seconds += now - t0
         telemetry.record_decode_prefill(len(joins), bp, now - t0)
         if self._breaker is not None:
             self._breaker.on_success()
 
     def _do_decode(self):
+        with self._span("gen.decode") as parent:
+            self._decode_window(parent)
+
+    def _decode_window(self, parent):
         cfg = self.config
         k = cfg.fused_steps
         t0 = time.monotonic()
-        with self._cond:
-            active_rows = [r for r in self._rows if r is not None]
-            max_pos = max((self._positions[r.row] for r in active_rows
-                           if r is not None), default=0)
-        # speculative window needs K+1 cache slots past the deepest row
-        # (K drafts + the bonus position); past that the iteration falls
-        # back to the plain fused window — the dynamic_update_slice
-        # clamp would otherwise corrupt valid slots. The fallback can
-        # leave the draft cache with unwritten slots, which degrades
-        # draft agreement but never output correctness (the verifier
-        # replays the target's own sampling rule regardless).
-        ks = self._spec_k
-        spec = (self._draft_dec is not None
-                and max_pos + ks + 1 <= self._dec.max_len)
-        need = max_pos + (ks + 1 if spec else k)
-        self._grow_to(min(need, self._dec.max_len))
+        with self._span("gen.decode.plan") as sp:
+            asked = time.monotonic_ns()
+            with self._cond:
+                _lock_wait(sp, asked)
+                active_rows = [r for r in self._rows if r is not None]
+                max_pos = max((self._positions[r.row] for r in active_rows
+                               if r is not None), default=0)
+            # speculative window needs K+1 cache slots past the deepest
+            # row (K drafts + the bonus position); past that the
+            # iteration falls back to the plain fused window — the
+            # dynamic_update_slice clamp would otherwise corrupt valid
+            # slots. The fallback can leave the draft cache with
+            # unwritten slots, which degrades draft agreement but never
+            # output correctness (the verifier replays the target's own
+            # sampling rule regardless).
+            ks = self._spec_k
+            spec = (self._draft_dec is not None
+                    and max_pos + ks + 1 <= self._dec.max_len)
+            need = max_pos + (ks + 1 if spec else k)
+            sp.annotate(grew=self._grow_to(min(need, self._dec.max_len)))
         accepted = None
 
         # NO retry on decode windows: the state pytrees are donated
@@ -822,87 +907,95 @@ class GenerationEngine:
         # consumed them — _on_dispatch_failure resets instead
         if spec:
             k = ks
-
-            def once():
-                faults.fault_point(self._fault_site)
+        with self._span("gen.decode.launch"):
+            faults.fault_point(self._fault_site)
+            if spec:
                 # ONE launch syncs the draft's cursor onto the target's
                 # (reconciling the previous window's rollback) and runs
                 # its fused K-step draft window
-                return self._draft_dec.spec_draft_fn(self._S, k)(
+                self._draft_state, drafts, _ = self._draft_dec.spec_draft_fn(
+                    self._S, k)(
                     self._draft_dec.params, self._draft_state,
                     self._state["tokens"], self._state["positions"],
                     self._state["active"])
-
-            self._draft_state, drafts, _ = once()
-            self._state, toks, emitted, accepted = self._dec.spec_verify_fn(
-                self._S, k)(self._net_params(), self._state, drafts)
-            accepted = np.asarray(accepted)
-        else:
-            def once():
-                faults.fault_point(self._fault_site)
-                return self._dec.decode_fn(self._S, k)(
-                    self._net_params(), self._state)
-
-            self._state, toks, emitted = once()
-        toks = np.asarray(toks)
-        emitted = np.asarray(emitted)
+                self._state, toks, emitted, accepted = \
+                    self._dec.spec_verify_fn(self._S, k)(
+                        self._net_params(), self._state, drafts)
+            else:
+                self._state, toks, emitted = self._dec.decode_fn(
+                    self._S, k)(self._net_params(), self._state)
+        with self._span("gen.decode.readback", sync=True):
+            if accepted is not None:
+                accepted = np.asarray(accepted)
+            toks = np.asarray(toks)
+            emitted = np.asarray(emitted)
         now = time.monotonic()
         n_emitted = int(emitted.sum())
         occupancy = 0
         released = []
-        with self._cond:
-            occupancy = sum(r is not None for r in self._rows)
-            for b, req in enumerate(self._rows):
-                if req is None:
-                    continue
-                if accepted is not None and emitted[0, b]:
-                    e_b = int(emitted[:, b].sum())
-                    telemetry.record_spec_window(
-                        int(accepted[b]), k, e_b)
-                    self._spec_windows += 1
-                    self._spec_drafted += k
-                    self._spec_accepted += int(accepted[b])
-                if req.trace is not None:
-                    req.trace.event("decode_window", {
-                        "k": k, "kv_bucket": self._S,
-                        "tokens": int(emitted[:, b].sum()),
-                        "ms": round((now - t0) * 1000.0, 3)})
-                done = False
-                for i in range(toks.shape[0]):
-                    if not emitted[i, b]:
-                        break
-                    t = int(toks[i, b])
-                    req.out.append(t)
-                    self._positions[b] += 1
-                    if t == req.eos or len(req.out) >= req.max_new:
-                        done = True
-                        break
-                if done:
-                    self._finish_locked(req, now)
-                    self._n_active -= 1
-                elif req.deadline is not None and now > req.deadline:
-                    req.error = DeadlineExpiredError(
-                        "deadline expired mid-generation after "
-                        f"{len(req.out)} tokens")
-                    telemetry.record_decode_request("expired", now - req.t0, model=self.name)
-                    tracing.finish_trace(req.trace, "expired",
-                                         {"tokens": len(req.out)})
-                    self._release_prefix(req)
-                    req.event.set()
-                    self._rows[b] = None
-                    self._n_active -= 1
-                    released.append(b)
-            self._tokens_total += n_emitted
-            self._decode_seconds += now - t0
-            rows_in_use = sum(r is not None for r in self._rows)
+        finished = 0
+        with self._span("gen.decode.account") as sp:
+            asked = time.monotonic_ns()
+            with self._cond:
+                _lock_wait(sp, asked)
+                occupancy = sum(r is not None for r in self._rows)
+                for b, req in enumerate(self._rows):
+                    if req is None:
+                        continue
+                    if accepted is not None and emitted[0, b]:
+                        e_b = int(emitted[:, b].sum())
+                        telemetry.record_spec_window(
+                            int(accepted[b]), k, e_b)
+                        self._spec_windows += 1
+                        self._spec_drafted += k
+                        self._spec_accepted += int(accepted[b])
+                    if req.trace is not None:
+                        req.trace.event("decode_window", {
+                            "k": k, "kv_bucket": self._S,
+                            "tokens": int(emitted[:, b].sum()),
+                            "ms": round((now - t0) * 1000.0, 3)})
+                    done = False
+                    for i in range(toks.shape[0]):
+                        if not emitted[i, b]:
+                            break
+                        t = int(toks[i, b])
+                        req.out.append(t)
+                        self._positions[b] += 1
+                        if t == req.eos or len(req.out) >= req.max_new:
+                            done = True
+                            break
+                    if done:
+                        self._finish_locked(req, now)
+                        self._n_active -= 1
+                        finished += 1
+                    elif req.deadline is not None and now > req.deadline:
+                        req.error = DeadlineExpiredError(
+                            "deadline expired mid-generation after "
+                            f"{len(req.out)} tokens")
+                        telemetry.record_decode_request("expired", now - req.t0, model=self.name)
+                        tracing.finish_trace(req.trace, "expired",
+                                             {"tokens": len(req.out)})
+                        self._release_prefix(req)
+                        req.complete(now)
+                        self._rows[b] = None
+                        self._n_active -= 1
+                        released.append(b)
+                self._tokens_total += n_emitted
+                self._decode_seconds += now - t0
+                rows_in_use = sum(r is not None for r in self._rows)
+                sp.annotate(finished=finished, expired=len(released))
         if released:
-            keep = np.ones((cfg.max_batch,), bool)
-            keep[released] = False
-            self._state = self._dec.release_fn(self._S)(self._state, keep)
+            with self._span("gen.decode.release", rows=len(released)):
+                keep = np.ones((cfg.max_batch,), bool)
+                keep[released] = False
+                self._state = self._dec.release_fn(self._S)(self._state,
+                                                            keep)
         telemetry.record_decode_iteration(
             n_emitted, occupancy, cfg.max_batch, rows_in_use, k, now - t0)
         if self._breaker is not None:
             self._breaker.on_success()
+        parent.annotate(k=k, kv_bucket=self._S, rows=occupancy,
+                        emitted=n_emitted, spec=bool(spec))
 
     def _net_params(self):
         return self._dec.params
@@ -917,7 +1010,7 @@ class GenerationEngine:
             self._slo.observe(self.name or "default", ok=True,
                               seconds=now - req.t0)
         self._release_prefix(req)
-        req.event.set()
+        req.complete(now)
 
     def _on_dispatch_failure(self, e: BaseException):
         """A prefill/decode dispatch raised. The decode state may have
@@ -926,6 +1019,7 @@ class GenerationEngine:
         same way), reset to a fresh zeroed state, and count the breaker
         failure — persistent failure trips it open and submits shed."""
         with self._cond:
+            now = time.monotonic()
             for b, req in enumerate(self._rows):
                 if req is None:
                     continue
@@ -936,7 +1030,7 @@ class GenerationEngine:
                 if self._slo is not None:
                     self._slo.observe(self.name or "default", ok=False)
                 self._release_prefix(req)
-                req.event.set()
+                req.complete(now)
                 self._rows[b] = None
             self._n_active = 0
             self._positions = [0] * self.config.max_batch
@@ -953,18 +1047,19 @@ class GenerationEngine:
         with self._cond:
             self._stop = True
             err = RuntimeError("generation engine closed")
+            now = time.monotonic()
             for req in self._queue:
                 req.error = err
                 tracing.finish_trace(req.trace, "shutdown")
                 self._release_prefix(req)
-                req.event.set()
+                req.complete(now)
             self._queue.clear()
             for b, req in enumerate(self._rows):
                 if req is not None:
                     req.error = err
                     tracing.finish_trace(req.trace, "shutdown")
                     self._release_prefix(req)
-                    req.event.set()
+                    req.complete(now)
                     self._rows[b] = None
             self._n_active = 0
             self._cond.notify_all()

@@ -2,12 +2,15 @@
 
 Three pillars (docs/observability.md has the guided tour):
 
-1. **Spans** (``telemetry.span("ingest"|"compute"|"grad_sync")``): a
-   low-overhead, nesting-aware span API recording into a ring buffer;
-   exported as Chrome-trace JSON and aggregated into per-phase
-   p50/p95/p99 histograms. ``enable(sync=True)`` makes spans
+1. **Spans** (``telemetry.span("ingest"|"compute"|"grad_sync"|
+   "fit.next_batch"|"drain"|"gen.decode"...)``): a low-overhead,
+   nesting-aware span API. Spans ALWAYS land in one bounded ring
+   (16,384 entries, ``time.monotonic_ns``, each with its own ``id`` and
+   its ``parent_id``), whether or not ``enable()`` was called; exported
+   as Chrome-trace JSON and aggregated into per-phase p50/p95/p99
+   histograms. ``enable(sync=True)`` makes spans
    ``jax.block_until_ready`` their registered result so durations are
-   true device times; the default async mode never syncs.
+   true device times; otherwise a span never syncs.
 2. **Registry** (``telemetry.registry.REGISTRY``): process-wide
    counters/gauges/histograms — steps, examples, collective bytes,
    ingest bytes, pipeline bubble fraction — plus scrape-time collectors
@@ -16,10 +19,12 @@ Three pillars (docs/observability.md has the guided tour):
    ``ui.server.UIServer``, a ``TelemetryListener`` bridging into
    ``ui.stats`` storages, and ``dump_jsonl`` for offline diffing.
 
-The master switch gates every hot-path write: with telemetry disabled
-(the default) each instrumented site costs ONE flag check — no
-allocation, no lock, no host sync. Scrape surfaces (collectors,
-``/metrics``) work even while disabled; only per-step recording stops.
+``enable()`` turns on what costs more than a span: the registry writes
+of the per-step helpers (``record_step``, ``record_collective``,
+``record_ingest``...), the ``host_gap`` clock and ``sync``. With
+telemetry disabled (the default) each of those costs ONE flag check — no
+lock, no host sync — and a span costs its one ring record (1 to 2 µs).
+Scrape surfaces (collectors, ``/metrics``) work even while disabled.
 """
 
 from __future__ import annotations
@@ -80,7 +85,7 @@ def reset() -> None:
 
 
 # --------------------------------------------------------------------------
-# hot-path recording helpers (each is one flag check when disabled)
+# hot-path registry helpers (each is one flag check until ``enable()``)
 # --------------------------------------------------------------------------
 
 def record_step(path: str, examples: int = 0, steps: int = 1) -> None:

@@ -385,6 +385,142 @@ def test_decode_telemetry_series():
     assert snap1.get(ok_key, 0) >= snap0.get(ok_key, 0) + 1
 
 
+# --- program spans of the engine (always recorded, telemetry never enabled) -
+
+def _drive_four_clients(eng, per_client=3):
+    """Four threads submit beside the loop; returns the handles."""
+    handles, lock = [], threading.Lock()
+
+    def client(i):
+        for j in range(per_client):
+            h = eng.submit([1 + i, 2, 3, 4 + j], max_new_tokens=5 + j)
+            with lock:
+                handles.append(h)
+            eng.result(h)
+
+    threads = [threading.Thread(target=client, args=(i,)) for i in range(4)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+        assert not t.is_alive()
+    return handles
+
+
+@pytest.fixture
+def engine_spans():
+    """(events of one four-client run, the engine's stats when it ended,
+    the handles): the ring records though telemetry was never enabled."""
+    from deeplearning4j_tpu import telemetry
+
+    assert not telemetry.enabled()
+    telemetry.spans.reset()
+    with _engine() as eng:
+        handles = _drive_four_clients(eng)
+        stats = eng.stats()
+    return telemetry.events(), stats, handles
+
+
+def test_engine_spans_names_ids_and_parents_nest_per_thread(engine_spans):
+    evs, _stats, _handles = engine_spans
+    gen = [e for e in evs if e["name"].startswith("gen.")]
+    by_id = {e["id"]: e for e in gen}
+    assert len(by_id) == len(gen), "span ids repeat"
+    loop = {e["thread"] for e in gen if e["name"] == "gen.decode"}
+    assert len(loop) == 1, "one decode-loop thread"
+    callers = {e["thread"] for e in gen if e["name"] == "gen.submit"}
+    assert len(callers) == 4 and not callers & loop
+    parents_of = {
+        "gen.wait": {"gen.admit"},
+        "gen.grow": {"gen.prefill.stage", "gen.decode.plan"},
+        **{f"gen.prefill.{c}": {"gen.prefill"}
+           for c in ("stage", "launch", "readback", "account")},
+        **{f"gen.decode.{c}": {"gen.decode"}
+           for c in ("plan", "launch", "readback", "account", "release")},
+        **{f"gen.submit.{c}": {"gen.submit"}
+           for c in ("validate", "key", "prefix", "enqueue")}}
+    for e in gen:
+        if e["name"] in ("gen.admit", "gen.prefill", "gen.decode",
+                         "gen.submit"):
+            assert e["parent_id"] is None and e["depth"] == 0, e
+            continue
+        parent = by_id[e["parent_id"]]
+        assert parent["name"] in parents_of[e["name"]], (e, parent)
+        assert parent["thread"] == e["thread"]
+        assert parent["start_ns"] <= e["start_ns"]
+        assert (e["start_ns"] + e["duration_ns"]
+                <= parent["start_ns"] + parent["duration_ns"])
+        if e["thread"] in loop:   # spans of one iteration share its n
+            assert e["attrs"]["n"] == parent["attrs"]["n"]
+    # every decode window has its four children, every submit its three
+    kids = {}
+    for e in gen:
+        kids.setdefault(e["parent_id"], []).append(e["name"])
+    for e in gen:
+        if e["name"] == "gen.decode":
+            assert sorted(kids[e["id"]]) == [
+                "gen.decode.account", "gen.decode.launch",
+                "gen.decode.plan", "gen.decode.readback"]
+        if e["name"] == "gen.submit":
+            assert sorted(kids[e["id"]]) == [
+                "gen.submit.enqueue", "gen.submit.key",
+                "gen.submit.validate"]
+
+
+def test_engine_spans_carry_the_counts(engine_spans):
+    evs, stats, handles = engine_spans
+    decode = [e for e in evs if e["name"] == "gen.decode"]
+    prefill = [e for e in evs if e["name"] == "gen.prefill"]
+    assert sum(e["attrs"]["emitted"] for e in decode) == (
+        stats["tokens_total"] - stats["joined_total"])
+    assert sum(e["attrs"]["joins"] for e in prefill) == (
+        stats["joined_total"]) == len(handles)
+    assert all(e["attrs"]["k"] == K and e["attrs"]["spec"] is False
+               and 1 <= e["attrs"]["rows"] <= MAX_BATCH for e in decode)
+    assert all(e["attrs"]["kind"] == "cold" for e in prefill)
+    for name in ("gen.admit", "gen.prefill.account", "gen.decode.plan",
+                 "gen.decode.account", "gen.submit.enqueue"):
+        waits = [e["attrs"]["lock_wait_us"] for e in evs
+                 if e["name"] == name]
+        assert waits and all(w >= 0 for w in waits), name
+    for name in ("gen.prefill.readback", "gen.decode.readback",
+                 "gen.submit.key"):
+        assert all(e["attrs"]["sync"] is True for e in evs
+                   if e["name"] == name)
+    admits = [e for e in evs if e["name"] == "gen.admit"]
+    assert sum(e["attrs"].get("joins", 0) for e in admits) == len(handles)
+
+
+def test_engine_loop_thread_is_covered_by_spans(engine_spans):
+    evs, _stats, _handles = engine_spans
+    loop = next(e["thread"] for e in evs if e["name"] == "gen.decode")
+    tops = sorted((e for e in evs if e["thread"] == loop
+                   and e["parent_id"] is None), key=lambda e: e["start_ns"])
+    # from the first admit to the end of the last decode window
+    last = max(i for i, e in enumerate(tops) if e["name"] == "gen.decode")
+    tops = tops[:last + 1]
+    wall = (tops[-1]["start_ns"] + tops[-1]["duration_ns"]
+            - tops[0]["start_ns"])
+    inside = sum(e["duration_ns"] for e in tops)
+    assert inside <= wall
+    assert inside / wall >= 0.95, (inside, wall)
+
+
+def test_handle_times_are_ordered(engine_spans):
+    _evs, _stats, handles = engine_spans
+    assert len(handles) == 12
+    for h in handles:
+        assert h.error is None
+        assert h.t0 <= h.t_join <= h.t_first <= h.t_done
+
+
+def test_failed_handle_gets_t_done_too():
+    eng = _engine()
+    h = eng.submit([1, 2, 3], max_new_tokens=4)
+    eng.close()
+    assert h.event.is_set() and h.t_done is not None and h.t_done >= h.t0
+
+
 def test_generation_panel_renders():
     from deeplearning4j_tpu.ui.server import UIServer
 

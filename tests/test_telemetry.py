@@ -53,14 +53,40 @@ def _ds(n=8, seed=0):
 # spans
 # --------------------------------------------------------------------------
 
-def test_disabled_mode_zero_allocation_fast_path():
-    assert not tel.enabled()
-    # one shared no-op singleton, nothing recorded
-    assert tel.span("a") is tel.span("b")
+def test_disabled_mode_zero_allocation_fast_path(monkeypatch):
+    """The contract since PR 25: with telemetry never enabled a span
+    still lands in the bounded ring, and that is ALL it does: no
+    registry write and no host sync. ``enable()`` is what turns on the
+    registry, the host-gap clock and ``sync``."""
+    import jax
+
+    syncs = []
+    monkeypatch.setattr(jax, "block_until_ready", syncs.append)
+    assert not tel.enabled() and not tel.sync_mode()
+    before = tel.REGISTRY.snapshot(run_collectors=False)
     with tel.span("ingest"):
         pass
-    assert tel.events() == []
-    assert tel.phase_stats() == {}
+    with tel.span("compute") as sp:
+        assert sp.set_result("a device result") == "a device result"
+    tel.record_step("multilayer", 32)
+    tel.record_collective("grad_psum", 4096)
+    tel.record_ingest(1 << 20)
+    tel.host_gap_reset()
+    tel.host_gap_open()
+    tel.host_gap_close()
+    assert [e["name"] for e in tel.events()] == ["ingest", "compute"]
+    assert tel.REGISTRY.snapshot(run_collectors=False) == before
+    assert set(tel.phase_stats()) == {"ingest", "compute"}
+    # sync=True holds only while enabled
+    tel.enable(sync=True)
+    tel.disable()
+    with tel.span("compute") as sp:
+        sp.set_result("a device result")
+    assert syncs == []
+    tel.enable(sync=True)
+    with tel.span("compute") as sp:
+        sp.set_result("a device result")
+    assert syncs == ["a device result"]
 
 
 def test_span_nesting_records_depth_and_parent():
@@ -74,6 +100,8 @@ def test_span_nesting_records_depth_and_parent():
     assert by_name["outer"]["parent"] is None
     assert by_name["inner"]["depth"] == 1
     assert by_name["inner"]["parent"] == "outer"
+    assert by_name["outer"]["parent_id"] is None
+    assert by_name["inner"]["parent_id"] == by_name["outer"]["id"]
     # inner closes first and is contained in outer
     assert by_name["inner"]["duration_ns"] <= by_name["outer"]["duration_ns"]
 
@@ -85,7 +113,8 @@ def test_span_aggregation_math():
         s = tel.spans.Span("phase")
         s.t0 = 0
         s.t1 = ms * 1_000_000
-        tel.spans._ring.append((s.name, s.t0, s.t1, 0, None, 0, None))
+        tel.spans._ring.append((s.name, s.t0, s.t1, 0, None, 0, None,
+                                0, None))
     st = tel.phase_stats()["phase"]
     assert st["count"] == 5
     assert st["total_ms"] == pytest.approx(110.0)
@@ -104,7 +133,65 @@ def test_span_ring_is_bounded():
         with tel.span("s"):
             pass
     assert len(tel.events()) == 16
-    tel.enable(ring_size=4096)  # restore default for other tests
+    tel.enable(ring_size=tel.spans.RING_SIZE)  # restore the default
+
+
+def test_default_ring_holds_ring_size_spans_and_no_more():
+    """Never enabled, never resized: the ring is bounded at RING_SIZE
+    (16,384: the module docstring says how it was sized) and evicts the
+    oldest in silence."""
+    assert tel.spans.RING_SIZE == 16384
+    assert tel.spans._ring.maxlen == tel.spans.RING_SIZE
+    for i in range(tel.spans.RING_SIZE + 100):
+        with tel.span("s") as sp:
+            sp.annotate(i=i)
+    evts = tel.events()
+    assert len(evts) == tel.spans.RING_SIZE
+    assert evts[0]["attrs"]["i"] == 100
+    assert evts[-1]["attrs"]["i"] == tel.spans.RING_SIZE + 99
+
+
+def test_spans_read_the_monotonic_clock():
+    """One clock for spans, request traces, the engine's handles and the
+    benchmark's trace window: ``time.monotonic_ns``."""
+    t0 = time.monotonic_ns()
+    with tel.span("s"):
+        pass
+    t1 = time.monotonic_ns()
+    (e,) = tel.events()
+    assert t0 <= e["start_ns"] <= e["start_ns"] + e["duration_ns"] <= t1
+
+
+def test_span_ids_tell_two_spans_of_one_name_apart_across_threads():
+    import threading
+
+    def work(tag):
+        for _ in range(50):
+            with tel.span("window") as outer:
+                outer.annotate(tag=tag)
+                with tel.span("child"):
+                    pass
+
+    threads = [threading.Thread(target=work, args=(i,)) for i in range(4)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60)
+        assert not t.is_alive()
+    evts = tel.events()
+    by_id = {e["id"]: e for e in evts}
+    assert len(by_id) == len(evts) == 400
+    for e in evts:
+        if e["name"] == "child":
+            parent = by_id[e["parent_id"]]
+            assert parent["name"] == "window"
+            assert parent["thread"] == e["thread"]
+            assert parent["start_ns"] <= e["start_ns"]
+        else:
+            assert e["parent_id"] is None
+    # each window owns exactly one child
+    owners = [e["parent_id"] for e in evts if e["name"] == "child"]
+    assert len(set(owners)) == 200
 
 
 def test_chrome_trace_export(tmp_path):
@@ -118,6 +205,7 @@ def test_chrome_trace_export(tmp_path):
     assert evts[0]["name"] == "compute"
     assert evts[0]["args"]["step"] == 3
     assert evts[0]["dur"] >= 0
+    assert evts[0]["args"]["id"] == tel.events()[0]["id"]
 
 
 # --------------------------------------------------------------------------
@@ -234,6 +322,69 @@ def test_multilayer_and_graph_record_steps():
     st = tel.phase_stats()
     assert st["ingest"]["count"] == 3
     assert st["compute"]["count"] == 3
+
+
+def _graph():
+    from deeplearning4j_tpu.nn.graph import ComputationGraph
+
+    conf = (NeuralNetConfiguration.builder().seed(3).updater(Sgd(0.1))
+            .graph_builder().add_inputs("in")
+            .add_layer("h", DenseLayer(n_out=4, activation=Activation.TANH),
+                       "in")
+            .add_layer("out", OutputLayer(n_out=2,
+                                          activation=Activation.SOFTMAX,
+                                          loss_fn=LossMCXENT()), "h")
+            .set_outputs("out")
+            .set_input_types(InputType.feed_forward(3)).build())
+    return ComputationGraph(conf).init()
+
+
+@pytest.mark.parametrize("build", [_net, _graph],
+                         ids=["multilayer", "graph"])
+def test_fit_span_tree_without_enabling_telemetry(build):
+    """``fit`` -> ``fit.next_batch`` / ``ingest`` / ``compute`` /
+    ``listeners``, and one ``drain`` per DISPATCH_DEPTH steps plus the
+    forced one at the epoch's end: the same breakdown from both network
+    classes, recorded with telemetry never enabled."""
+    from deeplearning4j_tpu.datasets.iterators import ListDataSetIterator
+    from deeplearning4j_tpu.nn import io as nn_io
+    from deeplearning4j_tpu.optimize.listeners import TrainingListener
+
+    class Seen(TrainingListener):
+        n = 0
+
+        def iteration_done(self, model, iteration, epoch, score):
+            self.n += 1
+
+    assert not tel.enabled()
+    net = build()
+    seen = Seen()
+    net.set_listeners(seen)
+    steps = 2 * nn_io.DISPATCH_DEPTH + 3
+    net.fit(ListDataSetIterator([_ds(seed=i) for i in range(steps)]))
+    assert seen.n == steps
+    evts = tel.events()
+    (root,) = [e for e in evts if e["name"] == "fit"]
+    assert root["attrs"] == {"epochs": 1} and root["parent_id"] is None
+    kids = [e for e in evts if e["parent_id"] == root["id"]]
+    count = {}
+    for e in kids:
+        count[e["name"]] = count.get(e["name"], 0) + 1
+        assert e["thread"] == root["thread"]
+    assert count == {"fit.next_batch": steps + 1, "ingest": steps,
+                     "compute": steps, "grad_sync": steps,
+                     "listeners": steps, "drain": 3}
+    drains = [e["attrs"] for e in kids if e["name"] == "drain"]
+    assert drains == [{"sync": True, "steps": nn_io.DISPATCH_DEPTH}] * 2 + [
+        {"sync": True, "steps": 3}]
+    # per step, in order: wait for input, stage it, enqueue, listeners
+    order = [e["name"] for e in sorted(kids, key=lambda e: e["start_ns"])]
+    assert order[:5] == ["fit.next_batch", "ingest", "compute", "grad_sync",
+                         "listeners"]
+    assert order[-2:] == ["fit.next_batch", "drain"]
+    # nothing was written to the registry
+    snap = tel.REGISTRY.snapshot(run_collectors=False)
+    assert not any(k.startswith("dl4j_training_steps_total") for k in snap)
 
 
 def test_device_ring_iterator_records_ingest():
