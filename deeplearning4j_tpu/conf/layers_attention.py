@@ -164,10 +164,12 @@ class SelfAttentionLayer(BaseLayer):
     # --- KV-cached autoregressive decode (nn.decoding / generation) -------
     #
     # The serving decode path splits the forward into two phases sharing
-    # one cache layout — ``k/v: [max_batch, max_len, n_heads, head_size]``
-    # plus a per-sequence slot count — so a sequence's keys/values are
-    # projected exactly once and every later token attends them from the
-    # cache instead of re-running the whole-prompt projection.
+    # one cache layout — ``k/v: [max_batch, max_len, n_heads * head_size]``,
+    # a position's projection as ``x @ Wk`` produces it (why, in
+    # ``ops/attention.py``), plus a per-sequence slot count — so a
+    # sequence's keys/values are projected exactly once and every later
+    # token attends them from the cache instead of re-running the
+    # whole-prompt projection.
 
     def _decode_check(self):
         if not self.project_input:
@@ -178,23 +180,26 @@ class SelfAttentionLayer(BaseLayer):
 
     def init_kv_cache(self, max_batch, max_len, n_in, dtype=jnp.float32):
         """Preallocated per-sequence KV buffers for this layer:
-        ``{"k","v"}: [max_batch, max_len, n_heads, head_size]`` zeros."""
-        self._decode_check()
-        hs = self._head_size(n_in)
-        shape = (max_batch, max_len, self.n_heads, hs)
+        ``{"k","v"}: [max_batch, max_len, n_heads * head_size]`` zeros."""
+        shape = self.kv_cache_shape(max_batch, max_len, n_in)
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+    def kv_cache_shape(self, batch, length, n_in):
+        """Shape of one K or V buffer in cache layout — a cache at
+        ``length = max_len``, a prefill or prefix-page block at a prompt
+        bucket: ``(batch, length, n_heads * head_size)``."""
+        self._decode_check()
+        return (batch, length, self.n_heads * self._head_size(n_in))
 
     def prefill(self, params, x, key_mask=None, use_kernels=False):
         """Whole-prompt forward that ALSO returns the projected keys and
         values so the caller can seed a KV cache in one launch.
         ``x: [batch, time, features]``; returns ``(y, k, v)`` with
-        ``k/v: [batch, time, n_heads, head_size]`` (cache layout) and
+        ``k/v: [batch, time, n_heads * head_size]`` (cache layout) and
         ``y`` identical to :meth:`forward` in eval mode (activation and
         mask-zeroing applied). ``use_kernels`` swaps the attention core
         for the tuned flash kernel when this envelope has a winner."""
         self._decode_check()
-        b, t, _ = x.shape
-        hs = params["Wk"].shape[1] // self.n_heads
         q = x @ params["Wq"] + params["bq"]
         k = x @ params["Wk"] + params["bk"]
         v = x @ params["Wv"] + params["bv"]
@@ -206,8 +211,7 @@ class SelfAttentionLayer(BaseLayer):
                                   + params["bo"])
         if key_mask is not None:
             y = y * jnp.asarray(key_mask, y.dtype)[:, :, None]
-        return (y, k.reshape(b, t, self.n_heads, hs),
-                v.reshape(b, t, self.n_heads, hs))
+        return y, k, v
 
     def decode_step(self, params, x, cache, positions, use_kernels=False):
         """One token of causal attention against the KV cache.
@@ -215,7 +219,7 @@ class SelfAttentionLayer(BaseLayer):
         ``positions: [batch]`` the cache slot it occupies (== number of
         tokens already cached for that row). Projects q/k/v for the
         token, writes k/v into the cache at ``positions`` via
-        ``dynamic_update_slice``, attends slots ``0..positions``
+        :func:`cache_update`, attends slots ``0..positions``
         inclusive, and returns ``(y [batch, features_out], new_cache)``.
         The caller donates the cache buffers into the compiled step so
         the write is in-place (PRG201 audits this). ``use_kernels``
@@ -226,8 +230,8 @@ class SelfAttentionLayer(BaseLayer):
         nh = self.n_heads
         hs = params["Wk"].shape[1] // nh
         q = (x @ params["Wq"] + params["bq"]).reshape(b, nh, hs)
-        k_new = (x @ params["Wk"] + params["bk"]).reshape(b, 1, nh, hs)
-        v_new = (x @ params["Wv"] + params["bv"]).reshape(b, 1, nh, hs)
+        k_new = (x @ params["Wk"] + params["bk"])[:, None]
+        v_new = (x @ params["Wv"] + params["bv"])[:, None]
         k_cache = cache_update(cache["k"], k_new, positions)
         v_cache = cache_update(cache["v"], v_new, positions)
         o = None
@@ -249,7 +253,7 @@ class SelfAttentionLayer(BaseLayer):
         are the window's representations; token ``i`` of row ``b``
         occupies cache slot ``positions[b] + i``. Projects q/k/v for the
         whole window, writes the k/v block at ``positions`` in one
-        ``dynamic_update_slice``, attends each token causally through
+        :func:`cache_update`, attends each token causally through
         :func:`chunk_decode_attention`, and returns
         ``(y [batch, t, features_out], new_cache)``. Stays on the stock
         core even under ``use_kernels``: the window's PER-ROW cache
@@ -260,8 +264,8 @@ class SelfAttentionLayer(BaseLayer):
         nh = self.n_heads
         hs = params["Wk"].shape[1] // nh
         q = (x @ params["Wq"] + params["bq"]).reshape(b, t, nh, hs)
-        k_new = (x @ params["Wk"] + params["bk"]).reshape(b, t, nh, hs)
-        v_new = (x @ params["Wv"] + params["bv"]).reshape(b, t, nh, hs)
+        k_new = x @ params["Wk"] + params["bk"]
+        v_new = x @ params["Wv"] + params["bv"]
         k_cache = cache_update(cache["k"], k_new, positions)
         v_cache = cache_update(cache["v"], v_new, positions)
         o = chunk_decode_attention(q, k_cache, v_cache, positions)
@@ -274,7 +278,7 @@ class SelfAttentionLayer(BaseLayer):
         """Prompt-suffix prefill against an already-projected prefix —
         the prefix-cache-hit twin of :meth:`prefill`. ``x: [batch,
         t_suffix, features]`` holds the suffix tokens' representations;
-        ``prefix_k/prefix_v: [batch, t_prefix, n_heads, head_size]`` are
+        ``prefix_k/prefix_v: [batch, t_prefix, n_heads * head_size]`` are
         the shared prefix pages in cache layout (padding masked by
         ``prefix_mask: [batch, t_prefix]``). The suffix queries attend
         the concatenation ``[prefix ; suffix]``: with ``Tk = t_prefix +
@@ -287,10 +291,9 @@ class SelfAttentionLayer(BaseLayer):
         self._decode_check()
         b, t, _ = x.shape
         nh = self.n_heads
-        hs = params["Wk"].shape[1] // nh
         q = x @ params["Wq"] + params["bq"]
-        k = (x @ params["Wk"] + params["bk"]).reshape(b, t, nh, hs)
-        v = (x @ params["Wv"] + params["bv"]).reshape(b, t, nh, hs)
+        k = x @ params["Wk"] + params["bk"]
+        v = x @ params["Wv"] + params["bv"]
         k_full = jnp.concatenate([prefix_k, k], axis=1)
         v_full = jnp.concatenate([prefix_v, v], axis=1)
         if key_mask is None:
@@ -298,10 +301,9 @@ class SelfAttentionLayer(BaseLayer):
         mask = jnp.concatenate(
             [jnp.asarray(prefix_mask, x.dtype),
              jnp.asarray(key_mask, x.dtype)], axis=1)
-        kh = jnp.transpose(k_full, (0, 2, 1, 3))
-        vh = jnp.transpose(v_full, (0, 2, 1, 3))
         # flash handles Tq != Tk via the same off = Tk - Tq causal rule
-        o = _attn_core(_split_heads(q, nh), kh, vh, mask, True,
+        o = _attn_core(_split_heads(q, nh), _split_heads(k_full, nh),
+                       _split_heads(v_full, nh), mask, True,
                        self.attention_impl, False, use_kernels)
         y = self.activation.apply(_merge_heads(o) @ params["Wo"]
                                   + params["bo"])

@@ -431,7 +431,7 @@ class PagedDecodeAttentionKernel(Kernel):
     are legal."""
 
     kernel_id = "paged_decode_attention"
-    version = 1
+    version = 2  # 1 paged a [batch, max_len, heads, head_dim] cache
 
     def supports(self, env) -> bool:
         return (_attention_supports(env) and env.tq == 1
@@ -469,8 +469,8 @@ class PagedDecodeAttentionKernel(Kernel):
         import jax.numpy as jnp
 
         q, kc, vc = _rand_attn(env, seed, [(env.b, env.h, env.d),
-                                           (env.b, env.tk, env.h, env.d),
-                                           (env.b, env.tk, env.h, env.d)])
+                                           (env.b, env.tk, env.h * env.d),
+                                           (env.b, env.tk, env.h * env.d)])
         pos = jax.random.randint(jax.random.PRNGKey(seed + 1),
                                  (env.b,), 0, env.tk, jnp.int32)
         return q, kc, vc, pos

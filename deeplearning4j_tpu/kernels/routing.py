@@ -276,7 +276,7 @@ def maybe_flash_attention(q, k, v, key_mask=None, causal=False):
 
 def maybe_decode_attention(q, k_cache, v_cache, positions):
     """Route single-token decode attention (``q [B, H, D]`` against
-    ``[B, S, H, D]`` caches valid through ``positions``) through the
+    ``[B, S, H * D]`` caches valid through ``positions``) through the
     tuned paged-gather kernel, or return ``None`` for the stock masked
     full-cache read."""
     b, h, d = q.shape

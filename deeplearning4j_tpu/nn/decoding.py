@@ -11,8 +11,9 @@ phases every production LLM server uses:
 - ``prefill``: the whole prompt in ONE launch — full causal attention,
   the projected keys/values of every layer captured in cache layout and
   scattered into the preallocated per-sequence KV buffers
-  (``[max_batch, kv_bucket, heads, head_dim]`` + a per-sequence slot
-  count), the first output token sampled from the last valid position.
+  (``[max_batch, kv_bucket, heads * head_dim]`` + a per-sequence slot
+  count; ``ops/attention.py`` says why that layout), the first output
+  token sampled from the last valid position.
 - ``decode_step``: one token per sequence per step against the cache —
   each step projects q/k/v for the new token only, writes k/v at the
   sequence's slot via ``dynamic_update_slice``, and attends the cached
@@ -233,15 +234,8 @@ class TransformerDecoder:
         (``AotStep.warm`` only needs avals)."""
         b = self.max_batch
         sds = jax.ShapeDtypeStruct
-        caches = {}
-        for name, n_in in self._attn.items():
-            layer = self._layer(name)
-            hs = layer._head_size(n_in)
-            shape = (b, s, layer.n_heads, hs)
-            caches[name] = {"k": sds(shape, self._dtype),
-                            "v": sds(shape, self._dtype)}
         return {
-            "caches": caches,
+            "caches": self._kv_struct(b, s),
             "tokens": sds((b,), jnp.int32),
             "positions": sds((b,), jnp.int32),
             "prompt_lens": sds((b,), jnp.int32),
@@ -362,7 +356,7 @@ class TransformerDecoder:
     def _run_suffix(self, params, suffix, suf_lens, prefix_kv, prefix_lens):
         """Prompt-SUFFIX prefill walk against already-projected prefix
         KV pages: ``suffix [Bp, Ts] int32`` holds only the uncached tail
-        of each prompt, ``prefix_kv[name]{k,v} [Bp, Tpre, heads, hd]``
+        of each prompt, ``prefix_kv[name]{k,v} [Bp, Tpre, heads * hd]``
         the shared pages (valid up to ``prefix_lens[b]``). Position
         embeddings are gathered at the suffix tokens' TRUE positions
         (``prefix_lens + i``), and each attention layer attends the
@@ -495,7 +489,7 @@ class TransformerDecoder:
         if key not in self._fns:
             def fn(state, kv, rows, tok, lengths, max_new, eos, temps,
                    rng, active):
-                pad = ((0, 0), (0, s - tp), (0, 0), (0, 0))
+                pad = ((0, 0), (0, s - tp), (0, 0))
                 caches = {}
                 for name, c in state["caches"].items():
                     caches[name] = {
@@ -532,7 +526,7 @@ class TransformerDecoder:
         key = ("grow", s, s2, tag)
         if key not in self._fns:
             def fn(state):
-                pad = ((0, 0), (0, s2 - s), (0, 0), (0, 0))
+                pad = ((0, 0), (0, s2 - s), (0, 0))
                 caches = {name: {"k": jnp.pad(c["k"], pad),
                                  "v": jnp.pad(c["v"], pad)}
                           for name, c in state["caches"].items()}
@@ -667,7 +661,7 @@ class TransformerDecoder:
     def prefix_attach_fn(self, s: int, tpre: int, bp: int):
         """Scatter shared prefix KV pages into joining rows' caches —
         the ``prefill_join`` shape applied to cached pages instead of a
-        fresh prefill: ``prefix_kv[name]{k,v} [bp, tpre, heads, hd]``
+        fresh prefill: ``prefix_kv[name]{k,v} [bp, tpre, heads * hd]``
         lands at slots ``[0, tpre)`` of each row in ``rows`` (OOB slots
         are padding, dropped), ``positions`` is set to the per-row valid
         prefix length. State DONATED — the audit-visible in-place cache
@@ -742,19 +736,19 @@ class TransformerDecoder:
                     ck, cv = c["k"], c["v"]
                     for i in range(bp):
                         cur_k = jax.lax.dynamic_slice(
-                            ck, (rc[i], off[i], 0, 0),
+                            ck, (rc[i], off[i], 0),
                             (1,) + kv[name]["k"].shape[1:])
                         cur_v = jax.lax.dynamic_slice(
-                            cv, (rc[i], off[i], 0, 0),
+                            cv, (rc[i], off[i], 0),
                             (1,) + kv[name]["v"].shape[1:])
                         new_k = jnp.where(valid[i], kv[name]["k"][i][None],
                                           cur_k)
                         new_v = jnp.where(valid[i], kv[name]["v"][i][None],
                                           cur_v)
                         ck = jax.lax.dynamic_update_slice(
-                            ck, new_k, (rc[i], off[i], 0, 0))
+                            ck, new_k, (rc[i], off[i], 0))
                         cv = jax.lax.dynamic_update_slice(
-                            cv, new_v, (rc[i], off[i], 0, 0))
+                            cv, new_v, (rc[i], off[i], 0))
                     caches[name] = {"k": ck, "v": cv}
                 at = lambda a, v: a.at[rows].set(v, mode="drop")  # noqa: E731
                 return dict(
@@ -776,13 +770,13 @@ class TransformerDecoder:
 
     # --- warmup -------------------------------------------------------------
     def _kv_struct(self, bp: int, tp: int):
-        """ShapeDtypeStruct pytree of a ``[bp, tp]`` per-layer kv block
-        (prefill output / prefix-page layout)."""
+        """ShapeDtypeStruct pytree of per-layer ``{"k", "v"}`` blocks of
+        ``bp`` rows by ``tp`` positions in cache layout (the caches
+        themselves, a prefill's output, prefix pages)."""
         sds = jax.ShapeDtypeStruct
         kv = {}
         for name, n_in in self._attn.items():
-            layer = self._layer(name)
-            shape = (bp, tp, layer.n_heads, layer._head_size(n_in))
+            shape = self._layer(name).kv_cache_shape(bp, tp, n_in)
             kv[name] = {"k": sds(shape, self._dtype),
                         "v": sds(shape, self._dtype)}
         return kv
@@ -889,15 +883,9 @@ class TransformerDecoder:
                 for s in self.kv_ladder:
                     if tp > s:
                         continue
-                    kv = {}
-                    for name, n_in in self._attn.items():
-                        layer = self._layer(name)
-                        shape = (bp, tp, layer.n_heads,
-                                 layer._head_size(n_in))
-                        kv[name] = {"k": row(shape, self._dtype),
-                                    "v": row(shape, self._dtype)}
                     self.join_fn(s, tp, bp).warm(
-                        self._struct_of(s), kv, row((bp,), jnp.int32),
+                        self._struct_of(s), self._kv_struct(bp, tp),
+                        row((bp,), jnp.int32),
                         row((bp,), jnp.int32), row((bp,), jnp.int32),
                         row((bp,), jnp.int32), row((bp,), jnp.int32),
                         row((bp,), jnp.float32), row((bp, 2), jnp.uint32),

@@ -64,65 +64,107 @@ def reference_attention(q, k, v, key_mask=None, causal=False, scale=None):
 # ---------------------------------------------------------------------------
 # KV-cached single-token decode (autoregressive serving)
 # ---------------------------------------------------------------------------
+#
+# THE cache layout, stated once: keys and values are stored
+# ``[batch, max_len, heads * head_dim]`` — a position's whole projection
+# (what ``x @ Wk`` produces, before any split into heads) in the minor
+# (lane) dimension, positions in the second-minor (sublane) one. Why:
+#
+# - The TPU tiles an array's two minor dimensions by (8, 128). Bucket
+#   lengths are powers of two and model widths multiples of 128, so the
+#   tiled cache is exactly its logical size; with ``[.., heads,
+#   head_dim]`` minor, 20 heads of 64 filled 64 of 128 lanes and 20 of
+#   24 sublanes and attention streamed 2.4 times the bytes.
+# - A token's write is one full-width sublane row, in place, and it is
+#   the layout XLA keeps the array in at rest, so a donated cache aliases
+#   from the executable's arguments through the fused decode loop to its
+#   results with no relayout copy on either side.
+# - The per-head products cannot reshape the lane dimension into
+#   ``[heads, head_dim]`` (that IS a relayout of the whole cache), so
+#   they run on the matrix unit against a block-diagonal operand built
+#   from the small side: scores ``K[s, :] @ Qb`` with ``Qb[(g, d), g'] =
+#   q[g, d]`` where ``g == g'`` and 0 elsewhere, output ``P^T @ V`` of
+#   which each head keeps its own ``head_dim`` columns. The zeros add
+#   exact zeros. ``Precision.HIGHEST`` keeps float32 operands float32
+#   (the matrix unit's default rounds them to bfloat16); the step is
+#   bound by reading the cache, not by the matrix unit, either way.
 
-def decode_attention(q, k_cache, v_cache, positions, scale=None):
-    """One decode step of causal attention against a preallocated KV
-    cache. ``q: [batch, heads, head_dim]`` is the new token's query,
-    ``k_cache/v_cache: [batch, max_len, heads, head_dim]`` hold every
-    previously-written key/value (including the new token's own, written
-    by the caller via ``dynamic_update_slice`` before this call), and
-    ``positions: [batch]`` is the cache slot the new token occupies —
-    slots ``0..positions[b]`` inclusive are attended, everything beyond
-    is masked to ``NEG_INF`` exactly like the padding mask in
-    :func:`reference_attention` (exp underflows to 0.0, so garbage in
-    unwritten slots can never leak into the output as long as it is
-    finite — zeros or stale keys from a retired sequence both qualify).
-
-    This is ``reference_attention`` math at ``Tq=1`` — the full [S]
-    score row per head, no online softmax — because a decode step's
-    score row is tiny and one fused softmax is the fastest shape for it.
-    """
-    sm = _scale(q, scale)
-    s = jnp.einsum("bhd,bshd->bhs", q, k_cache) * sm
-    live = jnp.arange(k_cache.shape[1])[None, :] <= positions[:, None]
-    s = jnp.where(live[:, None, :], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhs,bshd->bhd", p, v_cache)
+def _block_diagonal(q):
+    """``q: [batch, time, heads, head_dim]`` as the right-hand side of the
+    score product: ``[batch, heads * head_dim, time * heads]``, column
+    ``(t, g)`` holding query ``t``'s head ``g`` in rows
+    ``g * head_dim .. (g + 1) * head_dim`` and zeros in every other
+    head's rows."""
+    b, t, h, d = q.shape
+    eye = jnp.eye(h, dtype=q.dtype)
+    blocks = q[:, :, :, :, None] * eye[:, None, :]       # [b, t, h, d, g]
+    return jnp.transpose(blocks, (0, 2, 3, 1, 4)).reshape(b, h * d, t * h)
 
 
 def chunk_decode_attention(q, k_cache, v_cache, positions, scale=None):
     """A ``Tq``-token window of causal attention against a preallocated
-    KV cache — the speculative-verification generalization of
-    :func:`decode_attention`. ``q: [batch, time, heads, head_dim]`` holds
-    the window's queries; query ``i`` of row ``b`` sits at cache slot
-    ``positions[b] + i`` (its own k/v already written by the caller via
-    :func:`cache_update`), so it may attend slots
-    ``0 .. positions[b] + i`` inclusive and everything beyond is masked
-    to ``NEG_INF`` exactly like the single-token step. One wide launch
-    scores the whole drafted window — ``lax.scan``-free, which is the
-    entire point of ``spec_verify:s:k``: K+1 target positions for one
-    dispatch instead of K+1 sequential steps."""
+    KV cache. ``q: [batch, time, heads, head_dim]`` holds the window's
+    queries, ``k_cache/v_cache: [batch, max_len, heads * head_dim]``
+    (cache layout, above) every previously-written key/value, including
+    the window's own (written by the caller via :func:`cache_update`
+    before this call). Query ``i`` of row ``b`` sits at cache slot
+    ``positions[b] + i``, so it may attend slots ``0 .. positions[b] + i``
+    inclusive; everything beyond is masked to ``NEG_INF`` exactly like
+    the padding mask in :func:`reference_attention` (exp underflows to
+    0.0, so garbage in unwritten slots can never leak into the output as
+    long as it is finite — zeros or stale keys from a retired sequence
+    both qualify). One softmax over the whole row of scores, no online
+    softmax: the row is small and one fused pass is its fastest shape.
+
+    ``Tq = K + 1`` is the speculative verifier's window (``spec_verify``:
+    K+1 target positions for one dispatch instead of K+1 sequential
+    steps); ``Tq = 1`` is an ordinary decode step
+    (:func:`decode_attention`). Returns ``[batch, time, heads,
+    head_dim]``."""
+    b, t, h, d = q.shape
+    s = k_cache.shape[1]
     sm = _scale(q, scale)
-    s = jnp.einsum("bthd,bshd->bhts", q, k_cache) * sm
-    slot = jnp.arange(k_cache.shape[1])[None, None, :]
-    qpos = positions[:, None, None] + jnp.arange(q.shape[1])[None, :, None]
-    s = jnp.where((slot <= qpos)[:, None], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhts,bshd->bthd", p, v_cache)
+    scores = jnp.einsum("bse,bek->bsk", k_cache, _block_diagonal(q),
+                        precision=jax.lax.Precision.HIGHEST) * sm
+    slot = jnp.arange(s)[None, :, None]
+    qpos = positions[:, None, None] + jnp.arange(t)[None, None, :]
+    scores = jnp.where((slot <= qpos)[..., None],
+                       scores.reshape(b, s, t, h), NEG_INF)
+    p = jax.nn.softmax(scores, axis=1)
+    # [t * heads, heads * head_dim]: head g's output is columns
+    # g * head_dim .. of row (t, g); the other blocks are discarded
+    out = jnp.einsum("bsk,bse->bke", p.reshape(b, s, t * h), v_cache,
+                     precision=jax.lax.Precision.HIGHEST)
+    return jnp.einsum("btggd->btgd", out.reshape(b, t, h, h, d))
 
 
-def _paged_decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
+def decode_attention(q, k_cache, v_cache, positions, scale=None):
+    """One decode step of causal attention against the KV cache:
+    :func:`chunk_decode_attention` at ``Tq = 1``. ``q: [batch, heads,
+    head_dim]`` is the new token's query, ``positions: [batch]`` the
+    cache slot it occupies (its own k/v already written there); returns
+    ``[batch, heads, head_dim]``."""
+    return chunk_decode_attention(q[:, None], k_cache, v_cache, positions,
+                                  scale)[:, 0]
+
+
+def _paged_decode_kernel(pos_ref, qb_ref, own_ref, k_ref, v_ref, o_ref,
                          m_sc, l_sc, acc_sc, *, sm, page, npages):
     """Online-softmax decode over KV pages. Grid (batch, page); the page
-    dim is innermost/sequential so the [h, ·] scratch accumulates across
-    pages. ``pos_ref`` is scalar-prefetched: the kernel AND the index
-    maps read it before the body runs, so dead pages (wholly past
-    ``positions[b]``) skip both their DMA (index-map redirect to page 0,
-    same trick as the flash causal skip) and their compute
-    (``pl.when``) — O(used pages) work per row, not O(max_len)."""
+    dim is innermost/sequential so the scratch accumulates across pages.
+    ``pos_ref`` is scalar-prefetched: the kernel AND the index maps read
+    it before the body runs, so dead pages (wholly past ``positions[b]``)
+    skip both their DMA (index-map redirect to page 0, same trick as the
+    flash causal skip) and their compute (``pl.when``) — O(used pages)
+    work per row, not O(max_len). The two products are the block-diagonal
+    matrix products of :func:`chunk_decode_attention`, heads along the
+    lanes of every intermediate: ``qb_ref`` is the query as
+    :func:`_block_diagonal` lays it out, ``own_ref[(g, d), g']`` is 1
+    where ``g == g'``."""
     b = pl.program_id(0)
     j = pl.program_id(1)
     pos = pos_ref[b]
+    highest = jax.lax.Precision.HIGHEST
 
     @pl.when(j == 0)
     def _init():
@@ -132,34 +174,31 @@ def _paged_decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(j * page <= pos)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)           # [h, d]
-        k = k_ref[0].astype(jnp.float32)           # [page, h, d]
-        v = v_ref[0].astype(jnp.float32)           # [page, h, d]
-        s = jnp.sum(q[None] * k, axis=2).T * sm    # [h, page]
+        k = k_ref[0].astype(jnp.float32)           # [page, e]
+        v = v_ref[0].astype(jnp.float32)           # [page, e]
+        s = jnp.dot(k, qb_ref[0].astype(jnp.float32), precision=highest,
+                    preferred_element_type=jnp.float32) * sm  # [page, h]
         # boundary page: slots past positions[b] masked exactly like the
         # masked full-cache read (exp underflows to 0.0 — garbage in
         # unwritten slots can never leak)
-        slot = j * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
+        slot = j * page + jax.lax.broadcasted_iota(jnp.int32, (page, 1), 0)
         s = jnp.where(slot <= pos, s, NEG_INF)
-        m_prev, l_prev = m_sc[...], l_sc[...]
-        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])
-        p = jnp.exp(s - _rep(m_next, page))
+        m_prev, l_prev = m_sc[...], l_sc[...]      # [1, h]
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+        p = jnp.exp(s - m_next)
         alpha = jnp.exp(m_prev - m_next)
-        l_corr = alpha * l_prev
-        l_next = jnp.sum(p, axis=1)[:, None] + l_corr
         m_sc[...] = m_next
-        l_sc[...] = l_next
-        # pre-normalized accumulator (flash-kernel convention): rescale
-        # by 1/l every step so the final store is a cast
-        l_inv = jnp.where(l_next == 0.0, 1.0, 1.0 / l_next)
-        d = acc_sc.shape[1]
-        acc_sc[...] *= _rep(l_corr * l_inv, d)
-        pv = jnp.sum(p.T[:, :, None] * v, axis=0)  # [h, d]
-        acc_sc[...] += pv * _rep(l_inv, d)
+        l_sc[...] = alpha * l_prev + jnp.sum(p, axis=0, keepdims=True)
+        acc_sc[...] = acc_sc[...] * alpha + jnp.dot(
+            v.T, p, precision=highest,
+            preferred_element_type=jnp.float32)    # [e, h]
 
     @pl.when(j == npages - 1)
     def _store():
-        o_ref[0] = acc_sc[...].astype(o_ref.dtype)
+        l = l_sc[...]
+        l_inv = jnp.where(l == 0.0, 1.0, 1.0 / l)
+        o_ref[0] = jnp.sum(acc_sc[...] * l_inv * own_ref[...], axis=1,
+                           keepdims=True).astype(o_ref.dtype)   # [e, 1]
 
 
 def paged_decode_attention(q, k_cache, v_cache, positions, scale=None,
@@ -171,7 +210,7 @@ def paged_decode_attention(q, k_cache, v_cache, positions, scale=None,
     level (scalar-prefetched positions drive the index map), and the
     boundary page masks per-slot. Same signature and semantics as the
     masked full-cache read — ``q: [batch, heads, head_dim]``,
-    ``k_cache/v_cache: [batch, max_len, heads, head_dim]``,
+    ``k_cache/v_cache: [batch, max_len, heads * head_dim]``,
     ``positions: [batch]`` — and bitwise the same masking rule, so the
     parity tests pin it directly against :func:`decode_attention`.
 
@@ -180,59 +219,67 @@ def paged_decode_attention(q, k_cache, v_cache, positions, scale=None,
     ``interpret=None`` auto-enables the Pallas interpreter off-TPU."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    b, s, h, d = k_cache.shape
+    b, h, d = q.shape
+    s, e = k_cache.shape[1:]
     page = min(int(page), s)
     if s % page:
         raise ValueError(f"page {page} must divide cache length {s}")
     npages = s // page
     sm = _scale(q, scale)
     pos = positions.astype(jnp.int32)
+    own = jnp.repeat(jnp.eye(h, dtype=jnp.float32), d, axis=0)   # [e, h]
 
     def q_map(b_, j, p):
         return (b_, 0, 0)
 
     def kv_map(b_, j, p):
         live = j * page <= p[b_]
-        return (b_, jax.lax.select(live, j, 0), 0, 0)
+        return (b_, jax.lax.select(live, j, 0), 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b, npages),
-        in_specs=[pl.BlockSpec((1, h, d), q_map),
-                  pl.BlockSpec((1, page, h, d), kv_map),
-                  pl.BlockSpec((1, page, h, d), kv_map)],
-        out_specs=pl.BlockSpec((1, h, d), q_map),
-        scratch_shapes=[pltpu.VMEM((h, _LANES), jnp.float32),
-                        pltpu.VMEM((h, _LANES), jnp.float32),
-                        pltpu.VMEM((h, d), jnp.float32)],
+        in_specs=[pl.BlockSpec((1, e, h), q_map),
+                  pl.BlockSpec((e, h), lambda b_, j, p: (0, 0)),
+                  pl.BlockSpec((1, page, e), kv_map),
+                  pl.BlockSpec((1, page, e), kv_map)],
+        out_specs=pl.BlockSpec((1, e, 1), q_map),
+        scratch_shapes=[pltpu.VMEM((1, h), jnp.float32),
+                        pltpu.VMEM((1, h), jnp.float32),
+                        pltpu.VMEM((e, h), jnp.float32)],
     )
     params = None
     if not interpret:
         params = pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"))
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, sm=sm, page=page,
                           npages=npages),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, e, 1), q.dtype),
         compiler_params=params,
         interpret=interpret,
-    )(pos, q, k_cache, v_cache)
+    )(pos, _block_diagonal(q[:, None]), own, k_cache, v_cache)
+    return out.reshape(b, h, d)
 
 
 def cache_update(cache, new, positions):
-    """Write a token block ``new: [batch, t, heads, head_dim]`` (t = 1
+    """Write a token block ``new: [batch, t, heads * head_dim]`` (t = 1
     for ordinary decode, t = K+1 for a speculative verify window) into
-    ``cache: [batch, max_len, heads, head_dim]`` at per-sequence slot
-    ``positions: [batch]`` via a vmapped ``dynamic_update_slice`` (the
-    slot index is traced, so one executable serves every position).
+    ``cache: [batch, max_len, heads * head_dim]`` at per-sequence slot
+    ``positions: [batch]``: one ``dynamic_update_slice`` a row (the slot
+    index is traced, so one executable serves every position), ``t``
+    whole sublane rows each, in place. Written out row by row and NOT as
+    one vmapped update: that lowers to a scatter, which XLA expands into
+    a ``while`` over the rows and, with the cache at its logical size,
+    stages each whole cache through VMEM and back every step to run it.
     Out-of-range positions clamp to the last slot (``dynamic_update_slice``
     semantics) — harmless by construction: only retired rows ever sit at
     a position that high, and their slots are never attended."""
-    def write(c, n, p):
-        return jax.lax.dynamic_update_slice(c, n, (p, 0, 0))
-
-    return jax.vmap(write)(cache, new, positions)
+    for i in range(cache.shape[0]):
+        cache = jax.lax.dynamic_update_slice(cache, new[i:i + 1],
+                                             (i, positions[i], 0))
+    return cache
 
 
 # ---------------------------------------------------------------------------

@@ -766,7 +766,7 @@ class GenerationEngine:
     def _insert_pages(self, joins, kv, offset):
         """Donate a prefill launch's KV to the prefix cache: full pages
         of each request's prompt that the trie lacks. ``kv`` is the
-        device block ``[bp, t, heads, hd]`` per layer; ``offset`` is 0
+        device block ``[bp, t, heads * hd]`` per layer; ``offset`` is 0
         for a cold prefill or ``"prefix"`` when ``kv`` holds only the
         suffix (page starts shift down by the row's prefix length — the
         prefix portion is already in the tree and pinned, so the slicer

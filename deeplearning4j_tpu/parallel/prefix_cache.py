@@ -4,7 +4,8 @@ Shared-prefix serving traffic (few-shot templates, system prompts, chat
 history) re-prefills the same prompt head for every request.  This module
 keeps a token-keyed radix tree whose nodes own **pages** — fixed-size
 blocks of per-layer KV activations captured from a finished prefill, held
-host-side as numpy so device buffers stay donation-friendly.  A new
+host-side as numpy in the device's cache layout (``[tokens, heads *
+head_dim]``) so device buffers stay donation-friendly.  A new
 request walks the tree under the lock, pins the longest cached prefix
 (whole-path refcount increment), and only its suffix is prefilled; the
 engine scatters the pinned pages into the joining row's cache with the
@@ -40,7 +41,7 @@ class _Node:
 
     def __init__(self, key, kv, parent):
         self.key = key            # tuple of page_tokens token ids
-        self.kv = kv              # {layer: {"k": np[t,h,d], "v": ...}}
+        self.kv = kv              # {layer: {"k": np[t, h*d], "v": ...}}
         self.children = {}        # key tuple -> _Node
         self.parent = parent
         self.refs = 0
@@ -194,7 +195,7 @@ class PrefixCache:
     def assemble(self, nodes, width):
         """Concatenate a pinned path's pages into per-layer host KV
         blocks zero-padded to ``width`` tokens (the engine's padded
-        ``tpre`` bucket).  Returns {layer: {"k": np[width,h,d], ...}}."""
+        ``tpre`` bucket).  Returns {layer: {"k": np[width, h*d], ...}}."""
         if not nodes:
             raise ValueError("assemble needs a non-empty node path")
         out = {}

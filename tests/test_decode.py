@@ -597,8 +597,9 @@ def test_prefix_cache_radix_unit():
 
     def slicer(start, stop):
         made.append((start, stop))
-        return {"l": {"k": np.full((stop - start, 2, 4), float(start)),
-                      "v": np.full((stop - start, 2, 4), float(start))}}
+        # pages are host copies in cache layout: [tokens, heads * head_dim]
+        return {"l": {"k": np.full((stop - start, 8), float(start)),
+                      "v": np.full((stop - start, 8), float(start))}}
 
     pc = PrefixCache(page_tokens=4, max_pages=2)
     toks = list(range(12))
@@ -608,7 +609,10 @@ def test_prefix_cache_radix_unit():
     pc.release(path)
     m, nodes = pc.match(toks, limit=11)          # page-aligned, <= limit
     assert m == 8 and len(nodes) == 2
-    assert nodes[0].kv["l"]["k"][0, 0, 0] == 0.0
+    assert nodes[0].kv["l"]["k"][0, 0] == 0.0
+    blk = pc.assemble(nodes, 16)["l"]            # pages end to end, padded
+    assert blk["k"].shape == (16, 8)
+    assert blk["v"][:, 3].tolist() == [0.0] * 4 + [4.0] * 4 + [0.0] * 8
     m2, nodes2 = pc.match(toks, limit=11, fits=lambda mm: mm <= 4)
     assert m2 == 4 and len(nodes2) == 1          # fits() backs off a page
     pc.release(nodes + nodes2)

@@ -1,0 +1,285 @@
+"""The KV cache's layout, ``[batch, positions, heads * head_dim]``: the
+three functions that read and write it against the oracle, the arithmetic
+of the TPU's (8, 128) tiles, the decode executables' jaxprs (nothing
+cache-sized but the write and the two products; the caches donated), and
+the compiled decode window for a v5e (no relayout copy outside the loop,
+one layout at rest and in the loop, the cache read by the two products and
+by nothing else) with no chip attached.
+
+The ahead-of-time compiles load the TPU's compiler: they stay in THIS file,
+and the topology is described inside a fixture, never at import.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deeplearning4j_tpu.ops.attention import (
+    cache_update,
+    chunk_decode_attention,
+    decode_attention,
+    reference_attention,
+)
+from deeplearning4j_tpu.zoo.graphs import TransformerEncoder
+
+pytestmark = pytest.mark.decode
+
+# (heads, head size): the benchmark's GPT-2-large, chip_smoke.py's
+# GPT-2-small widths, a model with heads of 128, a toy
+GEOMETRIES = [(20, 64), (12, 64), (8, 128), (4, 32)]
+
+
+# --- the three functions against the oracle ---------------------------------
+
+@pytest.mark.parametrize("tq", [1, 5])
+@pytest.mark.parametrize("heads,hs", GEOMETRIES)
+def test_cache_ops_match_reference(heads, hs, tq):
+    """Write a ``tq``-token block at per-row positions into a cache full
+    of stale values, attend, and compare each row with
+    ``reference_attention`` over exactly the slots that row may see."""
+    b, s = 3, 64
+    rng = np.random.default_rng(heads * 1000 + hs + tq)
+    positions = np.asarray([0, 17, s - tq], np.int32)
+    e = heads * hs
+    stale_k = rng.normal(size=(b, s, e)).astype(np.float32) * 3.0
+    stale_v = rng.normal(size=(b, s, e)).astype(np.float32) * 3.0
+    q = rng.normal(size=(b, tq, heads, hs)).astype(np.float32)
+    k_new = rng.normal(size=(b, tq, e)).astype(np.float32)
+    v_new = rng.normal(size=(b, tq, e)).astype(np.float32)
+
+    kc = cache_update(jnp.asarray(stale_k), jnp.asarray(k_new), positions)
+    vc = cache_update(jnp.asarray(stale_v), jnp.asarray(v_new), positions)
+    assert kc.shape == (b, s, e)
+    for i, p in enumerate(positions):
+        want = stale_k[i].copy()
+        want[p:p + tq] = k_new[i]
+        np.testing.assert_array_equal(np.asarray(kc[i]), want)
+
+    if tq == 1:
+        got = decode_attention(jnp.asarray(q[:, 0]), kc, vc, positions)
+        got = np.asarray(got)[:, None]
+    else:
+        got = np.asarray(chunk_decode_attention(jnp.asarray(q), kc, vc,
+                                                positions))
+    for i, p in enumerate(positions):
+        live = p + tq  # slots 0 .. p + tq - 1; query j sees 0 .. p + j
+        heads_first = lambda c: jnp.swapaxes(  # noqa: E731
+            c[i:i + 1, :live].reshape(1, live, heads, hs), 1, 2)
+        kh, vh = heads_first(kc), heads_first(vc)           # [1, h, t, d]
+        qh = jnp.swapaxes(jnp.asarray(q[i:i + 1]), 1, 2)    # [1, h, tq, d]
+        want = reference_attention(qh, kh, vh, causal=True)
+        np.testing.assert_allclose(
+            got[i], np.asarray(jnp.swapaxes(want, 1, 2))[0],
+            rtol=2e-5, atol=2e-5)
+
+
+# --- tiles ---------------------------------------------------------------------
+
+def _tiled_elems(shape, tile=(8, 128)):
+    """Elements an array occupies when its two minor dimensions are tiled
+    by ``tile`` (the TPU's layout for 32-bit types)."""
+    up = lambda n, t: -(-n // t) * t  # noqa: E731
+    lead = int(np.prod(shape[:-2], dtype=np.int64))
+    return lead * up(shape[-2], tile[0]) * up(shape[-1], tile[1])
+
+
+@pytest.mark.parametrize("heads,hs", GEOMETRIES)
+def test_cache_shape_fills_its_tiles(heads, hs):
+    """At the cell's batch and bucket the cache's tiled size IS its
+    logical size for every model width that is a multiple of 128; the
+    layout it replaced (``[.., heads, head_dim]`` minor) padded every
+    geometry but heads of 128 in multiples of 8."""
+    from deeplearning4j_tpu.conf.layers_attention import SelfAttentionLayer
+
+    layer = SelfAttentionLayer(n_out=heads * hs, n_heads=heads, causal=True)
+    shape = layer.kv_cache_shape(8, 1024, heads * hs)
+    assert shape == (8, 1024, heads * hs)
+    assert _tiled_elems(shape) == int(np.prod(shape))
+    old = _tiled_elems((8, 1024, heads, hs)) / np.prod(shape)
+    assert old == {(20, 64): 2.4, (12, 64): 8 / 3, (8, 128): 1.0,
+                   (4, 32): 8.0}[(heads, hs)]
+
+
+# --- jaxprs ----------------------------------------------------------------------
+
+def _tiny_decoder():
+    m = TransformerEncoder(vocab_size=48, embed_dim=16, n_heads=2,
+                           n_layers=2, max_len=32, causal=True,
+                           lm_head=True, seed=3)
+    return m.decoder(max_batch=4, kv_bucket_min=32, prompt_bucket_min=8)
+
+
+def _walk(jaxpr, in_scan=False):
+    """Every equation at every depth, with whether a ``scan`` encloses it."""
+    for eqn in jaxpr.eqns:
+        yield eqn, in_scan
+        inner = in_scan or eqn.primitive.name == "scan"
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (list, tuple)) else [val]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _walk(sub, inner)
+
+
+_WRAPPERS = {"pjit", "jit", "closed_call", "core_call", "custom_jvp_call",
+             "custom_vjp_call", "scan"}
+
+
+def _cache_sized_primitives(fn, args, n_cache):
+    """``(outside, inside)``: names of the primitives with an operand or a
+    result of at least ``n_cache`` elements, outside and inside a scan."""
+    found = (set(), set())
+    for eqn, in_scan in _walk(jax.make_jaxpr(fn)(*args).jaxpr):
+        if eqn.primitive.name in _WRAPPERS:
+            continue
+        avals = [v.aval for v in list(eqn.invars) + list(eqn.outvars)
+                 if hasattr(v.aval, "shape")]
+        if any(int(np.prod(a.shape)) >= n_cache for a in avals):
+            found[in_scan].add(eqn.primitive.name)
+    return found
+
+
+def _executables():
+    dec = _tiny_decoder()
+    b, s = dec.max_batch, 32
+    st = dec._struct_of(s)
+    sds = jax.ShapeDtypeStruct
+    params = jax.tree_util.tree_map(lambda x: sds(x.shape, x.dtype),
+                                    dec.params)
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    row = (i32(b), i32(b), i32(b), sds((b,), jnp.float32),
+           sds((b, 2), jnp.uint32), sds((b,), jnp.bool_))
+    # name -> (executable, arguments, index of the donated state,
+    #          primitives that may touch something cache-sized
+    #          outside a scan, and inside one)
+    write_read = {"dynamic_update_slice", "dot_general"}
+    return dec, {
+        "decode": (dec.decode_fn(s, 4), (params, st), 1, set(), write_read),
+        "spec_draft": (dec.spec_draft_fn(s, 4),
+                       (params, st, i32(b), i32(b), sds((b,), jnp.bool_)),
+                       1, set(), write_read),
+        "spec_verify": (dec.spec_verify_fn(s, 4), (params, st, i32(4, b)),
+                        1, write_read, set()),
+        "join": (dec.join_fn(s, 8, 1),
+                 (st, dec._kv_struct(1, 8), i32(1), i32(1)) + tuple(
+                     sds((1,) + r.shape[1:], r.dtype) for r in row),
+                 0, {"scatter"}, set()),
+        "prefix_attach": (dec.prefix_attach_fn(s, 8, 1),
+                          (st, dec._kv_struct(1, 8), i32(1), i32(1)),
+                          0, {"scatter"}, set()),
+        "suffix_join": (dec.suffix_join_fn(s, 8, 1),
+                        (st, dec._kv_struct(1, 8), i32(1), i32(1), i32(1))
+                        + tuple(sds((1,) + r.shape[1:], r.dtype)
+                                for r in row),
+                        0, {"dynamic_slice", "dynamic_update_slice"}, set()),
+        "grow": (dec.grow_fn(16, s), (dec._struct_of(16),), None,
+                 {"pad"}, set()),
+    }
+
+
+@pytest.mark.parametrize("name", ["decode", "spec_draft", "spec_verify",
+                                  "join", "prefix_attach", "suffix_join",
+                                  "grow"])
+def test_executables_touch_the_cache_only_to_write_and_read_it(name):
+    """No ``transpose``/``reshape``/``copy``/``select_n`` of anything
+    cache-sized in any decoder executable: outside the decode window's
+    scan NOTHING touches a cache, inside it only the token write and the
+    two products do; the joins scatter, the bucket hop pads. And every
+    executable that takes the state to rewrite it has it donated."""
+    dec, table = _executables()
+    step, args, donated, outside_ok, inside_ok = table[name]
+    n_cache = int(np.prod(dec._layer(next(iter(dec._attn))).kv_cache_shape(
+        dec.max_batch, 16 if name == "grow" else 32, 16)))
+    outside, inside = _cache_sized_primitives(step.jit_fn, args, n_cache)
+    assert outside <= outside_ok, (name, outside)
+    assert inside <= inside_ok, (name, inside)
+    if name in ("decode", "spec_draft", "spec_verify"):
+        # both products and the write are really there to be seen
+        seen = inside if inside_ok else outside
+        assert seen == {"dot_general", "dynamic_update_slice"}
+    if donated is not None:
+        info = step.jit_fn.lower(*args).args_info[0][donated]
+        leaves = jax.tree_util.tree_leaves(info["caches"])
+        assert leaves and all(leaf.donated for leaf in leaves)
+
+
+# --- the compiled decode window, for a v5e, without one ----------------------
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu, or it cannot describe the topology
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+_PLUMBING = {"parameter", "get-tuple-element", "tuple", "bitcast", "while",
+             # the compiler's own moves of a whole buffer between HBM and
+             # VMEM for the loop: same layout, another memory space
+             "copy-start", "copy-done", "slice-start", "slice-done"}
+
+
+@pytest.mark.parametrize("heads,hs", GEOMETRIES[:3])
+def test_compiled_decode_window_keeps_one_cache_layout(one_chip, heads, hs):
+    """``decode_fn(1024, 4)`` of a two-layer decoder at the cell's batch,
+    bucket and K, compiled by the TPU's own compiler: every cache, at
+    rest (the entry parameters), in the ``while`` and in the results, has
+    ONE tiled layout, row-major over ``[b, s, h * d]`` with (8, 128)
+    tiles (which that shape fills exactly); ``ENTRY`` holds no operation
+    with a cache-sized result but plumbing, so the donated caches alias
+    straight through the loop; in the loop a cache is written by
+    ``dynamic-update-slice`` alone (no cache-sized ``copy``, no staging
+    through VMEM and back); and the program's temporaries are smaller
+    than two caches (the layout this replaced held a padded copy of every
+    cache: 370 MB here, 8.9 GB for the benchmark's 36 layers)."""
+    b, s, k = 8, 1024, 4
+    m = TransformerEncoder(vocab_size=256, embed_dim=heads * hs,
+                           n_heads=heads, n_layers=2, max_len=s, causal=True,
+                           lm_head=True, seed=0)
+    dec = m.decoder(max_batch=b, kv_bucket_min=s, prompt_bucket_min=128)
+    sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,  # noqa: E731
+                                         sharding=one_chip)
+    params = jax.tree_util.tree_map(sds, dec.params)
+    state = jax.tree_util.tree_map(sds, dec._struct_of(s))
+    compiled = dec.decode_fn(s, k).jit_fn.trace(params, state).lower(
+        lowering_platforms=("tpu",)).compile()
+    txt = compiled.as_text()
+
+    dims = f"{b},{s},{heads * hs}"
+    layouts = {re.sub(r"S\(\d+\)", "", lay) for lay in re.findall(
+        r"f32\[" + dims + r"\](\{[^}]*\})", txt)}
+    assert layouts == {"{2,1,0:T(8,128)}"}, layouts
+
+    entry = txt[txt.index("\nENTRY "):]
+    offenders = []
+    for line in entry.splitlines():
+        mo = re.match(r"\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(.*?)\s([\w\-]+)\(",
+                      line)
+        if not mo or f"[{dims}]" not in mo.group(1):
+            continue
+        op = mo.group(2)
+        if op == "custom-call" and "ConcatBitcast" in line:
+            continue  # reassembles slice-start/-done pieces, moves nothing
+        if op not in _PLUMBING:
+            offenders.append(line.strip()[:200])
+    assert not offenders, offenders
+
+    produced = set(re.findall(
+        r"=\s*f32\[" + dims + r"\]\{[^}]*\}\s+([\w\-]+)\(", txt))
+    assert produced <= _PLUMBING | {"dynamic-update-slice", "custom-call",
+                                    "fusion"}, produced
+    fused_roots = set(re.findall(
+        r"ROOT\s+%?[\w.\-]+\s*=\s*f32\[" + dims
+        + r"\]\{[^}]*\}\s+([\w\-]+)\(", txt))
+    assert fused_roots <= {"dynamic-update-slice", "bitcast"}, fused_roots
+
+    cache_bytes = 4 * b * heads * hs * s
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * cache_bytes
