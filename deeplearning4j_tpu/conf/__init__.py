@@ -13,6 +13,7 @@ from deeplearning4j_tpu.conf.weights import WeightInit
 # import layer/loss/updater modules for their serde tag registrations, so
 # from_json works regardless of which entry point the user imported first
 from deeplearning4j_tpu.conf import (  # noqa: E402,F401
-    layers, layers_attention, layers_cnn, layers_extra, layers_objdetect,
+    layers, layers_attention, layers_cnn, layers_extra, layers_hybrid,
+    layers_objdetect,
     layers_quant, layers_rnn, losses, regularization, schedules, updaters,
 )

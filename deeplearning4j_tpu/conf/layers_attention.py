@@ -273,6 +273,38 @@ class SelfAttentionLayer(BaseLayer):
         return (self.activation.apply(y),
                 {"k": k_cache, "v": v_cache})
 
+    # --- the per-layer cache interface (nn.decoding walks it; the other
+    # layer kinds and the contract: conf/layers_hybrid.py) ----------------
+    cache_kinds = {"k": "kv", "v": "kv"}
+
+    def cache_init(self, batch, length, n_in, dtype=jnp.float32):
+        return self.init_kv_cache(batch, length, n_in, dtype)
+
+    def cache_prefill(self, params, x, key_mask=None, dtype=None,
+                      use_kernels=False):
+        y, k, v = self.prefill(params, x, key_mask, use_kernels=use_kernels)
+        return y, {"k": k, "v": v}
+
+    def cache_join(self, cache, block, rows, length):
+        """Prefilled rows written whole: the block padded to the bucket,
+        rows past the cache dropped."""
+        pad = ((0, 0), (0, length - block["k"].shape[1]), (0, 0))
+        return {n: cache[n].at[rows].set(jnp.pad(block[n], pad), mode="drop")
+                for n in ("k", "v")}
+
+    def cache_step(self, params, x, cache, positions, active=None,
+                   use_kernels=False):
+        y, cache = self.decode_step(params, x, cache, positions,
+                                    use_kernels=use_kernels)
+        return y, cache, {}
+
+    def cache_grow(self, cache, length):
+        pad = ((0, 0), (0, length - cache["k"].shape[1]), (0, 0))
+        return {n: jnp.pad(cache[n], pad) for n in ("k", "v")}
+
+    def cache_release(self, cache, keep):
+        return cache
+
     def prefill_suffix(self, params, x, prefix_k, prefix_v, prefix_mask,
                        key_mask=None, use_kernels=False):
         """Prompt-suffix prefill against an already-projected prefix —

@@ -32,6 +32,17 @@ dominate it); the PRG201 donation audit covers the ``decode_step*`` /
 ``prefill*`` kinds, so a regression that silently copies the cache every
 token is a lint ERROR, not a memory mystery.
 
+Per-row state is whatever the graph's layers keep: every layer with the
+cache interface of ``conf/layers_hybrid.py`` (``cache_init``,
+``cache_prefill``, ``cache_join``, ``cache_step``, ``cache_grow``,
+``cache_release``) is served: KV buffers (``SelfAttentionLayer``), KV
+buffers beside compressed keys (``BlockSparseAttentionLayer``), a
+fixed-size recurrent state (``LightningAttentionLayer``), side by side in
+the one donated state pytree, each in its own type. The chunk and suffix
+walks (speculation, the prefix cache) need ``decode_chunk`` /
+``prefill_suffix`` on every such layer and refuse a graph that has a
+layer without them, by name.
+
 Scheduling on top of this lives in ``parallel.generation`` — this module
 is the pure model path plus :meth:`TransformerDecoder.generate`, the
 sequential one-request-at-a-time reference the continuous-batching
@@ -131,13 +142,18 @@ class TransformerDecoder:
 
     def __init__(self, net, max_batch: int = 8, max_len: Optional[int] = None,
                  kv_bucket_min: int = 32, prompt_bucket_min: int = 8,
-                 pad_id: int = 0):
+                 pad_id: int = 0, cache_dtype=None,
+                 join_bucket_max: Optional[int] = None):
         self._net = net
         if net.params is None:
             net.init()
         self.max_batch = int(max_batch)
         self.pad_id = int(pad_id)
         self._dtype = net._dtype
+        # the serving configuration's type for the caches; a layer's own
+        # cache_dtype / state_dtype wins over it (conf/layers_hybrid.py)
+        self._cache_dtype = (jnp.dtype(cache_dtype) if cache_dtype
+                             else self._dtype)
         self._fns: Dict[tuple, object] = {}
         self.use_kernels = bool(getattr(net.conf, "use_kernels", False))
         conf = net.conf
@@ -147,22 +163,30 @@ class TransformerDecoder:
         self._input = conf.network_inputs[0]
         types = conf.vertex_output_types()
         self._plan = []
-        self._attn: Dict[str, int] = {}  # name -> n_in (cache head dims)
+        # every layer that keeps per-row state (the cache interface of
+        # conf/layers_hybrid.py): name -> n_in; _attn the KV-cached
+        # SelfAttentionLayers among them (kernels/routing.py tunes those)
+        self._cached: Dict[str, int] = {}
+        self._attn: Dict[str, int] = {}
         derived_max = None
         reject = _reject_types()
         for name in net._topo:
             spec = net._vmap[name]
             layer = getattr(spec.vertex, "layer", None)
-            if isinstance(layer, reject) or getattr(
-                    spec.vertex, "has_carry", False):
+            cached = hasattr(layer, "cache_step")
+            if isinstance(layer, reject) or (getattr(
+                    spec.vertex, "has_carry", False) and not cached):
                 raise ValueError(
                     f"vertex {name!r} ({type(layer or spec.vertex).__name__})"
                     " is not supported in the KV-cached decode path")
-            if isinstance(layer, SelfAttentionLayer):
-                layer._decode_check()  # causal + projected, or raise
+            if cached:
+                if isinstance(layer, SelfAttentionLayer):
+                    layer._decode_check()  # causal + projected, or raise
                 src_t = types[spec.inputs[0]] if spec.inputs[0] in types \
                     else conf.input_types[0]
-                self._attn[name] = src_t.size
+                self._cached[name] = src_t.size
+                if isinstance(layer, SelfAttentionLayer):
+                    self._attn[name] = src_t.size
                 kind = "attn"
             elif isinstance(layer, PositionEmbeddingLayer):
                 derived_max = layer.max_len if derived_max is None \
@@ -176,9 +200,10 @@ class TransformerDecoder:
             else:
                 kind = "gen"
             self._plan.append((kind, name, spec))
-        if not self._attn:
-            raise ValueError("graph has no causal SelfAttentionLayer — "
-                             "nothing to KV-cache")
+        if not self._cached:
+            raise ValueError("graph has no causal SelfAttentionLayer (or "
+                             "other layer with a decode cache) — nothing "
+                             "to KV-cache")
         first = self._plan[0]
         if not (first[2].inputs == [self._input] or
                 tuple(first[2].inputs) == (self._input,)) or \
@@ -199,7 +224,13 @@ class TransformerDecoder:
                                      self.max_len)
         self.prompt_ladder = pow2_ladder(min(prompt_bucket_min, self.max_len),
                                          self.max_len)
-        self.join_ladder = pow2_ladder(1, self.max_batch)
+        self.join_ladder = pow2_ladder(
+            1, min(int(join_bucket_max or self.max_batch), self.max_batch))
+        # per-row counts the cached layers report at a step (summed over
+        # the active rows of a decode window, returned beside its tokens)
+        self.counter_names = sorted({
+            k for name in self._cached
+            for k in getattr(self._layer(name), "cache_counters", ())})
         # any decode-state entry for a planned vertex would be silently
         # frozen at its init value — refuse rather than mis-serve
         stateful = [n for _, n, _ in self._plan if net.state.get(n)]
@@ -212,10 +243,9 @@ class TransformerDecoder:
         """Fresh device-resident decode state at KV bucket ``s``: zeroed
         caches + per-row scheduler arrays (all rows inactive)."""
         b = self.max_batch
-        caches = {}
-        for name, n_in in self._attn.items():
-            layer = self._layer(name)
-            caches[name] = layer.init_kv_cache(b, s, n_in, self._dtype)
+        caches = {name: self._layer(name).cache_init(b, s, n_in,
+                                                     self._cache_dtype)
+                  for name, n_in in self._cached.items()}
         return {
             "caches": caches,
             "tokens": jnp.zeros((b,), jnp.int32),
@@ -249,6 +279,34 @@ class TransformerDecoder:
     def _layer(self, name):
         return self._net._vmap[name].vertex.layer
 
+    def state_bytes(self, s: int) -> Dict[str, int]:
+        """Bytes the caches hold at KV bucket ``s``, by kind of state
+        (``kv``, ``compressed_keys``, ``recurrent``): what each layer's
+        ``cache_kinds`` calls its leaves."""
+        out: Dict[str, int] = {}
+        for name, leaves in self._kv_struct(self.max_batch, s).items():
+            kinds = self._layer(name).cache_kinds
+            for leaf, a in leaves.items():
+                out[kinds[leaf]] = out.get(kinds[leaf], 0) + int(
+                    np.prod(a.shape)) * a.dtype.itemsize
+        return out
+
+    def walks_missing(self, method: str) -> List[str]:
+        """The cached vertices whose layer lacks ``method`` (``decode_chunk``:
+        the speculative verify walk; ``prefill_suffix``: the prefix-cache
+        walk), as ``name (LayerType)``."""
+        return [f"{name!r} ({type(self._layer(name)).__name__})"
+                for name in self._cached
+                if not hasattr(self._layer(name), method)]
+
+    def _need(self, method: str, walk: str):
+        missing = self.walks_missing(method)
+        if missing:
+            raise NotImplementedError(
+                f"{walk} needs {method}() on every cached layer; "
+                f"{', '.join(missing)} keep state it cannot rebuild from "
+                f"K/V pages or roll back by a cursor")
+
     def _graph_key(self):
         return self._net._graph_key()
 
@@ -274,18 +332,23 @@ class TransformerDecoder:
         return self._net.params
 
     # --- pure model walks ---------------------------------------------------
-    def _run_token(self, params, tokens, positions, caches):
+    def _run_token(self, params, tokens, positions, caches, active=None):
         """One token through the graph against the caches:
-        ``tokens [B] int32`` → (vocab logits ``[B, V]``, new caches)."""
+        ``tokens [B] int32`` → (vocab logits ``[B, V]``, new caches,
+        the layers' counts ``{name: [B] int32}`` summed over the
+        layers)."""
         acts = {self._input: tokens}
         caches = dict(caches)
         logits = None
+        counts: Dict[str, object] = {}
         for kind, name, spec in self._plan:
             xs = [acts[src] for src in spec.inputs]
             if kind == "attn":
-                y, caches[name] = self._layer(name).decode_step(
+                y, caches[name], own = self._layer(name).cache_step(
                     params[name], xs[0], caches[name], positions,
-                    use_kernels=self.use_kernels)
+                    active=active, use_kernels=self.use_kernels)
+                for k, v in own.items():
+                    counts[k] = counts[k] + v if k in counts else v
             elif kind == "pos":
                 y = xs[0] + params[name]["P"][positions]
             elif kind == "head":
@@ -295,7 +358,7 @@ class TransformerDecoder:
                 y, _ = spec.vertex.forward(params.get(name, {}), {}, xs,
                                            train=False, rng=None)
             acts[name] = y
-        return logits, caches
+        return logits, caches, counts
 
     def _run_prompt(self, params, prompts, lengths):
         """Whole-prompt prefill walk: ``prompts [Bp, Tp] int32`` →
@@ -310,14 +373,16 @@ class TransformerDecoder:
         for kind, name, spec in self._plan:
             xs = [acts[src] for src in spec.inputs]
             if kind == "attn":
-                y, k, v = self._layer(name).prefill(
-                    params[name], xs[0], key_mask,
+                y, kv[name] = self._layer(name).cache_prefill(
+                    params[name], xs[0], key_mask, dtype=self._cache_dtype,
                     use_kernels=self.use_kernels)
-                kv[name] = {"k": k, "v": v}
             elif kind == "head":
-                full = self._layer(name).pre_output(params[name], xs[0])
+                # the head over the last valid position alone: a whole
+                # [Tp, vocab] of logits is never needed (and at a 32k
+                # bucket of a 73k vocabulary would not fit)
                 idx = jnp.maximum(lengths - 1, 0)[:, None, None]
-                logits = jnp.take_along_axis(full, idx, axis=1)[:, 0]
+                last = jnp.take_along_axis(xs[0], idx, axis=1)[:, 0]
+                logits = self._layer(name).pre_output(params[name], last)
                 continue
             else:  # pos + generic both run the ordinary layer forward
                 y, _ = spec.vertex.forward(params.get(name, {}), {}, xs,
@@ -331,6 +396,7 @@ class TransformerDecoder:
         at cache slot ``positions[b] + i``. Returns (full per-position
         logits ``[B, T, V]``, new caches) — the speculative verifier
         scores every drafted position from one launch of this walk."""
+        self._need("decode_chunk", "the speculative verify walk")
         t = tokens.shape[1]
         acts = {self._input: tokens}
         caches = dict(caches)
@@ -363,6 +429,7 @@ class TransformerDecoder:
         ``[prefix ; suffix]`` concatenation — cold-prefill semantics
         minus re-projecting the prefix. Returns (last-valid-position
         logits ``[Bp, V]``, suffix-only kv blocks)."""
+        self._need("prefill_suffix", "the prefix-cache suffix walk")
         ts = suffix.shape[1]
         tpre = next(iter(prefix_kv.values()))["k"].shape[1]
         key_mask = (jnp.arange(ts)[None, :]
@@ -407,7 +474,14 @@ class TransformerDecoder:
         key = ("decode", s, k, tag)
         if key not in self._fns:
             def fn(params, state):
-                return self._decode_window(params, state, k)
+                st, toks, emitted, counts = self._decode_window(
+                    params, state, k)
+                if not self.counter_names:
+                    return st, toks, emitted
+                # the layers' counters ride the window's own outputs: the
+                # engine reads them with the tokens, no further sync
+                return st, toks, emitted, jnp.stack(
+                    [counts[n] for n in self.counter_names])
 
             self._fns[key] = aot_cache.wrap(
                 jax.jit(fn, donate_argnums=(1,)), self._graph_key(),
@@ -420,8 +494,11 @@ class TransformerDecoder:
         with in-graph EOS/max-tokens masking."""
         def body(st, _):
             active = st["active"]
-            logits, caches = self._run_token(
-                params, st["tokens"], st["positions"], st["caches"])
+            logits, caches, counts = self._run_token(
+                params, st["tokens"], st["positions"], st["caches"],
+                active=active)
+            counts = {n: jnp.sum(jnp.where(active, counts[n], 0))
+                      for n in self.counter_names}
             step_keys, rng_next = _advance_rng(st["rng"])
             tok = _sample_tokens(logits, step_keys, st["temps"])
             tok = jnp.where(active, tok, st["tokens"])
@@ -432,10 +509,12 @@ class TransformerDecoder:
                       positions=new_pos, active=nxt,
                       rng=jnp.where(active[:, None], rng_next,
                                     st["rng"]))
-            return st, (tok, active)
+            return st, (tok, active, counts)
 
-        st, (toks, emitted) = jax.lax.scan(body, state, None, length=k)
-        return st, toks, emitted
+        st, (toks, emitted, counts) = jax.lax.scan(body, state, None,
+                                                   length=k)
+        return st, toks, emitted, {n: jnp.sum(c, dtype=jnp.int32)
+                                   for n, c in counts.items()}
 
     def spec_draft_fn(self, s: int, k: int):
         """The DRAFT side of a speculative iteration in ONE launch:
@@ -452,7 +531,7 @@ class TransformerDecoder:
             def fn(params, state, tokens, positions, active):
                 st = dict(state, tokens=tokens, positions=positions,
                           active=active)
-                return self._decode_window(params, st, k)
+                return self._decode_window(params, st, k)[:3]
 
             self._fns[key] = aot_cache.wrap(
                 jax.jit(fn, donate_argnums=(1,)), self._graph_key(),
@@ -489,15 +568,9 @@ class TransformerDecoder:
         if key not in self._fns:
             def fn(state, kv, rows, tok, lengths, max_new, eos, temps,
                    rng, active):
-                pad = ((0, 0), (0, s - tp), (0, 0))
-                caches = {}
-                for name, c in state["caches"].items():
-                    caches[name] = {
-                        "k": c["k"].at[rows].set(
-                            jnp.pad(kv[name]["k"], pad), mode="drop"),
-                        "v": c["v"].at[rows].set(
-                            jnp.pad(kv[name]["v"], pad), mode="drop"),
-                    }
+                caches = {name: self._layer(name).cache_join(
+                    c, kv[name], rows, s)
+                    for name, c in state["caches"].items()}
                 at = lambda a, v: a.at[rows].set(v, mode="drop")  # noqa: E731
                 return dict(
                     state, caches=caches,
@@ -526,9 +599,7 @@ class TransformerDecoder:
         key = ("grow", s, s2, tag)
         if key not in self._fns:
             def fn(state):
-                pad = ((0, 0), (0, s2 - s), (0, 0))
-                caches = {name: {"k": jnp.pad(c["k"], pad),
-                                 "v": jnp.pad(c["v"], pad)}
+                caches = {name: self._layer(name).cache_grow(c, s2)
                           for name, c in state["caches"].items()}
                 return dict(state, caches=caches)
 
@@ -544,7 +615,10 @@ class TransformerDecoder:
         key = ("release", s, tag)
         if key not in self._fns:
             def fn(state, keep):
-                return dict(state, active=state["active"] & keep)
+                caches = {name: self._layer(name).cache_release(c, keep)
+                          for name, c in state["caches"].items()}
+                return dict(state, caches=caches,
+                            active=state["active"] & keep)
 
             self._fns[key] = aot_cache.wrap(
                 jax.jit(fn, donate_argnums=(0,)), self._graph_key(),
@@ -770,16 +844,13 @@ class TransformerDecoder:
 
     # --- warmup -------------------------------------------------------------
     def _kv_struct(self, bp: int, tp: int):
-        """ShapeDtypeStruct pytree of per-layer ``{"k", "v"}`` blocks of
-        ``bp`` rows by ``tp`` positions in cache layout (the caches
-        themselves, a prefill's output, prefix pages)."""
-        sds = jax.ShapeDtypeStruct
-        kv = {}
-        for name, n_in in self._attn.items():
-            shape = self._layer(name).kv_cache_shape(bp, tp, n_in)
-            kv[name] = {"k": sds(shape, self._dtype),
-                        "v": sds(shape, self._dtype)}
-        return kv
+        """ShapeDtypeStruct pytree of the per-layer state of ``bp`` rows
+        by ``tp`` positions (the caches themselves, a prefill's output,
+        prefix pages): whatever each layer's ``cache_init`` returns."""
+        return {name: jax.eval_shape(
+            lambda layer=self._layer(name), n_in=n_in: layer.cache_init(
+                bp, tp, n_in, self._cache_dtype))
+            for name, n_in in self._cached.items()}
 
     def _ladder_floor(self, ladder: List[int], b: int) -> int:
         """Smallest real length that maps to bucket ``b`` (one past the
@@ -955,7 +1026,7 @@ class TransformerDecoder:
         alive = bool(np.asarray(active)[0])
         step = self.decode_fn(s, int(fused_steps))
         while alive:
-            state, toks_w, emitted = step(self._net.params, state)
+            state, toks_w, emitted = step(self._net.params, state)[:3]
             toks_w = np.asarray(toks_w)
             emitted = np.asarray(emitted)
             for i in range(toks_w.shape[0]):

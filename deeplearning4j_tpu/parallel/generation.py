@@ -136,6 +136,10 @@ class GenerationConfig:
     prefix_cache: bool = False
     prefix_page: int = 16
     prefix_cache_pages: int = 256
+    # widest group of prompts one prefill launch takes (default
+    # max_batch): a long-context model compiles one prefill per prompt
+    # bucket at width 1, not one per power of two up to max_batch
+    join_bucket_max: Optional[int] = None
 
 
 class _GenRequest:
@@ -210,18 +214,32 @@ class GenerationEngine:
             self._dec = TransformerDecoder(
                 model, max_batch=cfg.max_batch,
                 kv_bucket_min=cfg.kv_bucket_min,
-                prompt_bucket_min=cfg.prompt_bucket_min)
+                prompt_bucket_min=cfg.prompt_bucket_min,
+                join_bucket_max=cfg.join_bucket_max)
         elif hasattr(model, "decoder"):  # a zoo TransformerEncoder config
             self._dec = model.decoder(
                 max_batch=cfg.max_batch,
                 kv_bucket_min=cfg.kv_bucket_min,
-                prompt_bucket_min=cfg.prompt_bucket_min)
+                prompt_bucket_min=cfg.prompt_bucket_min,
+                join_bucket_max=cfg.join_bucket_max)
         else:
             raise TypeError(
                 "model must be a TransformerDecoder, a causal-LM "
                 "ComputationGraph, or a zoo config with .decoder()")
         if self._dec.max_batch != cfg.max_batch:
             cfg.max_batch = self._dec.max_batch
+        # a layer whose state is not K/V pages cannot be rebuilt from the
+        # prefix cache's pages or rolled back by the verifier's cursor:
+        # refused here, by name, not at the first hit or window
+        for wanted, method, what in (
+                (cfg.prefix_cache, "prefill_suffix", "prefix_cache=True"),
+                (cfg.draft_conf is not None, "decode_chunk",
+                 "draft_conf (speculative decoding)")):
+            missing = self._dec.walks_missing(method) if wanted else []
+            if missing:
+                raise ValueError(
+                    f"{what} is not supported with {', '.join(missing)}: "
+                    f"no {method}() for that layer's state")
         self._draft_dec: Optional[TransformerDecoder] = None
         self._draft_state = None
         self._spec_k = int(cfg.spec_tokens or cfg.fused_steps)
@@ -263,6 +281,9 @@ class GenerationEngine:
         # outcomes observed synchronously at the same points telemetry
         # records them
         self._slo = None
+        held = self._dec.state_bytes(self._S)
+        self._state_kinds = ",".join(sorted(held))
+        telemetry.record_decode_state_bytes(held)
         telemetry.register_generation_engine(self)
 
     def _coerce_draft(self, model) -> TransformerDecoder:
@@ -608,6 +629,8 @@ class GenerationEngine:
                     self._draft_state = self._draft_dec.grow_fn(
                         self._S, s2)(self._draft_state)
                 self._S = s2
+            telemetry.record_decode_state_bytes(
+                self._dec.state_bytes(self._S))
         return True
 
     def _do_prefill(self, joins: List[_GenRequest]):
@@ -620,8 +643,9 @@ class GenerationEngine:
         prefix cache) so speculation starts on the next window."""
         cold = [r for r in joins if not r.prefix_len]
         hits = [r for r in joins if r.prefix_len]
-        if cold:
-            self._prefill_cold(cold)
+        widest = self._dec.join_ladder[-1]
+        for i in range(0, len(cold), widest):
+            self._prefill_cold(cold[i:i + widest])
         if hits:
             groups = {}
             for r in hits:
@@ -639,7 +663,8 @@ class GenerationEngine:
         tp = bucket_for(max(r.n for r in joins), self._dec.prompt_ladder)
         bp = bucket_for(len(joins), self._dec.join_ladder)
         with self._span("gen.prefill", kind="cold", joins=len(joins),
-                        prompt_bucket=tp, rows=bp):
+                        prompt_bucket=tp, rows=bp,
+                        state_kinds=self._state_kinds):
             with self._span("gen.prefill.stage"):
                 self._grow_to(max(tp, self._S))
                 prompts = np.full((bp, tp), self._dec.pad_id, np.int32)
@@ -664,7 +689,8 @@ class GenerationEngine:
                     self._net_params(), prompts, lengths, max_new, eos,
                     temps, rng)
 
-            with self._span("gen.prefill.launch"):
+            with self._span("gen.prefill.launch", prompt_bucket=tp,
+                            state_kinds=self._state_kinds):
                 kv, tok, active, rng2 = self._call_prefill(once, joins)
                 self._state = self._dec.join_fn(self._S, tp, bp)(
                     self._state, kv, rows, tok, lengths, max_new, eos, temps,
@@ -900,7 +926,7 @@ class GenerationEngine:
                     and max_pos + ks + 1 <= self._dec.max_len)
             need = max_pos + (ks + 1 if spec else k)
             sp.annotate(grew=self._grow_to(min(need, self._dec.max_len)))
-        accepted = None
+        accepted = counts = None
 
         # NO retry on decode windows: the state pytrees are donated
         # into the executables, so a mid-flight failure may have
@@ -922,13 +948,15 @@ class GenerationEngine:
                     self._dec.spec_verify_fn(self._S, k)(
                         self._net_params(), self._state, drafts)
             else:
-                self._state, toks, emitted = self._dec.decode_fn(
+                self._state, toks, emitted, *counts = self._dec.decode_fn(
                     self._S, k)(self._net_params(), self._state)
         with self._span("gen.decode.readback", sync=True):
             if accepted is not None:
                 accepted = np.asarray(accepted)
             toks = np.asarray(toks)
             emitted = np.asarray(emitted)
+            if counts:      # the cached layers' counters, same read-back
+                counts = np.asarray(counts[0])
         now = time.monotonic()
         n_emitted = int(emitted.sum())
         occupancy = 0
@@ -982,6 +1010,9 @@ class GenerationEngine:
                         released.append(b)
                 self._tokens_total += n_emitted
                 self._decode_seconds += now - t0
+                if counts is not None and len(counts):
+                    telemetry.record_decode_layer_counts(dict(zip(
+                        self._dec.counter_names, counts.tolist())))
                 rows_in_use = sum(r is not None for r in self._rows)
                 sp.annotate(finished=finished, expired=len(released))
         if released:

@@ -483,6 +483,29 @@ def record_decode_first_token(seconds: float) -> None:
         seconds)
 
 
+def record_decode_state_bytes(by_kind: dict) -> None:
+    """Bytes of per-row state a generation engine's caches hold, by kind
+    (``kv``, ``compressed_keys``, ``recurrent``): set at engine build and
+    at every hop to a wider KV bucket."""
+    for kind, n in by_kind.items():
+        REGISTRY.gauge("dl4j_gen_state_bytes",
+                       help="bytes of per-row decode state by kind",
+                       kind=kind).set(n)
+
+
+def record_decode_layer_counts(counts: dict) -> None:
+    """What the cached layers counted in-graph over one decode window
+    (``nn.decoding``: summed over the active rows and the window's steps,
+    read with the window's tokens): ``dl4j_<name>_total`` each —
+    ``sparse_attended_positions`` / ``sparse_context_positions`` per
+    (sparse-layer query, KV head), ``sparse_dense_fallback_queries``,
+    ``recurrent_state_updates``."""
+    for name, n in counts.items():
+        REGISTRY.counter(f"dl4j_{name}_total",
+                         help="summed in-graph by the decode window "
+                              "(docs/observability.md)").inc(n)
+
+
 def record_prefix_cache(hits: int = 0, misses: int = 0, evictions: int = 0,
                         pages: int = None, hit_tokens: int = 0) -> None:
     """Radix prefix-cache accounting: lookups that matched at least one

@@ -1087,3 +1087,131 @@ class TransformerEncoder(GraphZooModel):
 
         return TransformerDecoder(net if net is not None else self.init(),
                                   max_len=self.max_len, **kw)
+
+
+class HybridDecoderLM(GraphZooModel):
+    """A causal language model whose mixer differs by layer:
+    ``mixer_types[i]`` is ``"lightning-attn"`` (linear attention with a
+    recurrent state, ``conf.layers_hybrid.LightningAttentionLayer``) or
+    ``"minicpm4"`` (block-sparse attention with grouped KV heads,
+    ``BlockSparseAttentionLayer``). Scaled token embedding, then per
+    layer ``h = x + c Mixer(RMSNorm(x))``, ``x' = h + c FFN(RMSNorm(h))``
+    with a gated feed-forward and ``c = scale_depth / sqrt(depth_for_scale)``,
+    a final RMS norm and an untied head whose logits are divided by
+    ``hidden / dim_model_base``. No position embedding: the lightning
+    layers rotate, the sparse ones take order from causality alone.
+
+    ``layer_indices`` gives each built layer its index among
+    ``n_layers_total`` (a served slice of a deeper model keeps its
+    layers' own decays); ``sparse`` holds ``BlockSparseAttentionLayer``'s
+    selection sizes. ``weight_dtype`` / ``cache_dtype`` are the matrices'
+    and the KV caches' types; the recurrent state is float32."""
+
+    MIXERS = ("lightning-attn", "minicpm4")
+
+    def __init__(self, vocab_size: int, hidden: int, ffn_dim: int,
+                 mixer_types, n_heads: int, head_dim: int,
+                 n_kv_heads: int, lightning_heads: int = 0,
+                 lightning_head_dim: int = 0, layer_indices=None,
+                 n_layers_total: int = 0, depth_for_scale: int = 0,
+                 scale_emb: float = 1.0, scale_depth: float = 1.0,
+                 dim_model_base: int = 0, rope_theta: float = 10000.0,
+                 eps: float = 1e-6, sparse: dict | None = None,
+                 max_len: int = 4096, weight_dtype: str = "",
+                 cache_dtype: str = "", seed: int = 123,
+                 updater: IUpdater | None = None):
+        self.mixer_types = list(mixer_types)
+        unknown = sorted(set(self.mixer_types) - set(self.MIXERS))
+        if unknown:
+            raise ValueError(f"unknown mixer types {unknown}; have "
+                             f"{list(self.MIXERS)}")
+        n = len(self.mixer_types)
+        self.vocab_size, self.hidden, self.ffn_dim = vocab_size, hidden, ffn_dim
+        self.n_heads, self.head_dim, self.n_kv_heads = (n_heads, head_dim,
+                                                        n_kv_heads)
+        self.lightning_heads = lightning_heads or n_heads
+        self.lightning_head_dim = lightning_head_dim or head_dim
+        self.layer_indices = list(layer_indices or range(n))
+        if len(self.layer_indices) != n:
+            raise ValueError("layer_indices needs one index a layer")
+        self.n_layers_total = n_layers_total or n
+        self.residual_scale = scale_depth / (depth_for_scale
+                                             or self.n_layers_total) ** 0.5
+        self.scale_emb = scale_emb
+        self.logit_scale = (dim_model_base / hidden if dim_model_base
+                            else 1.0)
+        self.rope_theta, self.eps = rope_theta, eps
+        self.sparse = dict(sparse or {})
+        self.max_len = max_len
+        self.weight_dtype, self.cache_dtype = weight_dtype, cache_dtype
+        self.seed = seed
+        self.updater = updater or Adam(learning_rate=1e-3)
+
+    def conf(self) -> ComputationGraphConfiguration:
+        from deeplearning4j_tpu.conf.layers_hybrid import (
+            BlockSparseAttentionLayer,
+            GatedFeedForwardLayer,
+            LightningAttentionLayer,
+            LMHeadLayer,
+            ResidualAddVertex,
+            RMSNormLayer,
+            ScaledEmbeddingLayer,
+        )
+
+        e, wd, c = self.hidden, self.weight_dtype, self.residual_scale
+        g = (NeuralNetConfiguration.builder()
+             .seed(self.seed).updater(self.updater)
+             .weight_init(WeightInit.XAVIER)
+             .graph_builder()
+             .add_inputs("input")
+             .set_input_types(InputType.recurrent(1, timesteps=self.max_len)))
+        g.add_layer("embed", ScaledEmbeddingLayer(
+            n_in=self.vocab_size, n_out=e, scale=self.scale_emb,
+            weight_dtype=wd), "input")
+        prev = "embed"
+        for i, kind in enumerate(self.mixer_types):
+            g.add_layer(f"b{i}_norm1", RMSNormLayer(eps=self.eps), prev)
+            if kind == "lightning-attn":
+                mixer = LightningAttentionLayer(
+                    n_out=e, n_heads=self.lightning_heads,
+                    head_size=self.lightning_head_dim,
+                    layer_index=self.layer_indices[i],
+                    n_layers_total=self.n_layers_total,
+                    rope_theta=self.rope_theta, eps=self.eps, out_scale=c,
+                    weight_dtype=wd)
+            else:
+                mixer = BlockSparseAttentionLayer(
+                    n_out=e, n_heads=self.n_heads,
+                    n_kv_heads=self.n_kv_heads, head_size=self.head_dim,
+                    eps=self.eps, out_scale=c, weight_dtype=wd,
+                    cache_dtype=self.cache_dtype, **self.sparse)
+            g.add_layer(f"b{i}_mix", mixer, f"b{i}_norm1")
+            g.add_vertex(f"b{i}_res1", ResidualAddVertex(),
+                         prev, f"b{i}_mix")
+            g.add_layer(f"b{i}_norm2", RMSNormLayer(eps=self.eps),
+                        f"b{i}_res1")
+            g.add_layer(f"b{i}_ffn", GatedFeedForwardLayer(
+                n_out=e, n_hidden=self.ffn_dim, out_scale=c,
+                weight_dtype=wd), f"b{i}_norm2")
+            g.add_vertex(f"b{i}_res2", ResidualAddVertex(),
+                         f"b{i}_res1", f"b{i}_ffn")
+            prev = f"b{i}_res2"
+        g.add_layer("final_norm", RMSNormLayer(eps=self.eps), prev)
+        g.add_layer("output", LMHeadLayer(
+            n_out=self.vocab_size, activation=Activation.SOFTMAX,
+            loss_fn=LossMCXENT(), logit_scale=self.logit_scale,
+            weight_dtype=wd), "final_norm")
+        g.set_outputs("output")
+        return g.build()
+
+    def decoder(self, net=None, **kw):
+        """The serving front (``nn.decoding.TransformerDecoder``): three
+        kinds of per-row state in one donated pytree. ``cache_dtype``
+        defaults to this model's."""
+        from deeplearning4j_tpu.nn.decoding import TransformerDecoder
+
+        kw.setdefault("max_len", self.max_len)
+        if self.cache_dtype:
+            kw.setdefault("cache_dtype", self.cache_dtype)
+        return TransformerDecoder(net if net is not None else self.init(),
+                                  **kw)
