@@ -33,9 +33,9 @@ from deeplearning4j_tpu.conf import inputs as it
 from deeplearning4j_tpu.conf.activations import Activation
 from deeplearning4j_tpu.conf.layers import BaseLayer
 from deeplearning4j_tpu.ops import (
+    bounded_decode_attention,
     cache_update,
     chunk_decode_attention,
-    decode_attention,
     dot_product_attention,
 )
 
@@ -213,7 +213,7 @@ class SelfAttentionLayer(BaseLayer):
             y = y * jnp.asarray(key_mask, y.dtype)[:, :, None]
         return y, k, v
 
-    def decode_step(self, params, x, cache, positions, use_kernels=False):
+    def decode_step(self, params, x, cache, positions):
         """One token of causal attention against the KV cache.
         ``x: [batch, features]`` is the new token's representation,
         ``positions: [batch]`` the cache slot it occupies (== number of
@@ -222,29 +222,12 @@ class SelfAttentionLayer(BaseLayer):
         :func:`cache_update`, attends slots ``0..positions``
         inclusive, and returns ``(y [batch, features_out], new_cache)``.
         The caller donates the cache buffers into the compiled step so
-        the write is in-place (PRG201 audits this). ``use_kernels``
-        swaps the masked full-cache read for the tuned paged-gather
-        kernel when this cache bucket has a winner."""
-        self._decode_check()
-        b = x.shape[0]
-        nh = self.n_heads
-        hs = params["Wk"].shape[1] // nh
-        q = (x @ params["Wq"] + params["bq"]).reshape(b, nh, hs)
-        k_new = (x @ params["Wk"] + params["bk"])[:, None]
-        v_new = (x @ params["Wv"] + params["bv"])[:, None]
-        k_cache = cache_update(cache["k"], k_new, positions)
-        v_cache = cache_update(cache["v"], v_new, positions)
-        o = None
-        if use_kernels:
-            from deeplearning4j_tpu.kernels import routing as _routing
-
-            o = _routing.maybe_decode_attention(q, k_cache, v_cache,
-                                                positions)
-        if o is None:
-            o = decode_attention(q, k_cache, v_cache, positions)
-        y = o.reshape(b, nh * hs) @ params["Wo"] + params["bo"]
-        return (self.activation.apply(y),
-                {"k": k_cache, "v": v_cache})
+        the write is in-place (PRG201 audits this). The read is bounded
+        per row by ``positions`` (:func:`bounded_decode_attention`: the
+        paged kernel where the program is lowered for a TPU and the
+        shape fills its tiles, the masked read of the bucket
+        elsewhere)."""
+        return self.cache_step(params, x, cache, positions)[:2]
 
     def decode_chunk(self, params, x, cache, positions):
         """A ``t``-token window of causal attention against the KV cache
@@ -276,6 +259,8 @@ class SelfAttentionLayer(BaseLayer):
     # --- the per-layer cache interface (nn.decoding walks it; the other
     # layer kinds and the contract: conf/layers_hybrid.py) ----------------
     cache_kinds = {"k": "kv", "v": "kv"}
+    cache_counters = ("decode_kv_read_positions",
+                      "decode_kv_bucket_positions")
 
     def cache_init(self, batch, length, n_in, dtype=jnp.float32):
         return self.init_kv_cache(batch, length, n_in, dtype)
@@ -294,9 +279,26 @@ class SelfAttentionLayer(BaseLayer):
 
     def cache_step(self, params, x, cache, positions, active=None,
                    use_kernels=False):
-        y, cache = self.decode_step(params, x, cache, positions,
-                                    use_kernels=use_kernels)
-        return y, cache, {}
+        """:meth:`decode_step` beside what it read: per row, the cached
+        positions the attention streamed and the positions the bucket
+        holds (their ratio over a window is the share of the bucket the
+        bound left; 1 = the bound is off)."""
+        self._decode_check()
+        b = x.shape[0]
+        nh = self.n_heads
+        hs = params["Wk"].shape[1] // nh
+        q = (x @ params["Wq"] + params["bq"]).reshape(b, nh, hs)
+        k_new = (x @ params["Wk"] + params["bk"])[:, None]
+        v_new = (x @ params["Wv"] + params["bv"])[:, None]
+        k_cache = cache_update(cache["k"], k_new, positions)
+        v_cache = cache_update(cache["v"], v_new, positions)
+        o, read = bounded_decode_attention(q, k_cache, v_cache, positions)
+        y = o.reshape(b, nh * hs) @ params["Wo"] + params["bo"]
+        counts = {"decode_kv_read_positions": read,
+                  "decode_kv_bucket_positions": jnp.full_like(
+                      read, k_cache.shape[1])}
+        return (self.activation.apply(y), {"k": k_cache, "v": v_cache},
+                counts)
 
     def cache_grow(self, cache, length):
         pad = ((0, 0), (0, length - cache["k"].shape[1]), (0, 0))

@@ -424,11 +424,12 @@ class FlashAttentionKernel(Kernel):
 class PagedDecodeAttentionKernel(Kernel):
     """Single-token decode against the KV cache as an in-kernel page
     gather (``ops.attention.paged_decode_attention``): the cache streams
-    page-by-page, pages wholly past ``positions[b]`` skip their DMA via
-    the scalar-prefetched index map, so a row's decode step costs
+    page-by-page and the grid is the list of the rows' LIVE pages, built
+    from the scalar-prefetched positions, so a row's decode step costs
     O(used pages) instead of the masked full-cache read. The tuned
     tiling is the 1-tuple ``(page,)``; only divisors of the cache bucket
-    are legal."""
+    are legal. The serving decode step runs the same kernel at
+    ``ops.attention.decode_page``, without asking this registry."""
 
     kernel_id = "paged_decode_attention"
     version = 2  # 1 paged a [batch, max_len, heads, head_dim] cache

@@ -22,13 +22,17 @@ Routed classes:
   softmax(QK^T)V core is swapped (dropout / projections / activation /
   mask-zeroing stay single-sourced in the layer).
 
-The serving decode path routes through the functional twins
-:func:`maybe_flash_attention` (prefill) and
-:func:`maybe_decode_attention` (the paged single-token kernel), called
-from inside ``SelfAttentionLayer.prefill`` / ``decode_step`` when the
-decoder passes ``use_kernels=True``; :func:`decoder_envelopes` /
-:func:`autotune_decoder` plan and tune the bucket-ladder envelopes
-those steps bake.
+The serving prefill routes through the functional twin
+:func:`maybe_flash_attention`, called from inside
+``SelfAttentionLayer.prefill`` when the decoder passes
+``use_kernels=True``. The decode step does NOT come through here: it
+runs ``ops.attention.bounded_decode_attention``, which puts the paged
+kernel on the path wherever the program is lowered for a TPU, at a page
+worked out from the shape, tuned or not. :func:`maybe_decode_attention`
+is the registry's own entry to the same kernel at a tuned page (the
+autotuner, the smoke and the benches call it).
+:func:`decoder_envelopes` / :func:`autotune_decoder` plan and tune the
+bucket-ladder envelopes.
 
 Selection happens at TRACE time (shapes are static under jit), so a
 routed executable bakes exactly one tuned layout — which is why the
@@ -277,8 +281,9 @@ def maybe_flash_attention(q, k, v, key_mask=None, causal=False):
 def maybe_decode_attention(q, k_cache, v_cache, positions):
     """Route single-token decode attention (``q [B, H, D]`` against
     ``[B, S, H * D]`` caches valid through ``positions``) through the
-    tuned paged-gather kernel, or return ``None`` for the stock masked
-    full-cache read."""
+    paged kernel at its TUNED page, or return ``None`` for an untuned
+    envelope. Off the serving path (``SelfAttentionLayer.cache_step``
+    calls ``ops.attention.bounded_decode_attention``)."""
     b, h, d = q.shape
     env = _attn_env(b, h, 1, k_cache.shape[1], d, q.dtype, causal=True,
                     masked=False)

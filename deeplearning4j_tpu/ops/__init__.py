@@ -8,6 +8,7 @@ plan).
 """
 
 from deeplearning4j_tpu.ops.attention import (  # noqa: F401
+    bounded_decode_attention,
     cache_update,
     chunk_decode_attention,
     decode_attention,

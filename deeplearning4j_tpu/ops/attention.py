@@ -148,22 +148,27 @@ def decode_attention(q, k_cache, v_cache, positions, scale=None):
                                   scale)[:, 0]
 
 
-def _paged_decode_kernel(pos_ref, qb_ref, own_ref, k_ref, v_ref, o_ref,
-                         m_sc, l_sc, acc_sc, *, sm, page, npages):
-    """Online-softmax decode over KV pages. Grid (batch, page); the page
-    dim is innermost/sequential so the scratch accumulates across pages.
-    ``pos_ref`` is scalar-prefetched: the kernel AND the index maps read
-    it before the body runs, so dead pages (wholly past ``positions[b]``)
-    skip both their DMA (index-map redirect to page 0, same trick as the
-    flash causal skip) and their compute (``pl.when``) — O(used pages)
-    work per row, not O(max_len). The two products are the block-diagonal
-    matrix products of :func:`chunk_decode_attention`, heads along the
-    lanes of every intermediate: ``qb_ref`` is the query as
-    :func:`_block_diagonal` lays it out, ``own_ref[(g, d), g']`` is 1
-    where ``g == g'``."""
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    pos = pos_ref[b]
+def _paged_decode_kernel(pos_ref, row_ref, page_ref, q_ref, own_ref, k_ref,
+                         v_ref, o_ref, m_sc, l_sc, acc_sc, *, sm, page):
+    """Online-softmax decode over the LIVE KV pages of every row. The grid
+    is one flat list of (row, page) pairs, a row's pages in order and the
+    rows one after another, as long as the rows' positions make it (its
+    length is a traced value): no step is spent on a page wholly past
+    ``positions[b]``, and the pipeline fetches a row's first page while
+    the row before it finishes. ``pos_ref``, ``row_ref`` and ``page_ref``
+    are scalar-prefetched: the index maps read them before the body runs.
+    The scratch accumulates over a row's pages; it is reset at a row's
+    first page and the row's output written at its last. The two
+    products are the block-diagonal matrix products of
+    :func:`chunk_decode_attention` with the heads along the SUBLANES of
+    every intermediate (scores ``[heads, page]``, accumulator ``[heads,
+    heads * head_dim]``), which makes them the two plain forms of the
+    matrix unit, ``Q K^T`` and ``P V``, with no transpose of a page:
+    ``q_ref[g]`` holds head ``g``'s query in its own ``head_dim`` columns
+    and zeros elsewhere, ``own_ref[g, (g', d)]`` is 1 where ``g == g'``."""
+    w = pl.program_id(0)
+    j = page_ref[w]
+    pos = pos_ref[row_ref[w]]
     highest = jax.lax.Precision.HIGHEST
 
     @pl.when(j == 0)
@@ -172,51 +177,54 @@ def _paged_decode_kernel(pos_ref, qb_ref, own_ref, k_ref, v_ref, o_ref,
         l_sc[...] = jnp.zeros_like(l_sc)
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
-    @pl.when(j * page <= pos)
-    def _compute():
-        k = k_ref[0].astype(jnp.float32)           # [page, e]
-        v = v_ref[0].astype(jnp.float32)           # [page, e]
-        s = jnp.dot(k, qb_ref[0].astype(jnp.float32), precision=highest,
-                    preferred_element_type=jnp.float32) * sm  # [page, h]
-        # boundary page: slots past positions[b] masked exactly like the
-        # masked full-cache read (exp underflows to 0.0 — garbage in
-        # unwritten slots can never leak)
-        slot = j * page + jax.lax.broadcasted_iota(jnp.int32, (page, 1), 0)
-        s = jnp.where(slot <= pos, s, NEG_INF)
-        m_prev, l_prev = m_sc[...], l_sc[...]      # [1, h]
-        m_next = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
-        p = jnp.exp(s - m_next)
-        alpha = jnp.exp(m_prev - m_next)
-        m_sc[...] = m_next
-        l_sc[...] = alpha * l_prev + jnp.sum(p, axis=0, keepdims=True)
-        acc_sc[...] = acc_sc[...] * alpha + jnp.dot(
-            v.T, p, precision=highest,
-            preferred_element_type=jnp.float32)    # [e, h]
+    k = k_ref[0].astype(jnp.float32)           # [page, e]
+    v = v_ref[0].astype(jnp.float32)           # [page, e]
+    s = jax.lax.dot_general(
+        q_ref[0].astype(jnp.float32), k, (((1,), (1,)), ((), ())),
+        precision=highest, preferred_element_type=jnp.float32) * sm  # [h, page]
+    # boundary page: slots past positions[b] masked exactly like the
+    # masked full-cache read (exp underflows to 0.0 — garbage in
+    # unwritten slots can never leak)
+    slot = j * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
+    s = jnp.where(slot <= pos, s, NEG_INF)
+    m_prev, l_prev = m_sc[...], l_sc[...]      # [h, 1]
+    m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_next)
+    alpha = jnp.exp(m_prev - m_next)
+    m_sc[...] = m_next
+    l_sc[...] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+    acc_sc[...] = acc_sc[...] * alpha + jnp.dot(
+        p, v, precision=highest, preferred_element_type=jnp.float32)  # [h, e]
 
-    @pl.when(j == npages - 1)
+    @pl.when(j == pos // page)
     def _store():
-        l = l_sc[...]
-        l_inv = jnp.where(l == 0.0, 1.0, 1.0 / l)
-        o_ref[0] = jnp.sum(acc_sc[...] * l_inv * own_ref[...], axis=1,
-                           keepdims=True).astype(o_ref.dtype)   # [e, 1]
+        l_inv = 1.0 / l_sc[...]     # slot 0 is live in every row: l > 0
+        o_ref[0] = jnp.sum(acc_sc[...] * l_inv * own_ref[...], axis=0,
+                           keepdims=True).astype(o_ref.dtype)   # [1, e]
 
 
+# jitted so that a decoder's layers share ONE trace and one lowered body of
+# the kernel: 36 layers each tracing and lowering their own cost the decode
+# window 1.7 s more at every start, warm or cold, and a third more StableHLO
+@functools.partial(jax.jit, static_argnames=("scale", "page", "interpret"))
 def paged_decode_attention(q, k_cache, v_cache, positions, scale=None,
                            page: int = 64,
                            interpret: Optional[bool] = None):
     """:func:`decode_attention` as a Pallas kernel gathering KV **pages**
     in-kernel: ``page``-slot blocks of the cache stream HBM→VMEM one DMA
-    per page, pages wholly past ``positions[b]`` are skipped at the DMA
-    level (scalar-prefetched positions drive the index map), and the
-    boundary page masks per-slot. Same signature and semantics as the
-    masked full-cache read — ``q: [batch, heads, head_dim]``,
-    ``k_cache/v_cache: [batch, max_len, heads * head_dim]``,
-    ``positions: [batch]`` — and bitwise the same masking rule, so the
-    parity tests pin it directly against :func:`decode_attention`.
+    per page, and only the pages that hold a position up to
+    ``positions[b]`` are ever visited (the grid is the list of live
+    pages, built from the positions; the boundary page masks per-slot).
+    Same signature and semantics as the masked full-cache read — ``q:
+    [batch, heads, head_dim]``, ``k_cache/v_cache: [batch, max_len,
+    heads * head_dim]``, ``positions: [batch]`` (clamped into the cache,
+    as :func:`cache_update` clamps them) — and bitwise the same masking
+    rule, so the parity tests pin it directly against
+    :func:`decode_attention`.
 
     ``page`` must divide ``max_len`` (the pow2 bucket ladder guarantees
-    a divisor exists; the autotuner only proposes legal pages).
-    ``interpret=None`` auto-enables the Pallas interpreter off-TPU."""
+    a divisor exists). ``interpret=None`` auto-enables the Pallas
+    interpreter off-TPU."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, h, d = q.shape
@@ -224,43 +232,101 @@ def paged_decode_attention(q, k_cache, v_cache, positions, scale=None,
     page = min(int(page), s)
     if s % page:
         raise ValueError(f"page {page} must divide cache length {s}")
-    npages = s // page
     sm = _scale(q, scale)
-    pos = positions.astype(jnp.int32)
-    own = jnp.repeat(jnp.eye(h, dtype=jnp.float32), d, axis=0)   # [e, h]
+    pos = jnp.clip(positions.astype(jnp.int32), 0, s - 1)
+    own = jnp.repeat(jnp.eye(h, dtype=jnp.float32), d, axis=1)   # [h, e]
+    # the flat list of live pages: step w reads page page_of[w] of row
+    # row_of[w]; steps past the list's end (never run) name the last pair
+    pages = pos // page + 1
+    ends = jnp.cumsum(pages)
+    steps = jnp.minimum(jnp.arange(b * (s // page), dtype=jnp.int32),
+                        ends[-1] - 1)
+    row_of = jnp.sum(steps[:, None] >= ends[None, :], axis=1,
+                     dtype=jnp.int32)
+    page_of = steps - (ends - pages)[row_of]
 
-    def q_map(b_, j, p):
-        return (b_, 0, 0)
+    def row_map(w, p, r, j):
+        return (r[w], 0, 0)
 
-    def kv_map(b_, j, p):
-        live = j * page <= p[b_]
-        return (b_, jax.lax.select(live, j, 0), 0)
+    def kv_map(w, p, r, j):
+        return (r[w], j[w], 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, npages),
-        in_specs=[pl.BlockSpec((1, e, h), q_map),
-                  pl.BlockSpec((e, h), lambda b_, j, p: (0, 0)),
+        num_scalar_prefetch=3,
+        grid=(ends[-1],),
+        in_specs=[pl.BlockSpec((1, h, e), row_map),
+                  pl.BlockSpec((h, e), lambda w, p, r, j: (0, 0)),
                   pl.BlockSpec((1, page, e), kv_map),
                   pl.BlockSpec((1, page, e), kv_map)],
-        out_specs=pl.BlockSpec((1, e, 1), q_map),
-        scratch_shapes=[pltpu.VMEM((1, h), jnp.float32),
-                        pltpu.VMEM((1, h), jnp.float32),
-                        pltpu.VMEM((e, h), jnp.float32)],
+        out_specs=pl.BlockSpec((1, 1, e), row_map),
+        scratch_shapes=[pltpu.VMEM((h, 1), jnp.float32),
+                        pltpu.VMEM((h, 1), jnp.float32),
+                        pltpu.VMEM((h, e), jnp.float32)],
     )
     params = None
     if not interpret:
-        params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
+        params = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
     out = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, sm=sm, page=page,
-                          npages=npages),
+        functools.partial(_paged_decode_kernel, sm=sm, page=page),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, e, 1), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, 1, e), q.dtype),
         compiler_params=params,
         interpret=interpret,
-    )(pos, _block_diagonal(q[:, None]), own, k_cache, v_cache)
+    )(pos, row_of, page_of,
+      jnp.swapaxes(_block_diagonal(q[:, None]), 1, 2), own,
+      k_cache, v_cache)
     return out.reshape(b, h, d)
+
+
+# The decode step's page. What a row reads is rounded up to it, and a
+# grid step costs a fixed third of a microsecond beside its DMA: at
+# [8, 1024, 1280] float32 on the v5e, 128 reads a fifth-full bucket in
+# 40 us a layer (64: 42, 256: 45) and a full one in 117 (64: 145, 256:
+# 111) against the masked read's 113 (PERF.md §5).
+DECODE_PAGE = 128
+
+
+def decode_page(max_len: int, width: int) -> Optional[int]:
+    """The page :func:`bounded_decode_attention` reads a ``[batch,
+    max_len, width]`` cache by on the TPU, from the shape alone, or
+    ``None`` where the kernel does not apply: the width must fill whole
+    128-lane tiles and the bucket must hold at least two pages (with one
+    there is nothing to skip)."""
+    if width % 128 or max_len % DECODE_PAGE or max_len < 2 * DECODE_PAGE:
+        return None
+    return DECODE_PAGE
+
+
+def bounded_decode_attention(q, k_cache, v_cache, positions, scale=None):
+    """:func:`decode_attention` bounded PER ROW by ``positions``, which
+    is traced: a row that holds 150 positions costs 150 (rounded up to a
+    page), a row that holds 1,000 costs 1,000, in one executable. On the
+    TPU this is :func:`paged_decode_attention` at :func:`decode_page`;
+    on any other platform, and for a shape the kernel does not take, the
+    masked read of the whole bucket. The platform is the one the program
+    is LOWERED for (``lax.platform_dependent``), not the host's default
+    backend: a program compiled for a TPU from a CPU host gets the
+    kernel, and a CPU run never pays the Pallas interpreter.
+
+    Returns ``(out [batch, heads, head_dim], read [batch] int32)``:
+    ``read[b]`` is the number of cached positions the step streamed for
+    row ``b`` (whole pages; the whole bucket where the bound is off)."""
+    s, e = k_cache.shape[1:]
+    page = decode_page(s, e)
+
+    def masked(q, k_cache, v_cache, positions):
+        return (decode_attention(q, k_cache, v_cache, positions, scale),
+                jnp.full(positions.shape, s, jnp.int32))
+
+    def paged(q, k_cache, v_cache, positions):
+        out = paged_decode_attention(q, k_cache, v_cache, positions, scale,
+                                     page=page, interpret=False)
+        return out, (jnp.clip(positions, 0, s - 1) // page + 1) * page
+
+    if page is None:
+        return masked(q, k_cache, v_cache, positions)
+    return jax.lax.platform_dependent(q, k_cache, v_cache, positions,
+                                      tpu=paged, default=masked)
 
 
 def cache_update(cache, new, positions):

@@ -274,6 +274,8 @@ class GenerationEngine:
         # windows, each INCLUDING the wait for the device's result
         self._prefill_seconds = 0.0
         self._decode_seconds = 0.0
+        # what the cached layers counted in-graph, summed over the windows
+        self._layer_counts = dict.fromkeys(self._dec.counter_names, 0)
         # sequence number of the loop's iteration: every span of one
         # iteration (admit, prefills, decode window) carries it as ``n``
         self._window = 0
@@ -502,6 +504,7 @@ class GenerationEngine:
                 "tokens_total": self._tokens_total,
                 "prefill_seconds": round(self._prefill_seconds, 4),
                 "decode_seconds": round(self._decode_seconds, 4),
+                "layer_counts": dict(self._layer_counts),
             }
         out["buckets"] = {"kv": list(self._dec.kv_ladder),
                           "prompt": list(self._dec.prompt_ladder),
@@ -1011,8 +1014,11 @@ class GenerationEngine:
                 self._tokens_total += n_emitted
                 self._decode_seconds += now - t0
                 if counts is not None and len(counts):
-                    telemetry.record_decode_layer_counts(dict(zip(
-                        self._dec.counter_names, counts.tolist())))
+                    counts = dict(zip(self._dec.counter_names,
+                                      counts.tolist()))
+                    telemetry.record_decode_layer_counts(counts)
+                    for name, n in counts.items():
+                        self._layer_counts[name] += n
                 rows_in_use = sum(r is not None for r in self._rows)
                 sp.annotate(finished=finished, expired=len(released))
         if released:

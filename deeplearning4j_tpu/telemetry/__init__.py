@@ -499,7 +499,9 @@ def record_decode_layer_counts(counts: dict) -> None:
     read with the window's tokens): ``dl4j_<name>_total`` each —
     ``sparse_attended_positions`` / ``sparse_context_positions`` per
     (sparse-layer query, KV head), ``sparse_dense_fallback_queries``,
-    ``recurrent_state_updates``."""
+    ``recurrent_state_updates``; ``decode_kv_read_positions`` /
+    ``decode_kv_bucket_positions`` per (``SelfAttentionLayer``, row,
+    step)."""
     for name, n in counts.items():
         REGISTRY.counter(f"dl4j_{name}_total",
                          help="summed in-graph by the decode window "

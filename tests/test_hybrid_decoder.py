@@ -512,18 +512,22 @@ def test_self_attention_serves_through_the_cache_interface():
     net = zoo.init()
     dec = zoo.decoder(net, max_batch=2, kv_bucket_min=32,
                       prompt_bucket_min=8)
-    assert dec._cached == dec._attn and dec.counter_names == []
+    assert dec._cached == dec._attn and dec.counter_names == [
+        "decode_kv_bucket_positions", "decode_kv_read_positions"]
     assert dec.state_bytes(32) == {"kv": 2 * 2 * 2 * 32 * 16 * 4}
     state = dec.new_state(32)
     out = dec.decode_fn(32, 2)(net.params, state)
-    assert len(out) == 3            # no counters: the window's old outputs
+    assert len(out) == 4            # the layers' counts beside the tokens
+    assert np.asarray(out[3]).tolist() == [0, 0]    # no row is active
     layer = dec._layer("b0_attn")
     cache = layer.cache_init(2, 32, 16, jnp.float32)
     x = np.random.default_rng(0).normal(size=(2, 16)).astype(np.float32)
     pos = np.asarray([3, 9], np.int32)
     y1, c1 = layer.decode_step(net.params["b0_attn"], x, cache, pos)
     y2, c2, counts = layer.cache_step(net.params["b0_attn"], x, cache, pos)
-    assert counts == {}
+    assert {n: np.asarray(c).tolist() for n, c in counts.items()} == {
+        "decode_kv_read_positions": [32, 32],   # the masked read: all of it
+        "decode_kv_bucket_positions": [32, 32]}
     np.testing.assert_array_equal(y1, y2)
     np.testing.assert_array_equal(c1["k"], c2["k"])
     prompt = _tokens(11, 3, 31).tolist()

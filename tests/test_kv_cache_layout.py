@@ -3,8 +3,9 @@ three functions that read and write it against the oracle, the arithmetic
 of the TPU's (8, 128) tiles, the decode executables' jaxprs (nothing
 cache-sized but the write and the two products; the caches donated), and
 the compiled decode window for a v5e (no relayout copy outside the loop,
-one layout at rest and in the loop, the cache read by the two products and
-by nothing else) with no chip attached.
+one layout at rest and in the loop, the cache written by
+``dynamic-update-slice`` and read by the paged kernel and by nothing else)
+with no chip attached.
 
 The ahead-of-time compiles load the TPU's compiler: they stay in THIS file,
 and the topology is described inside a fixture, never at import.
@@ -18,9 +19,12 @@ import numpy as np
 import pytest
 
 from deeplearning4j_tpu.ops.attention import (
+    bounded_decode_attention,
     cache_update,
     chunk_decode_attention,
     decode_attention,
+    decode_page,
+    paged_decode_attention,
     reference_attention,
 )
 from deeplearning4j_tpu.zoo.graphs import TransformerEncoder
@@ -74,6 +78,147 @@ def test_cache_ops_match_reference(heads, hs, tq):
         np.testing.assert_allclose(
             got[i], np.asarray(jnp.swapaxes(want, 1, 2))[0],
             rtol=2e-5, atol=2e-5)
+
+
+# --- the bounded read against the masked read ---------------------------------
+
+def _ragged(page, s):
+    """Per-row positions at the places a page bound can go wrong: the
+    first slot, the last slot of a page, the first of the next, the
+    last of the bucket, and two in the middle of a page."""
+    return np.asarray([0, page - 1, page, s - 1, 17, 2 * page + 5], np.int32)
+
+
+@pytest.mark.parametrize("page", [16, 128])
+@pytest.mark.parametrize("heads,hs", GEOMETRIES)
+def test_paged_read_matches_masked_read(heads, hs, page):
+    """The kernel the decode step runs on the TPU (here through the
+    Pallas interpreter) against ``decode_attention``, rows at ragged
+    positions over caches FULL of a retired tenant's keys and values:
+    large, finite, and different beyond every row's position, so a page
+    or a slot read past the bound shows."""
+    s = 4 * page
+    positions = _ragged(page, s)
+    b, e = len(positions), heads * hs
+    rng = np.random.default_rng(heads * 100 + hs + page)
+    k = rng.normal(size=(b, s, e)).astype(np.float32)
+    v = rng.normal(size=(b, s, e)).astype(np.float32)
+    q = jnp.asarray(rng.normal(size=(b, heads, hs)).astype(np.float32))
+    want = np.asarray(decode_attention(q, jnp.asarray(k), jnp.asarray(v),
+                                       positions))
+    beyond = np.arange(s)[None, :, None] > positions[:, None, None]
+    stale_k = np.where(beyond, 300.0 * rng.normal(size=k.shape), k)
+    stale_v = np.where(beyond, 300.0 * rng.normal(size=v.shape), v)
+    got = paged_decode_attention(
+        q, jnp.asarray(stale_k, jnp.float32), jnp.asarray(stale_v,
+                                                          jnp.float32),
+        positions, page=page, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("heads,hs", GEOMETRIES)
+def test_bounded_read_off_the_tpu_is_the_masked_read(heads, hs):
+    """On the CPU the decode path IS the masked read, bit for bit, and
+    says so: it read the whole bucket. A position past the bucket (a
+    retired row's) reads as the last slot, as the write clamps it."""
+    s, e = 256, heads * hs
+    assert decode_page(s, e) == 128 and decode_page(128, e) is None
+    assert decode_page(s, e + 64) is None
+    positions = np.asarray([0, 127, 128, s - 1, s + 3], np.int32)
+    b = len(positions)
+    rng = np.random.default_rng(heads + hs)
+    k = jnp.asarray(rng.normal(size=(b, s, e)).astype(np.float32))
+    v = jnp.asarray(rng.normal(size=(b, s, e)).astype(np.float32))
+    q = jnp.asarray(rng.normal(size=(b, heads, hs)).astype(np.float32))
+    got, read = jax.jit(bounded_decode_attention)(q, k, v, positions)
+    np.testing.assert_array_equal(
+        np.asarray(got),
+        np.asarray(jax.jit(decode_attention)(q, k, v, positions)))
+    assert np.asarray(read).tolist() == [s] * b
+    paged = paged_decode_attention(q, k, v, positions, page=128,
+                                   interpret=True)
+    np.testing.assert_allclose(np.asarray(paged), np.asarray(got),
+                               rtol=2e-5, atol=2e-5)
+
+
+# --- what the engine says the step read ----------------------------------------
+
+def _steer_to_the_tpu_branch(monkeypatch, page):
+    """What lowering for a TPU chooses, chosen here for a CPU run: the
+    decode path's ``tpu`` branch, its kernel through the Pallas
+    interpreter, at a page a toy bucket holds several of."""
+    from deeplearning4j_tpu.ops import attention
+
+    kernel, choose = attention.paged_decode_attention, \
+        jax.lax.platform_dependent
+    monkeypatch.setattr(attention, "DECODE_PAGE", page)
+    monkeypatch.setattr(
+        attention, "paged_decode_attention",
+        lambda *a, **kw: kernel(*a, **{**kw, "interpret": True}))
+    monkeypatch.setattr(
+        jax.lax, "platform_dependent",
+        lambda *a, default=None, **per: (
+            per["tpu"](*a) if getattr(per.get("tpu"), "__name__", "")
+            == "paged" else choose(*a, default=default, **per)))
+
+
+@pytest.mark.parametrize("read", ["masked", "paged"])
+def test_engine_counts_the_positions_the_decode_step_reads(read, monkeypatch):
+    """``dl4j_decode_kv_read_positions_total`` over
+    ``dl4j_decode_kv_bucket_positions_total`` is the share of the bucket
+    the decode steps streamed. Both add up from the requests alone: a
+    request of ``n`` tokens after a prompt of ``L`` takes ``n - 1``
+    decode steps at positions ``L .. L + n - 2`` (the prefill emits the
+    first), each reads whole pages up to its position in every layer,
+    and the bucket holds ``s`` positions a row a layer a step. On the
+    CPU the page is the bucket (the masked read: ratio 1); through the
+    TPU's branch it is the kernel's page."""
+    from deeplearning4j_tpu import telemetry
+    from deeplearning4j_tpu.parallel.generation import (
+        GenerationConfig,
+        GenerationEngine,
+    )
+
+    s, layers = 64, 2
+    page = s
+    if read == "paged":
+        page = 16
+        _steer_to_the_tpu_branch(monkeypatch, page)
+    # a vocabulary of its own per case: the executables are cached by graph
+    zoo = TransformerEncoder(vocab_size=61 + page, embed_dim=128, n_heads=2,
+                             n_layers=layers, max_len=s, causal=True,
+                             lm_head=True, seed=11)
+    dec = zoo.decoder(max_batch=4, kv_bucket_min=s, prompt_bucket_min=8)
+    assert dec.counter_names == ["decode_kv_bucket_positions",
+                                 "decode_kv_read_positions"]
+    jobs = [(3, 6), (15, 9), (16, 5), (30, 12), (7, 3)]
+    rng = np.random.default_rng(5)
+
+    def series(name):
+        return telemetry.REGISTRY.counter(
+            f"dl4j_decode_kv_{name}_positions_total").value
+
+    before = {n: series(n) for n in ("read", "bucket")}
+    with GenerationEngine(dec, GenerationConfig(
+            max_batch=4, fused_steps=2, kv_bucket_min=s,
+            prompt_bucket_min=8)) as eng:
+        reqs = [eng.submit(rng.integers(1, 60, size=n).tolist(),
+                           max_new_tokens=m) for n, m in jobs]
+        outs = [eng.result(r) for r in reqs]
+        counts = eng.stats()["layer_counts"]
+        text = telemetry.REGISTRY.render_prometheus()
+    assert [len(o) for o in outs] == [m for _, m in jobs]
+    steps = [p for n, m in jobs for p in range(n, n + m - 1)]
+    want_read = layers * sum(-(-(p + 1) // page) * page for p in steps)
+    want_bucket = layers * s * len(steps)
+    assert counts == {"decode_kv_read_positions": want_read,
+                      "decode_kv_bucket_positions": want_bucket}
+    assert want_read <= want_bucket and (read == "masked") == (
+        want_read == want_bucket)
+    assert series("read") - before["read"] == want_read
+    assert series("bucket") - before["bucket"] == want_bucket
+    assert "dl4j_decode_kv_read_positions_total" in text
+    assert "dl4j_decode_kv_bucket_positions_total" in text
 
 
 # --- tiles ---------------------------------------------------------------------
@@ -227,23 +372,50 @@ _PLUMBING = {"parameter", "get-tuple-element", "tuple", "bitcast", "while",
              "copy-start", "copy-done", "slice-start", "slice-done"}
 
 
+def _without_layout_constraints(txt):
+    """The compiled text less every ``operand_layout_constraints={...}``
+    attribute: a custom call restates its operands' shapes there with
+    the logical layout alone, which is no array of the program."""
+    out, key = [], "operand_layout_constraints={"
+    while key in txt:
+        head, rest = txt.split(key, 1)
+        depth, i = 1, 0
+        while depth:
+            depth += {"{": 1, "}": -1}.get(rest[i], 0)
+            i += 1
+        out.append(head)
+        txt = rest[i:]
+    return "".join(out) + txt
+
+
+_INSTRUCTION = re.compile(
+    r"\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*?)\s([\w\-]+)\(([^)]*)\)")
+
+
 @pytest.mark.parametrize("heads,hs", GEOMETRIES[:3])
 def test_compiled_decode_window_keeps_one_cache_layout(one_chip, heads, hs):
     """``decode_fn(1024, 4)`` of a two-layer decoder at the cell's batch,
     bucket and K, compiled by the TPU's own compiler: every cache, at
-    rest (the entry parameters), in the ``while`` and in the results, has
-    ONE tiled layout, row-major over ``[b, s, h * d]`` with (8, 128)
-    tiles (which that shape fills exactly); ``ENTRY`` holds no operation
-    with a cache-sized result but plumbing, so the donated caches alias
-    straight through the loop; in the loop a cache is written by
-    ``dynamic-update-slice`` alone (no cache-sized ``copy``, no staging
-    through VMEM and back); and the program's temporaries are smaller
-    than two caches (the layout this replaced held a padded copy of every
-    cache: 370 MB here, 8.9 GB for the benchmark's 36 layers)."""
+    rest (the entry parameters), in the ``while``, into the attention
+    and in the results, has ONE tiled layout, row-major over ``[b, s,
+    h * d]`` with (8, 128) tiles (which that shape fills exactly);
+    ``ENTRY`` holds no operation with a cache-sized result but plumbing,
+    so the donated caches alias straight through the loop; in the loop a
+    cache is written by ``dynamic-update-slice`` alone (no cache-sized
+    ``copy``, ``transpose`` or ``fusion``, no staging through VMEM and
+    back) and READ by the paged kernel alone, which takes it from the
+    write as it stands: the only operations with a whole cache among
+    their operands are the write, the Mosaic custom call (one a layer,
+    its K and its V) and the loop's own tuples; and the program's
+    temporaries are smaller than two caches (the layout this replaced
+    held a padded copy of every cache: 370 MB here, 8.9 GB for the
+    benchmark's 36 layers)."""
     b, s, k = 8, 1024, 4
+    layers = 2
+    assert decode_page(s, heads * hs) == 128
     m = TransformerEncoder(vocab_size=256, embed_dim=heads * hs,
-                           n_heads=heads, n_layers=2, max_len=s, causal=True,
-                           lm_head=True, seed=0)
+                           n_heads=heads, n_layers=layers, max_len=s,
+                           causal=True, lm_head=True, seed=0)
     dec = m.decoder(max_batch=b, kv_bucket_min=s, prompt_bucket_min=128)
     sds = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,  # noqa: E731
                                          sharding=one_chip)
@@ -251,7 +423,7 @@ def test_compiled_decode_window_keeps_one_cache_layout(one_chip, heads, hs):
     state = jax.tree_util.tree_map(sds, dec._struct_of(s))
     compiled = dec.decode_fn(s, k).jit_fn.trace(params, state).lower(
         lowering_platforms=("tpu",)).compile()
-    txt = compiled.as_text()
+    txt = _without_layout_constraints(compiled.as_text())
 
     dims = f"{b},{s},{heads * hs}"
     layouts = {re.sub(r"S\(\d+\)", "", lay) for lay in re.findall(
@@ -261,11 +433,10 @@ def test_compiled_decode_window_keeps_one_cache_layout(one_chip, heads, hs):
     entry = txt[txt.index("\nENTRY "):]
     offenders = []
     for line in entry.splitlines():
-        mo = re.match(r"\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(.*?)\s([\w\-]+)\(",
-                      line)
-        if not mo or f"[{dims}]" not in mo.group(1):
+        mo = _INSTRUCTION.match(line)
+        if not mo or f"[{dims}]" not in mo.group(2):
             continue
-        op = mo.group(2)
+        op = mo.group(3)
         if op == "custom-call" and "ConcatBitcast" in line:
             continue  # reassembles slice-start/-done pieces, moves nothing
         if op not in _PLUMBING:
@@ -280,6 +451,26 @@ def test_compiled_decode_window_keeps_one_cache_layout(one_chip, heads, hs):
         r"ROOT\s+%?[\w.\-]+\s*=\s*f32\[" + dims
         + r"\]\{[^}]*\}\s+([\w\-]+)\(", txt))
     assert fused_roots <= {"dynamic-update-slice", "bitcast"}, fused_roots
+
+    # who takes a whole cache as an operand, anywhere in the program
+    made = {}
+    for line in txt.splitlines():
+        mo = _INSTRUCTION.match(line)
+        if mo:
+            made[mo.group(1)] = (mo.group(2), mo.group(3), mo.group(4), line)
+    readers = {}
+    for name, (_, op, operands, line) in made.items():
+        whole = [a for a in re.findall(r"%([\w.\-]+)", operands)
+                 if made.get(a, ("",))[0].startswith(f"f32[{dims}]")]
+        if whole:
+            readers.setdefault(op, []).append((len(whole), line))
+    assert set(readers) <= _PLUMBING | {"dynamic-update-slice",
+                                        "custom-call"}, sorted(readers)
+    kernels = readers["custom-call"]
+    assert all('custom_call_target="tpu_custom_call"' in line
+               and n == 2 for n, line in kernels), kernels
+    assert len(kernels) == layers       # one bounded read a layer a step
+    assert len(readers["dynamic-update-slice"]) == 2 * layers * b
 
     cache_bytes = 4 * b * heads * hs * s
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * cache_bytes
