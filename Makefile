@@ -58,16 +58,10 @@ shard-smoke:
 .PHONY: decode-smoke
 # Continuous-batching generation smoke: KV-cache math vs the no-cache
 # oracle, continuous-vs-sequential token identity, late-join/EOS-retire
-# scheduling, breaker/deadline admission, zero recompiles after warmup —
-# then the closed-loop token-throughput bench in smoke mode (continuous
-# must beat sequential on aggregate tokens/s; prefix-cache leg must hit
-# the trie, speculative leg uses an oracle draft so acceptance and
-# identity assert without a training run).
+# scheduling, breaker/deadline admission, zero recompiles after warmup.
 decode-smoke:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests -q -m decode \
 		-p no:cacheprovider
-	JAX_PLATFORMS=cpu $(PY) bench_decode.py --smoke \
-		--prefix-cache --speculative
 
 .PHONY: comms-smoke
 # Collective-scheduler smoke: plan determinism/digests, scheduler-vs-
@@ -138,14 +132,12 @@ attention-smoke:
 # → join → decode windows → retire, replay-deterministic tail sampling
 # and SLO transitions, flight-recorder trace capture + keep-last-N,
 # /traces + /slo endpoints, SRC107 fixtures), then the tracing-overhead
-# A/B bench in both serving and decode shapes — tracing-on must hold
-# the pinned throughput budget with zero recompiles in BOTH modes.
+# A/B bench in the serving shape — tracing-on must hold the pinned
+# throughput budget with zero recompiles in BOTH modes.
 obs-smoke:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests -q -m obs -p no:cacheprovider
 	JAX_PLATFORMS=cpu $(PY) bench_serving.py --traces --seconds 1.5 \
 		--rounds 2 --out /tmp/bench_serving_traces_smoke.json
-	JAX_PLATFORMS=cpu $(PY) bench_decode.py --traces --smoke \
-		--out /tmp/bench_decode_traces_smoke.json
 
 .PHONY: lint
 # Repo-discipline source lint (analysis/source.py AST rules): host syncs

@@ -49,17 +49,14 @@ from deeplearning4j_tpu.analysis.findings import (
 # fused window, "prefill*" (prefill_join) scatters prompt KV into it,
 # and "gen_release*" passes it through with rows masked; a non-donated
 # decode-state executable silently doubles KV memory every token. The
-# speculative-decoding window ("spec_verify*"), the draft's fused
-# sync+window ("spec_draft*") and standalone reconciliation
-# ("spec_sync*") consume the same decode state, as do the prefix-cache
-# scatter ("prefix_attach*") and suffix join ("prefix_join*") — all
-# donate for the same reason. The suffix PREFILL ("gen_prompt_sfx*")
+# prefix-cache scatter ("prefix_attach*") and suffix join
+# ("prefix_join*") consume the same decode state and donate for the
+# same reason. The suffix PREFILL ("gen_prompt_sfx*")
 # is deliberately absent: its prefix-page input is a shared refcounted
 # buffer other requests attach concurrently, so it must NOT donate
 # (same construction-level exemption as gen_prompt).
 TRAIN_KIND_PREFIXES = ("train_step", "fused_scan", "tbptt_scan", "pw_",
                        "decode_step", "prefill", "gen_release",
-                       "spec_verify", "spec_sync", "spec_draft",
                        "prefix_attach", "prefix_join")
 
 # pod/reshard data-plane kinds (comms.reshard commit_compiled /

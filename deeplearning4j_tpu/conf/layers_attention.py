@@ -35,7 +35,6 @@ from deeplearning4j_tpu.conf.layers import BaseLayer
 from deeplearning4j_tpu.ops import (
     bounded_decode_attention,
     cache_update,
-    chunk_decode_attention,
     dot_product_attention,
 )
 
@@ -178,12 +177,6 @@ class SelfAttentionLayer(BaseLayer):
             raise ValueError("KV-cached decode requires causal=True "
                              "(bidirectional attention cannot stream)")
 
-    def init_kv_cache(self, max_batch, max_len, n_in, dtype=jnp.float32):
-        """Preallocated per-sequence KV buffers for this layer:
-        ``{"k","v"}: [max_batch, max_len, n_heads * head_size]`` zeros."""
-        shape = self.kv_cache_shape(max_batch, max_len, n_in)
-        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-
     def kv_cache_shape(self, batch, length, n_in):
         """Shape of one K or V buffer in cache layout — a cache at
         ``length = max_len``, a prefill or prefix-page block at a prompt
@@ -191,10 +184,23 @@ class SelfAttentionLayer(BaseLayer):
         self._decode_check()
         return (batch, length, self.n_heads * self._head_size(n_in))
 
-    def prefill(self, params, x, key_mask=None, use_kernels=False):
+    # --- the per-layer cache interface (nn.decoding walks it; the other
+    # layer kinds and the contract: conf/layers_hybrid.py) ----------------
+    cache_kinds = {"k": "kv", "v": "kv"}
+    cache_counters = ("decode_kv_read_positions",
+                      "decode_kv_bucket_positions")
+
+    def cache_init(self, batch, length, n_in, dtype=jnp.float32):
+        """Preallocated per-sequence KV buffers for this layer:
+        ``{"k","v"}: [batch, length, n_heads * head_size]`` zeros."""
+        shape = self.kv_cache_shape(batch, length, n_in)
+        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+    def cache_prefill(self, params, x, key_mask=None, dtype=None,
+                      use_kernels=False):
         """Whole-prompt forward that ALSO returns the projected keys and
         values so the caller can seed a KV cache in one launch.
-        ``x: [batch, time, features]``; returns ``(y, k, v)`` with
+        ``x: [batch, time, features]``; returns ``(y, {"k", "v"})`` with
         ``k/v: [batch, time, n_heads * head_size]`` (cache layout) and
         ``y`` identical to :meth:`forward` in eval mode (activation and
         mask-zeroing applied). ``use_kernels`` swaps the attention core
@@ -211,63 +217,6 @@ class SelfAttentionLayer(BaseLayer):
                                   + params["bo"])
         if key_mask is not None:
             y = y * jnp.asarray(key_mask, y.dtype)[:, :, None]
-        return y, k, v
-
-    def decode_step(self, params, x, cache, positions):
-        """One token of causal attention against the KV cache.
-        ``x: [batch, features]`` is the new token's representation,
-        ``positions: [batch]`` the cache slot it occupies (== number of
-        tokens already cached for that row). Projects q/k/v for the
-        token, writes k/v into the cache at ``positions`` via
-        :func:`cache_update`, attends slots ``0..positions``
-        inclusive, and returns ``(y [batch, features_out], new_cache)``.
-        The caller donates the cache buffers into the compiled step so
-        the write is in-place (PRG201 audits this). The read is bounded
-        per row by ``positions`` (:func:`bounded_decode_attention`: the
-        paged kernel where the program is lowered for a TPU and the
-        shape fills its tiles, the masked read of the bucket
-        elsewhere)."""
-        return self.cache_step(params, x, cache, positions)[:2]
-
-    def decode_chunk(self, params, x, cache, positions):
-        """A ``t``-token window of causal attention against the KV cache
-        — the multi-token twin of :meth:`decode_step` used by the
-        speculative ``spec_verify`` launch. ``x: [batch, t, features]``
-        are the window's representations; token ``i`` of row ``b``
-        occupies cache slot ``positions[b] + i``. Projects q/k/v for the
-        whole window, writes the k/v block at ``positions`` in one
-        :func:`cache_update`, attends each token causally through
-        :func:`chunk_decode_attention`, and returns
-        ``(y [batch, t, features_out], new_cache)``. Stays on the stock
-        core even under ``use_kernels``: the window's PER-ROW cache
-        offsets (``positions[b] + i``) don't fit the flash kernel's
-        single global ``Tk - Tq`` causal rule."""
-        self._decode_check()
-        b, t, _ = x.shape
-        nh = self.n_heads
-        hs = params["Wk"].shape[1] // nh
-        q = (x @ params["Wq"] + params["bq"]).reshape(b, t, nh, hs)
-        k_new = x @ params["Wk"] + params["bk"]
-        v_new = x @ params["Wv"] + params["bv"]
-        k_cache = cache_update(cache["k"], k_new, positions)
-        v_cache = cache_update(cache["v"], v_new, positions)
-        o = chunk_decode_attention(q, k_cache, v_cache, positions)
-        y = o.reshape(b, t, nh * hs) @ params["Wo"] + params["bo"]
-        return (self.activation.apply(y),
-                {"k": k_cache, "v": v_cache})
-
-    # --- the per-layer cache interface (nn.decoding walks it; the other
-    # layer kinds and the contract: conf/layers_hybrid.py) ----------------
-    cache_kinds = {"k": "kv", "v": "kv"}
-    cache_counters = ("decode_kv_read_positions",
-                      "decode_kv_bucket_positions")
-
-    def cache_init(self, batch, length, n_in, dtype=jnp.float32):
-        return self.init_kv_cache(batch, length, n_in, dtype)
-
-    def cache_prefill(self, params, x, key_mask=None, dtype=None,
-                      use_kernels=False):
-        y, k, v = self.prefill(params, x, key_mask, use_kernels=use_kernels)
         return y, {"k": k, "v": v}
 
     def cache_join(self, cache, block, rows, length):
@@ -277,12 +226,22 @@ class SelfAttentionLayer(BaseLayer):
         return {n: cache[n].at[rows].set(jnp.pad(block[n], pad), mode="drop")
                 for n in ("k", "v")}
 
-    def cache_step(self, params, x, cache, positions, active=None,
-                   use_kernels=False):
-        """:meth:`decode_step` beside what it read: per row, the cached
-        positions the attention streamed and the positions the bucket
-        holds (their ratio over a window is the share of the bucket the
-        bound left; 1 = the bound is off)."""
+    def cache_step(self, params, x, cache, positions, active=None):
+        """One token of causal attention against the KV cache, beside
+        what it read. ``x: [batch, features]`` is the new token's
+        representation, ``positions: [batch]`` the cache slot it occupies
+        (== number of tokens already cached for that row). Projects q/k/v
+        for the token, writes k/v into the cache at ``positions`` via
+        :func:`cache_update`, attends slots ``0..positions`` inclusive,
+        and returns ``(y [batch, features_out], new_cache, counts)``. The
+        caller donates the cache buffers into the compiled step so the
+        write is in-place (PRG201 audits this). The read is bounded per
+        row by ``positions`` (:func:`bounded_decode_attention`: the paged
+        kernel where the program is lowered for a TPU and the shape fills
+        its tiles, the masked read of the bucket elsewhere). ``counts``:
+        per row, the cached positions the attention streamed and the
+        positions the bucket holds (their ratio over a window is the
+        share of the bucket the bound left; 1 = the bound is off)."""
         self._decode_check()
         b = x.shape[0]
         nh = self.n_heads
@@ -310,7 +269,7 @@ class SelfAttentionLayer(BaseLayer):
     def prefill_suffix(self, params, x, prefix_k, prefix_v, prefix_mask,
                        key_mask=None, use_kernels=False):
         """Prompt-suffix prefill against an already-projected prefix —
-        the prefix-cache-hit twin of :meth:`prefill`. ``x: [batch,
+        the prefix-cache-hit twin of :meth:`cache_prefill`. ``x: [batch,
         t_suffix, features]`` holds the suffix tokens' representations;
         ``prefix_k/prefix_v: [batch, t_prefix, n_heads * head_size]`` are
         the shared prefix pages in cache layout (padding masked by

@@ -403,8 +403,7 @@ class LightningAttentionLayer(_GatedMixer):
     def cache_join(self, cache, block, rows, length):
         return {"state": _join_rows(cache["state"], block["state"], rows)}
 
-    def cache_step(self, params, x, cache, positions, active=None,
-                   use_kernels=False):
+    def cache_step(self, params, x, cache, positions, active=None):
         q, k, v = self._qkv(params, x, positions)
         o, s = linear_attention_step(
             q, k, v, cache["state"].astype(jnp.float32), self._log_decay())
@@ -525,8 +524,7 @@ class BlockSparseAttentionLayer(_GatedMixer):
                 "v": _join_rows(cache["v"], block["v"], rows, length),
                 "ck": _join_rows(cache["ck"], block["ck"], rows, n_ck)}
 
-    def cache_step(self, params, x, cache, positions, active=None,
-                   use_kernels=False):
+    def cache_step(self, params, x, cache, positions, active=None):
         spec = self._spec()
         g = self.n_kv_heads
         q = self._heads(params, x, "q", self.n_heads)

@@ -49,7 +49,6 @@ from deeplearning4j_tpu.kernels.routing import (  # noqa: F401
     autotune_model,
     backend,
     decoder_envelopes,
-    maybe_decode_attention,
     maybe_flash_attention,
     maybe_forward,
     maybe_vertex_forward,

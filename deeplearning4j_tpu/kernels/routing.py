@@ -24,15 +24,14 @@ Routed classes:
 
 The serving prefill routes through the functional twin
 :func:`maybe_flash_attention`, called from inside
-``SelfAttentionLayer.prefill`` when the decoder passes
+``SelfAttentionLayer.cache_prefill`` when the decoder passes
 ``use_kernels=True``. The decode step does NOT come through here: it
 runs ``ops.attention.bounded_decode_attention``, which puts the paged
 kernel on the path wherever the program is lowered for a TPU, at a page
-worked out from the shape, tuned or not. :func:`maybe_decode_attention`
-is the registry's own entry to the same kernel at a tuned page (the
-autotuner, the smoke and the benches call it).
+worked out from the shape (the registry keeps
+``PagedDecodeAttentionKernel`` for the smoke's Mosaic check).
 :func:`decoder_envelopes` / :func:`autotune_decoder` plan and tune the
-bucket-ladder envelopes.
+prompt-bucket flash envelopes.
 
 Selection happens at TRACE time (shapes are static under jit), so a
 routed executable bakes exactly one tuned layout — which is why the
@@ -275,24 +274,6 @@ def maybe_flash_attention(q, k, v, key_mask=None, causal=False):
         return None
     out = sel.kernel.build(sel.env, sel.tiling)(q, k, v, key_mask)
     _record_selected("flash_attention", sel.env)
-    return out
-
-
-def maybe_decode_attention(q, k_cache, v_cache, positions):
-    """Route single-token decode attention (``q [B, H, D]`` against
-    ``[B, S, H * D]`` caches valid through ``positions``) through the
-    paged kernel at its TUNED page, or return ``None`` for an untuned
-    envelope. Off the serving path (``SelfAttentionLayer.cache_step``
-    calls ``ops.attention.bounded_decode_attention``)."""
-    b, h, d = q.shape
-    env = _attn_env(b, h, 1, k_cache.shape[1], d, q.dtype, causal=True,
-                    masked=False)
-    sel = REGISTRY.select("paged_decode_attention", env)
-    if sel is None:
-        return None
-    out = sel.kernel.build(sel.env, sel.tiling)(q, k_cache, v_cache,
-                                                positions)
-    _record_selected("paged_decode_attention", sel.env)
     return out
 
 
@@ -541,11 +522,11 @@ def decoder_envelopes(decoder,
                       mode: Optional[str] = None
                       ) -> List[Tuple[str, object]]:
     """The attention ``(kernel_id, envelope)`` list a ``use_kernels``
-    :class:`nn.decoding.TransformerDecoder` routes: one paged-decode
-    envelope per KV bucket (the fused decode window runs at full
-    ``max_batch``) and one flash envelope per (prompt bucket, join
-    width) — cold prefill always attends under the prompt-length key
-    mask, so those envelopes are ``masked=True``. Derived from the
+    :class:`nn.decoding.TransformerDecoder` routes: one flash envelope
+    per (prompt bucket, join width) — cold prefill always attends under
+    the prompt-length key mask, so those envelopes are ``masked=True``.
+    (The decode step chooses its kernel by platform and shape:
+    ``ops.attention.bounded_decode_attention``.) Derived from the
     decoder's ladders and attention geometry; needs no params or
     traffic."""
     out: List[Tuple[str, object]] = []
@@ -562,10 +543,6 @@ def decoder_envelopes(decoder,
         layer = decoder._layer(name)
         geoms.add((layer.n_heads, layer._head_size(n_in)))
     for h, d in sorted(geoms):
-        for s in decoder.kv_ladder:
-            add("paged_decode_attention",
-                _attn_env(decoder.max_batch, h, 1, s, d, dtype,
-                          causal=True, masked=False, mode=mode))
         for tp in decoder.prompt_ladder:
             for bp in decoder.join_ladder:
                 add("flash_attention",
@@ -577,10 +554,10 @@ def decoder_envelopes(decoder,
 def autotune_decoder(decoder, retune: bool = False,
                      **autotune_kw) -> List[object]:
     """Autotune every attention envelope a ``use_kernels`` decoder would
-    route (paged decode per KV bucket, flash prefill per prompt/join
-    bucket pair). Run BEFORE ``warm_all``: selection is baked at trace
-    time, so executables compiled before tuning keep the stock core
-    until their key's digest token changes."""
+    route (flash prefill per prompt/join bucket pair). Run BEFORE
+    ``warm_all``: selection is baked at trace time, so executables
+    compiled before tuning keep the stock core until their key's digest
+    token changes."""
     from deeplearning4j_tpu.kernels import tuner as tuner_mod
 
     results = []

@@ -38,10 +38,9 @@ cache interface of ``conf/layers_hybrid.py`` (``cache_init``,
 ``cache_release``) is served: KV buffers (``SelfAttentionLayer``), KV
 buffers beside compressed keys (``BlockSparseAttentionLayer``), a
 fixed-size recurrent state (``LightningAttentionLayer``), side by side in
-the one donated state pytree, each in its own type. The chunk and suffix
-walks (speculation, the prefix cache) need ``decode_chunk`` /
-``prefill_suffix`` on every such layer and refuse a graph that has a
-layer without them, by name.
+the one donated state pytree, each in its own type. The suffix walk (the
+prefix cache) needs ``prefill_suffix`` on every such layer and refuses a
+graph that has a layer without it, by name.
 
 Scheduling on top of this lives in ``parallel.generation`` — this module
 is the pure model path plus :meth:`TransformerDecoder.generate`, the
@@ -116,6 +115,33 @@ def _sample_tokens(logits, step_keys, temps):
     return jnp.where(temps > 0, sampled, greedy)
 
 
+def _first_token(logits, max_new, eos, temps, rng):
+    """What a prefill samples from its last-position logits: the first
+    token, the row's liveness (EOS on the first token / ``max_new == 1``
+    rows are born retired) and the carried PRNG keys."""
+    step_keys, rng_next = _advance_rng(rng)
+    tok = _sample_tokens(logits, step_keys, temps)
+    return tok, (tok != eos) & (max_new > 1), rng_next
+
+
+def _seed_rows(state, caches, rows, tok, lengths, max_new, eos, temps, rng,
+               active):
+    """The state with a join group's caches and its per-row scheduler
+    arrays written at ``rows`` (slots >= ``max_batch`` are padding, dropped
+    by the scatter)."""
+    at = lambda a, v: a.at[rows].set(v, mode="drop")  # noqa: E731
+    return dict(
+        state, caches=caches,
+        tokens=at(state["tokens"], tok),
+        positions=at(state["positions"], lengths),
+        prompt_lens=at(state["prompt_lens"], jnp.maximum(lengths, 1)),
+        max_new=at(state["max_new"], max_new),
+        eos=at(state["eos"], eos),
+        temps=at(state["temps"], temps),
+        rng=at(state["rng"], rng),
+        active=at(state["active"], active))
+
+
 def _reject_types():
     # MoE routing is cross-row (capacity is shared over the whole
     # batch), which breaks both decode-shape assumptions and the
@@ -154,7 +180,7 @@ class TransformerDecoder:
         # cache_dtype / state_dtype wins over it (conf/layers_hybrid.py)
         self._cache_dtype = (jnp.dtype(cache_dtype) if cache_dtype
                              else self._dtype)
-        self._fns: Dict[tuple, object] = {}
+        self._fns: Dict[str, object] = {}
         self.use_kernels = bool(getattr(net.conf, "use_kernels", False))
         conf = net.conf
         if len(conf.network_inputs) != 1 or len(conf.network_outputs) != 1:
@@ -292,9 +318,9 @@ class TransformerDecoder:
         return out
 
     def walks_missing(self, method: str) -> List[str]:
-        """The cached vertices whose layer lacks ``method`` (``decode_chunk``:
-        the speculative verify walk; ``prefill_suffix``: the prefix-cache
-        walk), as ``name (LayerType)``."""
+        """The cached vertices whose layer lacks ``method``
+        (``prefill_suffix``: the prefix-cache walk), as
+        ``name (LayerType)``."""
         return [f"{name!r} ({type(self._layer(name)).__name__})"
                 for name in self._cached
                 if not hasattr(self._layer(name), method)]
@@ -305,7 +331,7 @@ class TransformerDecoder:
             raise NotImplementedError(
                 f"{walk} needs {method}() on every cached layer; "
                 f"{', '.join(missing)} keep state it cannot rebuild from "
-                f"K/V pages or roll back by a cursor")
+                f"K/V pages")
 
     def _graph_key(self):
         return self._net._graph_key()
@@ -331,93 +357,73 @@ class TransformerDecoder:
     def params(self):
         return self._net.params
 
-    # --- pure model walks ---------------------------------------------------
-    def _run_token(self, params, tokens, positions, caches, active=None):
-        """One token through the graph against the caches:
-        ``tokens [B] int32`` → (vocab logits ``[B, V]``, new caches,
-        the layers' counts ``{name: [B] int32}`` summed over the
-        layers)."""
+    # --- the model walk ------------------------------------------------------
+    def _walk(self, params, tokens, cached, positions=None, lengths=None):
+        """The one walk over the plan: ``tokens`` (``[B]`` for a decode
+        step, ``[B, T]`` for a prompt or a suffix) → vocab logits. What
+        differs between the walks is the caller's: ``cached(name, params,
+        x) -> y`` runs a layer that keeps per-row state (and keeps that
+        layer's new cache or KV block, and its counts, for the caller);
+        ``positions`` is where a position vertex gathers its table
+        (None: the layer's own forward, positions ``0..T-1``);
+        ``lengths`` puts the head on each row's last valid position
+        alone (None: on all of ``x``) — a whole ``[T, vocab]`` of logits
+        is never needed, and at a 32k bucket of a 73k vocabulary would
+        not fit."""
         acts = {self._input: tokens}
-        caches = dict(caches)
         logits = None
-        counts: Dict[str, object] = {}
         for kind, name, spec in self._plan:
             xs = [acts[src] for src in spec.inputs]
             if kind == "attn":
-                y, caches[name], own = self._layer(name).cache_step(
-                    params[name], xs[0], caches[name], positions,
-                    active=active, use_kernels=self.use_kernels)
-                for k, v in own.items():
-                    counts[k] = counts[k] + v if k in counts else v
-            elif kind == "pos":
+                y = cached(name, params[name], xs[0])
+            elif kind == "pos" and positions is not None:
                 y = xs[0] + params[name]["P"][positions]
             elif kind == "head":
-                logits = self._layer(name).pre_output(params[name], xs[0])
+                x = xs[0]
+                if lengths is not None:
+                    idx = jnp.maximum(lengths - 1, 0)[:, None, None]
+                    x = jnp.take_along_axis(x, idx, axis=1)[:, 0]
+                logits = self._layer(name).pre_output(params[name], x)
                 continue
             else:
                 y, _ = spec.vertex.forward(params.get(name, {}), {}, xs,
                                            train=False, rng=None)
             acts[name] = y
+        return logits
+
+    def _run_token(self, params, tokens, positions, caches, active=None):
+        """One token through the graph against the caches:
+        ``tokens [B] int32`` → (vocab logits ``[B, V]``, new caches,
+        the layers' counts ``{name: [B] int32}`` summed over the
+        layers)."""
+        caches = dict(caches)
+        counts: Dict[str, object] = {}
+
+        def step(name, p, x):
+            y, caches[name], own = self._layer(name).cache_step(
+                p, x, caches[name], positions, active=active)
+            for k, v in own.items():
+                counts[k] = counts[k] + v if k in counts else v
+            return y
+
+        logits = self._walk(params, tokens, step, positions=positions)
         return logits, caches, counts
 
     def _run_prompt(self, params, prompts, lengths):
         """Whole-prompt prefill walk: ``prompts [Bp, Tp] int32`` →
         (last-valid-position logits ``[Bp, V]``, per-layer kv blocks in
         cache layout)."""
-        tp = prompts.shape[1]
-        key_mask = (jnp.arange(tp)[None, :]
+        key_mask = (jnp.arange(prompts.shape[1])[None, :]
                     < lengths[:, None]).astype(self._dtype)
-        acts = {self._input: prompts}
         kv = {}
-        logits = None
-        for kind, name, spec in self._plan:
-            xs = [acts[src] for src in spec.inputs]
-            if kind == "attn":
-                y, kv[name] = self._layer(name).cache_prefill(
-                    params[name], xs[0], key_mask, dtype=self._cache_dtype,
-                    use_kernels=self.use_kernels)
-            elif kind == "head":
-                # the head over the last valid position alone: a whole
-                # [Tp, vocab] of logits is never needed (and at a 32k
-                # bucket of a 73k vocabulary would not fit)
-                idx = jnp.maximum(lengths - 1, 0)[:, None, None]
-                last = jnp.take_along_axis(xs[0], idx, axis=1)[:, 0]
-                logits = self._layer(name).pre_output(params[name], last)
-                continue
-            else:  # pos + generic both run the ordinary layer forward
-                y, _ = spec.vertex.forward(params.get(name, {}), {}, xs,
-                                           train=False, rng=None)
-            acts[name] = y
-        return logits, kv
 
-    def _run_chunk(self, params, tokens, positions, caches):
-        """A ``[B, T]`` window of tokens through the graph against the
-        caches in ONE wide step (no scan): token ``i`` of row ``b`` sits
-        at cache slot ``positions[b] + i``. Returns (full per-position
-        logits ``[B, T, V]``, new caches) — the speculative verifier
-        scores every drafted position from one launch of this walk."""
-        self._need("decode_chunk", "the speculative verify walk")
-        t = tokens.shape[1]
-        acts = {self._input: tokens}
-        caches = dict(caches)
-        logits = None
-        for kind, name, spec in self._plan:
-            xs = [acts[src] for src in spec.inputs]
-            if kind == "attn":
-                y, caches[name] = self._layer(name).decode_chunk(
-                    params[name], xs[0], caches[name], positions)
-            elif kind == "pos":
-                idx = jnp.clip(positions[:, None] + jnp.arange(t),
-                               0, self.max_len - 1)
-                y = xs[0] + params[name]["P"][idx]
-            elif kind == "head":
-                logits = self._layer(name).pre_output(params[name], xs[0])
-                continue
-            else:
-                y, _ = spec.vertex.forward(params.get(name, {}), {}, xs,
-                                           train=False, rng=None)
-            acts[name] = y
-        return logits, caches
+        def prefill(name, p, x):
+            y, kv[name] = self._layer(name).cache_prefill(
+                p, x, key_mask, dtype=self._cache_dtype,
+                use_kernels=self.use_kernels)
+            return y
+
+        return self._walk(params, prompts, prefill, lengths=lengths), kv
 
     def _run_suffix(self, params, suffix, suf_lens, prefix_kv, prefix_lens):
         """Prompt-SUFFIX prefill walk against already-projected prefix
@@ -436,33 +442,31 @@ class TransformerDecoder:
                     < suf_lens[:, None]).astype(self._dtype)
         prefix_mask = (jnp.arange(tpre)[None, :]
                        < prefix_lens[:, None]).astype(self._dtype)
-        acts = {self._input: suffix}
         kv = {}
-        logits = None
-        for kind, name, spec in self._plan:
-            xs = [acts[src] for src in spec.inputs]
-            if kind == "attn":
-                y, k, v = self._layer(name).prefill_suffix(
-                    params[name], xs[0], prefix_kv[name]["k"],
-                    prefix_kv[name]["v"], prefix_mask, key_mask,
-                    use_kernels=self.use_kernels)
-                kv[name] = {"k": k, "v": v}
-            elif kind == "pos":
-                idx = jnp.clip(prefix_lens[:, None] + jnp.arange(ts),
-                               0, self.max_len - 1)
-                y = xs[0] + params[name]["P"][idx]
-            elif kind == "head":
-                full = self._layer(name).pre_output(params[name], xs[0])
-                idx = jnp.maximum(suf_lens - 1, 0)[:, None, None]
-                logits = jnp.take_along_axis(full, idx, axis=1)[:, 0]
-                continue
-            else:
-                y, _ = spec.vertex.forward(params.get(name, {}), {}, xs,
-                                           train=False, rng=None)
-            acts[name] = y
-        return logits, kv
+
+        def prefill(name, p, x):
+            y, k, v = self._layer(name).prefill_suffix(
+                p, x, prefix_kv[name]["k"], prefix_kv[name]["v"],
+                prefix_mask, key_mask, use_kernels=self.use_kernels)
+            kv[name] = {"k": k, "v": v}
+            return y
+
+        at = jnp.clip(prefix_lens[:, None] + jnp.arange(ts),
+                      0, self.max_len - 1)
+        return self._walk(params, suffix, prefill, positions=at,
+                          lengths=suf_lens), kv
 
     # --- compiled executables (all through optimize/aot_cache) -------------
+    def _exe(self, kind: str, fn, donate=()):
+        """``fn`` jitted behind the AOT cache under the step kind ``kind``
+        plus the kernel tag (:meth:`_ktag`), built once a kind: a retune
+        changes the tag, and the next call traces a new executable."""
+        kind += self._ktag()
+        if kind not in self._fns:
+            self._fns[kind] = aot_cache.wrap(
+                jax.jit(fn, donate_argnums=donate), self._graph_key(), kind)
+        return self._fns[kind]
+
     def decode_fn(self, s: int, k: int):
         """K fused decode steps at KV bucket ``s``: ``lax.scan`` of the
         single-token walk, in-graph EOS/max-tokens masking (finished
@@ -470,28 +474,22 @@ class TransformerDecoder:
         DONATED. Returns ``(state', tokens [K, B], emitted [K, B])`` —
         ``emitted[i, b]`` is True where row b was live going into step i
         (the host appends exactly those tokens)."""
-        tag = self._ktag()
-        key = ("decode", s, k, tag)
-        if key not in self._fns:
-            def fn(params, state):
-                st, toks, emitted, counts = self._decode_window(
-                    params, state, k)
-                if not self.counter_names:
-                    return st, toks, emitted
-                # the layers' counters ride the window's own outputs: the
-                # engine reads them with the tokens, no further sync
-                return st, toks, emitted, jnp.stack(
-                    [counts[n] for n in self.counter_names])
+        def fn(params, state):
+            st, toks, emitted, counts = self._decode_window(
+                params, state, k)
+            if not self.counter_names:
+                return st, toks, emitted
+            # the layers' counters ride the window's own outputs: the
+            # engine reads them with the tokens, no further sync
+            return st, toks, emitted, jnp.stack(
+                [counts[n] for n in self.counter_names])
 
-            self._fns[key] = aot_cache.wrap(
-                jax.jit(fn, donate_argnums=(1,)), self._graph_key(),
-                f"decode_step:s{s}:k{k}{tag}")
-        return self._fns[key]
+        return self._exe(f"decode_step:s{s}:k{k}", fn, donate=(1,))
 
     def _decode_window(self, params, state, k):
-        """The fused K-step window body shared by :meth:`decode_fn` and
-        :meth:`spec_draft_fn`: ``lax.scan`` of the single-token walk
-        with in-graph EOS/max-tokens masking."""
+        """The fused K-step window body of :meth:`decode_fn`:
+        ``lax.scan`` of the single-token walk with in-graph
+        EOS/max-tokens masking."""
         def body(st, _):
             active = st["active"]
             logits, caches, counts = self._run_token(
@@ -516,46 +514,15 @@ class TransformerDecoder:
         return st, toks, emitted, {n: jnp.sum(c, dtype=jnp.int32)
                                    for n, c in counts.items()}
 
-    def spec_draft_fn(self, s: int, k: int):
-        """The DRAFT side of a speculative iteration in ONE launch:
-        overwrite the draft's cursor with the target's (the spec_sync
-        reconciliation — accepted slots already hold the right k/v, so
-        it is pure bookkeeping) and run the fused K-step window from
-        there. Folding the sync into the window halves the draft-side
-        dispatches per iteration, which is most of speculation's cost
-        on a dispatch-bound host. State DONATED; the cursor arrays come
-        from the TARGET's state and are not."""
-        tag = self._ktag()
-        key = ("spec_draft", s, k, tag)
-        if key not in self._fns:
-            def fn(params, state, tokens, positions, active):
-                st = dict(state, tokens=tokens, positions=positions,
-                          active=active)
-                return self._decode_window(params, st, k)[:3]
-
-            self._fns[key] = aot_cache.wrap(
-                jax.jit(fn, donate_argnums=(1,)), self._graph_key(),
-                f"spec_draft:s{s}:k{k}{tag}")
-        return self._fns[key]
-
     def prompt_fn(self, tp: int, bp: int):
         """Prefill forward for a compact ``[bp, tp]`` group of joining
         prompts: kv blocks + sampled first token + in-graph liveness
-        (EOS-on-first-token / max_new == 1 rows are born retired)."""
-        tag = self._ktag()
-        key = ("prompt", tp, bp, tag)
-        if key not in self._fns:
-            def fn(params, prompts, lengths, max_new, eos, temps, rng):
-                logits, kv = self._run_prompt(params, prompts, lengths)
-                step_keys, rng_next = _advance_rng(rng)
-                tok = _sample_tokens(logits, step_keys, temps)
-                active = (tok != eos) & (max_new > 1)
-                return kv, tok, active, rng_next
+        (:func:`_first_token`)."""
+        def fn(params, prompts, lengths, max_new, eos, temps, rng):
+            logits, kv = self._run_prompt(params, prompts, lengths)
+            return (kv,) + _first_token(logits, max_new, eos, temps, rng)
 
-            self._fns[key] = aot_cache.wrap(
-                jax.jit(fn), self._graph_key(),
-                f"gen_prompt:t{tp}:b{bp}{tag}")
-        return self._fns[key]
+        return self._exe(f"gen_prompt:t{tp}:b{bp}", fn)
 
     def join_fn(self, s: int, tp: int, bp: int):
         """Scatter a prefilled group into the running state at given row
@@ -563,31 +530,15 @@ class TransformerDecoder:
         dropped by the scatter). State DONATED — this is the ``prefill*``
         kind the PRG201 donation audit proves writes the KV cache in
         place."""
-        tag = self._ktag()
-        key = ("join", s, tp, bp, tag)
-        if key not in self._fns:
-            def fn(state, kv, rows, tok, lengths, max_new, eos, temps,
-                   rng, active):
-                caches = {name: self._layer(name).cache_join(
-                    c, kv[name], rows, s)
-                    for name, c in state["caches"].items()}
-                at = lambda a, v: a.at[rows].set(v, mode="drop")  # noqa: E731
-                return dict(
-                    state, caches=caches,
-                    tokens=at(state["tokens"], tok),
-                    positions=at(state["positions"], lengths),
-                    prompt_lens=at(state["prompt_lens"],
-                                   jnp.maximum(lengths, 1)),
-                    max_new=at(state["max_new"], max_new),
-                    eos=at(state["eos"], eos),
-                    temps=at(state["temps"], temps),
-                    rng=at(state["rng"], rng),
-                    active=at(state["active"], active))
+        def fn(state, kv, rows, tok, lengths, max_new, eos, temps,
+               rng, active):
+            caches = {name: self._layer(name).cache_join(
+                c, kv[name], rows, s)
+                for name, c in state["caches"].items()}
+            return _seed_rows(state, caches, rows, tok, lengths, max_new,
+                              eos, temps, rng, active)
 
-            self._fns[key] = aot_cache.wrap(
-                jax.jit(fn, donate_argnums=(0,)), self._graph_key(),
-                f"prefill_join:s{s}:t{tp}:b{bp}{tag}")
-        return self._fns[key]
+        return self._exe(f"prefill_join:s{s}:t{tp}:b{bp}", fn, donate=(0,))
 
     def grow_fn(self, s: int, s2: int):
         """Pad every cache from KV bucket ``s`` to ``s2`` (the bucket
@@ -595,141 +546,24 @@ class TransformerDecoder:
         Not donated: the cache shapes differ, so XLA could not alias
         them anyway — the old buffers free by refcount when the engine
         swaps states."""
-        tag = self._ktag()
-        key = ("grow", s, s2, tag)
-        if key not in self._fns:
-            def fn(state):
-                caches = {name: self._layer(name).cache_grow(c, s2)
-                          for name, c in state["caches"].items()}
-                return dict(state, caches=caches)
+        def fn(state):
+            caches = {name: self._layer(name).cache_grow(c, s2)
+                      for name, c in state["caches"].items()}
+            return dict(state, caches=caches)
 
-            self._fns[key] = aot_cache.wrap(
-                jax.jit(fn), self._graph_key(), f"kv_grow:s{s}:{s2}{tag}")
-        return self._fns[key]
+        return self._exe(f"kv_grow:s{s}:{s2}", fn)
 
     def release_fn(self, s: int):
         """Deactivate rows in-graph (deadline aborts, breaker resets):
         ``active &= keep``. State donated; everything else passes
         through aliased."""
-        tag = self._ktag()
-        key = ("release", s, tag)
-        if key not in self._fns:
-            def fn(state, keep):
-                caches = {name: self._layer(name).cache_release(c, keep)
-                          for name, c in state["caches"].items()}
-                return dict(state, caches=caches,
-                            active=state["active"] & keep)
+        def fn(state, keep):
+            caches = {name: self._layer(name).cache_release(c, keep)
+                      for name, c in state["caches"].items()}
+            return dict(state, caches=caches,
+                        active=state["active"] & keep)
 
-            self._fns[key] = aot_cache.wrap(
-                jax.jit(fn, donate_argnums=(0,)), self._graph_key(),
-                f"gen_release:s{s}{tag}")
-        return self._fns[key]
-
-    # --- speculative decoding (draft K, verify K+1 in one launch) ----------
-    def spec_verify_fn(self, s: int, k: int):
-        """Score a K-token drafted window in ONE wide launch — the
-        speculative-decoding verifier. Input ``drafts [K, B]`` holds the
-        draft model's proposals; the window fed through the graph is
-        ``[current token ; drafts]`` (K+1 positions), scored by
-        :meth:`_run_chunk` without a scan. Acceptance is resolved
-        in-graph: position ``i`` emits the token the TARGET samples
-        there (greedy argmax, or a categorical draw from the row's
-        frozen PRNG stream — the SAME rule sequential decode applies),
-        and emission continues only while the draft agreed at every
-        earlier position, so the emitted stream is token-identical to
-        non-speculative decode at ANY acceptance rate; drafts merely
-        decide how many positions one launch may emit. Per-row rollback
-        is the KV write cursor: all K+1 k/v blocks are written, but
-        ``positions`` advances only by the emitted count and the row's
-        PRNG stream consumes exactly that many draws — slots beyond the
-        cursor are dead weight the attention mask never reads, and the
-        next window overwrites them. State DONATED. Returns
-        ``(state', tokens [K+1, B], emitted [K+1, B],
-        accepted [B])`` — ``accepted`` counts the drafted tokens that
-        survived (emitted minus the always-emitted first position)."""
-        tag = self._ktag()
-        key = ("spec_verify", s, k, tag)
-        if key not in self._fns:
-            w = k + 1
-
-            def fn(params, state, drafts):
-                active = state["active"]
-                p0 = state["positions"]
-                window = jnp.concatenate(
-                    [state["tokens"][:, None],
-                     jnp.transpose(drafts)], axis=1)  # [B, K+1]
-                logits, caches = self._run_chunk(
-                    params, window, p0, state["caches"])
-
-                def split(carry, _):
-                    ks = jax.vmap(jax.random.split)(carry)
-                    return ks[:, 1], (ks[:, 0], ks[:, 1])
-
-                rng0 = state["rng"].astype(jnp.uint32)
-                _, (step_keys, chain) = jax.lax.scan(
-                    split, rng0, None, length=w)
-                tstar = jnp.stack([
-                    _sample_tokens(logits[:, i], step_keys[i],
-                                   state["temps"])
-                    for i in range(w)])  # [K+1, B]
-                match = jnp.cumprod(
-                    (drafts == tstar[:k]).astype(jnp.int32), axis=0)
-                a = match.sum(axis=0)  # accepted drafted prefix [B]
-                emits = []
-                emit = active
-                for i in range(w):
-                    if i > 0:
-                        gen_prev = p0 + i + 1 - state["prompt_lens"]
-                        emit = emit & (a >= i) \
-                            & (tstar[i - 1] != state["eos"]) \
-                            & (gen_prev < state["max_new"])
-                    emits.append(emit)
-                emitted = jnp.stack(emits)  # [K+1, B] bool
-                e = emitted.astype(jnp.int32).sum(axis=0)
-                positions_new = p0 + e
-                last_i = jnp.maximum(e - 1, 0)
-                last = jnp.take_along_axis(
-                    tstar, last_i[None, :], axis=0)[0]
-                tokens_new = jnp.where(e > 0, last, state["tokens"])
-                rng_sel = jnp.take_along_axis(
-                    chain, jnp.broadcast_to(
-                        last_i[None, :, None], (1,) + chain.shape[1:]),
-                    axis=0)[0]
-                rng_new = jnp.where((e > 0)[:, None], rng_sel,
-                                    state["rng"])
-                gen_now = positions_new - state["prompt_lens"] + 1
-                active_new = (e > 0) & (tokens_new != state["eos"]) \
-                    & (gen_now < state["max_new"])
-                accepted = jnp.maximum(e - 1, 0)
-                st = dict(state, caches=caches, tokens=tokens_new,
-                          positions=positions_new, active=active_new,
-                          rng=rng_new)
-                return st, tstar, emitted, accepted
-
-            self._fns[key] = aot_cache.wrap(
-                jax.jit(fn, donate_argnums=(1,)), self._graph_key(),
-                f"spec_verify:s{s}:k{k}{tag}")
-        return self._fns[key]
-
-    def spec_sync_fn(self, s: int):
-        """Roll the DRAFT state's cursor back onto the target's after a
-        verify window: the draft speculated K steps ahead on its own
-        chain, but its k/v for the accepted slots are already correct
-        (accepted means the drafted token WAS the emitted token), so
-        reconciliation is pure bookkeeping — set tokens/positions/active
-        to the target's and let the mask strand the rejected tail. State
-        DONATED; caches pass through aliased."""
-        tag = self._ktag()
-        key = ("spec_sync", s, tag)
-        if key not in self._fns:
-            def fn(state, tokens, positions, active):
-                return dict(state, tokens=tokens, positions=positions,
-                            active=active)
-
-            self._fns[key] = aot_cache.wrap(
-                jax.jit(fn, donate_argnums=(0,)), self._graph_key(),
-                f"spec_sync:s{s}{tag}")
-        return self._fns[key]
+        return self._exe(f"gen_release:s{s}", fn, donate=(0,))
 
     # --- prefix-cache executables ------------------------------------------
     def prefix_attach_fn(self, s: int, tpre: int, bp: int):
@@ -741,27 +575,21 @@ class TransformerDecoder:
         prefix length. State DONATED — the audit-visible in-place cache
         write that makes a hit O(pages copied), not O(prefix
         re-projected)."""
-        tag = self._ktag()
-        key = ("prefix_attach", s, tpre, bp, tag)
-        if key not in self._fns:
-            def fn(state, prefix_kv, rows, prefix_lens):
-                caches = {}
-                for name, c in state["caches"].items():
-                    caches[name] = {
-                        "k": c["k"].at[rows, :tpre].set(
-                            prefix_kv[name]["k"], mode="drop"),
-                        "v": c["v"].at[rows, :tpre].set(
-                            prefix_kv[name]["v"], mode="drop"),
-                    }
-                return dict(
-                    state, caches=caches,
-                    positions=state["positions"].at[rows].set(
-                        prefix_lens, mode="drop"))
+        def fn(state, prefix_kv, rows, prefix_lens):
+            caches = {}
+            for name, c in state["caches"].items():
+                caches[name] = {
+                    "k": c["k"].at[rows, :tpre].set(
+                        prefix_kv[name]["k"], mode="drop"),
+                    "v": c["v"].at[rows, :tpre].set(
+                        prefix_kv[name]["v"], mode="drop"),
+                }
+            return dict(
+                state, caches=caches,
+                positions=state["positions"].at[rows].set(
+                    prefix_lens, mode="drop"))
 
-            self._fns[key] = aot_cache.wrap(
-                jax.jit(fn, donate_argnums=(0,)), self._graph_key(),
-                f"prefix_attach:s{s}:t{tpre}:b{bp}{tag}")
-        return self._fns[key]
+        return self._exe(f"prefix_attach:s{s}:t{tpre}:b{bp}", fn, donate=(0,))
 
     def suffix_prompt_fn(self, ts: int, tpre: int, bp: int):
         """Suffix-only prefill for a prefix-cache-hit join group: like
@@ -769,22 +597,13 @@ class TransformerDecoder:
         the shared prefix pages (see :meth:`_run_suffix`). NOT donated —
         the prefix pages are shared, refcounted buffers that other
         requests may attach concurrently."""
-        tag = self._ktag()
-        key = ("suffix_prompt", ts, tpre, bp, tag)
-        if key not in self._fns:
-            def fn(params, suffix, suf_lens, prefix_kv, prefix_lens,
-                   max_new, eos, temps, rng):
-                logits, kv = self._run_suffix(
-                    params, suffix, suf_lens, prefix_kv, prefix_lens)
-                step_keys, rng_next = _advance_rng(rng)
-                tok = _sample_tokens(logits, step_keys, temps)
-                active = (tok != eos) & (max_new > 1)
-                return kv, tok, active, rng_next
+        def fn(params, suffix, suf_lens, prefix_kv, prefix_lens,
+               max_new, eos, temps, rng):
+            logits, kv = self._run_suffix(
+                params, suffix, suf_lens, prefix_kv, prefix_lens)
+            return (kv,) + _first_token(logits, max_new, eos, temps, rng)
 
-            self._fns[key] = aot_cache.wrap(
-                jax.jit(fn), self._graph_key(),
-                f"gen_prompt_sfx:t{ts}:p{tpre}:b{bp}{tag}")
-        return self._fns[key]
+        return self._exe(f"gen_prompt_sfx:t{ts}:p{tpre}:b{bp}", fn)
 
     def suffix_join_fn(self, s: int, ts: int, bp: int):
         """Join a suffix-prefilled group behind its attached prefix: the
@@ -796,51 +615,35 @@ class TransformerDecoder:
         group slots write back what the target row already holds (a
         gather/select no-op) because ``dynamic_update_slice`` clamps
         instead of dropping. State DONATED."""
-        tag = self._ktag()
-        key = ("suffix_join", s, ts, bp, tag)
-        if key not in self._fns:
-            def fn(state, kv, rows, tok, prefix_lens, lengths, max_new,
-                   eos, temps, rng, active):
-                b = self.max_batch
-                valid = rows < b
-                rc = jnp.minimum(rows, b - 1)
-                off = jnp.clip(prefix_lens, 0, s - ts)
-                caches = {}
-                for name, c in state["caches"].items():
-                    ck, cv = c["k"], c["v"]
-                    for i in range(bp):
-                        cur_k = jax.lax.dynamic_slice(
-                            ck, (rc[i], off[i], 0),
-                            (1,) + kv[name]["k"].shape[1:])
-                        cur_v = jax.lax.dynamic_slice(
-                            cv, (rc[i], off[i], 0),
-                            (1,) + kv[name]["v"].shape[1:])
-                        new_k = jnp.where(valid[i], kv[name]["k"][i][None],
-                                          cur_k)
-                        new_v = jnp.where(valid[i], kv[name]["v"][i][None],
-                                          cur_v)
-                        ck = jax.lax.dynamic_update_slice(
-                            ck, new_k, (rc[i], off[i], 0))
-                        cv = jax.lax.dynamic_update_slice(
-                            cv, new_v, (rc[i], off[i], 0))
-                    caches[name] = {"k": ck, "v": cv}
-                at = lambda a, v: a.at[rows].set(v, mode="drop")  # noqa: E731
-                return dict(
-                    state, caches=caches,
-                    tokens=at(state["tokens"], tok),
-                    positions=at(state["positions"], lengths),
-                    prompt_lens=at(state["prompt_lens"],
-                                   jnp.maximum(lengths, 1)),
-                    max_new=at(state["max_new"], max_new),
-                    eos=at(state["eos"], eos),
-                    temps=at(state["temps"], temps),
-                    rng=at(state["rng"], rng),
-                    active=at(state["active"], active))
+        def fn(state, kv, rows, tok, prefix_lens, lengths, max_new,
+               eos, temps, rng, active):
+            b = self.max_batch
+            valid = rows < b
+            rc = jnp.minimum(rows, b - 1)
+            off = jnp.clip(prefix_lens, 0, s - ts)
+            caches = {}
+            for name, c in state["caches"].items():
+                ck, cv = c["k"], c["v"]
+                for i in range(bp):
+                    cur_k = jax.lax.dynamic_slice(
+                        ck, (rc[i], off[i], 0),
+                        (1,) + kv[name]["k"].shape[1:])
+                    cur_v = jax.lax.dynamic_slice(
+                        cv, (rc[i], off[i], 0),
+                        (1,) + kv[name]["v"].shape[1:])
+                    new_k = jnp.where(valid[i], kv[name]["k"][i][None],
+                                      cur_k)
+                    new_v = jnp.where(valid[i], kv[name]["v"][i][None],
+                                      cur_v)
+                    ck = jax.lax.dynamic_update_slice(
+                        ck, new_k, (rc[i], off[i], 0))
+                    cv = jax.lax.dynamic_update_slice(
+                        cv, new_v, (rc[i], off[i], 0))
+                caches[name] = {"k": ck, "v": cv}
+            return _seed_rows(state, caches, rows, tok, lengths, max_new,
+                              eos, temps, rng, active)
 
-            self._fns[key] = aot_cache.wrap(
-                jax.jit(fn, donate_argnums=(0,)), self._graph_key(),
-                f"prefix_join:s{s}:t{ts}:b{bp}{tag}")
-        return self._fns[key]
+        return self._exe(f"prefix_join:s{s}:t{ts}:b{bp}", fn, donate=(0,))
 
     # --- warmup -------------------------------------------------------------
     def _kv_struct(self, bp: int, tp: int):
@@ -858,52 +661,35 @@ class TransformerDecoder:
         i = ladder.index(b)
         return 1 if i == 0 else ladder[i - 1] + 1
 
-    def warm_all(self, fused_steps=(1,), spec_steps=(), spec_sync=False,
-                 spec_draft=(), prefix=False) -> dict:
+    def warm_all(self, fused_steps=(1,), prefix=False) -> dict:
         """Compile every (bucket, K) combination WITHOUT dispatching
         (``AotStep.warm`` on ShapeDtypeStructs): all KV buckets × K for
         decode, prompt × join buckets for prefill, every (S, T<=S, B)
-        join, every upward grow hop, the release fn. ``spec_steps``
-        additionally warms the ``spec_verify:s:k`` verifier (+ the sync
-        op) per KV bucket; ``spec_sync`` warms just the draft-side sync;
-        ``prefix`` warms every feasible prefix-attach / suffix-prefill /
-        suffix-join bucket combination (feasible = some real prefix and
-        suffix lengths map to the pair without exceeding ``max_len``).
-        After this, mixed prompt/output-length traffic — including mixed
-        prefix hit/miss and speculative accept/reject — is
-        zero-recompile by construction (pinned in tests and reported by
-        ``bench_decode.py``)."""
+        join, every upward grow hop, the release fn. ``prefix`` warms
+        every feasible prefix-attach / suffix-prefill / suffix-join
+        bucket combination (feasible = some real prefix and suffix
+        lengths map to the pair without exceeding ``max_len``). After
+        this, mixed prompt/output-length traffic — including mixed
+        prefix hit/miss — is zero-recompile by construction (pinned in
+        tests)."""
         sds = jax.ShapeDtypeStruct
         params = jax.tree_util.tree_map(
             lambda x: sds(jnp.shape(x), x.dtype), self._net.params)
 
-        def row(shape, dt):
-            return sds(shape, dt)
+        def rows(bp):
+            """Avals of a join group of ``bp``: one int32 a row, and the
+            request arrays every prefill takes (max_new, eos, temps,
+            rng)."""
+            i32 = sds((bp,), jnp.int32)
+            return i32, (i32, i32, sds((bp,), jnp.float32),
+                         sds((bp, 2), jnp.uint32)), sds((bp,), jnp.bool_)
 
-        nb = self.max_batch
         before = aot_cache.stats()
         for s in self.kv_ladder:
             st = self._struct_of(s)
             for k in fused_steps:
                 self.decode_fn(s, int(k)).warm(params, st)
-            for k in spec_steps:
-                # the K+1-wide verify window cannot fit a bucket
-                # shorter than it; the engine grows the bucket past
-                # max_pos + K + 1 before ever dispatching a spec
-                # window, so the small-bucket shapes are unreachable
-                if s < int(k) + 1:
-                    continue
-                self.spec_verify_fn(s, int(k)).warm(
-                    params, st, row((int(k), nb), jnp.int32))
-            for k in spec_draft:
-                self.spec_draft_fn(s, int(k)).warm(
-                    params, st, row((nb,), jnp.int32),
-                    row((nb,), jnp.int32), row((nb,), jnp.bool_))
-            if spec_sync:
-                self.spec_sync_fn(s).warm(
-                    st, row((nb,), jnp.int32), row((nb,), jnp.int32),
-                    row((nb,), jnp.bool_))
-            self.release_fn(s).warm(st, row((self.max_batch,), jnp.bool_))
+            self.release_fn(s).warm(st, sds((self.max_batch,), jnp.bool_))
             for s2 in self.kv_ladder:
                 if s2 > s:
                     self.grow_fn(s, s2).warm(st)
@@ -913,62 +699,44 @@ class TransformerDecoder:
             # prefix machinery compiles ONE join-width per shape — the
             # full join ladder here would multiply the warm set ~4x
             # for no measurable prefill win at these sizes
-            bp = nb
+            bp = self.max_batch
+            i32, req, live = rows(bp)
             for tpre in self.prompt_ladder:
                 m_min = self._ladder_floor(self.prompt_ladder, tpre)
                 for s in self.kv_ladder:
                     if tpre <= s:
                         self.prefix_attach_fn(s, tpre, bp).warm(
-                            self._struct_of(s),
-                            self._kv_struct(bp, tpre),
-                            row((bp,), jnp.int32), row((bp,), jnp.int32))
+                            self._struct_of(s), self._kv_struct(bp, tpre),
+                            i32, i32)
                 for ts in self.prompt_ladder:
                     if m_min + self._ladder_floor(
                             self.prompt_ladder, ts) > self.max_len:
                         continue
                     self.suffix_prompt_fn(ts, tpre, bp).warm(
-                        params, row((bp, ts), jnp.int32),
-                        row((bp,), jnp.int32),
-                        self._kv_struct(bp, tpre),
-                        row((bp,), jnp.int32), row((bp,), jnp.int32),
-                        row((bp,), jnp.int32), row((bp,), jnp.float32),
-                        row((bp, 2), jnp.uint32))
+                        params, sds((bp, ts), jnp.int32), i32,
+                        self._kv_struct(bp, tpre), i32, *req)
             for s in self.kv_ladder:
                 for ts in self.prompt_ladder:
-                    if ts > s:
-                        continue
-                    self.suffix_join_fn(s, ts, bp).warm(
-                        self._struct_of(s), self._kv_struct(bp, ts),
-                        row((bp,), jnp.int32), row((bp,), jnp.int32),
-                        row((bp,), jnp.int32), row((bp,), jnp.int32),
-                        row((bp,), jnp.int32), row((bp,), jnp.int32),
-                        row((bp,), jnp.float32),
-                        row((bp, 2), jnp.uint32), row((bp,), jnp.bool_))
+                    if ts <= s:
+                        self.suffix_join_fn(s, ts, bp).warm(
+                            self._struct_of(s), self._kv_struct(bp, ts),
+                            i32, i32, i32, i32, *req, live)
         for tp in self.prompt_ladder:
             for bp in self.join_ladder:
-                args = (params, row((bp, tp), jnp.int32),
-                        row((bp,), jnp.int32), row((bp,), jnp.int32),
-                        row((bp,), jnp.int32), row((bp,), jnp.float32),
-                        row((bp, 2), jnp.uint32))
-                self.prompt_fn(tp, bp).warm(*args)
+                i32, req, live = rows(bp)
+                self.prompt_fn(tp, bp).warm(
+                    params, sds((bp, tp), jnp.int32), i32, *req)
                 for s in self.kv_ladder:
-                    if tp > s:
-                        continue
-                    self.join_fn(s, tp, bp).warm(
-                        self._struct_of(s), self._kv_struct(bp, tp),
-                        row((bp,), jnp.int32),
-                        row((bp,), jnp.int32), row((bp,), jnp.int32),
-                        row((bp,), jnp.int32), row((bp,), jnp.int32),
-                        row((bp,), jnp.float32), row((bp, 2), jnp.uint32),
-                        row((bp,), jnp.bool_))
+                    if tp <= s:
+                        self.join_fn(s, tp, bp).warm(
+                            self._struct_of(s), self._kv_struct(bp, tp),
+                            i32, i32, i32, *req, live)
         after = aot_cache.stats()
         return {
             "kv_buckets": list(self.kv_ladder),
             "prompt_buckets": list(self.prompt_ladder),
             "join_buckets": list(self.join_ladder),
             "fused_steps": [int(k) for k in fused_steps],
-            "spec_steps": [int(k) for k in spec_steps],
-            "spec_draft": [int(k) for k in spec_draft],
             "prefix": bool(prefix),
             "compiled": after["misses"] - before["misses"],
             "compile_seconds": round(
@@ -997,8 +765,7 @@ class TransformerDecoder:
         executables the continuous engine uses (one live row, the other
         ``max_batch - 1`` rows inactive). This is the unbatched
         reference: the engine's continuous schedule is pinned to produce
-        token-identical greedy output, and ``bench_decode.py``'s
-        sequential baseline is this loop."""
+        token-identical greedy output."""
         toks = self.validate_request(tokens, max_new)
         ln = len(toks)
         tp = bucket_for(ln, self.prompt_ladder)

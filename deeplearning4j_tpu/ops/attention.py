@@ -116,11 +116,9 @@ def chunk_decode_attention(q, k_cache, v_cache, positions, scale=None):
     both qualify). One softmax over the whole row of scores, no online
     softmax: the row is small and one fused pass is its fastest shape.
 
-    ``Tq = K + 1`` is the speculative verifier's window (``spec_verify``:
-    K+1 target positions for one dispatch instead of K+1 sequential
-    steps); ``Tq = 1`` is an ordinary decode step
-    (:func:`decode_attention`). Returns ``[batch, time, heads,
-    head_dim]``."""
+    ``Tq = 1`` is an ordinary decode step (:func:`decode_attention`);
+    a wider window is a chunk of tokens in one dispatch. Returns
+    ``[batch, time, heads, head_dim]``."""
     b, t, h, d = q.shape
     s = k_cache.shape[1]
     sm = _scale(q, scale)
@@ -331,7 +329,7 @@ def bounded_decode_attention(q, k_cache, v_cache, positions, scale=None):
 
 def cache_update(cache, new, positions):
     """Write a token block ``new: [batch, t, heads * head_dim]`` (t = 1
-    for ordinary decode, t = K+1 for a speculative verify window) into
+    for ordinary decode, more for a chunk of tokens) into
     ``cache: [batch, max_len, heads * head_dim]`` at per-sequence slot
     ``positions: [batch]``: one ``dynamic_update_slice`` a row (the slot
     index is traced, so one executable serves every position), ``t``

@@ -36,8 +36,8 @@ arithmetic is row-independent and every row runs the same compiled
 executables, so continuous scheduling changes WHEN a sequence's tokens
 are computed, never WHAT they are.
 
-Two multiplicative throughput features ride on top, both OFF by
-default and composable with each other and with continuous batching:
+One throughput feature rides on top, OFF by default and composable
+with continuous batching:
 
 - **Radix-tree prefix caching** (``prefix_cache=True``): finished
   prefills donate page-aligned KV blocks to a refcounted
@@ -49,25 +49,10 @@ default and composable with each other and with continuous batching:
   the prompt served from cache. Pinned pages are decref'd on every
   terminal edge (finish, queue expiry, mid-generation deadline,
   dispatch failure, close), so the tree always returns to its
-  steady-state page count.
-- **Draft-model speculative decoding** (``draft_conf=...``): a small
-  same-vocabulary draft decoder speculates ``fused_steps`` tokens per
-  iteration with its own fused window; the target scores all K+1
-  positions in ONE wide ``spec_verify`` launch and emits the accepted
-  prefix plus one bonus token. Emission replays the target's own
-  sampling rule position by position, so output is token-identical to
-  non-speculative decode at ANY acceptance rate (greedy and seeded
-  sampling both) — the draft only decides how many tokens each launch
-  may emit. Near the context limit (``pos + K + 1 > max_len``) the
-  iteration falls back to the plain fused window, which can leave the
-  draft's KV with unwritten slots: that degrades draft agreement,
-  never output correctness.
-
-Both features key their executables into the AOT cache
-(``prefix_attach:s:t:b``, ``gen_prompt_sfx:t:p:b``,
-``prefix_join:s:t:b``, ``spec_verify:s:k``, ``spec_sync:s``) and
-``warmup()`` pre-compiles every feasible geometry, so mixed hit/miss
-and accept/reject traffic stays zero-recompile.
+  steady-state page count. Its executables are keyed into the AOT cache
+  (``prefix_attach:s:t:b``, ``gen_prompt_sfx:t:p:b``,
+  ``prefix_join:s:t:b``) and ``warmup()`` pre-compiles every feasible
+  geometry, so mixed hit/miss traffic stays zero-recompile.
 """
 
 from __future__ import annotations
@@ -121,16 +106,6 @@ class GenerationConfig:
     kv_bucket_min: int = 32     # smallest KV length bucket
     prompt_bucket_min: int = 8  # smallest prompt padding bucket
     max_new_default: int = 64   # max_new_tokens when the caller omits it
-    # speculative decoding: a small same-vocabulary causal LM (decoder /
-    # initialized graph / zoo config) that drafts spec_tokens tokens per
-    # iteration for the target to verify in one launch. None = off.
-    draft_conf: object = None
-    # draft window length K (default fused_steps). Unlike the plain
-    # fused window, a spec window costs ~one draft launch + one wide
-    # verify regardless of K, so K can run well past fused_steps — the
-    # verifier truncates emission wherever the draft diverges, so a
-    # long window never over-emits, it just caps the per-launch win.
-    spec_tokens: Optional[int] = None
     # radix-tree prompt-prefix KV cache. Off by default; page size is
     # the trie granularity in tokens, pages the LRU eviction budget.
     prefix_cache: bool = False
@@ -229,27 +204,16 @@ class GenerationEngine:
         if self._dec.max_batch != cfg.max_batch:
             cfg.max_batch = self._dec.max_batch
         # a layer whose state is not K/V pages cannot be rebuilt from the
-        # prefix cache's pages or rolled back by the verifier's cursor:
-        # refused here, by name, not at the first hit or window
-        for wanted, method, what in (
-                (cfg.prefix_cache, "prefill_suffix", "prefix_cache=True"),
-                (cfg.draft_conf is not None, "decode_chunk",
-                 "draft_conf (speculative decoding)")):
-            missing = self._dec.walks_missing(method) if wanted else []
-            if missing:
-                raise ValueError(
-                    f"{what} is not supported with {', '.join(missing)}: "
-                    f"no {method}() for that layer's state")
-        self._draft_dec: Optional[TransformerDecoder] = None
-        self._draft_state = None
-        self._spec_k = int(cfg.spec_tokens or cfg.fused_steps)
-        if cfg.draft_conf is not None:
-            self._draft_dec = self._coerce_draft(cfg.draft_conf)
+        # prefix cache's pages: refused here, by name, not at the first hit
+        missing = (self._dec.walks_missing("prefill_suffix")
+                   if cfg.prefix_cache else [])
+        if missing:
+            raise ValueError(
+                f"prefix_cache=True is not supported with "
+                f"{', '.join(missing)}: no prefill_suffix() for that "
+                f"layer's state")
         self._prefix = (PrefixCache(cfg.prefix_page, cfg.prefix_cache_pages)
                         if cfg.prefix_cache else None)
-        self._spec_windows = 0
-        self._spec_drafted = 0
-        self._spec_accepted = 0
         self._breaker = (CircuitBreaker(
             name=(f"serving:{name}" if name
                   else f"decode-{next(_ENGINE_SEQ)}"))
@@ -287,46 +251,6 @@ class GenerationEngine:
         self._state_kinds = ",".join(sorted(held))
         telemetry.record_decode_state_bytes(held)
         telemetry.register_generation_engine(self)
-
-    def _coerce_draft(self, model) -> TransformerDecoder:
-        """Build the draft decoder with the TARGET's bucket geometry and
-        reject mismatches up front: the verifier streams the draft's
-        proposals straight into target executables, so the two must
-        agree on vocabulary, row count and every ladder (otherwise
-        spec windows would silently recompile per geometry)."""
-        cfg = self.config
-        if isinstance(model, TransformerDecoder):
-            draft = model
-        elif hasattr(model, "params"):
-            draft = TransformerDecoder(
-                model, max_batch=cfg.max_batch,
-                max_len=self._dec.max_len,
-                kv_bucket_min=cfg.kv_bucket_min,
-                prompt_bucket_min=cfg.prompt_bucket_min)
-        elif hasattr(model, "decoder"):
-            draft = model.decoder(
-                max_batch=cfg.max_batch,
-                kv_bucket_min=cfg.kv_bucket_min,
-                prompt_bucket_min=cfg.prompt_bucket_min)
-        else:
-            raise TypeError(
-                "draft_conf must be a TransformerDecoder, a causal-LM "
-                "ComputationGraph, or a zoo config with .decoder()")
-        if draft.vocab_size != self._dec.vocab_size:
-            raise ValueError(
-                f"draft vocab {draft.vocab_size} != target "
-                f"{self._dec.vocab_size}: speculative tokens would be "
-                "meaningless to the verifier")
-        if (draft.max_batch != self._dec.max_batch
-                or draft.max_len != self._dec.max_len
-                or list(draft.kv_ladder) != list(self._dec.kv_ladder)
-                or list(draft.prompt_ladder) != list(
-                    self._dec.prompt_ladder)):
-            raise ValueError(
-                "draft/target bucket geometry must match (max_batch, "
-                "max_len, kv and prompt ladders) so draft windows ride "
-                "the same AOT keys as target windows")
-        return draft
 
     # --- submit / wait ------------------------------------------------------
     def submit(self, tokens: Sequence[int], max_new_tokens: int = None,
@@ -450,32 +374,21 @@ class GenerationEngine:
         (prompt bucket × join bucket) prefill, every join/grow hop —
         compile-only, no dispatch. After this the zero-recompile
         invariant holds for ANY mix of prompt/output lengths up to
-        ``max_len`` (pinned by test and reported by bench_decode.py).
-        With a draft model the verifier (``spec_verify``) and both sync
-        ops are warmed too; with the prefix cache every feasible
-        attach/suffix-prefill/suffix-join geometry is — so mixed
-        hit/miss and accept/reject traffic stays zero-recompile.
+        ``max_len`` (pinned by test). With the prefix cache every
+        feasible attach/suffix-prefill/suffix-join geometry is warmed
+        too, so mixed hit/miss traffic stays zero-recompile.
 
         ``autotune_kernels`` (with ``conf.use_kernels``) tunes every
-        bucket-ladder attention envelope FIRST, so the warmed
-        executables bake the paged-decode / flash-prefill winners —
-        tuning after warmup would mint new ``kern:`` keys and re-warm
-        from scratch."""
+        prompt-bucket attention envelope FIRST, so the warmed prefill
+        executables bake the flash winners — tuning after warmup would
+        mint new ``kern:`` keys and re-warm from scratch."""
         if autotune_kernels and self._dec.use_kernels:
             from deeplearning4j_tpu import kernels
 
             kernels.autotune_decoder(self._dec, **autotune_kw)
-            if self._draft_dec is not None:
-                kernels.autotune_decoder(self._draft_dec, **autotune_kw)
-        k = self.config.fused_steps
         out = self._dec.warm_all(
-            fused_steps=(1, k),
-            spec_steps=(self._spec_k,) if self._draft_dec is not None
-            else (),
+            fused_steps=(1, self.config.fused_steps),
             prefix=self._prefix is not None)
-        if self._draft_dec is not None:
-            out["draft"] = self._draft_dec.warm_all(
-                fused_steps=(1, k), spec_draft=(self._spec_k,))
         out["kernels"] = {"enabled": self._dec.use_kernels,
                           "tag": self._dec._ktag()}
         return out
@@ -514,15 +427,6 @@ class GenerationEngine:
         out["aot_cache"] = aot_cache.stats()
         if self._prefix is not None:
             out["prefix_cache"] = self._prefix.stats()
-        if self._draft_dec is not None:
-            drafted = self._spec_drafted
-            out["speculative"] = {
-                "windows": self._spec_windows,
-                "drafted": drafted,
-                "accepted": self._spec_accepted,
-                "acceptance": (self._spec_accepted / drafted
-                               if drafted else 0.0),
-            }
         if self._breaker is not None:
             out["circuit_breaker"] = self._breaker.status()
         return out
@@ -624,13 +528,8 @@ class GenerationEngine:
             if self._state is None:
                 self._S = max(self._S, s2)
                 self._state = self._dec.new_state(self._S)
-                if self._draft_dec is not None:
-                    self._draft_state = self._draft_dec.new_state(self._S)
             else:
                 self._state = self._dec.grow_fn(self._S, s2)(self._state)
-                if self._draft_dec is not None:
-                    self._draft_state = self._draft_dec.grow_fn(
-                        self._S, s2)(self._draft_state)
                 self._S = s2
             telemetry.record_decode_state_bytes(
                 self._dec.state_bytes(self._S))
@@ -641,9 +540,7 @@ class GenerationEngine:
         prefill in one full launch (and donate their KV pages to the
         prefix cache); prefix-cache hits prefill only their suffix,
         grouped by suffix bucket so each group's geometry is a warmed
-        AOT key; with a draft model every join also prefills the
-        draft's cache (full prompt — the draft does not ride the
-        prefix cache) so speculation starts on the next window."""
+        AOT key."""
         cold = [r for r in joins if not r.prefix_len]
         hits = [r for r in joins if r.prefix_len]
         widest = self._dec.join_ladder[-1]
@@ -657,8 +554,6 @@ class GenerationEngine:
                 groups.setdefault(ts, []).append(r)
             for ts in sorted(groups):
                 self._prefill_suffix_group(groups[ts], ts)
-        if self._draft_dec is not None:
-            self._draft_prefill(joins)
 
     def _prefill_cold(self, joins: List[_GenRequest]):
         cfg = self.config
@@ -825,50 +720,6 @@ class GenerationEngine:
             nodes = self._prefix.insert(r.tokens, r.n, make_slicer(i, off))
             r.prefix_nodes = list(r.prefix_nodes) + list(nodes)
 
-    def _draft_prefill(self, joins: List[_GenRequest]):
-        """Prefill the DRAFT's cache for every join (full prompt, one
-        launch) and seed its rows from the TARGET's first sampled token:
-        the draft row greedily extends the target's stream, never its
-        own (eos=-1 / max_new=max_len / temp=0 — the draft must never
-        self-terminate; the verifier decides all emission)."""
-        d = self._draft_dec
-        cfg = self.config
-        tp = bucket_for(max(r.n for r in joins), d.prompt_ladder)
-        bp = bucket_for(len(joins), d.join_ladder)
-        with self._span("gen.prefill", kind="draft", joins=len(joins),
-                        prompt_bucket=tp, rows=bp):
-            with self._span("gen.prefill.stage") as sp:
-                prompts = np.full((bp, tp), d.pad_id, np.int32)
-                lengths = np.zeros((bp,), np.int32)
-                rows = np.full((bp,), cfg.max_batch, np.int32)
-                max_new = np.full((bp,), d.max_len, np.int32)
-                eos = np.full((bp,), -1, np.int32)
-                temps = np.zeros((bp,), np.float32)
-                rng = np.zeros((bp, 2), np.uint32)
-                tok = np.zeros((bp,), np.int32)
-                active = np.zeros((bp,), bool)
-                asked = time.monotonic_ns()
-                with self._cond:
-                    _lock_wait(sp, asked)
-                    for i, r in enumerate(joins):
-                        prompts[i, :r.n] = r.tokens
-                        lengths[i] = r.n
-                        rows[i] = r.row
-                        rng[i] = r.rng
-                        tok[i] = r.out[0]
-                        active[i] = self._rows[r.row] is r
-
-            def once():
-                faults.fault_point(self._fault_site)
-                return d.prompt_fn(tp, bp)(
-                    d.params, prompts, lengths, max_new, eos, temps, rng)
-
-            with self._span("gen.prefill.launch"):
-                kv, _tok, _act, rng2 = self._call_prefill(once, joins)
-                self._draft_state = d.join_fn(self._S, tp, bp)(
-                    self._draft_state, kv, rows, tok, lengths, max_new, eos,
-                    temps, rng2, active)
-
     def _account_prefill(self, joins, tok, active, bp, t0):
         with self._span("gen.prefill.readback", sync=True):
             tok = np.asarray(tok)
@@ -879,6 +730,9 @@ class GenerationEngine:
             asked = time.monotonic_ns()
             with self._cond:
                 _lock_wait(sp, asked)
+                # counted before a row born retired wakes its waiter, as
+                # the decode window counts
+                telemetry.record_decode_prefill(len(joins), bp, now - t0)
                 for i, r in enumerate(joins):
                     r.out.append(int(tok[i]))
                     self._positions[r.row] = r.n
@@ -897,7 +751,6 @@ class GenerationEngine:
                 self._joined_total += len(joins)
                 self._tokens_total += len(joins)
                 self._prefill_seconds += now - t0
-        telemetry.record_decode_prefill(len(joins), bp, now - t0)
         if self._breaker is not None:
             self._breaker.on_success()
 
@@ -906,6 +759,9 @@ class GenerationEngine:
             self._decode_window(parent)
 
     def _decode_window(self, parent):
+        """One decode window: plan (grow the cache to hold K more
+        positions) → launch ``decode_fn(S, K)`` → read back → account →
+        release the rows whose deadline passed."""
         cfg = self.config
         k = cfg.fused_steps
         t0 = time.monotonic()
@@ -913,73 +769,36 @@ class GenerationEngine:
             asked = time.monotonic_ns()
             with self._cond:
                 _lock_wait(sp, asked)
-                active_rows = [r for r in self._rows if r is not None]
-                max_pos = max((self._positions[r.row] for r in active_rows
+                max_pos = max((self._positions[r.row] for r in self._rows
                                if r is not None), default=0)
-            # speculative window needs K+1 cache slots past the deepest
-            # row (K drafts + the bonus position); past that the
-            # iteration falls back to the plain fused window — the
-            # dynamic_update_slice clamp would otherwise corrupt valid
-            # slots. The fallback can leave the draft cache with
-            # unwritten slots, which degrades draft agreement but never
-            # output correctness (the verifier replays the target's own
-            # sampling rule regardless).
-            ks = self._spec_k
-            spec = (self._draft_dec is not None
-                    and max_pos + ks + 1 <= self._dec.max_len)
-            need = max_pos + (ks + 1 if spec else k)
-            sp.annotate(grew=self._grow_to(min(need, self._dec.max_len)))
-        accepted = counts = None
-
-        # NO retry on decode windows: the state pytrees are donated
-        # into the executables, so a mid-flight failure may have
-        # consumed them — _on_dispatch_failure resets instead
-        if spec:
-            k = ks
+            sp.annotate(grew=self._grow_to(
+                min(max_pos + k, self._dec.max_len)))
+        # NO retry on decode windows: the state pytree is donated into
+        # the executable, so a mid-flight failure may have consumed it —
+        # _on_dispatch_failure resets instead
         with self._span("gen.decode.launch"):
             faults.fault_point(self._fault_site)
-            if spec:
-                # ONE launch syncs the draft's cursor onto the target's
-                # (reconciling the previous window's rollback) and runs
-                # its fused K-step draft window
-                self._draft_state, drafts, _ = self._draft_dec.spec_draft_fn(
-                    self._S, k)(
-                    self._draft_dec.params, self._draft_state,
-                    self._state["tokens"], self._state["positions"],
-                    self._state["active"])
-                self._state, toks, emitted, accepted = \
-                    self._dec.spec_verify_fn(self._S, k)(
-                        self._net_params(), self._state, drafts)
-            else:
-                self._state, toks, emitted, *counts = self._dec.decode_fn(
-                    self._S, k)(self._net_params(), self._state)
+            self._state, toks, emitted, *counts = self._dec.decode_fn(
+                self._S, k)(self._net_params(), self._state)
         with self._span("gen.decode.readback", sync=True):
-            if accepted is not None:
-                accepted = np.asarray(accepted)
             toks = np.asarray(toks)
             emitted = np.asarray(emitted)
-            if counts:      # the cached layers' counters, same read-back
-                counts = np.asarray(counts[0])
+            # the cached layers' counters, same read-back
+            counts = dict(zip(self._dec.counter_names,
+                              np.asarray(counts[0]).tolist())) \
+                if counts else {}
         now = time.monotonic()
         n_emitted = int(emitted.sum())
-        occupancy = 0
         released = []
-        finished = 0
         with self._span("gen.decode.account") as sp:
             asked = time.monotonic_ns()
             with self._cond:
                 _lock_wait(sp, asked)
                 occupancy = sum(r is not None for r in self._rows)
+                finished = []
                 for b, req in enumerate(self._rows):
                     if req is None:
                         continue
-                    if accepted is not None and emitted[0, b]:
-                        e_b = int(emitted[:, b].sum())
-                        telemetry.record_spec_window(
-                            int(accepted[b]), k, e_b)
-                        self._spec_windows += 1
-                        self._spec_drafted += k
-                        self._spec_accepted += int(accepted[b])
                     if req.trace is not None:
                         req.trace.event("decode_window", {
                             "k": k, "kv_bucket": self._S,
@@ -996,43 +815,46 @@ class GenerationEngine:
                             done = True
                             break
                     if done:
-                        self._finish_locked(req, now)
-                        self._n_active -= 1
-                        finished += 1
+                        finished.append(req)
                     elif req.deadline is not None and now > req.deadline:
-                        req.error = DeadlineExpiredError(
-                            "deadline expired mid-generation after "
-                            f"{len(req.out)} tokens")
-                        telemetry.record_decode_request("expired", now - req.t0, model=self.name)
-                        tracing.finish_trace(req.trace, "expired",
-                                             {"tokens": len(req.out)})
-                        self._release_prefix(req)
-                        req.complete(now)
-                        self._rows[b] = None
-                        self._n_active -= 1
                         released.append(b)
                 self._tokens_total += n_emitted
                 self._decode_seconds += now - t0
-                if counts is not None and len(counts):
-                    counts = dict(zip(self._dec.counter_names,
-                                      counts.tolist()))
-                    telemetry.record_decode_layer_counts(counts)
-                    for name, n in counts.items():
-                        self._layer_counts[name] += n
-                rows_in_use = sum(r is not None for r in self._rows)
-                sp.annotate(finished=finished, expired=len(released))
+                for name, n in counts.items():
+                    self._layer_counts[name] += n
+                # count BEFORE a finished request's waiter wakes: a
+                # snapshot taken as generate() returns holds this
+                # window's tokens, and /metrics agrees with the request
+                telemetry.record_decode_layer_counts(counts)
+                telemetry.record_decode_iteration(
+                    n_emitted, occupancy, cfg.max_batch,
+                    occupancy - len(finished) - len(released), k, now - t0)
+                for req in finished:
+                    self._finish_locked(req, now)
+                for b in released:
+                    req = self._rows[b]
+                    req.error = DeadlineExpiredError(
+                        "deadline expired mid-generation after "
+                        f"{len(req.out)} tokens")
+                    telemetry.record_decode_request(
+                        "expired", now - req.t0, model=self.name)
+                    tracing.finish_trace(req.trace, "expired",
+                                         {"tokens": len(req.out)})
+                    self._release_prefix(req)
+                    req.complete(now)
+                    self._rows[b] = None
+                self._n_active -= len(finished) + len(released)
+                sp.annotate(finished=len(finished), expired=len(released))
         if released:
             with self._span("gen.decode.release", rows=len(released)):
                 keep = np.ones((cfg.max_batch,), bool)
                 keep[released] = False
                 self._state = self._dec.release_fn(self._S)(self._state,
                                                             keep)
-        telemetry.record_decode_iteration(
-            n_emitted, occupancy, cfg.max_batch, rows_in_use, k, now - t0)
         if self._breaker is not None:
             self._breaker.on_success()
         parent.annotate(k=k, kv_bucket=self._S, rows=occupancy,
-                        emitted=n_emitted, spec=bool(spec))
+                        emitted=n_emitted)
 
     def _net_params(self):
         return self._dec.params
@@ -1072,8 +894,6 @@ class GenerationEngine:
             self._n_active = 0
             self._positions = [0] * self.config.max_batch
         self._state = self._dec.new_state(self._S)
-        if self._draft_dec is not None:
-            self._draft_state = self._draft_dec.new_state(self._S)
         if self._breaker is not None:
             self._breaker.on_failure()
 
@@ -1106,7 +926,6 @@ class GenerationEngine:
             t.join(timeout=5)
         self._thread = None
         self._state = None
-        self._draft_state = None
         return self
 
     def __enter__(self):

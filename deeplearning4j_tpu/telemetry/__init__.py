@@ -463,7 +463,7 @@ def record_decode_prefill(rows: int, bucket_rows: int,
                           seconds: float) -> None:
     """One prefill launch: joining sequences, padded join-bucket fill,
     prompt-ingestion wall time (the prefill side of the prefill/decode
-    split bench_decode.py reports). Each joining row samples its first
+    split). Each joining row samples its first
     token in the prefill launch, so those count as generated tokens."""
     REGISTRY.counter("dl4j_decode_prefills_total",
                      help="prompt prefill launches").inc()
@@ -532,24 +532,6 @@ def record_prefix_cache(hits: int = 0, misses: int = 0, evictions: int = 0,
         REGISTRY.counter("dl4j_prefix_cache_hit_tokens_total",
                          help="prompt tokens served from cached KV "
                               "(prefill skipped)").inc(hit_tokens)
-
-
-def record_spec_window(accepted: int, k: int, emitted: int) -> None:
-    """One speculative verify window: drafted-and-accepted tokens out of
-    the K proposed (the acceptance histogram the bench reports), plus
-    total emitted (accepted drafts + the verifier's own bonus token)."""
-    REGISTRY.histogram("dl4j_spec_accepted_tokens",
-                       help="draft tokens accepted per verify "
-                            "window").observe(accepted)
-    REGISTRY.counter("dl4j_spec_draft_tokens_total",
-                     help="draft tokens proposed to the "
-                          "verifier").inc(k)
-    REGISTRY.counter("dl4j_spec_accepted_tokens_total",
-                     help="draft tokens accepted by the "
-                          "verifier").inc(accepted)
-    REGISTRY.counter("dl4j_spec_emitted_tokens_total",
-                     help="tokens emitted from verify windows "
-                          "(accepted + bonus)").inc(emitted)
 
 
 _SERVING_ENGINES = weakref.WeakSet()

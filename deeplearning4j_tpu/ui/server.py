@@ -373,17 +373,13 @@ class UIServer:
         """Continuous-batching generation metrics (parallel.generation):
         token counters, running-batch occupancy, KV-cache rows in use,
         per-token and time-to-first-token latency quantiles — next to
-        the serving panel. The prefix-cache (``dl4j_prefix_*``: hits /
-        misses / evictions / live pages / prefill tokens skipped) and
-        speculative-decoding (``dl4j_spec_*``: per-window acceptance
-        histogram, drafted vs accepted vs emitted counters) series
-        render in the same panel when those features are on."""
+        the serving panel. The prefix-cache series (``dl4j_prefix_*``:
+        hits / misses / evictions / live pages / prefill tokens skipped)
+        render in the same panel when that feature is on."""
         return (self._metric_table_panel("Generation (continuous batching)",
                                          "dl4j_decode_")
                 + self._metric_table_panel("Generation — prefix cache",
-                                           "dl4j_prefix_")
-                + self._metric_table_panel("Generation — speculative decode",
-                                           "dl4j_spec_"))
+                                           "dl4j_prefix_"))
 
     def _platform_panel(self) -> str:
         """Multi-tenant serving platform (parallel.platform): one row

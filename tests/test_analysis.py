@@ -61,18 +61,16 @@ def test_prg201_not_applied_to_inference_kinds():
     assert rules_of(program.lint_program(art)) == []
 
 
-def test_prg201_covers_spec_and_prefix_kinds():
-    """The speculative-decoding window/sync and prefix-cache attach/join
-    executables consume the donated decode state: an undonated fixture
-    under any of those kinds trips PRG201, a donated one is clean, and
-    the suffix PREFILL (reads shared refcounted pages — must NOT
-    donate) stays exempt by construction."""
+def test_prg201_covers_prefix_kinds():
+    """The prefix-cache attach/join executables consume the donated
+    decode state: an undonated fixture under either kind trips PRG201, a
+    donated one is clean, and the suffix PREFILL (reads shared refcounted
+    pages — must NOT donate) stays exempt by construction."""
     def step(state, upd):
         return state + upd
 
     args = (jnp.ones((16,)), jnp.ones((16,)))
-    for kind in ("spec_verify:s32:k2", "spec_sync:s32",
-                 "prefix_attach:s32:t8:b2", "prefix_join:s32:t8:b2"):
+    for kind in ("prefix_attach:s32:t8:b2", "prefix_join:s32:t8:b2"):
         art = program.trace_artifact(jax.jit(step), args, fn_key=kind)
         assert "PRG201" in rules_of(program.lint_program(art)), kind
         art = program.trace_artifact(

@@ -192,33 +192,31 @@ def test_sampled_engine_matches_sequential_reference():
 def test_late_join_completes_before_earlier_longer_sequence():
     """Token-granularity admission: a short request submitted AFTER a
     long one is already decoding joins the running batch at the next
-    iteration and finishes first — no request-granularity drain wait."""
+    iteration and finishes first — no request-granularity drain wait.
+    The long request's fourth launch (its prefill and two windows of K
+    are five tokens) is held open by a delay fault, and the test reads
+    the handle's token count and submits under the engine's own
+    condition, so no accounting runs between the reading and the
+    enqueue: nothing here depends on how fast a toy window is."""
     dec = _decoder()
     long_ref = dec.generate([7, 3], max_new=24)
     short_ref = dec.generate([9, 9, 2], max_new=3)
-    order = []
-    with _engine() as eng:
+    plan = FaultPlan().inject("decode.launch", on_calls=[4], action="delay",
+                              delay_s=0.5)
+    with plan.armed(), _engine() as eng:
         long_req = eng.submit([7, 3], max_new_tokens=24)
-        # wait until the long request is genuinely mid-generation
-        deadline = time.monotonic() + 5
-        while len(long_req.out) < 4:
-            assert time.monotonic() < deadline, "long request never started"
-            time.sleep(0.002)
-        short_req = eng.submit([9, 9, 2], max_new_tokens=3)
-
-        def wait(tag, req):
-            eng.result(req)
-            order.append(tag)
-
-        ts = [threading.Thread(target=wait, args=("long", long_req)),
-              threading.Thread(target=wait, args=("short", short_req))]
-        for t in ts:
-            t.start()
-        for t in ts:
-            t.join(timeout=30)
+        deadline = time.monotonic() + 30
+        with eng._cond:
+            # wait until the long request is genuinely mid-generation
+            while len(long_req.out) < 4:
+                assert time.monotonic() < deadline, "long request never started"
+                eng._cond.wait(0.001)
+            assert long_req.t_done is None and len(long_req.out) == 5
+            short_req = eng.submit([9, 9, 2], max_new_tokens=3)
         assert eng.result(short_req) == short_ref
         assert eng.result(long_req) == long_ref
-    assert order[0] == "short", "late-joining short request should retire first"
+    assert short_req.t_done < long_req.t_done, \
+        "late-joining short request should retire first"
 
 
 def test_eos_retirement_frees_rows():
@@ -475,7 +473,8 @@ def test_engine_spans_carry_the_counts(engine_spans):
         stats["tokens_total"] - stats["joined_total"])
     assert sum(e["attrs"]["joins"] for e in prefill) == (
         stats["joined_total"]) == len(handles)
-    assert all(e["attrs"]["k"] == K and e["attrs"]["spec"] is False
+    assert all(set(e["attrs"]) == {"n", "k", "kv_bucket", "rows", "emitted"}
+               and e["attrs"]["k"] == K
                and 1 <= e["attrs"]["rows"] <= MAX_BATCH for e in decode)
     assert all(e["attrs"]["kind"] == "cold" for e in prefill)
     for name in ("gen.admit", "gen.prefill.account", "gen.decode.plan",
@@ -491,19 +490,37 @@ def test_engine_spans_carry_the_counts(engine_spans):
     assert sum(e["attrs"].get("joins", 0) for e in admits) == len(handles)
 
 
-def test_engine_loop_thread_is_covered_by_spans(engine_spans):
-    evs, _stats, _handles = engine_spans
+def _loop_thread_coverage(evs):
+    """Share of the loop thread's time, from the first admit to the end
+    of the last decode window, that lies inside a top-level span."""
     loop = next(e["thread"] for e in evs if e["name"] == "gen.decode")
     tops = sorted((e for e in evs if e["thread"] == loop
                    and e["parent_id"] is None), key=lambda e: e["start_ns"])
-    # from the first admit to the end of the last decode window
     last = max(i for i, e in enumerate(tops) if e["name"] == "gen.decode")
     tops = tops[:last + 1]
     wall = (tops[-1]["start_ns"] + tops[-1]["duration_ns"]
             - tops[0]["start_ns"])
     inside = sum(e["duration_ns"] for e in tops)
     assert inside <= wall
-    assert inside / wall >= 0.95, (inside, wall)
+    return inside / wall
+
+
+def test_engine_loop_thread_is_covered_by_spans(engine_spans):
+    """A stretch of the loop no span covers shows in EVERY run; a loop
+    thread descheduled between two spans (60 ms of toy windows under six
+    workers: one lost time slice is a tenth of them) shows in one. So a
+    low reading is taken again, twice at most."""
+    from deeplearning4j_tpu import telemetry
+
+    share = _loop_thread_coverage(engine_spans[0])
+    for _ in range(2):
+        if share >= 0.95:
+            break
+        telemetry.spans.reset()
+        with _engine() as eng:
+            _drive_four_clients(eng)
+        share = _loop_thread_coverage(telemetry.events())
+    assert share >= 0.95, share
 
 
 def test_handle_times_are_ordered(engine_spans):
@@ -562,30 +579,7 @@ def test_donation_audit_covers_decode_kinds():
         assert rep["findings"] == 0
 
 
-# --- prefix caching + speculative decoding ---------------------------------
-
-@functools.lru_cache(maxsize=None)
-def _draft_decoder(seed=99) -> TransformerDecoder:
-    """A 1-layer draft with the TARGET's bucket geometry. Seed 99 gives
-    an untrained, disagreeing draft (the ~0%-acceptance leg); seed 7
-    with the target's architecture gives an oracle draft."""
-    m = TransformerEncoder(vocab_size=VOCAB, embed_dim=16, n_heads=2,
-                           n_layers=1, max_len=MAX_LEN, causal=True,
-                           lm_head=True, seed=seed)
-    return m.decoder(max_batch=MAX_BATCH, kv_bucket_min=16,
-                     prompt_bucket_min=4)
-
-
-@functools.lru_cache(maxsize=None)
-def _oracle_draft() -> TransformerDecoder:
-    """Same architecture AND seed as the target: greedy-agrees at every
-    position, so acceptance is 100% and windows emit K+1 tokens."""
-    m = TransformerEncoder(vocab_size=VOCAB, embed_dim=16, n_heads=2,
-                           n_layers=2, max_len=MAX_LEN, causal=True,
-                           lm_head=True, seed=7)
-    return m.decoder(max_batch=MAX_BATCH, kv_bucket_min=16,
-                     prompt_bucket_min=4)
-
+# --- prefix caching ---------------------------------------------------------
 
 def test_prefix_cache_radix_unit():
     """Trie mechanics in isolation: page-aligned match with pins,
@@ -694,118 +688,36 @@ def test_prefix_pages_released_on_all_edges():
     assert pinned(pc) == 0
 
 
-def test_speculative_greedy_token_identical():
-    """Speculation NEVER changes tokens: with an oracle draft (100%
-    acceptance) and with a disagreeing draft (~0% acceptance — the
-    degraded path emits exactly the non-speculative stream), engine
-    output equals the sequential reference."""
-    dec = _decoder()
-    prompts = [[3, 9, 1], [5, 6, 7, 8, 2, 11], [1], [14, 13, 12, 2]]
-    mns = [6, 9, 4, 12]
-    refs = [dec.generate(p, mn) for p, mn in zip(prompts, mns)]
-    with _engine(draft_conf=_oracle_draft()) as eng:
-        outs = [eng.generate(p, max_new_tokens=mn)
-                for p, mn in zip(prompts, mns)]
-        st = eng.stats()
-    assert outs == refs
-    assert st["speculative"]["accepted"] > 0     # oracle draft agrees
-    with _engine(draft_conf=_draft_decoder()) as eng:
-        outs2 = [eng.generate(p, max_new_tokens=mn)
-                 for p, mn in zip(prompts, mns)]
-        st2 = eng.stats()
-    assert outs2 == refs                         # 0%-acceptance degrades
-    assert st2["speculative"]["windows"] > 0     # ...but still speculated
+def test_speculation_fields_are_gone_from_the_config():
+    """Speculative decoding went with PR 31: a caller that still passes
+    its options learns at once, by the field's name."""
+    for field in ("draft_conf", "spec_tokens"):
+        with pytest.raises(TypeError, match=field):
+            GenerationConfig(**{field: None})
 
 
-def test_speculative_sampled_matches_reference():
-    """Seeded sampling through the verifier consumes the row's PRNG
-    chain exactly as sequential decode does: same (seed, temperature)
-    → same tokens, at any acceptance rate."""
-    dec = _decoder()
-    ref = dec.generate([2, 4, 6], max_new=7, temperature=0.8, seed=42)
-    with _engine(draft_conf=_draft_decoder()) as eng:
-        out = eng.generate([2, 4, 6], max_new_tokens=7, temperature=0.8,
-                           seed=42)
-    assert out == ref
-
-
-def test_spec_prefix_compose_zero_recompiles():
-    """Both features together under mixed traffic (hit + miss joins,
-    accept + reject windows, bucket growth) never miss the AOT cache
-    after warmup, and still match the sequential reference."""
-    dec = _decoder()
-    shared = [7, 3, 7, 3, 7, 3, 7, 3]
-    prompts = [shared + [i + 1] for i in range(3)] + [[9, 9, 2]]
-    refs = [dec.generate(p, 5) for p in prompts]
-    with _engine(draft_conf=_oracle_draft(), prefix_cache=True,
-                 prefix_page=4) as eng:
-        eng.warmup()
-        miss0 = aot_cache.stats()["misses"]
-        outs = [eng.generate(p, max_new_tokens=5) for p in prompts]
-        outs += [eng.generate(p, max_new_tokens=5) for p in prompts]
-        eng.generate([2] * 20, max_new_tokens=8)   # KV grow hop
-        st = eng.stats()
-    assert outs == refs + refs
-    assert st["prefix_cache"]["hits"] >= 1
-    assert aot_cache.stats()["misses"] == miss0, \
-        "prefix/spec traffic recompiled after warmup"
-
-
-def test_spec_fallback_near_context_limit():
-    """When a row is within K+1 slots of max_len the iteration falls
-    back to the plain fused window — output still matches the
-    sequential reference all the way to the context edge."""
-    dec = _decoder()
-    prompt = [1, 2, 3, 4]
-    mn = MAX_LEN - len(prompt)                    # decode to the edge
-    ref = dec.generate(prompt, mn)
-    with _engine(draft_conf=_oracle_draft()) as eng:
-        out = eng.generate(prompt, max_new_tokens=mn)
-    assert out == ref
-
-
-def test_draft_geometry_mismatch_rejected():
-    m = TransformerEncoder(vocab_size=VOCAB, embed_dim=16, n_heads=2,
-                           n_layers=1, max_len=16, causal=True,
-                           lm_head=True, seed=1)
-    bad = m.decoder(max_batch=MAX_BATCH, kv_bucket_min=16,
-                    prompt_bucket_min=4)          # max_len 16 != 32
-    with pytest.raises(ValueError, match="geometry"):
-        GenerationEngine(
-            _decoder(),
-            GenerationConfig(max_batch=MAX_BATCH, fused_steps=K,
-                             kv_bucket_min=16, prompt_bucket_min=4,
-                             draft_conf=bad))
-
-
-def test_prefix_and_spec_telemetry_series():
+def test_prefix_telemetry_series():
     snap0 = REGISTRY.snapshot(run_collectors=False)
     shared = [4, 4, 4, 4, 8, 8, 8, 8]
-    with _engine(draft_conf=_oracle_draft(), prefix_cache=True,
-                 prefix_page=4) as eng:
+    with _engine(prefix_cache=True, prefix_page=4) as eng:
         eng.generate(shared + [1], max_new_tokens=5)
         eng.generate(shared + [2], max_new_tokens=5)
         snap1 = REGISTRY.snapshot(run_collectors=False)
     for name in ("dl4j_prefix_cache_hits_total",
                  "dl4j_prefix_cache_misses_total",
-                 "dl4j_prefix_cache_hit_tokens_total",
-                 "dl4j_spec_draft_tokens_total",
-                 "dl4j_spec_accepted_tokens_total"):
+                 "dl4j_prefix_cache_hit_tokens_total"):
         assert snap1.get(name, 0) > snap0.get(name, 0), name
     assert "dl4j_prefix_cache_pages" in snap1
-    assert snap1["dl4j_spec_accepted_tokens"]["count"] > 0
 
 
-def test_generation_panel_includes_prefix_and_spec():
+def test_generation_panel_includes_prefix():
     from deeplearning4j_tpu.ui.server import UIServer
 
-    with _engine(draft_conf=_oracle_draft(), prefix_cache=True,
-                 prefix_page=4) as eng:
+    with _engine(prefix_cache=True, prefix_page=4) as eng:
         eng.generate([6, 6, 6, 6, 2], max_new_tokens=4)
     panel = UIServer.get_instance()._generation_panel()
     assert "Generation — prefix cache" in panel
-    assert "Generation — speculative decode" in panel
-    assert "dl4j_spec_accepted_tokens" in panel
+    assert "dl4j_prefix_cache_pages" in panel
 
 
 # --- Pallas attention kernels through the decode path -----------------------
@@ -835,7 +747,8 @@ def _kern_engine(**over):
 
 
 def test_kernels_decoder_token_identical_and_zero_recompile():
-    """use_kernels greedy decode (flash prefill + paged decode steps)
+    """use_kernels greedy decode (flash prefill; the decode step picks
+    its attention by platform and shape, tuned or not)
     is token-identical to the stock decoder for every prompt, at K=1
     and fused K, with ZERO recompiles after warmup — and every step key
     carries both attention kernel tokens."""
@@ -915,7 +828,7 @@ def test_kernel_bearing_decode_kinds_donate_and_audit_clean():
 
 def test_kernels_retune_mints_new_decoder_executable():
     """A retune bumps the tuning digest, every ``kern:``-keyed step
-    re-mints (AOT misses), and the retuned paged kernel is still
+    re-mints (AOT misses), and the retuned flash prefill is still
     token-identical. Runs LAST of the kernel-decode tests: it leaves
     the tuning table mutated."""
     from deeplearning4j_tpu import kernels
@@ -926,39 +839,39 @@ def test_kernels_retune_mints_new_decoder_executable():
     ref = dec.generate(prompt, 5)
     assert kdec.generate(prompt, 5) == ref
     tag0 = kdec._ktag()
-    kid = "paged_decode_attention"
+    kid = "flash_attention"
+    # the prompt's own bucket (4) at join width 1: the envelope the
+    # prefill of this very request routes
     env = next(e for k_, e in kernels.decoder_envelopes(kdec)
-               if k_ == kid and e.tk == 16)
+               if k_ == kid and e.tq == 4 and e.b == 1)
+    # at this size the kernel has one legal tiling: the retune records it
+    # again at another time, which is a new winner table all the same
     cur = tuple(kernels.TUNING.winner(kid, env.key)["tiling"])
-    alt = next(tuple(t) for t in
-               kernels.REGISTRY.get(kid).candidates(env)
-               if tuple(t) != cur)
     m0 = aot_cache.stats()["misses"]
-    kernels.TUNING.record(kid, env.key, alt, 0.0)
+    kernels.TUNING.record(kid, env.key, cur, 1.0)
     assert kdec._ktag() != tag0
     assert kdec.generate(prompt, 5) == ref
     assert aot_cache.stats()["misses"] > m0, \
         "a retuned kernel must be a NEW executable"
 
 
-def test_donation_audit_covers_spec_and_prefix_kinds():
-    """PRG201 satellite: the new decode-state consumers are in the
-    audit's train-kind set, every compiled one donates, and the suffix
-    prefill (shared refcounted pages) is deliberately exempt."""
+def test_donation_audit_covers_prefix_kinds():
+    """PRG201 satellite: the prefix cache's decode-state consumers are
+    in the audit's train-kind set, every compiled one donates, and the
+    suffix prefill (shared refcounted pages) is deliberately exempt."""
     from deeplearning4j_tpu.analysis import program
 
-    for kind in ("spec_verify", "spec_sync", "prefix_attach",
-                 "prefix_join"):
+    for kind in ("prefix_attach", "prefix_join"):
         assert kind in program.TRAIN_KIND_PREFIXES
-    with _engine(draft_conf=_oracle_draft(), prefix_cache=True,
-                 prefix_page=4) as eng:
+    assert not any("gen_prompt_sfx".startswith(p)
+                   for p in program.TRAIN_KIND_PREFIXES)
+    with _engine(prefix_cache=True, prefix_page=4) as eng:
         eng.generate([1, 2, 3, 4, 5], max_new_tokens=4)
         eng.generate([1, 2, 3, 4, 6], max_new_tokens=4)
     audit = program.donation_audit()
     kinds = {k: v for k, v in audit.items()
-             if k[1].startswith(("spec_verify", "spec_sync",
-                                 "prefix_attach", "prefix_join"))}
-    assert kinds, "no spec/prefix executables were audited"
+             if k[1].startswith(("prefix_attach", "prefix_join"))}
+    assert kinds, "no prefix executables were audited"
     for key, rep in kinds.items():
         assert rep["aliases"] > 0, f"{key[1]} does not donate its state"
         assert rep["findings"] == 0

@@ -420,32 +420,24 @@ def test_join_bucket_max_splits_a_group_of_joins(served):
 
 # --- what the walks refuse, by name -----------------------------------------
 
-def test_spec_and_prefix_walks_refuse_the_new_layers_by_name(served):
+def test_prefix_walk_refuses_the_new_layers_by_name(served):
     _, _, net, dec = served
-    st = dec._struct_of(128)
     with pytest.raises(NotImplementedError) as e:
-        dec.spec_verify_fn(128, 2).warm(
-            net.params, st, jax.ShapeDtypeStruct((2, 2), jnp.int32))
-    assert "'b0_mix' (BlockSparseAttentionLayer)" in str(e.value)
-    assert "'b1_mix' (LightningAttentionLayer)" in str(e.value)
-    assert "decode_chunk" in str(e.value)
-    with pytest.raises(NotImplementedError, match="prefill_suffix"):
         dec._run_suffix(net.params, np.zeros((1, 16), np.int32),
                         np.ones((1,), np.int32), {}, np.ones((1,), np.int32))
+    assert "'b0_mix' (BlockSparseAttentionLayer)" in str(e.value)
+    assert "'b1_mix' (LightningAttentionLayer)" in str(e.value)
+    assert "prefill_suffix" in str(e.value)
 
 
-@pytest.mark.parametrize("option,needs", [
-    ({"prefix_cache": True}, "prefill_suffix"),
-    ({"draft_conf": "self"}, "decode_chunk")])
-def test_engine_refuses_prefix_cache_and_speculation_by_name(served, option,
-                                                             needs):
+def test_engine_refuses_prefix_cache_by_name(served):
     _, _, _, dec = served
-    if option.get("draft_conf") == "self":
-        option = {"draft_conf": dec}
     with pytest.raises(ValueError) as e:
         GenerationEngine(dec, GenerationConfig(
-            max_batch=2, kv_bucket_min=128, prompt_bucket_min=16, **option))
-    assert needs in str(e.value) and "LightningAttentionLayer" in str(e.value)
+            max_batch=2, kv_bucket_min=128, prompt_bucket_min=16,
+            prefix_cache=True))
+    assert "prefill_suffix" in str(e.value)
+    assert "LightningAttentionLayer" in str(e.value)
 
 
 def _graph_with(layer_or_vertex):
@@ -523,13 +515,10 @@ def test_self_attention_serves_through_the_cache_interface():
     cache = layer.cache_init(2, 32, 16, jnp.float32)
     x = np.random.default_rng(0).normal(size=(2, 16)).astype(np.float32)
     pos = np.asarray([3, 9], np.int32)
-    y1, c1 = layer.decode_step(net.params["b0_attn"], x, cache, pos)
-    y2, c2, counts = layer.cache_step(net.params["b0_attn"], x, cache, pos)
+    _, _, counts = layer.cache_step(net.params["b0_attn"], x, cache, pos)
     assert {n: np.asarray(c).tolist() for n, c in counts.items()} == {
         "decode_kv_read_positions": [32, 32],   # the masked read: all of it
         "decode_kv_bucket_positions": [32, 32]}
-    np.testing.assert_array_equal(y1, y2)
-    np.testing.assert_array_equal(c1["k"], c2["k"])
     prompt = _tokens(11, 3, 31).tolist()
     seq, want = list(prompt), []
     for _ in range(6):
@@ -537,6 +526,70 @@ def test_self_attention_serves_through_the_cache_interface():
         want.append(int(o[0, len(seq) - 1].argmax()))
         seq.append(want[-1])
     assert dec.generate(prompt, 6) == want
+
+
+# --- the one walk, in its three shapes ----------------------------------------
+
+@pytest.fixture(scope="module")
+def gpt2_style():
+    zoo = TransformerEncoder(vocab_size=97, embed_dim=16, n_heads=2,
+                             n_layers=2, max_len=128, lm_head=True,
+                             causal=True, seed=5)
+    net = zoo.init()
+    return net, zoo.decoder(net, max_batch=2, kv_bucket_min=128,
+                            prompt_bucket_min=16)
+
+
+@pytest.mark.parametrize("decoder,walk", [
+    ("gpt2_style", "prompt"), ("gpt2_style", "token"),
+    ("gpt2_style", "suffix"), ("served", "prompt"), ("served", "token")])
+def test_the_one_walk_in_each_of_its_shapes(request, decoder, walk):
+    """``TransformerDecoder._walk`` under its three callers, on a prompt
+    of 41 tokens (beyond ``dense_len`` for the hybrid) padded to a bucket
+    of 64. ``prompt``: the logits are the graph's own ``output`` at the
+    last valid position. ``token``: the prompt joined into row 1 of a
+    cache and ONE token walked against it gives the logits the prompt
+    walk gives on the prompt extended by that token. ``suffix`` (the
+    layers with ``prefill_suffix``): the last 17 tokens walked against
+    the first 24's K/V pages give the cold prompt walk's logits."""
+    *_, net, dec = request.getfixturevalue(decoder)
+    n, tp, s = 41, 64, 128
+    toks = _tokens(n + 1, 11)
+    padded = np.zeros((1, tp), np.int32)
+    padded[0, :n] = toks[:n]
+    lengths = np.asarray([n], np.int32)
+    logits, kv = dec._run_prompt(net.params, padded, lengths)
+    if walk == "prompt":
+        # causal: the padding behind the prompt changes nothing before it
+        probs = np.asarray(net.output(padded))[0, n - 1]
+        np.testing.assert_allclose(jax.nn.log_softmax(logits[0]),
+                                   np.log(probs), atol=2e-5)
+        return
+    if walk == "token":
+        rows = np.asarray([1], np.int32)
+        caches = {name: dec._layer(name).cache_join(c, kv[name], rows, s)
+                  for name, c in dec.new_state(s)["caches"].items()}
+        got, _, counts = dec._run_token(
+            net.params, np.asarray([0, toks[n]], np.int32),
+            np.asarray([0, n], np.int32), caches,
+            active=np.asarray([False, True]))
+        assert sorted(counts) == dec.counter_names
+        padded[0, n] = toks[n]
+        want, _ = dec._run_prompt(net.params, padded, lengths + 1)
+        np.testing.assert_allclose(got[1], want[0], atol=2e-4)
+        return
+    pre, ts = 24, 32
+    suffix = np.zeros((1, ts), np.int32)
+    suffix[0, :n - pre] = toks[pre:n]
+    pages = {name: {leaf: a[:, :32] for leaf, a in block.items()}
+             for name, block in kv.items()}
+    got, kv_sfx = dec._run_suffix(
+        net.params, suffix, np.asarray([n - pre], np.int32), pages,
+        np.asarray([pre], np.int32))
+    np.testing.assert_allclose(got, logits, atol=2e-5)
+    for name, block in kv_sfx.items():
+        np.testing.assert_allclose(block["k"][:, :n - pre],
+                                   kv[name]["k"][:, pre:n], atol=1e-5)
 
 
 # --- the small layers --------------------------------------------------------

@@ -5,7 +5,9 @@ cache-sized but the write and the two products; the caches donated), and
 the compiled decode window for a v5e (no relayout copy outside the loop,
 one layout at rest and in the loop, the cache written by
 ``dynamic-update-slice`` and read by the paged kernel and by nothing else)
-with no chip attached.
+with no chip attached; and the hybrid decoder's decode window, prompt and
+join compiled the same way (each kind of state, KV, compressed keys,
+recurrent, taken whole by its write and its layer's read alone).
 
 The ahead-of-time compiles load the TPU's compiler: they stay in THIS file,
 and the topology is described inside a fixture, never at import.
@@ -303,11 +305,6 @@ def _executables():
     write_read = {"dynamic_update_slice", "dot_general"}
     return dec, {
         "decode": (dec.decode_fn(s, 4), (params, st), 1, set(), write_read),
-        "spec_draft": (dec.spec_draft_fn(s, 4),
-                       (params, st, i32(b), i32(b), sds((b,), jnp.bool_)),
-                       1, set(), write_read),
-        "spec_verify": (dec.spec_verify_fn(s, 4), (params, st, i32(4, b)),
-                        1, write_read, set()),
         "join": (dec.join_fn(s, 8, 1),
                  (st, dec._kv_struct(1, 8), i32(1), i32(1)) + tuple(
                      sds((1,) + r.shape[1:], r.dtype) for r in row),
@@ -325,9 +322,8 @@ def _executables():
     }
 
 
-@pytest.mark.parametrize("name", ["decode", "spec_draft", "spec_verify",
-                                  "join", "prefix_attach", "suffix_join",
-                                  "grow"])
+@pytest.mark.parametrize("name", ["decode", "join", "prefix_attach",
+                                  "suffix_join", "grow"])
 def test_executables_touch_the_cache_only_to_write_and_read_it(name):
     """No ``transpose``/``reshape``/``copy``/``select_n`` of anything
     cache-sized in any decoder executable: outside the decode window's
@@ -341,10 +337,9 @@ def test_executables_touch_the_cache_only_to_write_and_read_it(name):
     outside, inside = _cache_sized_primitives(step.jit_fn, args, n_cache)
     assert outside <= outside_ok, (name, outside)
     assert inside <= inside_ok, (name, inside)
-    if name in ("decode", "spec_draft", "spec_verify"):
+    if name == "decode":
         # both products and the write are really there to be seen
-        seen = inside if inside_ok else outside
-        assert seen == {"dot_general", "dynamic_update_slice"}
+        assert inside == {"dot_general", "dynamic_update_slice"}
     if donated is not None:
         info = step.jit_fn.lower(*args).args_info[0][donated]
         leaves = jax.tree_util.tree_leaves(info["caches"])
@@ -474,3 +469,176 @@ def test_compiled_decode_window_keeps_one_cache_layout(one_chip, heads, hs):
 
     cache_bytes = 4 * b * heads * hs * s
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * cache_bytes
+
+
+# --- the hybrid decoder's compiled programs, for a v5e, without one ----------
+
+_COMPUTATION = re.compile(r"\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$")
+
+
+def _consumers(txt, shapes):
+    """Who takes a whole array of one of ``shapes`` (``"f32[3,256,256]"``)
+    as an operand, anywhere in the compiled text: ``{(opcode, share)}``,
+    ``share`` ``"whole"`` where the operation's result holds more than a
+    quarter of the array's elements and ``"part"`` where it holds less.
+    A fusion is looked into (its parameter stands for the operand), a
+    bitcast is followed (its result is the same buffer), plumbing is
+    skipped."""
+    made, params, comp = {}, {}, None
+    for line in txt.splitlines():
+        mo = _COMPUTATION.match(line)
+        if mo:
+            comp = mo.group(1)
+            continue
+        mo = _INSTRUCTION.match(line)
+        if not mo:
+            continue
+        name, typ, op, operands = mo.groups()
+        made[name] = (typ, op, re.findall(r"%([\w.\-]+)", operands), line)
+        if op == "parameter":
+            params[comp, int(operands)] = name
+    n_whole = {s: int(np.prod([int(d) for d in re.findall(r"\d+",
+               s[s.index("["):])])) for s in shapes}
+    whole = {name: s for name, (typ, *_) in made.items()
+             for s in shapes if typ.startswith(s)}
+    grew = True
+    while grew:             # through bitcasts and into fusions
+        grew = False
+        for name, (typ, op, operands, line) in made.items():
+            for i, a in enumerate(operands):
+                if a not in whole:
+                    continue
+                if op == "bitcast":
+                    new = name
+                elif op == "fusion":
+                    new = params[re.search(r"calls=%?([\w.\-]+)",
+                                           line).group(1), i]
+                else:
+                    continue
+                if new not in whole:
+                    whole[new] = whole[a]
+                    grew = True
+    found = set()
+    for name, (typ, op, operands, line) in made.items():
+        if op in _PLUMBING or op in ("fusion", "conditional", "call"):
+            continue
+        for a in operands:
+            if a in whole:
+                n = max(int(np.prod([int(d) for d in dims.split(",") if d]))
+                        for dims in re.findall(r"\w\[([\d,]*)\]", typ))
+                found.add((op, "whole" if 4 * n > n_whole[whole[a]]
+                           else "part"))
+    return found
+
+
+@pytest.fixture(scope="module")
+def hybrid_programs(one_chip):
+    """Decode window, prompt and join of a two-layer ``HybridDecoderLM``
+    (one block-sparse layer, one lightning layer) compiled for the v5e
+    from avals: ``{program: (text, {kind of state: [shape, ...]})}``. The
+    caches' widths are the published ones per head (KV heads 2 x 128, a
+    recurrent state of 128 x 128 a head), so that they fill the (8, 128)
+    tiles as the cell's do (a toy width is relaid by the compiler whatever
+    the program says); the depth, the residual stream, the vocabulary and
+    the sparse sizes are small, and three rows make a cache's shape no
+    other array's."""
+    from deeplearning4j_tpu.nn.graph import ComputationGraph
+    from deeplearning4j_tpu.zoo.graphs import HybridDecoderLM
+
+    b, s, tp = 3, 4096, 1024
+    zoo = HybridDecoderLM(
+        vocab_size=512, hidden=384, ffn_dim=768,
+        mixer_types=["minicpm4", "lightning-attn"], n_heads=4, head_dim=128,
+        n_kv_heads=2, lightning_heads=3, lightning_head_dim=128,
+        sparse={"kernel_size": 32, "kernel_stride": 16, "block_size": 64,
+                "window_size": 256, "init_blocks": 1, "topk": 4,
+                "dense_len": 512},
+        max_len=s, weight_dtype="bfloat16", cache_dtype="bfloat16")
+    net = ComputationGraph(zoo.conf())
+    net.params = jax.eval_shape(
+        lambda: ComputationGraph(zoo.conf()).init().params)
+    net.state, net.opt_state = {}, {}
+    dec = zoo.decoder(net, max_batch=b, kv_bucket_min=s,
+                      prompt_bucket_min=tp, join_bucket_max=1)
+    on_chip = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        tree)
+    row = lambda dt, *tail: on_chip(  # noqa: E731
+        jax.ShapeDtypeStruct((1,) + tail, dt))
+    i32, req = row(jnp.int32), (row(jnp.int32), row(jnp.int32),
+                                row(jnp.float32), row(jnp.uint32, 2))
+    state, block = dec._struct_of(s), dec._kv_struct(1, tp)
+    table = {
+        "decode": (dec.decode_fn(s, 4), (net.params, state), state["caches"]),
+        "prompt": (dec.prompt_fn(tp, 1),
+                   (net.params, row(jnp.int32, tp), i32) + req, block),
+        "join": (dec.join_fn(s, tp, 1),
+                 (state, block, i32, i32, i32) + req + (row(jnp.bool_),),
+                 state["caches"]),
+    }
+    short = {"bfloat16": "bf16", "float32": "f32"}
+    out = {}
+    for program, (step, args, leaves) in table.items():
+        shapes = {}
+        for name, layer_leaves in leaves.items():
+            for leaf, a in layer_leaves.items():
+                shapes.setdefault(dec._layer(name).cache_kinds[leaf], set()) \
+                    .add(f"{short[str(a.dtype)]}"
+                         f"[{','.join(map(str, a.shape))}]")
+        txt = step.jit_fn.trace(*on_chip(args)).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+        out[program] = (_without_layout_constraints(txt), shapes)
+    return out
+
+
+# (program, kind of state) -> the operations that may take a whole array of
+# that kind as an operand, and how much of it they may return
+_WRITE = ("dynamic-update-slice", "whole")
+_HYBRID_READERS = {
+    # the step's token write; the reads are the compressed key's window,
+    # the local window, the first blocks and the dense branch's prefix
+    # (slices), and the chosen blocks (ONE gather of whole 256-lane rows:
+    # a slice of a KV head's lanes before it split the cache by head, and
+    # a vmapped dynamic_slice relaid it, 7 ms a step each at 32 k)
+    ("decode", "kv"): {_WRITE, ("dynamic-slice", "part"), ("slice", "part"),
+                       ("gather", "part")},
+    # the compressed key this token completes (read the old, write); the
+    # selection scores EVERY live compressed key, in float32 at HIGHEST:
+    # the convert and the maximum are the matrix unit's operand split
+    ("decode", "compressed_keys"): {
+        _WRITE, ("dynamic-slice", "part"), ("convolution", "part"),
+        ("convert", "whole"), ("maximum", "whole")},
+    # S' = decay * S + k^T v is the whole state by nature; q S reads it
+    ("decode", "recurrent"): {("multiply", "whole"), ("add", "whole"),
+                              ("reduce", "part")},
+    # a prompt's blocks are results: keys and values are split by KV head
+    # for the attention, the compressed keys scored, the recurrence carried
+    ("prompt", "kv"): {("slice", "whole")},
+    ("prompt", "compressed_keys"): {("slice", "whole"), ("multiply", "whole"),
+                                    ("add", "whole")},
+    ("prompt", "recurrent"): {("multiply", "whole"), ("add", "whole"),
+                              ("select", "whole")},
+    # a join writes the joining row alone (it reads that row back first:
+    # a slot past the cache is padding and keeps what is there); a row is
+    # a third of these three-row caches
+    ("join", "kv"): {_WRITE, ("dynamic-slice", "whole")},
+    ("join", "compressed_keys"): {_WRITE, ("dynamic-slice", "whole")},
+    ("join", "recurrent"): {_WRITE, ("dynamic-slice", "whole")},
+}
+
+
+@pytest.mark.parametrize("program,kind", sorted(_HYBRID_READERS))
+def test_hybrid_programs_touch_each_state_only_to_write_and_read_it(
+        hybrid_programs, program, kind):
+    """The text the TPU's compiler makes of the hybrid decoder's decode
+    window, prompt and join: nothing but the in-place write and the
+    layer's own read has a whole KV cache, compressed-key cache or
+    recurrent state among its operands: no ``copy``, ``transpose``,
+    ``pad`` or ``select`` of a cache, no read that returns more than a
+    quarter of one where the layer reads a part."""
+    txt, shapes = hybrid_programs[program]
+    found = _consumers(txt, shapes[kind])
+    assert found <= _HYBRID_READERS[program, kind], sorted(
+        found - _HYBRID_READERS[program, kind])
+    # the write and a read are really there to be seen
+    assert found >= _HYBRID_READERS[program, kind] & {_WRITE} and found

@@ -1,12 +1,12 @@
 #!/bin/bash
-# One run of gpt2-large-serve-backlog in a checkout under this one (say a parent
+# One run of a cell (CELL, by default gpt2-large-serve-backlog) in a checkout under this one (say a parent
 # unpacked by `git archive` into the git-ignored _chip_proof/parent), its last
 # line echoed and its output kept under chiprun_out/pr/:
 #   tools/chip/pairs.sh one <label> <dir> <seed> <trace 0|1>
 set -u
 OUT=$PWD/chiprun_out/pr
 mkdir -p $OUT
-CELL=gpt2-large-serve-backlog
+CELL=${CELL:-gpt2-large-serve-backlog}
 one() {  # label dir seed trace
   local label=$1 dir=$2 seed=$3 trace=$4
   ( cd $dir
