@@ -17,7 +17,13 @@ on top of ``nn.decoding.TransformerDecoder``:
 - between windows, finished sequences (EOS / max-tokens / expired
   deadline) retire and free their rows, and waiting prompts prefill
   into the freed rows in one launch — no sequence ever waits for the
-  batch to drain.
+  batch to drain;
+- the loop runs ONE WINDOW AHEAD of what it has read: window n+1 (and
+  any join before it) is dispatched onto the state window n returns
+  while n still runs, and only then are n's tokens read and accounted,
+  so the host's work between windows is hidden behind the device's
+  (:class:`GenerationEngine` says what is in flight and what that
+  costs).
 
 The admission-control surface is the batcher's, reused wholesale: the
 same queue semantics, ``max_queue`` → :class:`ServerOverloadedError`
@@ -120,7 +126,8 @@ class GenerationConfig:
 class _GenRequest:
     __slots__ = ("tokens", "n", "max_new", "eos", "temp", "rng", "deadline",
                  "event", "out", "error", "t0", "t_join", "t_first",
-                 "t_done", "row", "prefix_len", "prefix_nodes", "trace")
+                 "t_done", "row", "planned", "prefix_len", "prefix_nodes",
+                 "trace")
 
     def __init__(self, tokens, max_new, eos, temp, rng, deadline, t0,
                  trace=None):
@@ -142,6 +149,10 @@ class _GenRequest:
         self.t_first: Optional[float] = None
         self.t_done: Optional[float] = None
         self.row: Optional[int] = None
+        # the most tokens ``out`` can hold once every program dispatched
+        # for this request has been read: 1 for its prefill, K for each
+        # decode window launched with it
+        self.planned = 0
         self.prefix_len = 0          # tokens served from the prefix cache
         self.prefix_nodes: list = []  # pinned trie nodes (one pin each)
         self.trace = trace           # request trace (None when disabled)
@@ -150,6 +161,39 @@ class _GenRequest:
         """Stamp ``t_done`` and wake the waiter."""
         self.t_done = now
         self.event.set()
+
+    @property
+    def ends_in_flight(self) -> bool:
+        """Whether the programs already dispatched carry this request to
+        ``max_new`` tokens: it ends by length inside them, whether or not
+        a stop token comes first, and no later window can emit for it."""
+        return self.planned >= self.max_new
+
+
+class _Window:
+    """One decode window in flight: dispatched, not yet read. ``rows`` is
+    ITS OWN snapshot of row -> request (a row given away while the window
+    runs still yields this window's column to the request that held it);
+    the device arrays come with the launch."""
+
+    __slots__ = ("rows", "kv_bucket", "toks", "emitted", "counts")
+
+    def __init__(self, rows: dict):
+        self.rows = rows
+        self.kv_bucket = self.toks = self.emitted = self.counts = None
+
+
+class _Prefill:
+    """One prefill launch whose first tokens are not read yet."""
+
+    __slots__ = ("kind", "joins", "bp", "tok", "active", "kv", "offset",
+                 "seconds")
+
+    def __init__(self, kind, joins, bp, tok, active, kv, offset, seconds):
+        self.kind, self.joins, self.bp = kind, joins, bp
+        self.tok, self.active = tok, active
+        self.kv, self.offset = kv, offset   # for the prefix cache's pages
+        self.seconds = seconds              # host time of stage + launch
 
 
 class GenerationEngine:
@@ -168,6 +212,47 @@ class GenerationEngine:
     queue, output accumulation) lives behind one condition variable, the
     same discipline as the batcher; device state is touched only by the
     single decode-loop thread.
+
+    **The loop runs one decode window ahead of what it has read.** Nothing
+    a launch needs comes from a read-back: the donated state carries
+    tokens, positions, liveness, ``max_new``, ``eos`` and the rng, and a
+    row retires in-graph. So with window n in flight (dispatched, not
+    read) an iteration admits, dispatches any join's ``prompt_fn`` and
+    ``join_fn`` onto the state n returns, dispatches window n+1 behind
+    them, and only THEN blocks on n's tokens, accounts them and reads the
+    join's first tokens. The device runs n -> prompt -> join -> n+1 while
+    the host does all of that; the depth is the constant 1 (a further
+    window in flight would only delay a join by its whole length).
+
+    - *What is in flight*: at most two windows at the moment of a
+      read-back, each with its own snapshot of row -> request
+      (:class:`_Window`), and the prefills dispatched between them.
+      ``_rows`` is who owns a row for the NEXT launch.
+    - *When a row is given away*: the host knows ``planned``, the tokens a
+      request holds once everything dispatched is read (1 + K a window). A
+      row whose request ends by length inside the windows in flight counts
+      as free at admission, so the next prompt joins right behind the old
+      request's last window and the batch stays full; the old request
+      still gets its last tokens from that window's column.
+    - *What a stop token costs*: an early end is seen at the read-back,
+      one window late. The window already in flight carries the dead row
+      (K row-steps that emit nothing, masked in-graph) and the row is
+      free one window later than it could be. An end by length costs
+      nothing.
+    - *When it does not run ahead*: no window is launched unless a row can
+      still emit in it, so an engine going idle launches nothing into an
+      empty batch; with nothing in flight a join's first token is read at
+      once, as there is nothing to hide the read behind. ``gen.wait`` is
+      entered only with nothing in flight.
+    - A deadline that passes is seen at a read-back too: ``release_fn``
+      lands behind the window in flight, and what that window emitted for
+      the dead request is dropped. A dispatch failure discards the windows
+      in flight with the state and fails every request in them once.
+
+    ``stats()`` counts how often it engages: ``windows_total``,
+    ``windows_ahead_total`` (launched with another still unread),
+    ``joins_ahead_total`` (rows given away before their last window was
+    read), ``windows_empty_total`` (windows that emitted nothing).
     """
 
     def __init__(self, model, config: Optional[GenerationConfig] = None,
@@ -223,17 +308,25 @@ class GenerationEngine:
         self._cond = threading.Condition()
         self._stop = False
         self._thread: Optional[threading.Thread] = None
-        # device decode state + host mirrors (rows/positions), owned by
-        # the decode loop; _rows/_n_active are read under _cond by
-        # submit/stats
+        # device decode state, owned by the decode loop. _rows is who
+        # owns a row for the NEXT launch; _flight the decode windows
+        # dispatched and not read, oldest first, each with the rows IT
+        # decodes: both change under _cond only, _flight on the loop's
+        # thread only. _unread, the prefills dispatched behind a window
+        # in flight, is the loop's own.
         self._state = None
         self._S = self._dec.kv_ladder[0]
         self._rows: List[Optional[_GenRequest]] = [None] * cfg.max_batch
-        self._positions = [0] * cfg.max_batch  # host mirror of slot counts
-        self._n_active = 0
+        self._flight: deque = deque()
+        self._unread: List[_Prefill] = []
         self._joined_total = 0
         self._retired_total = 0
         self._tokens_total = 0
+        # how often the loop runs ahead (class docstring)
+        self._windows_total = 0
+        self._windows_ahead_total = 0
+        self._joins_ahead_total = 0
+        self._windows_empty_total = 0
         # host wall time of the prefill launches and of the decode
         # windows, each INCLUDING the wait for the device's result
         self._prefill_seconds = 0.0
@@ -402,7 +495,13 @@ class GenerationEngine:
         (zero-recompile invariant reads off ``misses``), breaker state.
         ``prefill_seconds`` and ``decode_seconds`` are HOST wall time of
         the prefill launches and the decode windows, the wait for the
-        device's result (the read-back) included: not device time."""
+        device's result (the read-back) included: not device time. A
+        decode window's is its ``gen.decode`` span up to the read-back's
+        end (the launch of the window ahead and the read of the one
+        before); a prefill's leaves out what the loop did between its
+        launch and the read of its first tokens. The four ``windows_*`` /
+        ``joins_ahead_total`` counts say how often the loop ran ahead
+        (class docstring)."""
         with self._cond:
             out = {
                 "rows": self.config.max_batch,
@@ -415,6 +514,10 @@ class GenerationEngine:
                 "joined_total": self._joined_total,
                 "retired_total": self._retired_total,
                 "tokens_total": self._tokens_total,
+                "windows_total": self._windows_total,
+                "windows_ahead_total": self._windows_ahead_total,
+                "joins_ahead_total": self._joins_ahead_total,
+                "windows_empty_total": self._windows_empty_total,
                 "prefill_seconds": round(self._prefill_seconds, 4),
                 "decode_seconds": round(self._decode_seconds, 4),
                 "layer_counts": dict(self._layer_counts),
@@ -453,20 +556,32 @@ class GenerationEngine:
         """A span of the loop's current iteration."""
         return telemetry.span(name, n=self._window, **attrs)
 
+    def _live_rows(self) -> dict:
+        """``{row: request}`` of the rows that can still emit in a window
+        launched now: owned, and not certain to end inside what is
+        already dispatched."""
+        return {b: r for b, r in enumerate(self._rows)
+                if r is not None and not r.ends_in_flight}
+
+    def _idle_locked(self) -> bool:
+        return not (self._queue or self._flight or self._live_rows())
+
     def _loop(self):
+        """One iteration, with window n in flight: admit -> dispatch the
+        joins behind n -> dispatch window n+1 behind them -> read and
+        account n -> read the joins' first tokens."""
         while True:
             self._window += 1
             with self._span("gen.admit") as sp:
                 asked = time.monotonic_ns()
                 with self._cond:
                     _lock_wait(sp, asked)
-                    if (not self._stop and not self._queue
-                            and self._n_active == 0):
+                    if not self._stop and self._idle_locked():
                         with self._span("gen.wait"):
-                            while (not self._stop and not self._queue
-                                   and self._n_active == 0):
+                            while not self._stop and self._idle_locked():
                                 self._cond.wait(0.1)
                     if self._stop:
+                        self._flight.clear()
                         return
                     self._expire_queued_locked(time.monotonic())
                     joins = self._pick_joins_locked()
@@ -474,8 +589,11 @@ class GenerationEngine:
             try:
                 if joins:
                     self._do_prefill(joins)
-                if self._n_active:
-                    self._do_decode()
+                self._do_decode()
+                while self._unread:
+                    p = self._unread.pop(0)
+                    with self._span("gen.prefill", kind=p.kind):
+                        self._account_prefill(p)
             except Exception as e:  # noqa: BLE001 — loop must survive
                 self._on_dispatch_failure(e)
 
@@ -500,16 +618,24 @@ class GenerationEngine:
     def _pick_joins_locked(self) -> List[_GenRequest]:
         """Token-granularity admission: every iteration, as many waiting
         prompts as there are free cache rows join the running batch —
-        FIFO, no waiting for a drain."""
-        free = [i for i, r in enumerate(self._rows) if r is None]
+        FIFO, no waiting for a drain. A row whose request ends by length
+        inside the windows in flight is free already: the join is
+        dispatched behind that request's last window, before it is
+        read."""
+        free = [b for b, r in enumerate(self._rows)
+                if r is None or r.ends_in_flight]
         n = min(len(free), len(self._queue))
         joins = []
         now = time.monotonic() if n else None
-        for _ in range(n):
+        for b in free[:n]:
             req = self._queue.popleft()
             req.t_join = now
-            req.row = free[len(joins)]
-            self._rows[req.row] = req
+            req.row = b
+            req.planned = 1
+            if self._rows[b] is not None:
+                self._joins_ahead_total += 1
+                telemetry.record_decode_run_ahead(joins_ahead=1)
+            self._rows[b] = req
             if req.trace is not None:
                 req.trace.event("join", {"row": req.row})
             joins.append(req)
@@ -593,13 +719,14 @@ class GenerationEngine:
                 self._state = self._dec.join_fn(self._S, tp, bp)(
                     self._state, kv, rows, tok, lengths, max_new, eos, temps,
                     rng2, active)
-                if self._prefix is not None:
-                    self._insert_pages(joins, kv, offset=0)
             for r in joins:
                 if r.trace is not None:
                     r.trace.event("prefill",
                                   {"prompt_bucket": tp, "rows": bp})
-            self._account_prefill(joins, tok, active, bp, t0)
+            self._launched_prefill(_Prefill(
+                "cold", joins, bp, tok, active,
+                kv if self._prefix is not None else None, 0,
+                time.monotonic() - t0))
 
     def _call_prefill(self, once, joins):
         """One prefill launch, retried (never past the joins' earliest
@@ -677,15 +804,28 @@ class GenerationEngine:
                 self._state = self._dec.suffix_join_fn(self._S, ts, bp)(
                     self._state, kv, rows, tok, plens, lengths, max_new,
                     eos, temps, rng2, active)
-                # extend the trie with the hit requests' own suffix pages
-                # (page extension: next time a LONGER shared prefix hits)
-                self._insert_pages(joins, kv, offset="prefix")
             for r in joins:
                 if r.trace is not None:
                     r.trace.event("prefix_attach",
                                   {"prefix_len": r.prefix_len,
                                    "suffix_bucket": ts})
-            self._account_prefill(joins, tok, active, bp, t0)
+            # the trie is extended with the hit requests' own suffix pages
+            # (page extension: next time a LONGER shared prefix hits)
+            self._launched_prefill(_Prefill(
+                "suffix", joins, bp, tok, active,
+                kv if self._prefix is not None else None, "prefix",
+                time.monotonic() - t0))
+
+    def _launched_prefill(self, p: _Prefill):
+        """Called inside the ``gen.prefill`` span that launched ``p``.
+        Behind a window in flight the first tokens are read after the
+        next launch (the loop opens a second ``gen.prefill`` span for
+        them); with nothing in flight there is nothing to hide the read
+        behind, and it happens here."""
+        if self._flight:
+            self._unread.append(p)
+        else:
+            self._account_prefill(p)
 
     def _insert_pages(self, joins, kv, offset):
         """Donate a prefill launch's KV to the prefix cache: full pages
@@ -698,7 +838,9 @@ class GenerationEngine:
         once per launch, and only when a new page is actually created.
         The inserted path's pins are appended to the request's node
         list, so its own pages cannot be evicted before it retires and
-        every pin still releases on the usual terminal edges."""
+        every pin still releases on the usual terminal edges; a request
+        that reached one while its prefill was in flight donates
+        nothing."""
         host = {}
 
         def make_slicer(i, off):
@@ -716,26 +858,37 @@ class GenerationEngine:
             return slicer
 
         for i, r in enumerate(joins):
+            if r.t_done is not None:
+                continue
             off = r.prefix_len if offset == "prefix" else 0
             nodes = self._prefix.insert(r.tokens, r.n, make_slicer(i, off))
             r.prefix_nodes = list(r.prefix_nodes) + list(nodes)
 
-    def _account_prefill(self, joins, tok, active, bp, t0):
+    def _account_prefill(self, p: _Prefill):
+        """Read a prefill's first tokens and account them (first token,
+        TTFT, who was born retired). The prefix cache's pages leave the
+        device here too: a blocking copy, like the tokens'."""
+        t0 = time.monotonic()
         with self._span("gen.prefill.readback", sync=True):
-            tok = np.asarray(tok)
-            active = np.asarray(active)
+            tok = np.asarray(p.tok)
+            active = np.asarray(p.active)
+            if self._prefix is not None:
+                self._insert_pages(p.joins, p.kv, p.offset)
         now = time.monotonic()
-        n_live = 0
+        seconds = p.seconds + now - t0
         with self._span("gen.prefill.account") as sp:
             asked = time.monotonic_ns()
             with self._cond:
                 _lock_wait(sp, asked)
+                # a join that completed while its prefill was in flight
+                # (close, a failure) has nothing to account
+                joins = [(i, r) for i, r in enumerate(p.joins)
+                         if r.t_done is None]
                 # counted before a row born retired wakes its waiter, as
                 # the decode window counts
-                telemetry.record_decode_prefill(len(joins), bp, now - t0)
-                for i, r in enumerate(joins):
+                telemetry.record_decode_prefill(len(joins), p.bp, seconds)
+                for i, r in joins:
                     r.out.append(int(tok[i]))
-                    self._positions[r.row] = r.n
                     r.t_first = now
                     telemetry.record_decode_first_token(now - r.t0)
                     if r.trace is not None:
@@ -743,96 +896,143 @@ class GenerationEngine:
                     if self._slo is not None:
                         self._slo.observe(self.name or "default",
                                           ttft=now - r.t0)
-                    if active[i]:
-                        n_live += 1
-                    else:
+                    if not active[i]:
                         self._finish_locked(r, now)
-                self._n_active += n_live
                 self._joined_total += len(joins)
                 self._tokens_total += len(joins)
-                self._prefill_seconds += now - t0
+                self._prefill_seconds += seconds
         if self._breaker is not None:
             self._breaker.on_success()
 
     def _do_decode(self):
+        if not self._flight and not self._live_rows():
+            return
         with self._span("gen.decode") as parent:
             self._decode_window(parent)
 
     def _decode_window(self, parent):
-        """One decode window: plan (grow the cache to hold K more
-        positions) → launch ``decode_fn(S, K)`` → read back → account →
-        release the rows whose deadline passed."""
-        cfg = self.config
-        k = cfg.fused_steps
+        """The iteration's decode part, under one ``gen.decode`` span that
+        reads back and accounts EXACTLY ONE window: the oldest in flight.
+        Before that read it launches the window ahead, on the state the
+        one in flight returns; with nothing in flight (the first
+        iteration of a burst) it launches that one first."""
         t0 = time.monotonic()
+        if not self._flight:
+            self._launch_window()
+        self._launch_window()
+        if self._flight:        # not closed before anything was launched
+            self._read_window(parent, t0)
+
+    def _launch_window(self) -> bool:
+        """Plan and dispatch ``decode_fn(S, K)`` for the rows that can
+        still emit, if there are any. The cache is grown from an upper
+        bound the host has without a read-back: a row holds at most
+        ``n + planned - 1`` positions once everything dispatched has run,
+        so a bucket hop is never late."""
+        k = self.config.fused_steps
         with self._span("gen.decode.plan") as sp:
             asked = time.monotonic_ns()
             with self._cond:
                 _lock_wait(sp, asked)
-                max_pos = max((self._positions[r.row] for r in self._rows
-                               if r is not None), default=0)
+                rows = self._live_rows()
+                if not rows:
+                    sp.annotate(grew=False, rows=0)
+                    return False
+                max_pos = max(r.n + r.planned - 1 for r in rows.values())
+                for r in rows.values():
+                    r.planned += k
+                ahead = bool(self._flight)
+                self._windows_total += 1
+                self._windows_ahead_total += ahead
+                telemetry.record_decode_run_ahead(windows=1,
+                                                  windows_ahead=ahead)
+                w = _Window(rows)
+                self._flight.append(w)
             sp.annotate(grew=self._grow_to(
-                min(max_pos + k, self._dec.max_len)))
+                min(max_pos + k, self._dec.max_len)), rows=len(rows))
+            w.kv_bucket = self._S
         # NO retry on decode windows: the state pytree is donated into
         # the executable, so a mid-flight failure may have consumed it —
         # _on_dispatch_failure resets instead
         with self._span("gen.decode.launch"):
             faults.fault_point(self._fault_site)
-            self._state, toks, emitted, *counts = self._dec.decode_fn(
+            self._state, w.toks, w.emitted, *w.counts = self._dec.decode_fn(
                 self._S, k)(self._net_params(), self._state)
+        return True
+
+    def _read_window(self, parent, t0: float):
+        """Read back the oldest window in flight → account its tokens to
+        the requests IT decoded → release the rows whose deadline passed
+        (behind whatever is in flight by now)."""
+        cfg = self.config
+        k = cfg.fused_steps
+        w = self._flight[0]
         with self._span("gen.decode.readback", sync=True):
-            toks = np.asarray(toks)
-            emitted = np.asarray(emitted)
+            toks = np.asarray(w.toks)
+            emitted = np.asarray(w.emitted)
             # the cached layers' counters, same read-back
             counts = dict(zip(self._dec.counter_names,
-                              np.asarray(counts[0]).tolist())) \
-                if counts else {}
+                              np.asarray(w.counts[0]).tolist())) \
+                if w.counts else {}
         now = time.monotonic()
-        n_emitted = int(emitted.sum())
+        n_emitted = 0
         released = []
         with self._span("gen.decode.account") as sp:
             asked = time.monotonic_ns()
             with self._cond:
                 _lock_wait(sp, asked)
-                occupancy = sum(r is not None for r in self._rows)
-                finished = []
-                for b, req in enumerate(self._rows):
-                    if req is None:
+                self._flight.popleft()
+                finished, expired = [], []
+                for b, req in w.rows.items():
+                    if req.t_done is not None:
+                        # completed while this window ran (a deadline, a
+                        # close): what it emitted for the row is dropped
                         continue
-                    if req.trace is not None:
-                        req.trace.event("decode_window", {
-                            "k": k, "kv_bucket": self._S,
-                            "tokens": int(emitted[:, b].sum()),
-                            "ms": round((now - t0) * 1000.0, 3)})
+                    n_row = 0
                     done = False
                     for i in range(toks.shape[0]):
                         if not emitted[i, b]:
                             break
                         t = int(toks[i, b])
                         req.out.append(t)
-                        self._positions[b] += 1
+                        n_row += 1
                         if t == req.eos or len(req.out) >= req.max_new:
                             done = True
                             break
+                    n_emitted += n_row
+                    if req.trace is not None:
+                        req.trace.event("decode_window", {
+                            "k": k, "kv_bucket": w.kv_bucket,
+                            "tokens": n_row,
+                            "ms": round((now - t0) * 1000.0, 3)})
                     if done:
                         finished.append(req)
                     elif req.deadline is not None and now > req.deadline:
-                        released.append(b)
+                        expired.append(req)
                 self._tokens_total += n_emitted
+                if not n_emitted and not self._stop:
+                    self._windows_empty_total += 1
+                    telemetry.record_decode_run_ahead(windows_empty=1)
                 self._decode_seconds += now - t0
                 for name, n in counts.items():
                     self._layer_counts[name] += n
                 # count BEFORE a finished request's waiter wakes: a
                 # snapshot taken as generate() returns holds this
                 # window's tokens, and /metrics agrees with the request
+                gone = finished + expired
                 telemetry.record_decode_layer_counts(counts)
                 telemetry.record_decode_iteration(
-                    n_emitted, occupancy, cfg.max_batch,
-                    occupancy - len(finished) - len(released), k, now - t0)
+                    n_emitted, len(w.rows), cfg.max_batch,
+                    sum(r is not None and r not in gone
+                        for r in self._rows), k, now - t0)
                 for req in finished:
                     self._finish_locked(req, now)
-                for b in released:
-                    req = self._rows[b]
+                for req in expired:
+                    # a row already given away is the next request's: the
+                    # join behind this one's last window overwrites it
+                    if self._rows[req.row] is req:
+                        self._rows[req.row] = None
+                        released.append(req.row)
                     req.error = DeadlineExpiredError(
                         "deadline expired mid-generation after "
                         f"{len(req.out)} tokens")
@@ -842,9 +1042,7 @@ class GenerationEngine:
                                          {"tokens": len(req.out)})
                     self._release_prefix(req)
                     req.complete(now)
-                    self._rows[b] = None
-                self._n_active -= len(finished) + len(released)
-                sp.annotate(finished=len(finished), expired=len(released))
+                sp.annotate(finished=len(finished), expired=len(expired))
         if released:
             with self._span("gen.decode.release", rows=len(released)):
                 keep = np.ones((cfg.max_batch,), bool)
@@ -853,14 +1051,15 @@ class GenerationEngine:
                                                             keep)
         if self._breaker is not None:
             self._breaker.on_success()
-        parent.annotate(k=k, kv_bucket=self._S, rows=occupancy,
+        parent.annotate(k=k, kv_bucket=w.kv_bucket, rows=len(w.rows),
                         emitted=n_emitted)
 
     def _net_params(self):
         return self._dec.params
 
     def _finish_locked(self, req: _GenRequest, now: float):
-        self._rows[req.row] = None
+        if self._rows[req.row] is req:      # not given away already
+            self._rows[req.row] = None
         self._retired_total += 1
         telemetry.record_decode_request("ok", now - req.t0, model=self.name)
         tracing.finish_trace(req.trace, "done",
@@ -871,36 +1070,52 @@ class GenerationEngine:
         self._release_prefix(req)
         req.complete(now)
 
+    def _abandon_locked(self, error: BaseException, outcome: str,
+                        attrs: Optional[dict] = None) -> List[_GenRequest]:
+        """Fail every request that holds a row or rides a window in
+        flight, each once, and free the rows; returns them. (A join whose
+        prefill is unread holds its row: rows change hands at admission
+        only.)"""
+        now = time.monotonic()
+        failed = []
+        for req in itertools.chain(
+                self._rows, *(w.rows.values() for w in self._flight)):
+            if req is None or req.t_done is not None:
+                continue
+            req.error = error if req.error is None else req.error
+            tracing.finish_trace(req.trace, outcome, attrs)
+            self._release_prefix(req)
+            req.complete(now)
+            failed.append(req)
+        self._rows = [None] * self.config.max_batch
+        return failed
+
     def _on_dispatch_failure(self, e: BaseException):
-        """A prefill/decode dispatch raised. The decode state may have
-        been donated into the failed executable, so it cannot be trusted:
-        fail every in-flight request (the batcher fails its batch the
-        same way), reset to a fresh zeroed state, and count the breaker
-        failure — persistent failure trips it open and submits shed."""
+        """A prefill/decode dispatch or read-back raised. The decode
+        state may have been donated into the failed executable, so it
+        cannot be trusted, and neither can a window in flight on it:
+        fail every request that holds a row or rides such a window (the
+        batcher fails its batch the same way), reset to a fresh zeroed
+        state, and count the breaker failure — persistent failure trips
+        it open and submits shed."""
         with self._cond:
-            now = time.monotonic()
-            for b, req in enumerate(self._rows):
-                if req is None:
-                    continue
-                req.error = e if req.error is None else req.error
+            for _ in self._abandon_locked(e, "rollback",
+                                          {"error": type(e).__name__}):
                 telemetry.record_decode_request("error", model=self.name)
-                tracing.finish_trace(req.trace, "rollback",
-                                     {"error": type(e).__name__})
                 if self._slo is not None:
                     self._slo.observe(self.name or "default", ok=False)
-                self._release_prefix(req)
-                req.complete(now)
-                self._rows[b] = None
-            self._n_active = 0
-            self._positions = [0] * self.config.max_batch
+            self._flight.clear()
+        self._unread.clear()
         self._state = self._dec.new_state(self._S)
         if self._breaker is not None:
             self._breaker.on_failure()
 
     # --- lifecycle ----------------------------------------------------------
     def close(self):
-        """Stop the decode loop; queued and in-flight requests fail with
-        a shutdown error. Idempotent."""
+        """Stop the decode loop; queued requests, those that hold a row
+        and those in a window in flight fail with a shutdown error. The
+        loop reads what it has in flight to the end (the tokens are
+        dropped) and goes. Idempotent."""
         with self._cond:
             self._stop = True
             err = RuntimeError("generation engine closed")
@@ -911,14 +1126,7 @@ class GenerationEngine:
                 self._release_prefix(req)
                 req.complete(now)
             self._queue.clear()
-            for b, req in enumerate(self._rows):
-                if req is not None:
-                    req.error = err
-                    tracing.finish_trace(req.trace, "shutdown")
-                    self._release_prefix(req)
-                    req.complete(now)
-                    self._rows[b] = None
-            self._n_active = 0
+            self._abandon_locked(err, "shutdown")
             self._cond.notify_all()
         telemetry.unregister_generation_engine(self)
         t = self._thread

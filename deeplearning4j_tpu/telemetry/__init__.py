@@ -459,6 +459,25 @@ def record_decode_iteration(tokens: int, active_rows: int, capacity: int,
                                 "(window time / K)").observe(seconds / k)
 
 
+def record_decode_run_ahead(windows: int = 0, windows_ahead: int = 0,
+                            joins_ahead: int = 0,
+                            windows_empty: int = 0) -> None:
+    """How often the generation loop runs ahead of what it has read
+    (``parallel.generation.GenerationEngine``): decode windows launched,
+    those launched with another still unread, joins placed in a row
+    before its last window was read, windows that emitted nothing."""
+    for name, n, text in (
+            ("windows", windows, "decode windows launched"),
+            ("windows_ahead", windows_ahead,
+             "decode windows launched ahead of a read-back"),
+            ("joins_ahead", joins_ahead,
+             "joins placed before the row's last window was read"),
+            ("windows_empty", windows_empty,
+             "decode windows that emitted nothing")):
+        if n:
+            REGISTRY.counter(f"dl4j_decode_{name}_total", help=text).inc(n)
+
+
 def record_decode_prefill(rows: int, bucket_rows: int,
                           seconds: float) -> None:
     """One prefill launch: joining sequences, padded join-bucket fill,

@@ -193,15 +193,18 @@ def test_late_join_completes_before_earlier_longer_sequence():
     """Token-granularity admission: a short request submitted AFTER a
     long one is already decoding joins the running batch at the next
     iteration and finishes first — no request-granularity drain wait.
-    The long request's fourth launch (its prefill and two windows of K
-    are five tokens) is held open by a delay fault, and the test reads
-    the handle's token count and submits under the engine's own
-    condition, so no accounting runs between the reading and the
-    enqueue: nothing here depends on how fast a toy window is."""
+    The loop runs a window ahead, so the long request's launches are: its
+    prefill, the first window and the one ahead of it (the first is then
+    read: three tokens), the third window (the second is read: five
+    tokens), the fourth. That FIFTH launch is held open by a delay fault
+    with five tokens accounted, and the test reads the handle's token
+    count and submits under the engine's own condition, so no accounting
+    runs between the reading and the enqueue: nothing here depends on
+    how fast a toy window is."""
     dec = _decoder()
     long_ref = dec.generate([7, 3], max_new=24)
     short_ref = dec.generate([9, 9, 2], max_new=3)
-    plan = FaultPlan().inject("decode.launch", on_calls=[4], action="delay",
+    plan = FaultPlan().inject("decode.launch", on_calls=[5], action="delay",
                               delay_s=0.5)
     with plan.armed(), _engine() as eng:
         long_req = eng.submit([7, 3], max_new_tokens=24)
@@ -303,8 +306,14 @@ def test_expired_deadline_fails_queued_request():
 
 def test_deadline_mid_generation_frees_row():
     """A deadline that expires while the sequence is decoding fails the
-    request at the next retire check and releases its cache row (the
-    in-graph ``gen_release`` mask keeps the dead row a no-op)."""
+    request at the next read-back and releases its cache row (the
+    in-graph ``gen_release`` mask keeps the dead row a no-op). A window
+    is in flight then, with the dead row in it: ``gen_release`` lands
+    behind it, what it emitted for the row is dropped (the handle holds
+    a prefix of the reference and never grows again), and the row
+    serves the next request as a clean one."""
+    dec = _decoder()
+    ref = dec.generate([1, 2, 3], max_new=28)
     plan = FaultPlan(seed=3)
     plan.inject("decode.launch", probability=1.0, action="delay",
                 delay_s=0.02)
@@ -313,10 +322,233 @@ def test_deadline_mid_generation_frees_row():
             req = eng.submit([1, 2, 3], max_new_tokens=28, timeout_ms=60)
             with pytest.raises(DeadlineExpiredError):
                 eng.result(req)
+        held = list(req.out)
+        assert 0 < len(held) < 28 and held == ref[:len(held)]
         deadline = time.monotonic() + 5
         while eng.stats()["rows_in_use"] and time.monotonic() < deadline:
             time.sleep(0.005)
-        assert eng.stats()["rows_in_use"] == 0
+        st = eng.stats()
+        assert st["rows_in_use"] == 0
+        # the window in flight at the expiry was read and dropped
+        assert st["windows_ahead_total"] >= 1
+        assert st["tokens_total"] == len(held)
+        assert eng.generate([1, 2, 3], max_new_tokens=28) == ref
+        assert req.out == held
+
+
+# --- the loop runs one window ahead of what it has read ---------------------
+
+def _wait_for(what, seconds=30.0):
+    deadline = time.monotonic() + seconds
+    while not what():
+        assert time.monotonic() < deadline, "never happened"
+        time.sleep(0.002)
+
+
+def _held_at_launch(n_call, delay_s=0.6):
+    """A plan that holds the ``n_call``-th launch of the engine (prefills
+    and decode windows count alike) open for ``delay_s``."""
+    return FaultPlan().inject("decode.launch", on_calls=[n_call],
+                              action="delay", delay_s=delay_s)
+
+
+def test_launch_precedes_the_read_of_the_window_before():
+    """One request of 24 tokens at K = 2. Its launches: the prefill (one
+    token), windows 1 and 2 (the first iteration launches two, then reads
+    window 1: three tokens), window 3 (window 2 is read: five tokens),
+    window 4, held open by a delay fault. While it is held the host has
+    accounted windows 1 and 2 only, though window 3 was launched an
+    iteration ago: it is in flight, unread, and window 4 is being
+    launched onto the state it returns. When the launch returns, the
+    read-back in the same span is window 3's, and window 4 is read an
+    iteration later: the tokens are the reference's."""
+    from deeplearning4j_tpu import telemetry
+
+    dec = _decoder()
+    ref = dec.generate([7, 3], max_new=24)
+    telemetry.spans.reset()
+    plan = _held_at_launch(5)
+    with plan.armed(), _engine() as eng:
+        req = eng.submit([7, 3], max_new_tokens=24)
+        _wait_for(lambda: plan.fired("decode.launch"))
+        with eng._cond:
+            seen = (len(req.out), eng._windows_total, len(eng._flight),
+                    eng._windows_ahead_total)
+        assert eng.result(req) == ref
+        st = eng.stats()
+    # five tokens accounted, four windows launched (the fourth is in the
+    # fault), two of them in flight: the one before it was not read first
+    assert seen == (5, 4, 2, 3)
+    # 23 tokens after the prefill's: 12 windows, all but the first ahead
+    assert (st["windows_total"], st["windows_ahead_total"],
+            st["windows_empty_total"]) == (12, 11, 0)
+    evs = telemetry.events()
+    decodes = sorted((e for e in evs if e["name"] == "gen.decode"),
+                     key=lambda e: e["start_ns"])
+    held = [e for e in decodes if e["duration_ns"] >= 0.5e9]
+    assert len(held) == 1 and decodes.index(held[0]) == 2
+    for e in decodes[1:-1]:     # the steady state: launch, then read
+        kids = {c["name"]: c for c in evs if c["parent_id"] == e["id"]}
+        launch, read = kids["gen.decode.launch"], kids["gen.decode.readback"]
+        assert launch["start_ns"] + launch["duration_ns"] <= read["start_ns"]
+    # each span accounts one window of K tokens: the held one the window
+    # launched before it
+    assert [e["attrs"]["emitted"] for e in decodes] == [K] * 11 + [1]
+
+
+def test_a_row_that_ends_by_length_is_rejoined_with_no_window_between():
+    """Four rows, five requests, all enqueued before the loop admits.
+    The first ends by length in the second window (5 tokens at K = 2); the
+    host knows that when the second window is launched, so the fifth
+    request's join goes behind it, before it is read, into the same row,
+    and the third window decodes four rows again: the batch never shows
+    the hole."""
+    from deeplearning4j_tpu import telemetry
+
+    dec = _decoder()
+    prompts = [[3, 9, 1], [5, 6, 7, 8], [1, 2], [14, 13, 12, 2], [9, 9, 2]]
+    mns = [5, 16, 16, 16, 6]
+    refs = [dec.generate(p, mn) for p, mn in zip(prompts, mns)]
+    telemetry.spans.reset()
+    with _engine() as eng:
+        with eng._cond:             # the loop cannot admit before all five
+            reqs = [eng.submit(p, max_new_tokens=mn)
+                    for p, mn in zip(prompts, mns)]
+        assert [eng.result(r) for r in reqs] == refs
+        st = eng.stats()
+    assert reqs[4].row == reqs[0].row
+    assert st["joins_ahead_total"] == 1 and st["windows_empty_total"] == 0
+    decodes = sorted((e for e in telemetry.events()
+                      if e["name"] == "gen.decode"),
+                     key=lambda e: e["start_ns"])
+    # windows 1 and 2 hold the first request, 3 to 5 the fifth in its row
+    # (its six tokens: the prefill's, then 2 + 2 + 1)
+    assert [e["attrs"]["rows"] for e in decodes[:5]] == [MAX_BATCH] * 5
+    assert [e["attrs"]["emitted"] for e in decodes[:5]] == [8, 8, 8, 8, 7]
+    # the old request's last tokens came from the window the join went
+    # behind; the new one's first token was read after that window
+    assert reqs[0].t_done <= reqs[4].t_first
+
+
+def test_stop_token_with_a_window_ahead_emits_the_reference_and_no_more():
+    """A row that ends EARLY is seen at the read-back, one window late:
+    the window already in flight carries the dead row, which emits
+    nothing (masked in-graph). Alone in the batch that is one empty
+    window, the price the class docstring names; the answer is the
+    reference's up to the stop token, and never grows."""
+    dec = _decoder()
+    ref = dec.generate([4, 8, 15], max_new=20)
+    eos = ref[2]
+    want = ref[:ref.index(eos) + 1]
+    assert len(want) <= 3
+    with _engine() as eng:
+        req = eng.submit([4, 8, 15], max_new_tokens=20, eos_id=eos)
+        assert eng.result(req) == want
+        _wait_for(lambda: not eng._flight)
+        st = eng.stats()
+        assert req.out == want
+    assert st["rows_in_use"] == 0 and st["retired_total"] == 1
+    assert st["tokens_total"] == len(want)
+    if len(want) > 1:   # it ended inside window 1, with window 2 launched
+        assert (st["windows_total"], st["windows_empty_total"]) == (2, 1)
+
+
+def test_raise_at_a_launch_with_a_window_in_flight_fails_each_request_once():
+    """The fourth launch (window 3, with window 2 in flight) raises: the
+    state and the window in flight on it are discarded, both requests fail
+    with that error, each counted once, and the engine serves again."""
+    dec = _decoder()
+    ref = dec.generate([2, 4, 6], max_new=9)
+    err_key = 'dl4j_decode_requests_total{status="error"}'
+    before = REGISTRY.snapshot(run_collectors=False).get(err_key, 0)
+    plan = FaultPlan().inject("decode.launch", on_calls=[4], action="raise")
+    eng = GenerationEngine(
+        _decoder(), GenerationConfig(max_batch=MAX_BATCH, fused_steps=K,
+                                     kv_bucket_min=16, prompt_bucket_min=4),
+        breaker=None, retry=None)
+    try:
+        with plan.armed():
+            with eng._cond:
+                reqs = [eng.submit([1, 2], max_new_tokens=20),
+                        eng.submit([3, 4, 5], max_new_tokens=20)]
+            for r in reqs:
+                with pytest.raises(Exception, match="injected"):
+                    eng.result(r)
+            assert all(len(r.out) == 3 for r in reqs)   # window 1 was read
+            assert not eng._flight and not eng._unread
+            assert eng.stats()["rows_in_use"] == 0
+            assert eng.generate([2, 4, 6], max_new_tokens=9) == ref
+        after = REGISTRY.snapshot(run_collectors=False).get(err_key, 0)
+        assert after - before == 2
+    finally:
+        eng.close()
+
+
+def test_close_with_a_window_in_flight():
+    """``close()`` while a launch is held open and another window is in
+    flight: it returns, the loop's thread goes, every handle is failed
+    once with the shutdown error and holds what was accounted before."""
+    plan = _held_at_launch(5, delay_s=0.4)
+    eng = _engine()
+    with plan.armed():
+        with eng._cond:
+            reqs = [eng.submit([7, 3], max_new_tokens=24),
+                    eng.submit([1], max_new_tokens=24)]
+        _wait_for(lambda: plan.fired("decode.launch"))
+        with eng._cond:
+            assert len(eng._flight) == 2
+        thread = eng._thread
+        t0 = time.monotonic()
+        eng.close()
+        assert time.monotonic() - t0 < 4 and not thread.is_alive()
+    for r in reqs:
+        assert len(r.out) == 5
+        with pytest.raises(RuntimeError, match="closed"):
+            eng.result(r)
+    assert not eng._flight and eng.stats()["rows_in_use"] == 0
+
+
+@pytest.mark.parametrize("max_new,windows", [(1, 0), (2, 1), (3, 1), (5, 2),
+                                             (6, 3)])
+def test_an_engine_going_idle_launches_no_empty_window(max_new, windows):
+    """The last row is certain to end in the window in flight (the host
+    counts its tokens), so nothing is launched ahead of it into an empty
+    batch: just the windows the answer needs at K = 2."""
+    ref = _decoder().generate([5, 6, 7], max_new=max_new)
+    with _engine() as eng:
+        assert eng.generate([5, 6, 7], max_new_tokens=max_new) == ref
+        time.sleep(0.05)
+        st = eng.stats()
+    assert (st["windows_total"], st["windows_empty_total"]) == (windows, 0)
+    assert st["windows_ahead_total"] == max(windows - 1, 0)
+
+
+def test_kv_bucket_hop_planned_from_the_upper_bound_is_never_late():
+    """KV ladder [16, 32], a prompt of 12 and 18 new tokens: window j is
+    planned when the host has READ windows up to j - 2 only, from the
+    bound ``n + planned - 1``: it writes positions up to ``12 + 2j``, so
+    the third window's plan must hop (18 > 16), before the second is
+    read. A late hop would clamp a write and change the tokens."""
+    from deeplearning4j_tpu import telemetry
+
+    dec = _decoder()
+    prompt = list(range(1, 13))
+    ref = dec.generate(prompt, max_new=18)
+    telemetry.spans.reset()
+    with _engine() as eng:
+        assert eng.stats()["buckets"]["kv"] == [16, 32]
+        assert eng.generate(prompt, max_new_tokens=18) == ref
+        assert eng.stats()["kv_bucket"] == 32
+    evs = telemetry.events()
+    plans = sorted((e for e in evs if e["name"] == "gen.decode.plan"
+                    and e["attrs"]["rows"]), key=lambda e: e["start_ns"])
+    assert [e["attrs"]["grew"] for e in plans] == (
+        [False, False, True] + [False] * 6)
+    decodes = sorted((e for e in evs if e["name"] == "gen.decode"),
+                     key=lambda e: e["start_ns"])
+    # the hop was planned in the second iteration, whose span read window 1
+    assert plans[2]["parent_id"] == decodes[1]["id"]
+    assert [e["attrs"]["kv_bucket"] for e in decodes] == [16, 16] + [32] * 7
 
 
 def test_breaker_trips_open_and_sheds_then_recovers():
@@ -381,6 +613,14 @@ def test_decode_telemetry_series():
     assert snap1["dl4j_decode_first_token_seconds"]["count"] > 0
     ok_key = 'dl4j_decode_requests_total{status="ok"}'
     assert snap1.get(ok_key, 0) >= snap0.get(ok_key, 0) + 1
+    # how often the loop ran ahead: 6 tokens at K = 2 are a prefill and
+    # three windows, of which the last two were launched with one unread
+    d = {name: snap1.get(f"dl4j_decode_{name}_total", 0)
+         - snap0.get(f"dl4j_decode_{name}_total", 0)
+         for name in ("windows", "windows_ahead", "joins_ahead",
+                      "windows_empty")}
+    assert d == {"windows": 3, "windows_ahead": 2, "joins_ahead": 0,
+                 "windows_empty": 0}
 
 
 # --- program spans of the engine (always recorded, telemetry never enabled) -
@@ -450,17 +690,63 @@ def test_engine_spans_names_ids_and_parents_nest_per_thread(engine_spans):
                 <= parent["start_ns"] + parent["duration_ns"])
         if e["thread"] in loop:   # spans of one iteration share its n
             assert e["attrs"]["n"] == parent["attrs"]["n"]
-    # every decode window has its four children, every submit its three
     kids = {}
     for e in gen:
-        kids.setdefault(e["parent_id"], []).append(e["name"])
+        kids.setdefault(e["parent_id"], []).append(e)
+    names = {i: [c["name"] for c in sorted(cs, key=lambda c: c["start_ns"])]
+             for i, cs in kids.items()}
+    # a decode span reads back and accounts EXACTLY ONE window, after the
+    # launches it makes: none where no row could still emit (the last of
+    # a burst), the window ahead in the steady state, two with nothing in
+    # flight (the first of a burst); every launch was planned, and a plan
+    # may decide against a launch
+    decodes = sorted((e for e in gen if e["name"] == "gen.decode"),
+                     key=lambda e: e["start_ns"])
+    launches = []
+    for e in decodes:
+        mine = names[e["id"]]
+        assert mine.count("gen.decode.readback") == 1, mine
+        assert mine.count("gen.decode.account") == 1, mine
+        n_launch = mine.count("gen.decode.launch")
+        assert n_launch <= 2 and mine.count("gen.decode.plan") in (
+            n_launch, n_launch + 1), mine
+        assert set(mine) <= {f"gen.decode.{c}" for c in (
+            "plan", "launch", "readback", "account", "release")}
+        tail = mine[mine.index("gen.decode.readback"):]
+        assert tail[:2] == ["gen.decode.readback", "gen.decode.account"]
+        assert "gen.decode.launch" not in tail and (
+            "gen.decode.plan" not in tail), mine
+        launches.append(n_launch)
+    assert sum(launches) == len(decodes), "every window launched is read"
+    assert launches[0] == 2 and launches[-1] == 0
+    assert launches.count(1) > len(launches) / 2, launches
+    # a prefill is staged and launched in one span; its first tokens are
+    # read in the same span with nothing in flight, else in a second span
+    # of the same iteration, after that iteration's decode span
+    prefills = sorted((e for e in gen if e["name"] == "gen.prefill"),
+                      key=lambda e: e["start_ns"])
+    whole = ["gen.prefill.stage", "gen.prefill.launch",
+             "gen.prefill.readback", "gen.prefill.account"]
+    pending = []
+    for e in prefills:
+        mine = [n for n in names[e["id"]] if n != "gen.grow"]
+        if mine == whole[:2]:
+            pending.append(e)
+        elif mine == whole[2:]:
+            first = pending.pop(0)
+            assert first["attrs"]["n"] == e["attrs"]["n"]
+            assert "joins" not in e["attrs"]
+            between = [d for d in decodes if first["start_ns"]
+                       < d["start_ns"] < e["start_ns"]]
+            assert len(between) == 1 and names[between[0]["id"]].count(
+                "gen.decode.launch") == 1, "read after the next launch"
+        else:
+            assert mine == whole, mine
+    assert not pending
+    assert any(names[e["id"]] == whole[2:] for e in prefills)
     for e in gen:
-        if e["name"] == "gen.decode":
-            assert sorted(kids[e["id"]]) == [
-                "gen.decode.account", "gen.decode.launch",
-                "gen.decode.plan", "gen.decode.readback"]
         if e["name"] == "gen.submit":
-            assert sorted(kids[e["id"]]) == [
+            assert sorted(names[e["id"]]) == [
                 "gen.submit.enqueue", "gen.submit.key",
                 "gen.submit.validate"]
 
@@ -471,12 +757,15 @@ def test_engine_spans_carry_the_counts(engine_spans):
     prefill = [e for e in evs if e["name"] == "gen.prefill"]
     assert sum(e["attrs"]["emitted"] for e in decode) == (
         stats["tokens_total"] - stats["joined_total"])
-    assert sum(e["attrs"]["joins"] for e in prefill) == (
+    assert sum(e["attrs"].get("joins", 0) for e in prefill) == (
         stats["joined_total"]) == len(handles)
     assert all(set(e["attrs"]) == {"n", "k", "kv_bucket", "rows", "emitted"}
                and e["attrs"]["k"] == K
                and 1 <= e["attrs"]["rows"] <= MAX_BATCH for e in decode)
     assert all(e["attrs"]["kind"] == "cold" for e in prefill)
+    assert len(decode) == stats["windows_total"]
+    plans = [e for e in evs if e["name"] == "gen.decode.plan"]
+    assert sum(e["attrs"]["rows"] > 0 for e in plans) == len(decode)
     for name in ("gen.admit", "gen.prefill.account", "gen.decode.plan",
                  "gen.decode.account", "gen.submit.enqueue"):
         waits = [e["attrs"]["lock_wait_us"] for e in evs
@@ -558,6 +847,11 @@ def test_stats_shape():
     assert st["prefill_seconds"] > 0 and st["decode_seconds"] > 0
     assert st["buckets"]["kv"] == [16, 32]
     assert "misses" in st["aot_cache"]
+    # 3 tokens at K = 2: the prefill and one window, which ends the row by
+    # length, so none is launched ahead of it into an empty batch
+    assert (st["windows_total"], st["windows_ahead_total"],
+            st["joins_ahead_total"], st["windows_empty_total"]) == (
+                1, 0, 0, 0)
 
 
 def test_donation_audit_covers_decode_kinds():

@@ -295,14 +295,17 @@ def served():
 REQUESTS = [(50, 30), (12, 9), (40, 25), (33, 40), (20, 5), (60, 20)]
 
 
+def _two_row_engine(dec):
+    return GenerationEngine(dec, GenerationConfig(
+        max_batch=2, fused_steps=4, kv_bucket_min=128, prompt_bucket_min=16))
+
+
 def test_engine_rows_give_what_they_give_alone(served):
     """Six requests over two rows: they join and leave at different
     times, every row is reused, and each answer is token for token what
     ``generate`` gives the request alone."""
     _, _, _, dec = served
-    with GenerationEngine(dec, GenerationConfig(
-            max_batch=2, fused_steps=4, kv_bucket_min=128,
-            prompt_bucket_min=16)) as eng:
+    with _two_row_engine(dec) as eng:
         handles = [eng.submit(_tokens(n, 10 + i).tolist(), max_new_tokens=m)
                    for i, (n, m) in enumerate(REQUESTS)]
         got = [eng.result(h) for h in handles]
@@ -311,6 +314,74 @@ def test_engine_rows_give_what_they_give_alone(served):
     for i, (n, m) in enumerate(REQUESTS):
         alone = dec.generate(_tokens(n, 10 + i).tolist(), m, fused_steps=4)
         assert got[i] == alone, i
+
+
+def test_engine_gives_rows_away_ahead_with_three_kinds_of_state(served):
+    """The loop runs a window ahead: with the queue full from the start
+    (all six enqueued before the loop admits) every request that ends by
+    length hands its row to the next one BEHIND its last window, before
+    that window is read. The recurrent state, the compressed keys and the
+    KV pages of the row are the new tenant's from the next window on, and
+    every answer is still what ``generate`` gives the request alone."""
+    _, _, _, dec = served
+    with _two_row_engine(dec) as eng:
+        with eng._cond:
+            handles = [eng.submit(_tokens(n, 10 + i).tolist(),
+                                  max_new_tokens=m)
+                       for i, (n, m) in enumerate(REQUESTS)]
+        got = [eng.result(h) for h in handles]
+        stats = eng.stats()
+    for i, (n, m) in enumerate(REQUESTS):
+        alone = dec.generate(_tokens(n, 10 + i).tolist(), m, fused_steps=4)
+        assert got[i] == alone, i
+    # four of the six joined a row whose tenant had not been read to its
+    # end; no stop token, so no window ran empty
+    assert stats["joins_ahead_total"] == len(REQUESTS) - 2
+    assert stats["windows_empty_total"] == 0
+    assert stats["windows_ahead_total"] >= stats["windows_total"] - 2
+
+
+def test_engine_stop_token_with_a_window_ahead(served):
+    """A stop token ends a row one window before the host sees it: the
+    answer is the lone one's up to the stop token, with the other row
+    decoding on beside it."""
+    _, _, _, dec = served
+    prompt, other = _tokens(33, 3).tolist(), _tokens(20, 4).tolist()
+    alone = dec.generate(prompt, 30, fused_steps=4)
+    eos = alone[9]
+    want = alone[:alone.index(eos) + 1]
+    with _two_row_engine(dec) as eng:
+        with eng._cond:
+            h = eng.submit(prompt, max_new_tokens=30, eos_id=eos)
+            h2 = eng.submit(other, max_new_tokens=24)
+        assert eng.result(h) == want
+        assert eng.result(h2) == dec.generate(other, 24, fused_steps=4)
+        assert h.out == want
+
+
+def test_engine_deadline_with_a_window_in_flight_frees_all_three_states(
+        served):
+    """A deadline passes while a window with the row is in flight: the
+    release lands behind it, its tokens for the row are dropped, and the
+    next tenant of the row decodes as alone (the recurrent state was
+    zeroed behind the window that still updated it)."""
+    from deeplearning4j_tpu.parallel.batcher import DeadlineExpiredError
+    from deeplearning4j_tpu.resilience.faults import FaultPlan
+
+    _, _, _, dec = served
+    prompt = _tokens(40, 6).tolist()
+    alone = dec.generate(prompt, 12, fused_steps=4)
+    plan = FaultPlan(seed=1).inject("decode.launch", probability=1.0,
+                                    action="delay", delay_s=0.05)
+    with _two_row_engine(dec) as eng:
+        with plan.armed():
+            dead = eng.submit(_tokens(50, 5).tolist(), max_new_tokens=60,
+                              timeout_ms=250)
+            with pytest.raises(DeadlineExpiredError):
+                eng.result(dead)
+        held = list(dead.out)
+        assert eng.generate(prompt, max_new_tokens=12) == alone
+        assert dead.out == held and eng.stats()["rows_in_use"] == 0
 
 
 @pytest.mark.parametrize("kind", ["recurrent", "kv"])
@@ -367,9 +438,7 @@ def test_engine_publishes_state_gauges_and_counters(served):
     from deeplearning4j_tpu import telemetry
 
     _, _, _, dec = served
-    with GenerationEngine(dec, GenerationConfig(
-            max_batch=2, fused_steps=4, kv_bucket_min=128,
-            prompt_bucket_min=16)) as eng:
+    with _two_row_engine(dec) as eng:
         eng.generate(_tokens(45, 8).tolist(), max_new_tokens=10)
         text = telemetry.REGISTRY.render_prometheus()
     for kind in ("kv", "compressed_keys", "recurrent"):

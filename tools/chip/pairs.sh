@@ -10,12 +10,10 @@ CELL=${CELL:-gpt2-large-serve-backlog}
 one() {  # label dir seed trace
   local label=$1 dir=$2 seed=$3 trace=$4
   ( cd $dir
-    if [ "$trace" = 1 ]; then
-      python $OLDPWD/tools/chip/dump_run.py pr/$label --workload $CELL --seed $seed --seconds 30 --trace 1
-    else
-      python benchmarks/run.py --workload $CELL --seed $seed --seconds 30 --trace 0
-    fi ) > $OUT/$label.out 2> $OUT/$label.err
-  echo "== $label rc=$? $(tail -n 1 $OUT/$label.out | cut -c1-1800)"
+    python $OLDPWD/tools/chip/dump_run.py pr/$label --workload $CELL --seed $seed --seconds 30 --trace $trace
+  ) > $OUT/$label.out 2> $OUT/$label.err
+  echo "== $label rc=$? $(tail -n 1 $OUT/$label.out | cut -c1-${CUT:-1800})"
+  grep "^# decode counters" $OUT/$label.err
   # a traced run in the parent's directory writes its ops under the parent
   [ -d $dir/chiprun_out/pr ] && [ "$dir" != "." ] && cp $dir/chiprun_out/pr/* $OUT/ 2>/dev/null
   return 0
