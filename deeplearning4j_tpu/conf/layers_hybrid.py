@@ -30,8 +30,15 @@ The per-layer cache interface (``nn.decoding`` walks it; ``docs/serving.md``):
   sums over the active rows.
 - ``cache_grow(cache, length)``, ``cache_release(cache, keep)``.
 - ``cache_kinds``: cache leaf -> the kind of state it is (``kv``,
-  ``compressed_keys``, ``recurrent``); ``cache_counters``: the names of
-  the counts ``cache_step`` returns.
+  ``kv_ring``, ``compressed_keys``, ``recurrent``); ``cache_counters``:
+  the names of the counts ``cache_step`` returns.
+
+A layer WITHOUT per-row state that still couples the rows of a batch (the
+routed experts of ``conf/layers_moe.py`` group tokens by expert) has
+``forward_live(params, x, live) -> (y, counts)`` and ``live_counters``
+instead: ``nn.decoding`` tells it which tokens are live (the active rows
+of a decode step, the prompt's real positions) and sums its ``counts``,
+one scalar a call, into the decode window's counters.
 """
 
 from __future__ import annotations
@@ -52,6 +59,13 @@ from deeplearning4j_tpu.conf.layers import (
     _as_ff_size,
 )
 from deeplearning4j_tpu.ops import block_sparse, cache_update
+from deeplearning4j_tpu.ops.attention import (
+    bounded_decode_attention,
+    grouped_causal_attention,
+    window_ring_attention,
+    window_ring_block,
+    window_ring_update,
+)
 from deeplearning4j_tpu.ops.block_sparse import SparseSpec
 from deeplearning4j_tpu.ops.linear_attention import (
     decay_slopes,
@@ -95,6 +109,9 @@ def _join_rows(cache, block, rows, length=None):
         block = jnp.pad(block, ((0, 0), (0, length - block.shape[1]))
                         + ((0, 0),) * (block.ndim - 2))
     return cache.at[rows].set(block.astype(cache.dtype), mode="drop")
+
+
+GATED_TOKEN_SPAN = 2048     # positions GatedAttentionLayer projects at a time
 
 
 def _token_spans(t: int, span: int):
@@ -572,6 +589,156 @@ class BlockSparseAttentionLayer(_GatedMixer):
 
         return {"k": pad(cache["k"], length), "v": pad(cache["v"], length),
                 "ck": pad(cache["ck"], -(-length // self.kernel_stride))}
+
+    def cache_release(self, cache, keep):
+        return cache
+
+
+@serde.register
+@dataclasses.dataclass
+class GatedAttentionLayer(_GatedMixer):
+    """Causal softmax attention with grouped KV heads, q/k RMS norm and a
+    sigmoid output gate, with two switches a model sets per layer:
+    ``rope_theta`` (0: no rotation; order from causality alone) and
+    ``window`` (0: every earlier position; ``w``: query ``t`` sees keys
+    ``t - w < j <= t``). A full layer's cache is keys and values
+    ``[rows, bucket, kv_heads * d]``; a window layer's is a RING of
+    ``window`` slots a row whatever the bucket (``ops/attention.py``):
+    ``cache_join`` writes the last ``window`` positions of a prompt, each
+    in its slot, ``cache_grow`` has nothing to grow, and ``state_bytes``
+    reports it under the kind ``kv_ring``."""
+
+    n_out: int = 0
+    n_heads: int = 1
+    n_kv_heads: int = 1
+    head_size: int = 0
+    window: int = 0
+    rope_theta: float = 0.0
+    eps: float = 1e-6
+    out_scale: float = 1.0
+    weight_dtype: str = ""
+    cache_dtype: str = ""
+
+    uses_mask = True
+    cache_counters = ("decode_kv_read_positions",
+                      "decode_kv_bucket_positions")
+
+    @property
+    def cache_kinds(self):
+        kind = "kv_ring" if self.window else "kv"
+        return {"k": kind, "v": kind}
+
+    def _kv_width(self):
+        return self.n_kv_heads * self.head_size
+
+    def init(self, key, input_type, dtype=jnp.float32):
+        return self._init_matrices(key, _as_ff_size(input_type),
+                                   self._kv_width(), dtype)
+
+    def param_order(self):
+        return ["Wq", "Wk", "Wv", "Wg", "Wo", "q_norm", "k_norm"]
+
+    def _q(self, params, u, positions):
+        q = self._heads(params, u, "q", self.n_heads)
+        return rotate(q, positions, self.rope_theta) if self.rope_theta else q
+
+    def _kv(self, params, u, positions, dtype):
+        """Keys (normed, rotated at their own position) and values in
+        cache layout ``[..., kv_heads * d]`` and the cache's type."""
+        k = self._heads(params, u, "k", self.n_kv_heads)
+        if self.rope_theta:
+            k = rotate(k, positions, self.rope_theta)
+        v = self._heads(params, u, "v", self.n_kv_heads)
+        flat = u.shape[:-1] + (self._kv_width(),)
+        return k.reshape(flat).astype(dtype), v.reshape(flat).astype(dtype)
+
+    def _sequence(self, params, x, dtype):
+        """Keys and values of the whole sequence first, then the queries a
+        span of ``GATED_TOKEN_SPAN`` positions at a time."""
+        t = x.shape[1]
+        k, v = self._kv(params, x, jnp.arange(t), dtype)
+        n, span = _token_spans(t, GATED_TOKEN_SPAN)
+
+        def body(xs):
+            xc, start = xs
+            with jax.named_scope("attn.window" if self.window
+                                 else "attn.full"):
+                o = grouped_causal_attention(
+                    self._q(params, xc, start + jnp.arange(span)), k, v,
+                    self.n_kv_heads, self.window, offset=start)
+            return self._finish(params, xc, o)
+
+        y = _merge_spans(jax.lax.map(body, (_split_spans(x, n, span),
+                                            jnp.arange(n) * span)))
+        return y, k, v
+
+    def forward(self, params, state, x, train=False, rng=None, mask=None):
+        x = self._dropout_input(x, train, rng)
+        y, _, _ = self._sequence(params, x,
+                                 _wdtype(self.cache_dtype, jnp.float32))
+        if mask is not None:
+            y = y * jnp.asarray(mask, y.dtype)[:, :, None]
+        return y, state
+
+    # --- the cache interface ------------------------------------------------
+    def cache_init(self, batch, length, n_in, dtype=jnp.float32):
+        shape = (batch, self.window or length, self._kv_width())
+        dt = _wdtype(self.cache_dtype, dtype)
+        return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+
+    def cache_prefill(self, params, x, key_mask=None, dtype=jnp.float32,
+                      use_kernels=False):
+        y, k, v = self._sequence(params, x, _wdtype(self.cache_dtype, dtype))
+        lengths = jnp.full((x.shape[0],), x.shape[1], jnp.int32)
+        if key_mask is not None:
+            y = y * jnp.asarray(key_mask, y.dtype)[:, :, None]
+            lengths = jnp.sum(key_mask > 0, axis=1).astype(jnp.int32)
+        if self.window:
+            k = window_ring_block(k, lengths, self.window)
+            v = window_ring_block(v, lengths, self.window)
+        return y, {"k": k, "v": v}
+
+    def cache_join(self, cache, block, rows, length):
+        length = None if self.window else length
+        return {n: _join_rows(cache[n], block[n], rows, length)
+                for n in ("k", "v")}
+
+    def cache_step(self, params, x, cache, positions, active=None):
+        """A full layer's read is bounded per row by ``positions``
+        (:func:`bounded_decode_attention`: the paged kernel where the
+        program is lowered for a TPU, the masked read of the bucket
+        elsewhere); a window layer's ring is read whole and masked.
+        ``counts``: per row, the cached positions the step streamed and,
+        for a full layer, the positions the bucket holds, for a window
+        layer the positions the row's context holds (that ratio is under
+        1 only where the window bounds the read)."""
+        g = self.n_kv_heads
+        q = self._q(params, x, positions)
+        k, v = self._kv(params, x, positions, cache["k"].dtype)
+        slots = jnp.full_like(positions, cache["k"].shape[1])
+        if self.window:
+            k_cache = window_ring_update(cache["k"], k[:, None], positions)
+            v_cache = window_ring_update(cache["v"], v[:, None], positions)
+            with jax.named_scope("attn.window"):
+                o = window_ring_attention(q, k_cache, v_cache, positions, g)
+            read, held = slots, positions + 1
+        else:
+            k_cache = cache_update(cache["k"], k[:, None], positions)
+            v_cache = cache_update(cache["v"], v[:, None], positions)
+            with jax.named_scope("attn.full"):
+                o, read = bounded_decode_attention(q, k_cache, v_cache,
+                                                   positions, groups=g)
+            held = slots
+        counts = {"decode_kv_read_positions": read,
+                  "decode_kv_bucket_positions": held}
+        return (self._finish(params, x, o), {"k": k_cache, "v": v_cache},
+                counts)
+
+    def cache_grow(self, cache, length):
+        if self.window:
+            return cache                    # a ring has nothing to grow
+        pad = ((0, 0), (0, length - cache["k"].shape[1]), (0, 0))
+        return {n: jnp.pad(cache[n], pad) for n in ("k", "v")}
 
     def cache_release(self, cache, keep):
         return cache

@@ -17,6 +17,11 @@ through the reserved state key :data:`AUX_LOSS_KEY`: the layer writes
 its (already ``aux_weight``-scaled) aux into the state it returns, and
 both network ``_loss`` implementations add every such entry to the
 score. In eval/``output()`` the state entry is ignored.
+
+:class:`RoutedExpertsLayer` is the DROPLESS layer serving runs
+(``nn.decoding`` refuses :class:`MoELayer`, whose capacity couples the
+rows of a batch): sigmoid scores, a top-k choice, no capacity, a grouped
+matrix product over the token slots sorted by expert.
 """
 
 from __future__ import annotations
@@ -24,15 +29,27 @@ from __future__ import annotations
 import dataclasses
 import math
 
+import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu import serde
 from deeplearning4j_tpu.conf import inputs as it
 from deeplearning4j_tpu.conf.layers import BaseLayer
+from deeplearning4j_tpu.conf.layers_hybrid import _dot, _matrix, _wdtype
 
 #: Reserved state key: layers put auxiliary (train-time) loss terms here;
 #: MultiLayerNetwork/ComputationGraph ``_loss`` sums them into the score.
 AUX_LOSS_KEY = "__aux_loss__"
+ROUTED_ROWS_MAX = 4096      # tokens a RoutedExpertsLayer routes at a time
+# Where the slots (token x chosen expert) an expert held gets lie in this
+# range, a RoutedExpertsLayer puts every token through every expert held;
+# elsewhere the slots go grouped by expert. Fewer, and most experts are
+# chosen by nobody: the grouped product never reads them. More, and every
+# expert is ``held / top_k`` times the matrix unit's work. Between, the
+# plain batched product streams the matrices at the HBM's rate and the
+# grouped one at half of it. Read on the v5e at hidden 2048, 128 experts
+# of 1024, top 8 (tools/chip/moe_crossover.py; PERF.md section 6, PR 33).
+EVERY_EXPERT_SLOTS = (2, 24)
 
 
 def sum_aux_losses(new_state, dtype):
@@ -146,3 +163,194 @@ class MoELayer(BaseLayer):
             state[AUX_LOSS_KEY].dtype)} if train else state
         y = self.activation.apply(y2).reshape(shape)
         return y, new_state
+
+
+@serde.register
+@dataclasses.dataclass
+class RoutedExpertsLayer(BaseLayer):
+    """A dropless routed feed-forward beside a shared expert:
+
+    ``s = sigmoid(Wr u)`` over ``n_experts`` in float32; the ``top_k``
+    largest of ``s + b`` are chosen (the bias ``b`` enters the choice
+    only); ``w_e = route_scale * s_e / (sum of the chosen s + 1e-20)``
+    (``route_norm``; else ``route_scale * s_e``); the output is
+    ``Shared(u) + sum_e w_e Expert_e(u)``, every expert ``Wd (silu(Wg u)
+    * Wu u)``. No capacity: every chosen (token, expert) slot is
+    computed, so a token's result does not depend on the rest of the
+    batch beyond rounding.
+
+    ``experts_held = (first, count)`` names the experts whose matrices
+    this layer holds (``count`` 0: all). It routes over all ``n_experts``
+    and computes its own experts' part of the sum; what the others would
+    add is another holder's. The shared expert is computed by every
+    holder alike.
+
+    One sum, two products by the number of tokens: the live ``tokens *
+    top_k`` slots are sorted by expert and go through
+    ``jax.lax.ragged_dot`` (a grouped matrix product on the TPU: an
+    expert nobody chose is never read), a prompt's thousands of tokens;
+    a decode step's rows (``EVERY_EXPERT_SLOTS``) go through every expert
+    held with the weight zero where it was not chosen. Tokens that are
+    not live (idle rows, prompt padding) are routed nowhere and weigh
+    nothing. More than ``ROUTED_ROWS_MAX`` tokens go through in slices.
+
+    ``forward_live`` is what ``nn.decoding`` calls (the interface of a
+    layer without state, ``conf/layers_hybrid.py``); its counts, one
+    scalar a call: ``moe_routed_slots`` (live token x chosen expert held
+    here), ``moe_experts_touched`` (experts held with at least one slot),
+    ``moe_expert_layer_steps`` (1 where any token was live),
+    ``moe_max_load`` (the fullest expert's slots)."""
+
+    n_out: int = 0
+    n_experts: int = 8
+    n_hidden: int = 0
+    top_k: int = 2
+    n_shared_hidden: int = 0        # 0: no shared expert
+    route_norm: bool = True
+    route_scale: float = 1.0
+    experts_held: tuple = (0, 0)    # (first, count); count 0: all
+    out_scale: float = 1.0
+    weight_dtype: str = ""
+
+    uses_mask = True
+    live_counters = ("moe_routed_slots", "moe_experts_touched",
+                     "moe_expert_layer_steps", "moe_max_load")
+
+    def _held(self):
+        first, count = self.experts_held
+        return int(first), int(count) or self.n_experts - int(first)
+
+    def output_type(self, input_type):
+        if isinstance(input_type, it.Recurrent):
+            return it.Recurrent(size=self.n_out,
+                                timesteps=input_type.timesteps)
+        return it.FeedForward(size=self.n_out)
+
+    def init(self, key, input_type, dtype=jnp.float32):
+        d = input_type.size
+        wd = _wdtype(self.weight_dtype, dtype)
+        h, held = self.n_hidden, self._held()[1]
+        ks = jax.random.split(key, 7)
+        p = {"Wr": _matrix(self, ks[0], (d, self.n_experts), jnp.float32),
+             "b": jnp.zeros((self.n_experts,), jnp.float32),
+             "Wg": self.weight_init.init(ks[1], (held, d, h), d, h, wd,
+                                         self.distribution),
+             "Wu": self.weight_init.init(ks[2], (held, d, h), d, h, wd,
+                                         self.distribution),
+             "Wd": self.weight_init.init(ks[3], (held, h, self.n_out), h,
+                                         self.n_out, wd, self.distribution)}
+        if self.n_shared_hidden:
+            s = self.n_shared_hidden
+            p.update(Sg=_matrix(self, ks[4], (d, s), wd),
+                     Su=_matrix(self, ks[5], (d, s), wd),
+                     Sd=_matrix(self, ks[6], (s, self.n_out), wd))
+        return p
+
+    def param_order(self):
+        shared = ["Sg", "Su", "Sd"] if self.n_shared_hidden else []
+        return ["Wr", "b", "Wg", "Wu", "Wd"] + shared
+
+    def regularized_param_keys(self):
+        return [k for k in self.param_order() if k not in ("Wr", "b")]
+
+    def route(self, params, u):
+        """``(experts [n, top_k] int32, weights [n, top_k] float32)`` of
+        tokens ``u: [n, d]``: float32 at the highest precision, because
+        the choice is discrete and a choice made from rounded scores
+        differs from the exact one at every near-tie."""
+        s = jax.nn.sigmoid(jnp.dot(u.astype(jnp.float32), params["Wr"],
+                                   precision=jax.lax.Precision.HIGHEST))
+        _, experts = jax.lax.top_k(s + params["b"], self.top_k)
+        w = jnp.take_along_axis(s, experts, axis=1)
+        if self.route_norm:
+            w = w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20)
+        return experts.astype(jnp.int32), w * self.route_scale
+
+    def _grouped(self, params, x, group, w, sizes):
+        """The grouped product: ``x: [n, d]``, each of its ``n * top_k``
+        slots with its expert ``group`` (``held``: not this holder's; it
+        sorts behind every group) and weight ``w``; ``sizes`` the slots an
+        expert got. An expert nobody chose is never read."""
+        order = jnp.argsort(group)
+        xs = x[order // self.top_k]
+        hidden = (jax.nn.silu(jax.lax.ragged_dot(
+            xs, params["Wg"], sizes, preferred_element_type=jnp.float32))
+            * jax.lax.ragged_dot(xs, params["Wu"], sizes,
+                                 preferred_element_type=jnp.float32))
+        ys = jax.lax.ragged_dot(hidden.astype(x.dtype), params["Wd"], sizes,
+                                preferred_element_type=jnp.float32)
+        # rows behind the last group are whatever the product left
+        ys = jnp.where((jnp.arange(order.size) < jnp.sum(sizes))[:, None],
+                       ys * w[order][:, None], 0.0)
+        back = jnp.zeros_like(order).at[order].set(jnp.arange(order.size))
+        return ys[back].reshape(x.shape[0], self.top_k, -1).sum(axis=1)
+
+    def _every_expert(self, params, x, w):
+        """The same sum for a decode step's rows (``EVERY_EXPERT_SLOTS``:
+        most experts are chosen by somebody anyway): every token through
+        every expert held, ``w: [n, held]`` zero where the expert was not
+        chosen."""
+        f32 = jnp.float32
+        hidden = (jax.nn.silu(jnp.einsum("nd,edh->enh", x, params["Wg"],
+                                         preferred_element_type=f32))
+                  * jnp.einsum("nd,edh->enh", x, params["Wu"],
+                               preferred_element_type=f32))
+        ys = jnp.einsum("enh,ehd->end", hidden.astype(x.dtype), params["Wd"],
+                        preferred_element_type=f32)
+        return jnp.einsum("end,ne->nd", ys, w)
+
+    def _slice(self, params, u, live):
+        """``u: [n, d]`` float32, ``live: [n]`` bool -> ``(y [n, n_out],
+        sizes [held])``, ``sizes`` the slots each expert held here got."""
+        n, k = u.shape[0], self.top_k
+        first, held = self._held()
+        wd = params["Wg"].dtype
+        with jax.named_scope("moe.route"):
+            experts, w = self.route(params, u)
+            mine = (live[:, None] & (experts >= first)
+                    & (experts < first + held))
+            # [n, k, held]: the slot's expert, among those held here
+            chosen = mine[:, :, None] & (
+                (experts - first)[:, :, None] == jnp.arange(held))
+            sizes = jnp.sum(chosen, axis=(0, 1), dtype=jnp.int32)
+        with jax.named_scope("moe.experts"):
+            fewest, most = EVERY_EXPERT_SLOTS
+            if fewest * held <= n * k <= most * held:
+                y = self._every_expert(params, u.astype(wd), jnp.sum(
+                    jnp.where(chosen, w[:, :, None], 0.0), axis=1))
+            else:
+                y = self._grouped(params, u.astype(wd), jnp.where(
+                    mine, experts - first, held).reshape(-1), w.reshape(-1),
+                    sizes)
+        if self.n_shared_hidden:
+            with jax.named_scope("moe.shared"):
+                y = y + _dot(jax.nn.silu(_dot(u, params["Sg"]))
+                             * _dot(u, params["Su"]), params["Sd"])
+        return y * self.out_scale, sizes
+
+    def forward_live(self, params, x, live):
+        """``x: [..., d]``, ``live: x.shape[:-1]`` (true where the token
+        is a real one) -> ``(y [..., n_out] float32, counts)``."""
+        flat = x.reshape(-1, x.shape[-1]).astype(jnp.float32)
+        live = jnp.asarray(live).reshape(-1) > 0
+        n = flat.shape[0]
+        if n > ROUTED_ROWS_MAX and n % ROUTED_ROWS_MAX == 0:
+            m = n // ROUTED_ROWS_MAX
+            y, sizes = jax.lax.map(
+                lambda a: self._slice(params, *a),
+                (flat.reshape(m, ROUTED_ROWS_MAX, -1),
+                 live.reshape(m, ROUTED_ROWS_MAX)))
+            sizes = jnp.sum(sizes, axis=0)
+        else:
+            y, sizes = self._slice(params, flat, live)
+        counts = {"moe_routed_slots": jnp.sum(sizes),
+                  "moe_experts_touched": jnp.sum(sizes > 0, dtype=jnp.int32),
+                  "moe_expert_layer_steps": jnp.any(live).astype(jnp.int32),
+                  "moe_max_load": jnp.max(sizes)}
+        return y.reshape(x.shape[:-1] + (-1,)), counts
+
+    def forward(self, params, state, x, train=False, rng=None, mask=None):
+        x = self._dropout_input(x, train, rng)
+        live = (jnp.ones(x.shape[:-1], bool) if mask is None
+                else jnp.asarray(mask) > 0)
+        return self.forward_live(params, x, live)[0], state

@@ -143,10 +143,12 @@ def _seed_rows(state, caches, rows, tok, lengths, max_new, eos, temps, rng,
 
 
 def _reject_types():
-    # MoE routing is cross-row (capacity is shared over the whole
+    # MoELayer's routing is cross-row (capacity is shared over the whole
     # batch), which breaks both decode-shape assumptions and the
     # row-independence the continuous-vs-sequential bit-identity pin
-    # rests on — refuse rather than silently mis-route
+    # rests on — refuse rather than silently mis-route. The dropless
+    # RoutedExpertsLayer has no capacity and is served (it is told the
+    # live tokens: ``forward_live``)
     from deeplearning4j_tpu.conf.layers_moe import MoELayer
 
     return (GlobalPoolingLayer, LearnedSelfAttentionLayer,
@@ -254,9 +256,15 @@ class TransformerDecoder:
             1, min(int(join_bucket_max or self.max_batch), self.max_batch))
         # per-row counts the cached layers report at a step (summed over
         # the active rows of a decode window, returned beside its tokens)
-        self.counter_names = sorted({
+        self._row_counters = sorted({
             k for name in self._cached
             for k in getattr(self._layer(name), "cache_counters", ())})
+        # and the counts, one scalar a layer-step, of the layers without
+        # state that are told the live tokens (``forward_live``)
+        self._step_counters = sorted({
+            k for _, name, _ in self._plan
+            for k in getattr(self._layer(name), "live_counters", ())})
+        self.counter_names = sorted(self._row_counters + self._step_counters)
         # any decode-state entry for a planned vertex would be silently
         # frozen at its init value — refuse rather than mis-serve
         stateful = [n for _, n, _ in self._plan if net.state.get(n)]
@@ -303,7 +311,7 @@ class TransformerDecoder:
         }
 
     def _layer(self, name):
-        return self._net._vmap[name].vertex.layer
+        return getattr(self._net._vmap[name].vertex, "layer", None)
 
     def state_bytes(self, s: int) -> Dict[str, int]:
         """Bytes the caches hold at KV bucket ``s``, by kind of state
@@ -358,7 +366,8 @@ class TransformerDecoder:
         return self._net.params
 
     # --- the model walk ------------------------------------------------------
-    def _walk(self, params, tokens, cached, positions=None, lengths=None):
+    def _walk(self, params, tokens, cached, positions=None, lengths=None,
+              live=None, tally=None):
         """The one walk over the plan: ``tokens`` (``[B]`` for a decode
         step, ``[B, T]`` for a prompt or a suffix) → vocab logits. What
         differs between the walks is the caller's: ``cached(name, params,
@@ -369,7 +378,10 @@ class TransformerDecoder:
         ``lengths`` puts the head on each row's last valid position
         alone (None: on all of ``x``) — a whole ``[T, vocab]`` of logits
         is never needed, and at a 32k bucket of a 73k vocabulary would
-        not fit."""
+        not fit. ``live`` (shaped like ``tokens``, true or above 0 where a
+        token is real; None: all) is for a layer without state that groups the batch
+        (``forward_live``: the routed experts); ``tally(counts)`` gets
+        what such a layer counted."""
         acts = {self._input: tokens}
         logits = None
         for kind, name, spec in self._plan:
@@ -385,6 +397,12 @@ class TransformerDecoder:
                     x = jnp.take_along_axis(x, idx, axis=1)[:, 0]
                 logits = self._layer(name).pre_output(params[name], x)
                 continue
+            elif hasattr(self._layer(name), "forward_live"):
+                y, own = self._layer(name).forward_live(
+                    params[name], xs[0],
+                    jnp.ones(tokens.shape, bool) if live is None else live)
+                if tally is not None:
+                    tally(own)
             else:
                 y, _ = spec.vertex.forward(params.get(name, {}), {}, xs,
                                            train=False, rng=None)
@@ -394,19 +412,23 @@ class TransformerDecoder:
     def _run_token(self, params, tokens, positions, caches, active=None):
         """One token through the graph against the caches:
         ``tokens [B] int32`` → (vocab logits ``[B, V]``, new caches,
-        the layers' counts ``{name: [B] int32}`` summed over the
-        layers)."""
+        the layers' counts summed over the layers: ``[B] int32`` each
+        for a cached layer's, a scalar for a ``forward_live`` layer's)."""
         caches = dict(caches)
         counts: Dict[str, object] = {}
+
+        def tally(own):
+            for k, v in own.items():
+                counts[k] = counts[k] + v if k in counts else v
 
         def step(name, p, x):
             y, caches[name], own = self._layer(name).cache_step(
                 p, x, caches[name], positions, active=active)
-            for k, v in own.items():
-                counts[k] = counts[k] + v if k in counts else v
+            tally(own)
             return y
 
-        logits = self._walk(params, tokens, step, positions=positions)
+        logits = self._walk(params, tokens, step, positions=positions,
+                            live=active, tally=tally)
         return logits, caches, counts
 
     def _run_prompt(self, params, prompts, lengths):
@@ -423,7 +445,8 @@ class TransformerDecoder:
                 use_kernels=self.use_kernels)
             return y
 
-        return self._walk(params, prompts, prefill, lengths=lengths), kv
+        return self._walk(params, prompts, prefill, lengths=lengths,
+                          live=key_mask), kv
 
     def _run_suffix(self, params, suffix, suf_lens, prefix_kv, prefix_lens):
         """Prompt-SUFFIX prefill walk against already-projected prefix
@@ -454,7 +477,7 @@ class TransformerDecoder:
         at = jnp.clip(prefix_lens[:, None] + jnp.arange(ts),
                       0, self.max_len - 1)
         return self._walk(params, suffix, prefill, positions=at,
-                          lengths=suf_lens), kv
+                          lengths=suf_lens, live=key_mask), kv
 
     # --- compiled executables (all through optimize/aot_cache) -------------
     def _exe(self, kind: str, fn, donate=()):
@@ -495,8 +518,9 @@ class TransformerDecoder:
             logits, caches, counts = self._run_token(
                 params, st["tokens"], st["positions"], st["caches"],
                 active=active)
-            counts = {n: jnp.sum(jnp.where(active, counts[n], 0))
-                      for n in self.counter_names}
+            counts = {**{n: jnp.sum(jnp.where(active, counts[n], 0))
+                         for n in self._row_counters},
+                      **{n: counts[n] for n in self._step_counters}}
             step_keys, rng_next = _advance_rng(st["rng"])
             tok = _sample_tokens(logits, step_keys, st["temps"])
             tok = jnp.where(active, tok, st["tokens"])

@@ -147,7 +147,8 @@ def decode_attention(q, k_cache, v_cache, positions, scale=None):
 
 
 def _paged_decode_kernel(pos_ref, row_ref, page_ref, q_ref, own_ref, k_ref,
-                         v_ref, o_ref, m_sc, l_sc, acc_sc, *, sm, page):
+                         v_ref, o_ref, m_sc, l_sc, acc_sc, *, sm, page,
+                         grouped=False):
     """Online-softmax decode over the LIVE KV pages of every row. The grid
     is one flat list of (row, page) pairs, a row's pages in order and the
     rows one after another, as long as the rows' positions make it (its
@@ -163,7 +164,11 @@ def _paged_decode_kernel(pos_ref, row_ref, page_ref, q_ref, own_ref, k_ref,
     heads * head_dim]``), which makes them the two plain forms of the
     matrix unit, ``Q K^T`` and ``P V``, with no transpose of a page:
     ``q_ref[g]`` holds head ``g``'s query in its own ``head_dim`` columns
-    and zeros elsewhere, ``own_ref[g, (g', d)]`` is 1 where ``g == g'``."""
+    and zeros elsewhere, ``own_ref[g, (g', d)]`` is 1 where ``g == g'``.
+    ``grouped`` (several query heads a KV head): a head's query sits in
+    its KV GROUP's columns, the heads of a group share them, and the
+    store keeps every head's row apart (``[heads, e]``, zeros outside the
+    head's own group)."""
     w = pl.program_id(0)
     j = page_ref[w]
     pos = pos_ref[row_ref[w]]
@@ -197,17 +202,23 @@ def _paged_decode_kernel(pos_ref, row_ref, page_ref, q_ref, own_ref, k_ref,
     @pl.when(j == pos // page)
     def _store():
         l_inv = 1.0 / l_sc[...]     # slot 0 is live in every row: l > 0
-        o_ref[0] = jnp.sum(acc_sc[...] * l_inv * own_ref[...], axis=0,
-                           keepdims=True).astype(o_ref.dtype)   # [1, e]
+        if grouped:
+            o_ref[0] = (acc_sc[...] * l_inv * own_ref[...]).astype(
+                o_ref.dtype)                                    # [h, e]
+        else:
+            o_ref[0] = jnp.sum(acc_sc[...] * l_inv * own_ref[...], axis=0,
+                               keepdims=True).astype(o_ref.dtype)   # [1, e]
 
 
 # jitted so that a decoder's layers share ONE trace and one lowered body of
 # the kernel: 36 layers each tracing and lowering their own cost the decode
 # window 1.7 s more at every start, warm or cold, and a third more StableHLO
-@functools.partial(jax.jit, static_argnames=("scale", "page", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "page", "interpret",
+                                             "groups"))
 def paged_decode_attention(q, k_cache, v_cache, positions, scale=None,
                            page: int = 64,
-                           interpret: Optional[bool] = None):
+                           interpret: Optional[bool] = None,
+                           groups: int = 0):
     """:func:`decode_attention` as a Pallas kernel gathering KV **pages**
     in-kernel: ``page``-slot blocks of the cache stream HBM→VMEM one DMA
     per page, and only the pages that hold a position up to
@@ -222,7 +233,9 @@ def paged_decode_attention(q, k_cache, v_cache, positions, scale=None,
 
     ``page`` must divide ``max_len`` (the pow2 bucket ladder guarantees
     a divisor exists). ``interpret=None`` auto-enables the Pallas
-    interpreter off-TPU."""
+    interpreter off-TPU. ``groups`` (0: one query head a KV head): the
+    caches hold ``groups`` KV heads, ``heads / groups`` query heads
+    each."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, h, d = q.shape
@@ -230,9 +243,21 @@ def paged_decode_attention(q, k_cache, v_cache, positions, scale=None,
     page = min(int(page), s)
     if s % page:
         raise ValueError(f"page {page} must divide cache length {s}")
+    if groups and (h % groups or e != groups * d):
+        raise ValueError(f"{h} heads of {d} must share {groups} KV heads of "
+                         f"a {e}-wide cache")
     sm = _scale(q, scale)
     pos = jnp.clip(positions.astype(jnp.int32), 0, s - 1)
-    own = jnp.repeat(jnp.eye(h, dtype=jnp.float32), d, axis=1)   # [h, e]
+    if groups:      # a head's query and output sit in its GROUP's columns
+        from deeplearning4j_tpu.ops.block_sparse import (
+            _grouped_block_diagonal,
+        )
+        own = jnp.repeat((jnp.arange(h) // (h // groups))[:, None]
+                         == jnp.arange(groups), d, axis=1).astype(jnp.float32)
+        q_rows = jnp.swapaxes(_grouped_block_diagonal(
+            q[:, None].astype(jnp.float32), groups), 1, 2)
+    else:
+        own = jnp.repeat(jnp.eye(h, dtype=jnp.float32), d, axis=1)  # [h, e]
     # the flat list of live pages: step w reads page page_of[w] of row
     # row_of[w]; steps past the list's end (never run) name the last pair
     pages = pos // page + 1
@@ -256,7 +281,7 @@ def paged_decode_attention(q, k_cache, v_cache, positions, scale=None,
                   pl.BlockSpec((h, e), lambda w, p, r, j: (0, 0)),
                   pl.BlockSpec((1, page, e), kv_map),
                   pl.BlockSpec((1, page, e), kv_map)],
-        out_specs=pl.BlockSpec((1, 1, e), row_map),
+        out_specs=pl.BlockSpec((1, h if groups else 1, e), row_map),
         scratch_shapes=[pltpu.VMEM((h, 1), jnp.float32),
                         pltpu.VMEM((h, 1), jnp.float32),
                         pltpu.VMEM((h, e), jnp.float32)],
@@ -265,14 +290,17 @@ def paged_decode_attention(q, k_cache, v_cache, positions, scale=None,
     if not interpret:
         params = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
     out = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, sm=sm, page=page),
+        functools.partial(_paged_decode_kernel, sm=sm, page=page,
+                          grouped=bool(groups)),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, 1, e), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h if groups else 1, e), q.dtype),
         compiler_params=params,
         interpret=interpret,
     )(pos, row_of, page_of,
-      jnp.swapaxes(_block_diagonal(q[:, None]), 1, 2), own,
-      k_cache, v_cache)
+      q_rows if groups else jnp.swapaxes(_block_diagonal(q[:, None]), 1, 2),
+      own, k_cache, v_cache)
+    if groups:      # the other groups' columns of a head's row are zeros
+        return out.reshape(b, h, groups, d).sum(axis=2)
     return out.reshape(b, h, d)
 
 
@@ -295,7 +323,8 @@ def decode_page(max_len: int, width: int) -> Optional[int]:
     return DECODE_PAGE
 
 
-def bounded_decode_attention(q, k_cache, v_cache, positions, scale=None):
+def bounded_decode_attention(q, k_cache, v_cache, positions, scale=None,
+                             groups: int = 0):
     """:func:`decode_attention` bounded PER ROW by ``positions``, which
     is traced: a row that holds 150 positions costs 150 (rounded up to a
     page), a row that holds 1,000 costs 1,000, in one executable. On the
@@ -306,19 +335,34 @@ def bounded_decode_attention(q, k_cache, v_cache, positions, scale=None):
     backend: a program compiled for a TPU from a CPU host gets the
     kernel, and a CPU run never pays the Pallas interpreter.
 
+    ``groups`` (0: one query head a KV head): grouped KV heads, caches
+    ``[batch, max_len, groups * head_dim]``; float32 out, and the scale
+    is ``1 / sqrt(head_dim)`` (the masked grouped read knows no other).
+
     Returns ``(out [batch, heads, head_dim], read [batch] int32)``:
     ``read[b]`` is the number of cached positions the step streamed for
     row ``b`` (whole pages; the whole bucket where the bound is off)."""
+    if groups and scale is not None:
+        raise ValueError("bounded_decode_attention: grouped KV heads take "
+                         "the default scale")
     s, e = k_cache.shape[1:]
     page = decode_page(s, e)
 
     def masked(q, k_cache, v_cache, positions):
-        return (decode_attention(q, k_cache, v_cache, positions, scale),
-                jnp.full(positions.shape, s, jnp.int32))
+        if groups:
+            from deeplearning4j_tpu.ops.block_sparse import (
+                dense_decode_attention,
+            )
+            out = dense_decode_attention(q, k_cache, v_cache, positions,
+                                         groups)
+        else:
+            out = decode_attention(q, k_cache, v_cache, positions, scale)
+        return out, jnp.full(positions.shape, s, jnp.int32)
 
     def paged(q, k_cache, v_cache, positions):
         out = paged_decode_attention(q, k_cache, v_cache, positions, scale,
-                                     page=page, interpret=False)
+                                     page=page, interpret=False,
+                                     groups=groups)
         return out, (jnp.clip(positions, 0, s - 1) // page + 1) * page
 
     if page is None:
@@ -344,6 +388,95 @@ def cache_update(cache, new, positions):
         cache = jax.lax.dynamic_update_slice(cache, new[i:i + 1],
                                              (i, positions[i], 0))
     return cache
+
+
+# ---------------------------------------------------------------------------
+# Grouped KV heads, a sliding window, a ring cache (stock XLA)
+# ---------------------------------------------------------------------------
+#
+# A WINDOW layer's query at position ``t`` sees keys ``j`` with ``t -
+# window < j <= t``. Its cache is a RING of ``window`` slots a row,
+# whatever the bucket: position ``p`` lives in slot ``p % window``, so a
+# token overwrites the key that has just left its window, and the slots a
+# row has written are always the prefix ``0 .. min(t, window - 1)``. Keys
+# are cached as they are attended (normed, rotated at their own
+# position), so the order of the slots does not matter to the softmax.
+
+def grouped_causal_attention(q, k, v, groups: int, window: int = 0,
+                             q_chunk: int = 128, offset=0):
+    """Causal attention of a prompt's queries over its keys, grouped KV
+    heads: ``q: [batch, tq, heads, d]`` at positions ``offset + 0..tq-1``
+    (``offset`` may be traced), ``k, v: [batch, t, groups * d]`` in cache
+    layout. ``window > 0`` bounds what a query sees to its last
+    ``window`` positions, itself included. Queries go ``q_chunk`` at a
+    time; a chunk is multiplied with the keys it can see and no others:
+    with a window a slice of ``window + q_chunk`` keys, so a long prompt
+    costs ``O(t * window)``. Padding needs no key mask: it lies past
+    every real query, and causality hides it. Returns ``[batch, tq,
+    heads, d]`` float32."""
+    from deeplearning4j_tpu.ops.block_sparse import _grouped_attend
+
+    b, tq, h, d = q.shape
+    t = k.shape[1]
+    c = min(int(q_chunk), tq)
+    if tq % c:
+        raise ValueError(f"{tq} queries are no multiple of the query "
+                         f"chunk {c}")
+    span = min(t, window + c) if window else t
+
+    def body(_, xs):
+        qc, start = xs                                   # [b, c, h, d]
+        pos = offset + start + jnp.arange(c)
+        lo = jnp.clip(pos[-1] + 1 - span, 0, t - span)
+        kc = jax.lax.dynamic_slice_in_dim(k, lo, span, axis=1)
+        vc = jax.lax.dynamic_slice_in_dim(v, lo, span, axis=1)
+        kpos = lo + jnp.arange(span)
+        seen = kpos[None, :] <= pos[:, None]
+        if window:
+            seen &= kpos[None, :] > pos[:, None] - window
+        mask = jnp.broadcast_to(seen[None, :, None, :], (b, c, groups, span))
+        return None, _grouped_attend(qc, kc, vc, mask, groups)
+
+    n = tq // c
+    qs = jnp.swapaxes(q.reshape(b, n, c, h, d), 0, 1)
+    _, o = jax.lax.scan(body, None, (qs, jnp.arange(n) * c))
+    return jnp.swapaxes(o, 0, 1).reshape(b, tq, h, d)
+
+
+def window_ring_block(k, lengths, window: int):
+    """A prompt's keys (or values) ``k: [batch, t, e]`` as the ring a
+    window layer joins: ``[batch, window, e]``, slot ``j`` holding the
+    LAST position ``p < lengths[b]`` with ``p % window == j`` (the last
+    ``window`` positions of a prompt longer than the window, each in its
+    own slot) and zeros where the row has no such position yet."""
+    t = k.shape[1]
+    j = jnp.arange(window)[None, :]
+    last = lengths[:, None] - 1
+    p = j + window * ((last - j) // window)              # floor: < 0 if none
+    held = (j <= last)[:, :, None]
+    rows = jnp.take_along_axis(k, jnp.clip(p, 0, t - 1)[:, :, None], axis=1)
+    return jnp.where(held, rows, jnp.zeros((), k.dtype))
+
+
+def window_ring_update(ring, new, positions):
+    """Write one token's ``new: [batch, 1, e]`` into its slot of ``ring:
+    [batch, window, e]``: ``positions % window``, in place
+    (:func:`cache_update`)."""
+    return cache_update(ring, new, positions % ring.shape[1])
+
+
+def window_ring_attention(q, k_ring, v_ring, positions, groups: int):
+    """One token against its window, the ring read whole and masked: ``q:
+    [batch, heads, d]``, rings ``[batch, window, groups * d]``,
+    ``positions: [batch]`` the token's own position (its key and value
+    already in their slot). The slots written are ``0 .. min(position,
+    window - 1)``; once a row has wrapped, every slot is inside its
+    window. Returns ``[batch, heads, d]`` float32."""
+    from deeplearning4j_tpu.ops.block_sparse import dense_decode_attention
+
+    return dense_decode_attention(
+        q, k_ring, v_ring, jnp.minimum(positions, k_ring.shape[1] - 1),
+        groups)
 
 
 # ---------------------------------------------------------------------------

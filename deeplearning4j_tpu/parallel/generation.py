@@ -688,6 +688,7 @@ class GenerationEngine:
         bp = bucket_for(len(joins), self._dec.join_ladder)
         with self._span("gen.prefill", kind="cold", joins=len(joins),
                         prompt_bucket=tp, rows=bp,
+                        live_tokens=sum(r.n for r in joins),
                         state_kinds=self._state_kinds):
             with self._span("gen.prefill.stage"):
                 self._grow_to(max(tp, self._S))
@@ -756,7 +757,8 @@ class GenerationEngine:
         # padding rows scatter out of bounds (dropped)
         bp = cfg.max_batch
         with self._span("gen.prefill", kind="suffix", joins=len(joins),
-                        prompt_bucket=ts, rows=bp):
+                        prompt_bucket=ts, rows=bp,
+                        live_tokens=sum(r.n - r.prefix_len for r in joins)):
             with self._span("gen.prefill.stage"):
                 self._grow_to(max(max_m + ts, self._S))
                 suffix = np.full((bp, ts), self._dec.pad_id, np.int32)

@@ -519,8 +519,13 @@ def record_decode_layer_counts(counts: dict) -> None:
     ``sparse_attended_positions`` / ``sparse_context_positions`` per
     (sparse-layer query, KV head), ``sparse_dense_fallback_queries``,
     ``recurrent_state_updates``; ``decode_kv_read_positions`` /
-    ``decode_kv_bucket_positions`` per (``SelfAttentionLayer``, row,
-    step)."""
+    ``decode_kv_bucket_positions`` per (attention layer, row, step): the
+    cached positions streamed over the positions held (a bucket; for a
+    window layer's ring the row's context); ``moe_routed_slots`` (live
+    token x chosen expert), ``moe_experts_touched``,
+    ``moe_expert_layer_steps`` and ``moe_max_load`` per (expert layer,
+    step): experts with a live token, layer-steps with one, the fullest
+    expert's slots."""
     for name, n in counts.items():
         REGISTRY.counter(f"dl4j_{name}_total",
                          help="summed in-graph by the decode window "

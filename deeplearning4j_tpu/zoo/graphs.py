@@ -1092,14 +1092,26 @@ class TransformerEncoder(GraphZooModel):
 class HybridDecoderLM(GraphZooModel):
     """A causal language model whose mixer differs by layer:
     ``mixer_types[i]`` is ``"lightning-attn"`` (linear attention with a
-    recurrent state, ``conf.layers_hybrid.LightningAttentionLayer``) or
+    recurrent state, ``conf.layers_hybrid.LightningAttentionLayer``),
     ``"minicpm4"`` (block-sparse attention with grouped KV heads,
-    ``BlockSparseAttentionLayer``). Scaled token embedding, then per
+    ``BlockSparseAttentionLayer``), ``"window-attn"`` (gated grouped-query
+    attention that rotates and sees its last ``window`` positions: a ring
+    cache, ``GatedAttentionLayer``) or ``"full-attn"`` (the same layer,
+    neither rotating nor bounded: the two kinds pair the layer's two
+    switches, ``window`` and ``rope_theta``, as the one family that uses
+    them does; a full layer that rotates needs a third name here, the
+    layer itself keeps the switches apart). Scaled token embedding, then per
     layer ``h = x + c Mixer(RMSNorm(x))``, ``x' = h + c FFN(RMSNorm(h))``
     with a gated feed-forward and ``c = scale_depth / sqrt(depth_for_scale)``,
     a final RMS norm and an untied head whose logits are divided by
-    ``hidden / dim_model_base``. No position embedding: the lightning
-    layers rotate, the sparse ones take order from causality alone.
+    ``hidden / dim_model_base``. No position embedding: the lightning and
+    window layers rotate, the others take order from causality alone.
+
+    ``ffn_types[i]`` is ``"dense"`` (default) or ``"moe"``: dropless routed
+    experts beside a shared one (``conf.layers_moe.RoutedExpertsLayer``,
+    its sizes in ``moe``). ``post_norms`` puts an RMS norm on each
+    branch's OUTPUT as well: ``h = x + c Norm(Mixer(Norm(x)))``, four
+    norms a layer.
 
     ``layer_indices`` gives each built layer its index among
     ``n_layers_total`` (a served slice of a deeper model keeps its
@@ -1107,7 +1119,7 @@ class HybridDecoderLM(GraphZooModel):
     selection sizes. ``weight_dtype`` / ``cache_dtype`` are the matrices'
     and the KV caches' types; the recurrent state is float32."""
 
-    MIXERS = ("lightning-attn", "minicpm4")
+    MIXERS = ("lightning-attn", "minicpm4", "window-attn", "full-attn")
 
     def __init__(self, vocab_size: int, hidden: int, ffn_dim: int,
                  mixer_types, n_heads: int, head_dim: int,
@@ -1119,7 +1131,9 @@ class HybridDecoderLM(GraphZooModel):
                  eps: float = 1e-6, sparse: dict | None = None,
                  max_len: int = 4096, weight_dtype: str = "",
                  cache_dtype: str = "", seed: int = 123,
-                 updater: IUpdater | None = None):
+                 updater: IUpdater | None = None, window: int = 0,
+                 ffn_types=None, moe: dict | None = None,
+                 post_norms: bool = False):
         self.mixer_types = list(mixer_types)
         unknown = sorted(set(self.mixer_types) - set(self.MIXERS))
         if unknown:
@@ -1142,6 +1156,13 @@ class HybridDecoderLM(GraphZooModel):
                             else 1.0)
         self.rope_theta, self.eps = rope_theta, eps
         self.sparse = dict(sparse or {})
+        self.window = window
+        self.ffn_types = list(ffn_types or ["dense"] * n)
+        if len(self.ffn_types) != n or set(self.ffn_types) - {"dense", "moe"}:
+            raise ValueError("ffn_types needs 'dense' or 'moe' for each "
+                             "layer")
+        self.moe = dict(moe or {})
+        self.post_norms = post_norms
         self.max_len = max_len
         self.weight_dtype, self.cache_dtype = weight_dtype, cache_dtype
         self.seed = seed
@@ -1150,6 +1171,7 @@ class HybridDecoderLM(GraphZooModel):
     def conf(self) -> ComputationGraphConfiguration:
         from deeplearning4j_tpu.conf.layers_hybrid import (
             BlockSparseAttentionLayer,
+            GatedAttentionLayer,
             GatedFeedForwardLayer,
             LightningAttentionLayer,
             LMHeadLayer,
@@ -1157,8 +1179,18 @@ class HybridDecoderLM(GraphZooModel):
             RMSNormLayer,
             ScaledEmbeddingLayer,
         )
+        from deeplearning4j_tpu.conf.layers_moe import RoutedExpertsLayer
 
         e, wd, c = self.hidden, self.weight_dtype, self.residual_scale
+
+        def branch(name):
+            """The vertex a residual sum takes: the branch's own output,
+            or its RMS norm."""
+            if not self.post_norms:
+                return name
+            g.add_layer(f"{name}_norm", RMSNormLayer(eps=self.eps), name)
+            return f"{name}_norm"
+
         g = (NeuralNetConfiguration.builder()
              .seed(self.seed).updater(self.updater)
              .weight_init(WeightInit.XAVIER)
@@ -1179,22 +1211,35 @@ class HybridDecoderLM(GraphZooModel):
                     n_layers_total=self.n_layers_total,
                     rope_theta=self.rope_theta, eps=self.eps, out_scale=c,
                     weight_dtype=wd)
-            else:
+            elif kind == "minicpm4":
                 mixer = BlockSparseAttentionLayer(
                     n_out=e, n_heads=self.n_heads,
                     n_kv_heads=self.n_kv_heads, head_size=self.head_dim,
                     eps=self.eps, out_scale=c, weight_dtype=wd,
                     cache_dtype=self.cache_dtype, **self.sparse)
+            else:
+                windowed = kind == "window-attn"
+                mixer = GatedAttentionLayer(
+                    n_out=e, n_heads=self.n_heads,
+                    n_kv_heads=self.n_kv_heads, head_size=self.head_dim,
+                    window=self.window if windowed else 0,
+                    rope_theta=self.rope_theta if windowed else 0.0,
+                    eps=self.eps, out_scale=c, weight_dtype=wd,
+                    cache_dtype=self.cache_dtype)
             g.add_layer(f"b{i}_mix", mixer, f"b{i}_norm1")
             g.add_vertex(f"b{i}_res1", ResidualAddVertex(),
-                         prev, f"b{i}_mix")
+                         prev, branch(f"b{i}_mix"))
             g.add_layer(f"b{i}_norm2", RMSNormLayer(eps=self.eps),
                         f"b{i}_res1")
-            g.add_layer(f"b{i}_ffn", GatedFeedForwardLayer(
-                n_out=e, n_hidden=self.ffn_dim, out_scale=c,
-                weight_dtype=wd), f"b{i}_norm2")
+            if self.ffn_types[i] == "moe":
+                ffn = RoutedExpertsLayer(n_out=e, out_scale=c,
+                                         weight_dtype=wd, **self.moe)
+            else:
+                ffn = GatedFeedForwardLayer(n_out=e, n_hidden=self.ffn_dim,
+                                            out_scale=c, weight_dtype=wd)
+            g.add_layer(f"b{i}_ffn", ffn, f"b{i}_norm2")
             g.add_vertex(f"b{i}_res2", ResidualAddVertex(),
-                         f"b{i}_res1", f"b{i}_ffn")
+                         f"b{i}_res1", branch(f"b{i}_ffn"))
             prev = f"b{i}_res2"
         g.add_layer("final_norm", RMSNormLayer(eps=self.eps), prev)
         g.add_layer("output", LMHeadLayer(
@@ -1205,8 +1250,8 @@ class HybridDecoderLM(GraphZooModel):
         return g.build()
 
     def decoder(self, net=None, **kw):
-        """The serving front (``nn.decoding.TransformerDecoder``): three
-        kinds of per-row state in one donated pytree. ``cache_dtype``
+        """The serving front (``nn.decoding.TransformerDecoder``): each
+        layer's kind of per-row state in one donated pytree. ``cache_dtype``
         defaults to this model's."""
         from deeplearning4j_tpu.nn.decoding import TransformerDecoder
 

@@ -7,7 +7,9 @@ one layout at rest and in the loop, the cache written by
 ``dynamic-update-slice`` and read by the paged kernel and by nothing else)
 with no chip attached; and the hybrid decoder's decode window, prompt and
 join compiled the same way (each kind of state, KV, compressed keys,
-recurrent, taken whole by its write and its layer's read alone).
+recurrent, taken whole by its write and its layer's read alone), and the
+routed-experts decoder's (a full layer's bucket, a window layer's ring;
+no ``[tokens, experts, hidden]`` array in the prompt walk).
 
 The ahead-of-time compiles load the TPU's compiler: they stay in THIS file,
 and the topology is described inside a fixture, never at import.
@@ -143,6 +145,46 @@ def test_bounded_read_off_the_tpu_is_the_masked_read(heads, hs):
                                rtol=2e-5, atol=2e-5)
 
 
+# (query heads, KV heads, head size): the routed-experts cell's, a toy
+GROUPED = [(32, 4, 128), (8, 2, 16)]
+
+
+@pytest.mark.parametrize("page", [16, 128])
+@pytest.mark.parametrize("heads,groups,hs", GROUPED)
+def test_grouped_paged_read_matches_masked_read(heads, groups, hs, page):
+    """The same kernel with several query heads a KV head (through the
+    Pallas interpreter) against the masked read of grouped caches, rows
+    at ragged positions over a retired tenant's keys and values."""
+    from deeplearning4j_tpu.ops.block_sparse import dense_decode_attention
+
+    s = 4 * page
+    positions = _ragged(page, s)
+    b, e = len(positions), groups * hs
+    rng = np.random.default_rng(heads * 100 + hs + page)
+    k = rng.normal(size=(b, s, e)).astype(np.float32)
+    v = rng.normal(size=(b, s, e)).astype(np.float32)
+    q = jnp.asarray(rng.normal(size=(b, heads, hs)).astype(np.float32))
+    want = np.asarray(dense_decode_attention(
+        q, jnp.asarray(k), jnp.asarray(v), positions, groups))
+    beyond = np.arange(s)[None, :, None] > positions[:, None, None]
+    stale_k = np.where(beyond, 300.0 * rng.normal(size=k.shape), k)
+    stale_v = np.where(beyond, 300.0 * rng.normal(size=v.shape), v)
+    got = paged_decode_attention(
+        q, jnp.asarray(stale_k, jnp.float32),
+        jnp.asarray(stale_v, jnp.float32), positions, page=page,
+        interpret=True, groups=groups)
+    assert got.shape == (b, heads, hs)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
+    # off the TPU the bounded read is the masked one and says so
+    got, read = jax.jit(lambda *a: bounded_decode_attention(
+        *a, groups=groups))(q, jnp.asarray(k), jnp.asarray(v), positions)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-6)
+    assert np.asarray(read).tolist() == [s] * b
+    with pytest.raises(ValueError, match="must share"):
+        paged_decode_attention(q, jnp.asarray(k), jnp.asarray(v), positions,
+                               page=page, interpret=True, groups=3)
+
+
 # --- what the engine says the step read ----------------------------------------
 
 def _steer_to_the_tpu_branch(monkeypatch, page):
@@ -221,6 +263,53 @@ def test_engine_counts_the_positions_the_decode_step_reads(read, monkeypatch):
     assert series("bucket") - before["bucket"] == want_bucket
     assert "dl4j_decode_kv_read_positions_total" in text
     assert "dl4j_decode_kv_bucket_positions_total" in text
+
+
+def test_window_and_full_layers_count_what_they_read(monkeypatch):
+    """A window layer and a full one behind the engine, the full layer's
+    read through the TPU's branch (the paged kernel with grouped KV
+    heads, interpreted): a step at position ``p`` streams the ring's 16
+    slots and whole pages of the bucket up to ``p``, and holds ``p + 1``
+    positions of context and the bucket's 64; the tokens are those of the
+    masked read."""
+    from deeplearning4j_tpu.parallel.generation import (
+        GenerationConfig,
+        GenerationEngine,
+    )
+    from deeplearning4j_tpu.zoo.graphs import HybridDecoderLM
+
+    s, window, page = 64, 16, 16
+
+    def build():
+        zoo = HybridDecoderLM(
+            vocab_size=83, hidden=128, ffn_dim=64,
+            mixer_types=["window-attn", "full-attn"], n_heads=4, head_dim=64,
+            n_kv_heads=2, window=window, post_norms=True, max_len=s, seed=3)
+        return zoo.decoder(max_batch=2, kv_bucket_min=s, prompt_bucket_min=8)
+
+    jobs = [(3, 6), (20, 9), (30, 12)]
+    prompts = [np.random.default_rng(i).integers(1, 80, size=n).tolist()
+               for i, (n, _) in enumerate(jobs)]
+    masked = build()
+    want = [masked.generate(p, m, fused_steps=2)
+            for p, (_, m) in zip(prompts, jobs)]
+    _steer_to_the_tpu_branch(monkeypatch, page)
+    from deeplearning4j_tpu.optimize import aot_cache
+    aot_cache.clear()           # the same graph, lowered the other way
+    dec = build()
+    with GenerationEngine(dec, GenerationConfig(
+            max_batch=2, fused_steps=2, kv_bucket_min=s,
+            prompt_bucket_min=8)) as eng:
+        outs = [eng.result(eng.submit(p, max_new_tokens=m))
+                for p, (_, m) in zip(prompts, jobs)]
+        counts = eng.stats()["layer_counts"]
+    aot_cache.clear()
+    assert outs == want
+    steps = [p for n, m in jobs for p in range(n, n + m - 1)]
+    assert counts == {
+        "decode_kv_read_positions": sum(
+            window + -(-(p + 1) // page) * page for p in steps),
+        "decode_kv_bucket_positions": sum(p + 1 + s for p in steps)}
 
 
 # --- tiles ---------------------------------------------------------------------
@@ -642,3 +731,110 @@ def test_hybrid_programs_touch_each_state_only_to_write_and_read_it(
         found - _HYBRID_READERS[program, kind])
     # the write and a read are really there to be seen
     assert found >= _HYBRID_READERS[program, kind] & {_WRITE} and found
+
+
+# --- the routed-experts decoder: a bucket, a ring, grouped experts ----------
+
+# three experts, two a token: a step's three rows give each expert two
+# slots (every expert held), a prompt's 1,024 tokens 683 (grouped)
+_ROUTED = {"tokens": 1024, "experts": 3, "expert_hidden": 128}
+
+
+@pytest.fixture(scope="module")
+def routed_programs(one_chip):
+    """Decode window, prompt and join of a two-layer ``HybridDecoderLM``
+    with a window layer over a dense feed-forward and a full layer over
+    routed experts, compiled for the v5e from avals: ``{program: (text,
+    {kind of state: [shape, ...]})}``. KV heads 2 x 128 fill the tiles as
+    the cell's 4 x 128 do; three rows make a cache's shape no other
+    array's; the ring holds 256 slots, the bucket 4,096."""
+    from deeplearning4j_tpu.nn.graph import ComputationGraph
+    from deeplearning4j_tpu.zoo.graphs import HybridDecoderLM
+
+    b, s, tp = 3, 4096, _ROUTED["tokens"]
+    zoo = HybridDecoderLM(
+        vocab_size=512, hidden=384, ffn_dim=768,
+        mixer_types=["window-attn", "full-attn"], ffn_types=["dense", "moe"],
+        moe={"n_experts": _ROUTED["experts"],
+             "n_hidden": _ROUTED["expert_hidden"], "top_k": 2,
+             "n_shared_hidden": 128, "route_scale": 2.826},
+        post_norms=True, n_heads=4, head_dim=128, n_kv_heads=2, window=256,
+        max_len=s, weight_dtype="bfloat16", cache_dtype="bfloat16")
+    net = ComputationGraph(zoo.conf())
+    net.params = jax.eval_shape(
+        lambda: ComputationGraph(zoo.conf()).init().params)
+    net.state, net.opt_state = {}, {}
+    dec = zoo.decoder(net, max_batch=b, kv_bucket_min=s,
+                      prompt_bucket_min=tp, join_bucket_max=1)
+    on_chip = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        tree)
+    row = lambda dt, *tail: on_chip(  # noqa: E731
+        jax.ShapeDtypeStruct((1,) + tail, dt))
+    i32, req = row(jnp.int32), (row(jnp.int32), row(jnp.int32),
+                                row(jnp.float32), row(jnp.uint32, 2))
+    state, block = dec._struct_of(s), dec._kv_struct(1, tp)
+    table = {
+        "decode": (dec.decode_fn(s, 4), (net.params, state), state["caches"]),
+        "prompt": (dec.prompt_fn(tp, 1),
+                   (net.params, row(jnp.int32, tp), i32) + req, block),
+        "join": (dec.join_fn(s, tp, 1),
+                 (state, block, i32, i32, i32) + req + (row(jnp.bool_),),
+                 state["caches"]),
+    }
+    out = {}
+    for program, (step, args, leaves) in table.items():
+        shapes = {}
+        for name, layer_leaves in leaves.items():
+            for leaf, a in layer_leaves.items():
+                shapes.setdefault(dec._layer(name).cache_kinds[leaf], set()) \
+                    .add(f"bf16[{','.join(map(str, a.shape))}]")
+        txt = step.jit_fn.trace(*on_chip(args)).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+        out[program] = (_without_layout_constraints(txt), shapes)
+    return out
+
+
+_ROUTED_READERS = {
+    # the token's write, in place; a full layer's bucket is read by the
+    # paged kernel (grouped KV heads), a ring by the two products of the
+    # masked read
+    ("decode", "kv"): {_WRITE, ("custom-call", "part")},
+    ("decode", "kv_ring"): {_WRITE, ("convolution", "part")},
+    # a join writes the joining row alone (a third of a three-row cache),
+    # bucket and ring alike
+    ("join", "kv"): {_WRITE, ("dynamic-slice", "whole")},
+    ("join", "kv_ring"): {_WRITE, ("dynamic-slice", "whole")},
+}
+
+
+@pytest.mark.parametrize("program,kind", sorted(_ROUTED_READERS))
+def test_routed_programs_touch_bucket_and_ring_only_to_write_and_read_them(
+        routed_programs, program, kind):
+    """No ``copy``, ``pad``, ``select`` or scatter's ``while`` has a whole
+    bucket or a whole ring among its operands (PR 26's and PR 29's
+    lesson), in the decode window or in the join."""
+    txt, shapes = routed_programs[program]
+    assert shapes == {"kv": {"bf16[3,4096,256]"},
+                      "kv_ring": {"bf16[3,256,256]"}}
+    found = _consumers(txt, shapes[kind])
+    assert found <= _ROUTED_READERS[program, kind], sorted(
+        found - _ROUTED_READERS[program, kind])
+    assert _WRITE in found
+
+
+def test_routed_prompt_walk_holds_no_tokens_by_experts_by_hidden_array(
+        routed_programs):
+    """The prompt walk groups: nowhere in its compiled text is an array of
+    tokens x experts x hidden (the dense form, every token through every
+    expert), while the decode window, whose three rows go through every
+    expert held, has its small one."""
+    t, e, h = (_ROUTED[k] for k in ("tokens", "experts", "expert_hidden"))
+    dense = re.compile(
+        rf"\[({t},{e},{h}|{e},{t},{h}|{2 * t},{e},{h}|{e},{2 * t},{h})\]")
+    prompt, _ = routed_programs["prompt"]
+    assert not dense.search(prompt)
+    assert "ragged-dot" in prompt
+    decode, _ = routed_programs["decode"]
+    assert re.search(rf"\[{e},3,{h}\]", decode)
+    assert "ragged-dot" not in decode
