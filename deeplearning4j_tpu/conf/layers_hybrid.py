@@ -470,7 +470,8 @@ class BlockSparseAttentionLayer(_GatedMixer):
     uses_mask = True
     cache_kinds = {"k": "kv", "v": "kv", "ck": "compressed_keys"}
     cache_counters = ("sparse_attended_positions", "sparse_context_positions",
-                      "sparse_dense_fallback_queries")
+                      "sparse_dense_fallback_queries",
+                      "sparse_read_positions")
 
     def _spec(self) -> SparseSpec:
         return SparseSpec(kernel=self.kernel_size, stride=self.kernel_stride,
@@ -565,8 +566,9 @@ class BlockSparseAttentionLayer(_GatedMixer):
             o = block_sparse.dense_decode_attention(q, k_cache, v_cache,
                                                     positions, g)
             attended = context
+            read = jnp.full_like(context, g * s_len)
         else:
-            o, attended = block_sparse.sparse_decode_attention(
+            o, attended, read = block_sparse.sparse_decode_attention(
                 q, k_cache, v_cache, ck_cache, positions, spec, g)
             wanted = dense if active is None else dense & active
             o_dense = jax.lax.cond(
@@ -577,9 +579,13 @@ class BlockSparseAttentionLayer(_GatedMixer):
                 lambda: jnp.zeros_like(o))
             o = jnp.where(dense[:, None, None], o_dense, o)
             attended = jnp.where(dense, context, attended)
+            # the dense branch, where a row wants it, streams every row's
+            # first dense_len positions beside the selection's
+            read = read + jnp.where(jnp.any(wanted), g * spec.dense_len, 0)
         counts = {"sparse_attended_positions": attended,
                   "sparse_context_positions": context,
-                  "sparse_dense_fallback_queries": dense.astype(jnp.int32)}
+                  "sparse_dense_fallback_queries": dense.astype(jnp.int32),
+                  "sparse_read_positions": read}
         return (self._finish(params, x, o),
                 {"k": k_cache, "v": v_cache, "ck": ck_cache}, counts)
 

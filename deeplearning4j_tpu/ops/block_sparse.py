@@ -25,6 +25,15 @@ A query at a position under ``dense_len`` attends every position up to
 its own. :class:`SparseSpec` carries the sizes; nothing here knows a
 model.
 
+A prompt's queries choose by ``lax.top_k`` and attend all keys under the
+mask of the chosen positions (:func:`sparse_prefill_attention`). The
+decode step (:func:`sparse_decode_attention`) needs only WHICH blocks: in
+a program lowered for a TPU it ranks them without a sort
+(:func:`rank_blocks`) and reads them where they lie in the caches, in one
+Pallas kernel (:func:`sparse_read_attention`); on every other platform it
+gathers them out and attends the copy (:func:`gathered_decode_attention`,
+which is also the kernel's reference).
+
 The choice of step 3 is discrete, so steps 1 to 3 run in float32 at
 ``Precision.HIGHEST`` over float32 compressed keys, whatever type the
 keys and values are cached in: with bfloat16 scores a served model's
@@ -40,10 +49,14 @@ layer at 16 rows of 32 k positions.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -102,13 +115,13 @@ def _grouped_block_diagonal(q, groups: int):
                                                           t * h)
 
 
-def select_blocks(q, ck, positions, spec: SparseSpec, groups: int):
-    """The chosen blocks of each query: ``q: [batch, time, heads, d]``,
+def block_scores(q, ck, positions, spec: SparseSpec, groups: int):
+    """Every block's score for each query: ``q: [batch, time, heads, d]``,
     ``ck: [batch, n_compressed, groups * d]``, ``positions: [batch,
-    time]`` each query's own position. Returns ``(idx, ok)``, both
-    ``[batch, time, groups, k]``: block numbers by falling score and
-    whether each is a real choice (a query with fewer candidate blocks
-    than ``topk`` has fewer)."""
+    time]`` each query's own position. Returns ``[batch, time, groups,
+    blocks]`` float32: a candidate block's score (0 or more), -1 for a
+    block that is no candidate (initial, or not yet outside the
+    window)."""
     b, t, h, d = q.shape
     nc = ck.shape[1]
     ratio = spec.block // spec.stride
@@ -137,9 +150,36 @@ def select_blocks(q, ck, positions, spec: SparseSpec, groups: int):
     candidate = ((start[None, :, None] <= positions[:, None, :] - spec.window)
                  & (jnp.arange(nb) >= spec.init_blocks)[None, :, None])
     score = jnp.where(candidate[..., None], score, -1.0)
-    vals, idx = jax.lax.top_k(jnp.transpose(score, (0, 2, 3, 1)),
-                              min(spec.topk, nb))
+    return jnp.transpose(score, (0, 2, 3, 1))
+
+
+def select_blocks(q, ck, positions, spec: SparseSpec, groups: int):
+    """The chosen blocks of each query (:func:`block_scores`' arguments).
+    Returns ``(idx, ok)``, both ``[batch, time, groups, k]``: block
+    numbers by falling score and whether each is a real choice (a query
+    with fewer candidate blocks than ``topk`` has fewer)."""
+    score = block_scores(q, ck, positions, spec, groups)
+    vals, idx = jax.lax.top_k(score, min(spec.topk, score.shape[-1]))
     return idx.astype(jnp.int32), vals >= 0.0
+
+
+def rank_blocks(score, k: int):
+    """:func:`jax.lax.top_k`'s ``(idx, vals >= 0)`` of ``score: [...,
+    blocks]`` without a sort: a block's place is the number of blocks that
+    beat it (a greater score, or an equal score and a lower number:
+    ``top_k``'s own order), and place ``r`` names the one block whose
+    count is ``r``. ``blocks ** 2`` comparisons, which the vector unit
+    makes faster than it sorts: on the TPU ``top_k`` of 512 scores is a
+    full sort of them (PERF.md §6)."""
+    nb = score.shape[-1]
+    i = jnp.arange(nb, dtype=jnp.int32)
+    first, second = score[..., :, None], score[..., None, :]
+    beats = (first > second) | ((first == second) & (i[:, None] < i))
+    place = beats.sum(axis=-2, dtype=jnp.int32)              # [..., nb]
+    hit = place[..., None] == jnp.arange(k, dtype=jnp.int32)  # [..., nb, k]
+    idx = jnp.sum(jnp.where(hit, i[:, None], 0), axis=-2)
+    ok = jnp.any(hit & (score[..., None] >= 0.0), axis=-2)
+    return idx, ok
 
 
 def allowed_positions(idx, ok, positions, n_positions: int,
@@ -250,20 +290,56 @@ def dense_decode_attention(q, k_cache, v_cache, positions, groups: int):
 def sparse_decode_attention(q, k_cache, v_cache, ck_cache, positions,
                             spec: SparseSpec, groups: int):
     """One token of the selection: score the compressed keys, choose the
-    blocks, gather their keys and values, attend them with the window and
-    the initial blocks. ``q: [batch, heads, d]``, ``positions: [batch]``
-    the slot of the token (its own key and value already written).
-    Returns ``(o [batch, heads, d] float32, attended [batch])``, the
-    second the count of (KV head, position) pairs attended."""
+    blocks, attend them with the window and the initial blocks. ``q:
+    [batch, heads, d]``, ``positions: [batch]`` the slot of the token (its
+    own key and value already written). Returns ``(o [batch, heads, d]
+    float32, attended [batch], read [batch])``: the count of (KV head,
+    position) pairs attended, and of those whose keys and values the step
+    streamed to attend them.
+
+    In a program LOWERED for a TPU (``lax.platform_dependent``, as
+    ``ops.attention.bounded_decode_attention``) the blocks are chosen by
+    :func:`rank_blocks` and read where they lie by
+    :func:`sparse_read_attention`, where the caches' shape allows
+    (:func:`sparse_read_applies`); on every other platform, so in every
+    CPU run, by ``top_k`` and :func:`gathered_decode_attention`, which is
+    also the kernel's reference."""
+    score = block_scores(q[:, None], ck_cache, positions[:, None], spec,
+                         groups)[:, 0]                   # [b, g, nb]
+    k = min(spec.topk, score.shape[-1])
+
+    def gathered(score, q, k_cache, v_cache, positions):
+        vals, idx = jax.lax.top_k(score, k)
+        return gathered_decode_attention(
+            q, k_cache, v_cache, idx.astype(jnp.int32), vals >= 0.0,
+            positions, spec, groups)
+
+    def in_place(score, q, k_cache, v_cache, positions):
+        idx, ok = rank_blocks(score, k)
+        return sparse_read_attention(q, k_cache, v_cache, idx, ok, positions,
+                                     spec, groups, interpret=False)
+
+    if not sparse_read_applies(k_cache.shape, k_cache.dtype, q.shape[-1],
+                               spec):
+        return gathered(score, q, k_cache, v_cache, positions)
+    return jax.lax.platform_dependent(score, q, k_cache, v_cache, positions,
+                                      tpu=in_place, default=gathered)
+
+
+def gathered_decode_attention(q, k_cache, v_cache, idx, ok, positions,
+                              spec: SparseSpec, groups: int):
+    """The chosen blocks ``idx, ok: [batch, groups, k]`` gathered out of
+    the caches, concatenated with the window and the initial blocks and
+    attended by one softmax (stock XLA). Returns as
+    :func:`sparse_decode_attention`; what it streams: the window and the
+    initial blocks once (both KV heads' lanes), each KV head's chosen
+    blocks with the other head's lanes beside them."""
     b, h, d = q.shape
     s_len = k_cache.shape[1]
     hpg = h // groups
     nb = s_len // spec.block
     window = min(spec.window, s_len)
     n_init = min(spec.init_blocks * spec.block, s_len)
-    idx, ok = select_blocks(q[:, None], ck_cache, positions[:, None], spec,
-                            groups)
-    idx, ok = idx[:, 0], ok[:, 0]                        # [b, g, k]
     pos = positions[:, None]
     # the window, one slice a row, and the initial blocks
     w0 = jnp.clip(positions - window + 1, 0, s_len - window)
@@ -308,4 +384,230 @@ def sparse_decode_attention(q, k_cache, v_cache, ck_cache, positions,
             at += n
         outs.append(o)
         attended = attended + mask.sum(axis=-1)
-    return jnp.concatenate(outs, axis=1), attended.astype(jnp.int32)
+    read = groups * (window + n_init + groups * idx.shape[-1] * spec.block)
+    return (jnp.concatenate(outs, axis=1), attended.astype(jnp.int32),
+            jnp.full((b,), read, jnp.int32))
+
+
+# ---------------------------------------------------------------------------
+# The decode step's read as one Pallas kernel: the caches stay in HBM
+# ---------------------------------------------------------------------------
+#
+# What decides its shape (PERF.md §6, PR 34): a row attends 64 + 33 + 1
+# blocks a KV head, and a grid step of the Pallas pipeline costs a third of
+# a microsecond beside its DMA (``ops.attention.DECODE_PAGE``), so one
+# block a grid step would be slower than the gathers it replaces. The grid
+# is the ROWS; a step starts the NEXT row's copies by hand (the window and
+# the initial blocks, both KV heads' lanes in one run each; every chosen
+# block of every KV head) into the other half of a VMEM slab, waits for its
+# own, and attends them while the next row's arrive. A chosen block is
+# copied with its own KV head's ``d`` lanes alone: with both heads' (whole
+# contiguous rows, twice the chosen bytes) the kernel took 236 us a layer
+# against 160 at the cell's shapes, where its copies alone take 138 (744
+# GB/s) and its arithmetic alone 56, hidden behind them.
+
+# positions a product takes at a time, the running softmax's step: 4096
+# (a KV head's chosen blocks in one) 160 us, 2048 169, 1024 183, 512 207
+SPARSE_READ_CHUNK = 4096
+
+
+def sparse_read_applies(cache_shape, cache_dtype, d: int,
+                        spec: SparseSpec) -> bool:
+    """Whether the TPU reads a ``[batch, positions, groups * d]`` cache by
+    :func:`sparse_read_attention`, from the shapes alone: a KV head fills
+    whole 128-lane tiles, a block whole sublane tiles of the cache's type,
+    the window whole blocks, and the bucket holds the window and a block
+    of slack beside a chosen block (or there is nothing to skip)."""
+    _, s_len, e = cache_shape
+    sublanes = 8 * 4 // jnp.dtype(cache_dtype).itemsize
+    return (d % 128 == 0 and e % d == 0 and spec.block % sublanes == 0
+            and spec.window % spec.block == 0 and s_len % spec.block == 0
+            and s_len >= spec.window + 2 * spec.block)
+
+
+def _sparse_read_kernel(pos_ref, idx_ref, q_ref, pb_ref, k_hbm, v_hbm, o_ref,
+                        kw, vw, kc, vc, sems, *, spec: SparseSpec,
+                        groups: int, d: int, topk: int, span: int,
+                        n_init: int, chunk: int):
+    """One row a grid step (module comment above). ``pos_ref: [batch]``
+    and ``idx_ref: [batch * groups * topk]`` (the chosen blocks) are
+    scalar-prefetched; ``q_ref: [1, heads, d]``; ``pb_ref: [1, groups,
+    topk * block]`` the position each chosen slot holds (past the cache
+    where the slot is no real choice); ``k_hbm, v_hbm`` the caches where
+    they lie. Slabs, two halves each: ``kw, vw: [2, span + n_init,
+    groups * d]`` the window from its block-aligned start, then the
+    initial blocks; ``kc, vc: [2, groups, topk * block, d]`` the chosen
+    blocks, each KV head's own lanes. ``sems: [2, 4]``, one a slab a
+    half: a slab's copies all signal it and ONE wait the size of the slab
+    takes them all."""
+    i = pl.program_id(0)
+    block, window = spec.block, spec.window
+    hpg = q_ref.shape[1] // groups
+    s_len = k_hbm.shape[1]
+
+    def window_start(r):
+        w0 = (pos_ref[r] + 1 - window) // block * block
+        return pl.multiple_of(jnp.clip(w0, 0, s_len - span), block)
+
+    def fetch(r, half):
+        w0 = window_start(r)
+        for j, (hbm, fixed) in enumerate(((k_hbm, kw), (v_hbm, vw))):
+            pltpu.make_async_copy(
+                hbm.at[r, pl.ds(w0, span), :],
+                fixed.at[half, pl.ds(0, span), :], sems.at[half, j]).start()
+            pltpu.make_async_copy(
+                hbm.at[r, pl.ds(0, n_init), :],
+                fixed.at[half, pl.ds(span, n_init), :],
+                sems.at[half, j]).start()
+
+        def some(n, carry):     # a few blocks an iteration, written out
+            for t in (n * unroll + u for u in range(unroll)):
+                for g in range(groups):
+                    at = pl.multiple_of(
+                        idx_ref[(r * groups + g) * topk + t] * block, block)
+                    to = pl.ds(pl.multiple_of(t * block, block), block)
+                    for j, (hbm, chosen) in enumerate(((k_hbm, kc),
+                                                       (v_hbm, vc))):
+                        pltpu.make_async_copy(
+                            hbm.at[r, pl.ds(at, block), pl.ds(g * d, d)],
+                            chosen.at[half, g, to, :],
+                            sems.at[half, 2 + j]).start()
+            return carry
+
+        unroll = math.gcd(topk, 8)
+        jax.lax.fori_loop(0, topk // unroll, some, None)
+
+    def wait(half):
+        for j, slab in enumerate((kw, vw, kc, vc)):
+            pltpu.make_async_copy(slab.at[half], slab.at[half],
+                                  sems.at[half, j]).wait()
+
+    half = i % 2
+
+    @pl.when(i == 0)
+    def _first():
+        fetch(0, 0)
+
+    @pl.when(i + 1 < pl.num_programs(0))
+    def _next():
+        fetch(i + 1, 1 - half)
+
+    wait(half)
+    pos = pos_ref[i]
+    edge = pos - window         # positions up to here lie outside the window
+    w0 = window_start(i)
+    sm = 1.0 / math.sqrt(d)
+
+    def parts(g):
+        """``(keys, values, attended)`` a chunk: the chosen blocks' slots,
+        then the window's and the initial blocks'."""
+        for c in range(0, topk * block, chunk):
+            at = slice(c, min(c + chunk, topk * block))
+            yield (kc[half, g, at, :], vc[half, g, at, :],
+                   pb_ref[0, g:g + 1, at] <= edge)
+        for c in range(0, span + n_init, chunk):
+            n = min(c + chunk, span + n_init) - c
+            col = c + jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
+            p = jnp.where(col < span, w0 + col, col - span)
+            seen = (p <= edge) ^ ((col < span) & (p <= pos))
+            lanes = slice(g * d, (g + 1) * d)
+            yield (kw[half, c:c + n, lanes], vw[half, c:c + n, lanes], seen)
+
+    for g in range(groups):
+        qg = q_ref[0, g * hpg:(g + 1) * hpg, :]              # [hpg, d]
+        m = jnp.full((hpg, 1), NEG_INF, jnp.float32)
+        l = jnp.zeros((hpg, 1), jnp.float32)
+        acc = jnp.zeros((hpg, d), jnp.float32)
+        for keys, values, seen in parts(g):
+            s = jax.lax.dot_general(
+                qg, keys, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm     # [hpg, n]
+            s = jnp.where(seen, s, NEG_INF)
+            m_next = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            # a chunk may hold no attended position at all: its exp(0)s
+            # must not count
+            p = jnp.where(seen, jnp.exp(s - m_next), 0.0)
+            alpha = jnp.exp(m - m_next)
+            l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+            acc = alpha * acc + jnp.dot(p.astype(values.dtype), values,
+                                        preferred_element_type=jnp.float32)
+            m = m_next
+        # the token's own position is in its window: l > 0
+        o_ref[0, g * hpg:(g + 1) * hpg, :] = (acc / l).astype(o_ref.dtype)
+
+
+# jitted so that a decoder's sparse layers share one trace and one lowered
+# body of the kernel (``ops.attention.paged_decode_attention``)
+@functools.partial(jax.jit, static_argnames=("spec", "groups", "interpret",
+                                             "chunk"))
+def sparse_read_attention(q, k_cache, v_cache, idx, ok, positions,
+                          spec: SparseSpec, groups: int,
+                          interpret: Optional[bool] = None,
+                          chunk: Optional[int] = None):
+    """:func:`gathered_decode_attention` as one Pallas kernel over the
+    caches left in HBM (the same arguments, the same three results): for
+    a row it copies the window (one run from a block-aligned start, a
+    block longer than the window), the initial blocks and the chosen
+    blocks into VMEM and attends them with a running softmax, float32
+    accumulation over the cache's type. The masks are the gathered
+    read's: a chosen or initial block gives its positions up to ``pos -
+    window``, the window those after it and up to ``pos``; a position
+    past the bucket reads as its last slot, as ``cache_update`` writes it.
+    The bucket must hold the window, a block of slack and whole blocks; a
+    TPU needs :func:`sparse_read_applies` besides. ``interpret=None``
+    runs the Pallas interpreter off the TPU."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    b, h, d = q.shape
+    s_len, e = k_cache.shape[1:]
+    block, window = spec.block, spec.window
+    topk = idx.shape[-1]
+    span, n_init = window + block, spec.init_blocks * block
+    if (window % block or s_len % block or s_len < span + block
+            or e != groups * d or h % groups):
+        raise ValueError(f"sparse_read_attention: a {k_cache.shape} cache "
+                         f"of {groups} KV heads of {d} does not hold a "
+                         f"window of {window} in whole blocks of {block}")
+    pos = jnp.clip(positions.astype(jnp.int32), 0, s_len - 1)
+    idx = idx.astype(jnp.int32)
+    in_block = jnp.arange(block, dtype=jnp.int32)
+    pb = jnp.where(ok[..., None], idx[..., None] * block + in_block,
+                   s_len).reshape(b, groups, topk * block)
+    kernel = functools.partial(
+        _sparse_read_kernel, spec=spec, groups=groups, d=d, topk=topk,
+        span=span, n_init=n_init, chunk=int(chunk or SPARSE_READ_CHUNK))
+    row = lambda i, *_: (i, 0, 0)  # noqa: E731
+    dt = k_cache.dtype
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, h, d), row),
+                  pl.BlockSpec((1, groups, topk * block), row),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, h, d), row),
+        scratch_shapes=[pltpu.VMEM((2, span + n_init, e), dt),
+                        pltpu.VMEM((2, span + n_init, e), dt),
+                        pltpu.VMEM((2, groups, topk * block, d), dt),
+                        pltpu.VMEM((2, groups, topk * block, d), dt),
+                        pltpu.SemaphoreType.DMA((2, 4))],
+    )
+    params = None
+    if not interpret:
+        slabs = 2 * 2 * (span + n_init + topk * block) * e * dt.itemsize
+        params = pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=int(slabs + (16 << 20)))
+    o = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, d), jnp.float32),
+        compiler_params=params, interpret=interpret,
+    )(pos, idx.reshape(-1), q.astype(dt), pb, k_cache, v_cache)
+    # what the masks above let through, and what the copies brought
+    outside = jnp.clip(pos[:, None, None] - window + 1 - idx * block, 0,
+                       block)
+    fixed = jnp.minimum(pos + 1, window) + jnp.clip(pos - window + 1, 0,
+                                                    n_init)
+    attended = groups * fixed + jnp.where(ok, outside, 0).sum(axis=(1, 2))
+    read = groups * (span + n_init + topk * block)
+    return o, attended.astype(jnp.int32), jnp.full((b,), read, jnp.int32)

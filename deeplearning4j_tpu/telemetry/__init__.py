@@ -516,7 +516,8 @@ def record_decode_layer_counts(counts: dict) -> None:
     """What the cached layers counted in-graph over one decode window
     (``nn.decoding``: summed over the active rows and the window's steps,
     read with the window's tokens): ``dl4j_<name>_total`` each —
-    ``sparse_attended_positions`` / ``sparse_context_positions`` per
+    ``sparse_attended_positions`` / ``sparse_context_positions`` /
+    ``sparse_read_positions`` (what the read streamed to attend that) per
     (sparse-layer query, KV head), ``sparse_dense_fallback_queries``,
     ``recurrent_state_updates``; ``decode_kv_read_positions`` /
     ``decode_kv_bucket_positions`` per (attention layer, row, step): the

@@ -267,7 +267,7 @@ def test_sparse_decode_equals_masked_dense_attention():
     v = rng.normal(size=(b, s, groups * d)).astype(np.float32)
     pos = np.asarray([40, 77, 127], np.int32)
     ck = block_sparse.compress_keys(jnp.asarray(k), spec)
-    o, attended = block_sparse.sparse_decode_attention(
+    o, attended, read = block_sparse.sparse_decode_attention(
         jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), ck, pos, spec, groups)
     idx, ok = block_sparse.select_blocks(q[:, None], ck, pos[:, None], spec,
                                          groups)
@@ -279,6 +279,192 @@ def test_sparse_decode_equals_masked_dense_attention():
     np.testing.assert_array_equal(attended,
                                   np.asarray(mask)[:, 0].sum(axis=(1, 2)))
     assert (np.asarray(attended) < groups * (pos + 1)).all()
+    # the window and the first block once, each KV head's two chosen blocks
+    # with the other head's lanes beside them
+    assert np.asarray(read).tolist() == [
+        groups * (16 + 8 + groups * 2 * 8)] * b
+
+
+# --- the decode read as a kernel, the choice without a sort -----------------
+
+_READ_SPEC = dict(kernel=8, stride=4, block=8, window=16, init_blocks=1,
+                  dense_len=32)
+# positions of the rows of one batch, in a bucket of 128, top-k
+_READ_CASES = {
+    # pos - window = 24 and 61: the edge of the window falls inside a block
+    # (24 = 3 * 8 is a block's FIRST position, 61 its sixth), which a
+    # chosen block and the window both hold: attended once
+    "a_chosen_block_straddles_the_edge": ([40, 77], 2),
+    # block-aligned edges: pos - window + 1 starts a block
+    "the_window_starts_a_block": ([39, 95, 127], 2),
+    # candidates are the blocks 1 .. (pos - 16) // 8: two at 33, three at 47
+    "fewer_candidates_than_topk": ([33, 47, 100], 4),
+    "just_over_dense_len": ([32, 33, 34], 2),
+    # a dense row (its output is discarded by the layer, its copies must
+    # stay inside the cache), the bucket's last slot, a position past it
+    "contexts_of_every_kind_in_one_batch": ([5, 15, 16, 60, 127, 130], 3),
+}
+
+
+def _read_inputs(positions, topk, dtype=np.float32, seed=3):
+    rng = np.random.default_rng(seed)
+    b, s, heads, groups, d = len(positions), 128, 4, 2, 8
+    spec = block_sparse.SparseSpec(topk=topk, **_READ_SPEC)
+    pos = np.asarray(positions, np.int32)
+    q = jnp.asarray(rng.normal(size=(b, heads, d)).astype(np.float32))
+    k = rng.normal(size=(b, s, groups * d)).astype(np.float32)
+    v = rng.normal(size=(b, s, groups * d)).astype(np.float32)
+    ck = block_sparse.compress_keys(jnp.asarray(k), spec)
+    # past a row's position: a retired tenant's keys and values
+    beyond = np.arange(s)[None, :, None] > pos[:, None, None]
+    k = np.where(beyond, 300.0 * rng.normal(size=k.shape), k)
+    v = np.where(beyond, 300.0 * rng.normal(size=v.shape), v)
+    score = block_sparse.block_scores(q[:, None], ck, pos[:, None], spec,
+                                      groups)[:, 0]
+    return (spec, groups, q, jnp.asarray(k, dtype), jnp.asarray(v, dtype),
+            jnp.asarray(pos), score)
+
+
+@pytest.mark.parametrize("chunk", [16, 4096])
+@pytest.mark.parametrize("case", sorted(_READ_CASES))
+def test_sparse_read_kernel_equals_the_gathered_read(case, chunk):
+    """``sparse_read_attention`` (through the Pallas interpreter) against
+    ``gathered_decode_attention`` on the same chosen blocks: the output
+    to float32 rounding, the attended count exactly, with the running
+    softmax in several steps and in one a slab; what it says it copied
+    is the slabs' size."""
+    positions, topk = _READ_CASES[case]
+    spec, groups, q, k, v, pos, score = _read_inputs(positions, topk)
+    vals, idx = jax.lax.top_k(score, topk)
+    ok = vals >= 0.0
+    if case == "fewer_candidates_than_topk":
+        assert np.asarray(ok).sum(axis=-1).tolist() == [[2, 2], [3, 3],
+                                                        [4, 4]]
+    # a position past the bucket (a retired row's) reads as the last slot,
+    # as the write clamps it
+    want, attended, _ = block_sparse.gathered_decode_attention(
+        q, k, v, idx, ok, jnp.minimum(pos, 127), spec, groups)
+    got, counted, read = block_sparse.sparse_read_attention(
+        q, k, v, idx, ok, pos, spec, groups, interpret=True, chunk=chunk)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5,
+                               atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(counted), np.asarray(attended))
+    copied = groups * (16 + 8 + 8 + topk * 8)
+    assert np.asarray(read).tolist() == [copied] * len(positions)
+    assert (np.asarray(read) >= np.asarray(counted)).all()
+
+
+def test_sparse_read_kernel_in_the_caches_type():
+    """bfloat16 caches: the products take bfloat16 operands and accumulate
+    in float32, as the gathered read's do."""
+    positions, topk = _READ_CASES["a_chosen_block_straddles_the_edge"]
+    spec, groups, q, k, v, pos, score = _read_inputs(positions, topk,
+                                                     jnp.bfloat16)
+    idx, ok = block_sparse.rank_blocks(score, topk)
+    want, attended, _ = block_sparse.gathered_decode_attention(
+        q, k, v, idx, ok, pos, spec, groups)
+    got, counted, _ = block_sparse.sparse_read_attention(
+        q, k, v, idx, ok, pos, spec, groups, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=0.02)
+    np.testing.assert_array_equal(np.asarray(counted), np.asarray(attended))
+
+
+def test_sparse_read_kernel_refuses_a_bucket_without_whole_blocks():
+    spec, groups, q, k, v, pos, score = _read_inputs([40, 77], 2)
+    idx, ok = block_sparse.rank_blocks(score, 2)
+    with pytest.raises(ValueError, match="whole blocks"):
+        block_sparse.sparse_read_attention(
+            q, k[:, :30], v[:, :30], idx, ok, pos, spec, groups,
+            interpret=True)
+
+
+def _planted_scores(kind):
+    rng = np.random.default_rng(11)
+    score = rng.random((3, 2, 40)).astype(np.float32)
+    if kind == "equal_scores":          # ties go to the lower block
+        score[0, 0, 5:30] = 0.5
+        score[1, 1, ::3] = score[1, 1, 0]
+        score[2, :, :] = 0.25
+    elif kind == "no_candidate_at_all":
+        score[:] = -1.0
+    elif kind == "fewer_candidates_than_k":
+        score[0, 0, 3:] = -1.0
+        score[1, :, 1:] = -1.0
+        score[2, 1, :] = -1.0
+    elif kind == "zeros_beside_candidates":     # a score of 0 is a choice
+        score[:, :, 10:] = 0.0
+        score[:, :, 30:] = -1.0
+    return jnp.asarray(score)
+
+
+@pytest.mark.parametrize("k", [1, 8, 40])
+@pytest.mark.parametrize("kind", ["random", "equal_scores",
+                                  "no_candidate_at_all",
+                                  "fewer_candidates_than_k",
+                                  "zeros_beside_candidates"])
+def test_rank_blocks_is_top_k_without_the_sort(kind, k):
+    """The same blocks in the same order as ``lax.top_k``, ties to the
+    lower block number, and the same ``ok`` flags."""
+    score = _planted_scores(kind)
+    vals, idx = jax.lax.top_k(score, k)
+    got, ok = jax.jit(lambda x: block_sparse.rank_blocks(x, k))(score)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(idx))
+    np.testing.assert_array_equal(np.asarray(ok), np.asarray(vals) >= 0.0)
+
+
+def test_off_the_tpu_the_decode_read_is_the_gathered_one():
+    """At a shape the kernel takes, a CPU run of
+    ``sparse_decode_attention`` is ``top_k`` and the gathered read, bit
+    for bit, and lowers to no kernel; the same function LOWERED for a TPU
+    (from this host) holds the kernel and no ``top_k``."""
+    rng = np.random.default_rng(9)
+    b, s, heads, groups, d = 2, 512, 4, 2, 128
+    spec = block_sparse.SparseSpec(kernel=32, stride=16, block=16, window=64,
+                                   init_blocks=1, topk=4, dense_len=128)
+    assert block_sparse.sparse_read_applies((b, s, groups * d), jnp.bfloat16,
+                                            d, spec)
+    assert not block_sparse.sparse_read_applies((b, s, groups * 8),
+                                                jnp.bfloat16, 8, spec)
+    assert not block_sparse.sparse_read_applies((b, 64, groups * d),
+                                                jnp.bfloat16, d, spec)
+    assert not block_sparse.sparse_read_applies(
+        (b, s, groups * d), jnp.bfloat16, d,
+        block_sparse.SparseSpec(kernel=16, stride=8, block=8, window=64))
+    q = jnp.asarray(rng.normal(size=(b, heads, d)).astype(np.float32))
+    k = jnp.asarray(rng.normal(size=(b, s, groups * d)), jnp.bfloat16)
+    v = jnp.asarray(rng.normal(size=(b, s, groups * d)), jnp.bfloat16)
+    pos = jnp.asarray([200, 470], jnp.int32)
+    ck = block_sparse.compress_keys(k, spec)
+
+    def step(q, k, v, ck, pos):
+        return block_sparse.sparse_decode_attention(q, k, v, ck, pos, spec,
+                                                    groups)
+
+    def by_hand(q, k, v, ck, pos):
+        idx, ok = block_sparse.select_blocks(q[:, None], ck, pos[:, None],
+                                             spec, groups)
+        return block_sparse.gathered_decode_attention(
+            q, k, v, idx[:, 0], ok[:, 0], pos, spec, groups)
+
+    got = jax.jit(step)(q, k, v, ck, pos)
+    want = jax.jit(by_hand)(q, k, v, ck, pos)
+    for a, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(w))
+    # both KV heads' lanes ride with each head's chosen blocks
+    assert np.asarray(got[2]).tolist() == [
+        groups * (64 + 16 + 2 * 4 * 16)] * b
+    traced = jax.jit(step).trace(q, k, v, ck, pos)
+    here = traced.lower().as_text()
+    assert "tpu_custom_call" not in here and "top_k" in here
+    there = traced.lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in there and "top_k" not in there
+    # and through the interpreter the kernel's path gives the same answer
+    idx, ok = block_sparse.rank_blocks(block_sparse.block_scores(
+        q[:, None], ck, pos[:, None], spec, groups)[:, 0], 4)
+    o, attended, _ = block_sparse.sparse_read_attention(
+        q, k, v, idx, ok, pos, spec, groups, interpret=True)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(want[0]), atol=0.02)
+    np.testing.assert_array_equal(np.asarray(attended), np.asarray(want[1]))
 
 
 # --- through GenerationEngine -------------------------------------------------
@@ -415,7 +601,8 @@ def test_decode_window_returns_the_layers_counts(served):
     _, _, net, dec = served
     assert dec.counter_names == [
         "recurrent_state_updates", "sparse_attended_positions",
-        "sparse_context_positions", "sparse_dense_fallback_queries"]
+        "sparse_context_positions", "sparse_dense_fallback_queries",
+        "sparse_read_positions"]
     state = dec.new_state(128)
     state = dict(state, active=jnp.asarray([True, True]),
                  positions=jnp.asarray([10, 70], jnp.int32),
@@ -432,6 +619,62 @@ def test_decode_window_returns_the_layers_counts(served):
         sum(range(11, 15)) + sum(range(71, 75)))
     assert counts["sparse_attended_positions"] < counts[
         "sparse_context_positions"]
+    # what the steps streamed to attend that: off the TPU the gathered
+    # read (the window of 16, the first block of 8, two chosen blocks of 8
+    # a KV head with both heads' lanes) and, for the dense row's sake, the
+    # first dense_len = 32 positions, all for both rows, two KV heads
+    assert counts["sparse_read_positions"] == 2 * 2 * 4 * 2 * (
+        16 + 8 + 2 * 2 * 8 + 32)
+    assert counts["sparse_read_positions"] > counts[
+        "sparse_attended_positions"]
+
+
+def test_decode_window_through_the_kernel_gives_the_same_tokens(monkeypatch):
+    """The decode window as a TPU lowers it, chosen here for a CPU run
+    (the sortless choice and the kernel through the Pallas interpreter,
+    steered in the test as ``test_kv_cache_layout`` steers the paged
+    read): the tokens and the attended count of the gathered path, and
+    ``sparse_read_positions`` says what the kernel copied."""
+    from deeplearning4j_tpu.optimize import aot_cache
+
+    def window():
+        aot_cache.clear()       # the same graph, lowered the other way
+        zoo, net, _ = _net(_cfg("sparse"))
+        dec = zoo.decoder(net, max_batch=2, kv_bucket_min=128,
+                          prompt_bucket_min=16)
+        state = dec.new_state(128)
+        state = dict(state, active=jnp.asarray([True, True]),
+                     positions=jnp.asarray([40, 70], jnp.int32),
+                     max_new=jnp.asarray([99, 99], jnp.int32))
+        _, toks, _, counts = dec.decode_fn(128, 4)(net.params, state)
+        return np.asarray(toks), dict(zip(dec.counter_names,
+                                          np.asarray(counts).tolist()))
+
+    want, gathered = window()
+    kernel, choose = block_sparse.sparse_read_attention, \
+        jax.lax.platform_dependent
+    monkeypatch.setattr(block_sparse, "sparse_read_applies",
+                        lambda *a: True)
+    monkeypatch.setattr(
+        block_sparse, "sparse_read_attention",
+        lambda *a, **kw: kernel(*a, **{**kw, "interpret": True}))
+    monkeypatch.setattr(
+        jax.lax, "platform_dependent",
+        lambda *a, default=None, **per: (
+            per["tpu"](*a) if getattr(per.get("tpu"), "__name__", "")
+            == "in_place" else choose(*a, default=default, **per)))
+    got, in_place = window()
+    aot_cache.clear()
+    np.testing.assert_array_equal(got, want)
+    for name in ("sparse_attended_positions", "sparse_context_positions",
+                 "sparse_dense_fallback_queries"):
+        assert in_place[name] == gathered[name], name
+    # two layers, two rows, four steps, two KV heads: the window from its
+    # block's start (16 + 8), the first block, two chosen blocks a head
+    assert in_place["sparse_read_positions"] == 2 * 2 * 4 * 2 * (
+        24 + 8 + 2 * 8)
+    assert gathered["sparse_read_positions"] == 2 * 2 * 4 * 2 * (
+        16 + 8 + 2 * 2 * 8)
 
 
 def test_engine_publishes_state_gauges_and_counters(served):
@@ -444,8 +687,10 @@ def test_engine_publishes_state_gauges_and_counters(served):
     for kind in ("kv", "compressed_keys", "recurrent"):
         assert f'dl4j_gen_state_bytes{{kind="{kind}"}}' in text
     for name in ("sparse_attended_positions", "sparse_context_positions",
-                 "sparse_dense_fallback_queries", "recurrent_state_updates"):
+                 "sparse_dense_fallback_queries", "sparse_read_positions",
+                 "recurrent_state_updates"):
         assert f"dl4j_{name}_total" in text
+    assert eng.stats()["layer_counts"]["sparse_read_positions"] > 0
     spans = [e for e in telemetry.spans.events()
              if e["name"] in ("gen.prefill", "gen.prefill.launch")]
     assert spans and all(

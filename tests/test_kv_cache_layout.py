@@ -7,7 +7,8 @@ one layout at rest and in the loop, the cache written by
 ``dynamic-update-slice`` and read by the paged kernel and by nothing else)
 with no chip attached; and the hybrid decoder's decode window, prompt and
 join compiled the same way (each kind of state, KV, compressed keys,
-recurrent, taken whole by its write and its layer's read alone), and the
+recurrent, taken whole by its write and its layer's read alone; the decode
+window with no ``sort`` and no ``gather``), and the
 routed-experts decoder's (a full layer's bucket, a window layer's ring;
 no ``[tokens, experts, hidden]`` array in the prompt walk).
 
@@ -684,13 +685,15 @@ def hybrid_programs(one_chip):
 # that kind as an operand, and how much of it they may return
 _WRITE = ("dynamic-update-slice", "whole")
 _HYBRID_READERS = {
-    # the step's token write; the reads are the compressed key's window,
-    # the local window, the first blocks and the dense branch's prefix
-    # (slices), and the chosen blocks (ONE gather of whole 256-lane rows:
-    # a slice of a KV head's lanes before it split the cache by head, and
-    # a vmapped dynamic_slice relaid it, 7 ms a step each at 32 k)
+    # the step's token write; the reads are the compressed key's window
+    # (a dynamic slice of 32 positions), the dense branch's prefix (a
+    # slice, inside its conditional) and the selection's ONE kernel, which
+    # takes the caches where they lie (PR 34: before it two window slices
+    # and a gather of whole 256-lane rows a cache; a slice of a KV head's
+    # lanes before the gather split the cache by head, and a vmapped
+    # dynamic_slice relaid it, 7 ms a step each at 32 k)
     ("decode", "kv"): {_WRITE, ("dynamic-slice", "part"), ("slice", "part"),
-                       ("gather", "part")},
+                       ("custom-call", "part")},
     # the compressed key this token completes (read the old, write); the
     # selection scores EVERY live compressed key, in float32 at HIGHEST:
     # the convert and the maximum are the matrix unit's operand split
@@ -731,6 +734,35 @@ def test_hybrid_programs_touch_each_state_only_to_write_and_read_it(
         found - _HYBRID_READERS[program, kind])
     # the write and a read are really there to be seen
     assert found >= _HYBRID_READERS[program, kind] & {_WRITE} and found
+
+
+def test_hybrid_decode_window_neither_sorts_nor_gathers(hybrid_programs):
+    """The decode window compiled for the v5e chooses its blocks without a
+    ``sort`` and reads them without a ``gather`` or a blocked view of a
+    cache: the program's one Mosaic kernel is the selection's read, once
+    in the step's body (a loop of K steps), and the test above says it
+    takes the KV caches as they stand; ``top_k`` and the gathered read
+    are what a CPU lowers to (``tests/test_hybrid_decoder.py``)."""
+    txt, shapes = hybrid_programs["decode"]
+    (kv,) = shapes["kv"]
+    b, n, e = re.findall(r"\d+", kv[kv.index("["):])
+    blocked = f"bf16[{b},{int(n) // 64},64,{e}]"    # the gathered read's view
+    ops = {}
+    for line in txt.splitlines():
+        mo = _INSTRUCTION.match(line)
+        if mo:
+            ops.setdefault(mo.group(3), []).append(line)
+    assert "sort" not in ops
+    # the one gather left is the embedding's
+    assert not [line for line in ops.get("gather", ())
+                if kv in line or blocked in line]
+    assert blocked not in txt
+    assert not re.search(r"custom_call_target=\"TopK\"", txt)
+    kernels = [line for line in txt.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 1 and "sparse_read_attention" in kernels[0]
+    # and the prompt walk keeps its top_k: a prompt's choice is not touched
+    assert re.search(r"\bsort\(|TopK", hybrid_programs["prompt"][0])
 
 
 # --- the routed-experts decoder: a bucket, a ring, grouped experts ----------

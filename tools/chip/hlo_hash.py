@@ -89,7 +89,8 @@ def main(argv):
     root = os.path.abspath(argv[0] if argv else here)
     sys.path.insert(0, root)
     os.chdir(root)
-    for name in ("gpt2-large-serve", "minicpm-sala-serve"):
+    for name in ("gpt2-large-serve", "minicpm-sala-serve",
+                 "trinity-mini-serve"):
         with open(os.path.join(root, "benchmarks", "configs",
                                name + ".json")) as f:
             cfg = json.load(f)
