@@ -30,7 +30,8 @@ The per-layer cache interface (``nn.decoding`` walks it; ``docs/serving.md``):
   sums over the active rows.
 - ``cache_grow(cache, length)``, ``cache_release(cache, keep)``.
 - ``cache_kinds``: cache leaf -> the kind of state it is (``kv``,
-  ``kv_ring``, ``compressed_keys``, ``recurrent``); ``cache_counters``:
+  ``kv_ring``, ``compressed_keys``, ``recurrent``, ``conv_window``: a
+  state-space mixer's two, ``conf/layers_ssm.py``); ``cache_counters``:
   the names of the counts ``cache_step`` returns.
 
 A layer WITHOUT per-row state that still couples the rows of a batch (the
@@ -244,18 +245,38 @@ class GatedFeedForwardLayer(BaseLayer):
 @dataclasses.dataclass
 class LMHeadLayer(OutputLayer):
     """Vocabulary logits ``logit_scale * (x W)`` (muP's
-    ``dim_model_base / hidden``), no bias by default."""
+    ``dim_model_base / hidden``), no bias by default. ``tied_to`` names
+    the embedding vertex whose table ``[vocabulary, features]`` IS the
+    head (``x E^T``): the layer then has no parameters of its own, the
+    graph and the decoder's walk hand it that vertex's
+    (``ComputationGraph._params_of``), and a gradient step updates the
+    one matrix through both uses."""
 
     has_bias: bool = False
     logit_scale: float = 1.0
     weight_dtype: str = ""
+    tied_to: str = ""
 
     def init(self, key, input_type, dtype=jnp.float32):
+        if self.tied_to:
+            return {}
         return super().init(key, input_type,
                             _wdtype(self.weight_dtype, dtype))
 
+    def param_order(self):
+        return [] if self.tied_to else super().param_order()
+
+    def regularized_param_keys(self):
+        return [] if self.tied_to else super().regularized_param_keys()
+
     def pre_output(self, params, x):
-        y = _dot(x, params["W"])
+        w = params["W"]
+        if self.tied_to:
+            y = jax.lax.dot_general(
+                x.astype(w.dtype), w, (((x.ndim - 1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        else:
+            y = _dot(x, w)
         if self.has_bias:
             y = y + params["b"].astype(jnp.float32)
         return y * self.logit_scale
@@ -748,3 +769,35 @@ class GatedAttentionLayer(_GatedMixer):
 
     def cache_release(self, cache, keep):
         return cache
+
+
+@serde.register
+@dataclasses.dataclass
+class GroupedAttentionLayer(GatedAttentionLayer):
+    """:class:`GatedAttentionLayer` with neither the q/k norm nor the
+    output gate: ``q = Wq u``, ``k = Wk u``, ``v = Wv u``, causal softmax
+    attention over grouped KV heads, ``Wo``. The switches (``window``,
+    ``rope_theta``) and every cache method are the parent's."""
+
+    def init(self, key, input_type, dtype=jnp.float32):
+        wd = _wdtype(self.weight_dtype, dtype)
+        n_in, e = _as_ff_size(input_type), self.n_heads * self.head_size
+        ks = jax.random.split(key, 4)
+        return {"Wq": _matrix(self, ks[0], (n_in, e), wd),
+                "Wk": _matrix(self, ks[1], (n_in, self._kv_width()), wd),
+                "Wv": _matrix(self, ks[2], (n_in, self._kv_width()), wd),
+                "Wo": _matrix(self, ks[3], (e, self.n_out), wd)}
+
+    def param_order(self):
+        return ["Wq", "Wk", "Wv", "Wo"]
+
+    def regularized_param_keys(self):
+        return ["Wq", "Wk", "Wv", "Wo"]
+
+    def _heads(self, params, u, name, n):
+        return _dot(u, params["W" + name]).reshape(
+            u.shape[:-1] + (n, self.head_size))
+
+    def _finish(self, params, u, o):
+        o = o.reshape(o.shape[:-2] + (-1,))
+        return self.activation.apply(_dot(o, params["Wo"]) * self.out_scale)

@@ -1,0 +1,245 @@
+"""A state-space ("Mamba") mixer on the per-layer cache interface of
+``conf/layers_hybrid.py``: an input projection into ``x`` and a gate ``z``,
+a depthwise causal convolution over ``x`` that keeps its last
+``d_conv - 1`` inputs, a selective scan (``ops/selective_scan``) whose
+step, ``B`` and ``C`` come from the convolved input, the gate, an output
+projection.
+
+TWO kinds of per-row state, both float32 and neither depending on the
+bucket: the scan's state ``[rows, d_state, d_inner]`` (kind
+``recurrent``) and the convolution's last ``d_conv - 1`` inputs (kind
+``conv_window``), a RING ``[rows, (d_conv - 1) * d_inner]``: the input of
+position ``p`` lies in slot ``p mod (d_conv - 1)``, so a decode step
+overwrites the oldest input where it lies and moves nothing (a window
+kept oldest-first is shifted every step: the compiler copied every
+layer's whole window a step to do it; and flat, so that no dimension of 3
+meets the TPU's tiles of 8 x 128). What ``cache_prefill`` owes a
+RIGHT-padded row (``docs/serving.md``): the scan's state after the row's
+last REAL token (a padded position has a step of 0: the state stands
+still) and the row's last ``d_conv - 1`` REAL inputs, each in its
+position's slot (zeros where the prompt is shorter), not the bucket's
+tail.
+
+Types as ``conf/layers_hybrid.py``: matrices in ``weight_dtype``, every
+product rounds its left operand to the matrix's type and accumulates in
+float32; the convolution's taps, the inner norms, the step's bias, ``A``,
+``D``, the scan and both states float32.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+
+from deeplearning4j_tpu import serde
+from deeplearning4j_tpu.conf import inputs as it
+from deeplearning4j_tpu.conf.layers import BaseLayer, _as_ff_size
+from deeplearning4j_tpu.conf.layers_hybrid import (
+    _dot,
+    _join_rows,
+    _matrix,
+    _merge_spans,
+    _split_spans,
+    _token_spans,
+    _wdtype,
+    rms_norm,
+)
+from deeplearning4j_tpu.ops.selective_scan import (
+    selective_scan,
+    selective_scan_loop,
+    selective_scan_step,
+)
+
+
+SSM_TOKEN_SPAN = 2048      # positions MambaMixerLayer projects and scans at a time
+
+
+@serde.register
+@dataclasses.dataclass
+class MambaMixerLayer(BaseLayer):
+    """``[x ; z] = W_in u``; ``x~_t = silu(b_c + sum_j w_c[j] x_{t-K+1+j})``
+    over ``K = d_conv`` taps; ``[delta ; B ; C] = W_x x~``, each RMS-normed
+    with a gain; ``dt = softplus(W_dt delta +
+    b_dt)``; ``A = -exp(A_log)``; the selective scan; ``W_out (y *
+    silu(z))``. The mask of a sequence is taken to be a RIGHT padding
+    (each row's real positions first)."""
+
+    n_out: int = 0
+    d_inner: int = 0
+    d_state: int = 16
+    d_conv: int = 4
+    dt_rank: int = 0
+    eps: float = 1e-6
+    out_scale: float = 1.0
+    weight_dtype: str = ""
+
+    uses_mask = True
+    cache_kinds = {"state": "recurrent", "conv": "conv_window"}
+    cache_counters = ("ssm_state_updates",)
+
+    def output_type(self, input_type):
+        ts = (input_type.timesteps if isinstance(input_type, it.Recurrent)
+              else -1)
+        return it.Recurrent(size=self.n_out, timesteps=ts)
+
+    def streaming_safe(self) -> bool:
+        return False
+
+    def init(self, key, input_type, dtype=jnp.float32):
+        """Matrices by the layer's initializer; what decides how long the
+        state remembers as the published initialisation has it: ``A_log =
+        log(1..d_state)`` a channel, ``D = 1``, ``b_dt`` the inverse
+        softplus of steps log-uniform in [1e-3, 1e-1]."""
+        n_in, d, n, r = (_as_ff_size(input_type), self.d_inner,
+                         self.d_state, self.dt_rank)
+        wd = _wdtype(self.weight_dtype, dtype)
+        ks = jax.random.split(key, 6)
+        step = jnp.exp(jax.random.uniform(
+            ks[5], (d,), jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))
+        taps = jax.random.uniform(ks[1], (self.d_conv + 1, d), jnp.float32,
+                                  -1.0, 1.0) / self.d_conv ** 0.5
+        return {"W_in": _matrix(self, ks[0], (n_in, 2 * d), wd),
+                "conv_w": taps[:-1], "conv_b": taps[-1],
+                "W_x": _matrix(self, ks[2], (d, r + 2 * n), wd),
+                "W_dt": _matrix(self, ks[3], (r, d), wd),
+                "b_dt": step + jnp.log(-jnp.expm1(-step)),
+                "A_log": jnp.broadcast_to(jnp.log(jnp.arange(
+                    1, n + 1, dtype=jnp.float32))[:, None], (n, d)),
+                "D": jnp.ones((d,), jnp.float32),
+                "W_out": _matrix(self, ks[4], (d, self.n_out), wd),
+                "dt_norm": jnp.ones((r,), jnp.float32),
+                "b_norm": jnp.ones((n,), jnp.float32),
+                "c_norm": jnp.ones((n,), jnp.float32)}
+
+    def param_order(self):
+        return ["W_in", "conv_w", "conv_b", "W_x", "W_dt", "b_dt", "A_log",
+                "D", "W_out", "dt_norm", "b_norm", "c_norm"]
+
+    def regularized_param_keys(self):
+        return ["W_in", "W_x", "W_dt", "W_out"]
+
+    # --- the mathematics -------------------------------------------------------
+    def _project(self, params, u):
+        with jax.named_scope("ssm.in_proj"):
+            xz = _dot(u, params["W_in"])
+        return xz[..., :self.d_inner], xz[..., self.d_inner:]
+
+    def _scan_inputs(self, params, xc):
+        """The convolved input ``xc`` -> ``(dt, B, C)`` of the scan."""
+        r, n = self.dt_rank, self.d_state
+        dbc = _dot(xc, params["W_x"])
+        delta, b, c = dbc[..., :r], dbc[..., r:r + n], dbc[..., r + n:]
+        delta = rms_norm(delta, params["dt_norm"], self.eps)
+        b = rms_norm(b, params["b_norm"], self.eps)
+        c = rms_norm(c, params["c_norm"], self.eps)
+        dt = jax.nn.softplus(_dot(delta, params["W_dt"]) + params["b_dt"])
+        return dt, b, c
+
+    def _finish(self, params, y, z):
+        with jax.named_scope("ssm.out_proj"):
+            return self.activation.apply(
+                _dot(y * jax.nn.silu(z), params["W_out"]) * self.out_scale)
+
+    def _sequence(self, params, u, mask, state, conv, scan):
+        """``u: [batch, time, features]`` from the scan's ``state`` and the
+        convolution's last inputs ``conv: [batch, d_conv - 1, d_inner]``,
+        oldest first: a ``lax.scan`` over spans of ``SSM_TOKEN_SPAN``
+        positions, both states its carry. Returns ``(y, state, conv)``."""
+        b, t, _ = u.shape
+        k = self.d_conv - 1
+        n, span = _token_spans(t, SSM_TOKEN_SPAN)
+        mask = (jnp.ones((b, t), jnp.float32) if mask is None
+                else (jnp.asarray(mask) > 0).astype(jnp.float32))
+        a = -jnp.exp(params["A_log"])
+
+        def body(carry, xs):
+            h, tail = carry
+            uc, mc = xs
+            x, z = self._project(params, uc)
+            with jax.named_scope("ssm.conv"):
+                padded = jnp.concatenate([tail, x], axis=1)  # [b, k + span, d]
+                xc = params["conv_b"] + sum(
+                    params["conv_w"][j] * padded[:, j:j + span]
+                    for j in range(self.d_conv))
+                xc = jax.nn.silu(xc)
+                # the last k REAL inputs: a right-padded row's real
+                # positions are the span's first sum(mask)
+                real = jnp.sum(mc, axis=1).astype(jnp.int32)
+                tail = jnp.take_along_axis(
+                    padded, (real[:, None] + jnp.arange(k))[:, :, None],
+                    axis=1)
+            dt, bm, cm = self._scan_inputs(params, xc)
+            with jax.named_scope("ssm.scan"):
+                y, h = scan(xc, dt, a, bm, cm, params["D"], mc, h)
+            return (h, tail), self._finish(params, y, z) * mc[:, :, None]
+
+        (state, conv), y = jax.lax.scan(
+            body, (state, conv),
+            (_split_spans(u, n, span), _split_spans(mask, n, span)))
+        return _merge_spans(y), state, conv
+
+    def _zeros(self, batch):
+        return (jnp.zeros((batch, self.d_state, self.d_inner), jnp.float32),
+                jnp.zeros((batch, self.d_conv - 1, self.d_inner),
+                          jnp.float32))
+
+    def forward(self, params, state, x, train=False, rng=None, mask=None):
+        """The differentiable walk (``selective_scan_loop``): the kernel
+        has no backward."""
+        x = self._dropout_input(x, train, rng)
+        y, _, _ = self._sequence(params, x, mask, *self._zeros(x.shape[0]),
+                                 selective_scan_loop)
+        return y, state
+
+    # --- the cache interface ------------------------------------------------
+    def cache_init(self, batch, length, n_in, dtype=jnp.float32):
+        state, conv = self._zeros(batch)
+        return {"state": state, "conv": conv.reshape(batch, -1)}
+
+    def cache_prefill(self, params, x, key_mask=None, dtype=jnp.float32,
+                      use_kernels=False):
+        b, k, d = x.shape[0], self.d_conv - 1, self.d_inner
+        y, state, tail = self._sequence(
+            params, x, key_mask, *self._zeros(b), selective_scan)
+        lengths = (jnp.full((b,), x.shape[1], jnp.int32) if key_mask is None
+                   else jnp.sum(key_mask > 0, axis=1).astype(jnp.int32))
+        # oldest-first -> the ring: slot s holds the input of the position
+        # p = s (mod k) among the last k, the tail's entry (s - length) mod k
+        entry = (jnp.arange(k)[None, :] - lengths[:, None]) % k
+        ring = jnp.take_along_axis(tail, entry[:, :, None], axis=1)
+        return y, {"state": state, "conv": ring.reshape(b, k * d)}
+
+    def cache_join(self, cache, block, rows, length):
+        return {n: _join_rows(cache[n], block[n], rows)
+                for n in ("state", "conv")}
+
+    def cache_step(self, params, x, cache, positions, active=None):
+        with jax.named_scope("ssm.step"):
+            xi, z = self._project(params, x)
+            k, d = self.d_conv - 1, self.d_inner
+            ring = cache["conv"]
+            # the ring's slot of each lane, and how many positions back
+            # from the oldest input (slot positions mod k) it lies
+            slot = jax.lax.broadcasted_iota(jnp.int32, ring.shape, 1) // d
+            age = (slot - (positions % k)[:, None]) % k
+            taps = sum(jnp.where(age == j, jnp.tile(params["conv_w"][j], k),
+                                 0.0) for j in range(k)) * ring
+            xc = jax.nn.silu(params["conv_b"] + params["conv_w"][k] * xi + sum(
+                taps[:, j * d:(j + 1) * d] for j in range(k)))
+            ring = jnp.where(age == 0, jnp.tile(xi, (1, k)), ring)
+            dt, b, c = self._scan_inputs(params, xc)
+            y, state = selective_scan_step(
+                xc, dt, -jnp.exp(params["A_log"]), b, c, params["D"],
+                cache["state"])
+        counts = {"ssm_state_updates": jnp.ones_like(positions)}
+        return (self._finish(params, y, z),
+                {"state": state, "conv": ring}, counts)
+
+    def cache_grow(self, cache, length):
+        return cache
+
+    def cache_release(self, cache, keep):
+        return {"state": jnp.where(keep[:, None, None], cache["state"], 0),
+                "conv": jnp.where(keep[:, None], cache["conv"], 0)}

@@ -37,7 +37,9 @@ cache interface of ``conf/layers_hybrid.py`` (``cache_init``,
 ``cache_prefill``, ``cache_join``, ``cache_step``, ``cache_grow``,
 ``cache_release``) is served: KV buffers (``SelfAttentionLayer``), KV
 buffers beside compressed keys (``BlockSparseAttentionLayer``), a
-fixed-size recurrent state (``LightningAttentionLayer``), side by side in
+fixed-size recurrent state (``LightningAttentionLayer``), a scan's state
+beside a convolution's window (``conf.layers_ssm.MambaMixerLayer``), side
+by side in
 the one donated state pytree, each in its own type. The suffix walk (the
 prefix cache) needs ``prefill_suffix`` on every such layer and refuses a
 graph that has a layer without it, by name.
@@ -171,7 +173,8 @@ class TransformerDecoder:
     def __init__(self, net, max_batch: int = 8, max_len: Optional[int] = None,
                  kv_bucket_min: int = 32, prompt_bucket_min: int = 8,
                  pad_id: int = 0, cache_dtype=None,
-                 join_bucket_max: Optional[int] = None):
+                 join_bucket_max: Optional[int] = None,
+                 prompt_bucket_max: Optional[int] = None):
         self._net = net
         if net.params is None:
             net.init()
@@ -250,8 +253,12 @@ class TransformerDecoder:
                            else min(max_len, derived_max))
         self.kv_ladder = pow2_ladder(min(kv_bucket_min, self.max_len),
                                      self.max_len)
-        self.prompt_ladder = pow2_ladder(min(prompt_bucket_min, self.max_len),
-                                         self.max_len)
+        # the longest prompt served (default max_len): a deployment whose
+        # prompts are short beside its answers compiles no prompt or join
+        # program for buckets no request of its reaches
+        longest = min(int(prompt_bucket_max or self.max_len), self.max_len)
+        self.prompt_ladder = pow2_ladder(min(prompt_bucket_min, longest),
+                                         longest)
         self.join_ladder = pow2_ladder(
             1, min(int(join_bucket_max or self.max_batch), self.max_batch))
         # per-row counts the cached layers report at a step (summed over
@@ -315,8 +322,9 @@ class TransformerDecoder:
 
     def state_bytes(self, s: int) -> Dict[str, int]:
         """Bytes the caches hold at KV bucket ``s``, by kind of state
-        (``kv``, ``compressed_keys``, ``recurrent``): what each layer's
-        ``cache_kinds`` calls its leaves."""
+        (``kv``, ``kv_ring``, ``compressed_keys``, ``recurrent``,
+        ``conv_window``): what each layer's ``cache_kinds`` calls its
+        leaves."""
         out: Dict[str, int] = {}
         for name, leaves in self._kv_struct(self.max_batch, s).items():
             kinds = self._layer(name).cache_kinds
@@ -395,7 +403,8 @@ class TransformerDecoder:
                 if lengths is not None:
                     idx = jnp.maximum(lengths - 1, 0)[:, None, None]
                     x = jnp.take_along_axis(x, idx, axis=1)[:, 0]
-                logits = self._layer(name).pre_output(params[name], x)
+                logits = self._layer(name).pre_output(
+                    self._net._params_of(params, name), x)
                 continue
             elif hasattr(self._layer(name), "forward_live"):
                 y, own = self._layer(name).forward_live(
@@ -780,6 +789,10 @@ class TransformerDecoder:
             raise ValueError(
                 f"prompt ({len(toks)}) + max_new_tokens ({max_new}) "
                 f"exceeds max_len={self.max_len}")
+        if len(toks) > self.prompt_ladder[-1]:
+            raise ValueError(
+                f"prompt ({len(toks)}) exceeds the largest prompt bucket "
+                f"{self.prompt_ladder[-1]}")
         return toks
 
     def generate(self, tokens, max_new: int, eos_id: Optional[int] = None,

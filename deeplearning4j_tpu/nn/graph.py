@@ -82,6 +82,12 @@ class ComputationGraph(nn_io.LazyScoreMixin):
         self._base_key = jax.random.PRNGKey(conf.seed)
         self._topo = conf.topo_order()
         self._vmap = conf.vertex_map()
+        # a vertex whose layer is tied to another's parameters (a language
+        # model's head that IS its embedding): name -> the vertex it reads
+        self._tied = {
+            name: spec.vertex.layer.tied_to
+            for name, spec in self._vmap.items()
+            if getattr(getattr(spec.vertex, "layer", None), "tied_to", "")}
         # feature-mask propagation: see nn_io.propagate_mask (reference
         # ComputationGraph feedForwardMaskArrays) — decided per vertex from
         # TRACED output shapes in _forward, so variable-length configs
@@ -109,6 +115,12 @@ class ComputationGraph(nn_io.LazyScoreMixin):
 
     def _input_type_of(self, src: str, types: Dict[str, object]):
         return types[src]
+
+    def _params_of(self, params, name: str):
+        """The parameters vertex ``name`` computes with: its own, or those
+        of the vertex its layer is tied to (an entry under a tied vertex's
+        own name is ``_fwd_cast``'s: the master it keeps for the head)."""
+        return params.get(name) or params.get(self._tied.get(name, name), {})
 
     def set_listeners(self, *listeners: TrainingListener):
         self.listeners = list(listeners)
@@ -170,7 +182,7 @@ class ComputationGraph(nn_io.LazyScoreMixin):
             mask = None
             for m in in_masks:
                 mask = m if mask is None else jnp.minimum(mask, m)
-            p = params.get(name, {})
+            p = self._params_of(params, name)
             s = state.get(name, {})
             vrng = jax.random.fold_in(rng, i) if rng is not None else None
             kw = ({"mask": mask} if mask is not None
@@ -227,8 +239,9 @@ class ComputationGraph(nn_io.LazyScoreMixin):
         cast = nn_io.cast_floats(params, self._cdtype)
         if full:
             for name in self.conf.network_outputs:
-                if name in params:
-                    cast[name] = params[name]
+                master = self._params_of(params, name)
+                if master:
+                    cast[name] = master
         return cast, nn_io.cast_floats(tuple(features), self._cdtype)
 
     def _loss(self, params, state, features: Sequence, labels: Sequence,
@@ -249,8 +262,8 @@ class ComputationGraph(nn_io.LazyScoreMixin):
             # output-vertex activation + loss in the storage dtype on the
             # f32 master params (bf16 log-softmax loses gradient bits)
             x = acts[spec.inputs[0]].astype(self._dtype)
-            loss = loss + spec.vertex.score(params.get(spec.name, {}), x,
-                                            labels[i], lmasks[i])
+            loss = loss + spec.vertex.score(
+                self._params_of(params, spec.name), x, labels[i], lmasks[i])
         loss = loss + self._regularization_score(params)
         # auxiliary TRAIN-time loss terms layers stash in their state
         # (MoE load-balance); eval scores must not pick up the stale

@@ -330,6 +330,10 @@ class GenerationEngine:
         # host wall time of the prefill launches and of the decode
         # windows, each INCLUDING the wait for the device's result
         self._prefill_seconds = 0.0
+        # prompt tokens the prefill launches walked: the prompts' own, and
+        # the launches' buckets (rows x positions, padding included)
+        self._prefill_live_tokens = 0
+        self._prefill_bucket_tokens = 0
         self._decode_seconds = 0.0
         # what the cached layers counted in-graph, summed over the windows
         self._layer_counts = dict.fromkeys(self._dec.counter_names, 0)
@@ -501,7 +505,10 @@ class GenerationEngine:
         before); a prefill's leaves out what the loop did between its
         launch and the read of its first tokens. The four ``windows_*`` /
         ``joins_ahead_total`` counts say how often the loop ran ahead
-        (class docstring)."""
+        (class docstring). ``prefill_live_tokens_total`` over
+        ``prefill_bucket_tokens_total``: the prompts' own tokens over the
+        positions their launches walked (rows x prompt bucket: a layer
+        that scans a prompt walks its padding too)."""
         with self._cond:
             out = {
                 "rows": self.config.max_batch,
@@ -519,6 +526,8 @@ class GenerationEngine:
                 "joins_ahead_total": self._joins_ahead_total,
                 "windows_empty_total": self._windows_empty_total,
                 "prefill_seconds": round(self._prefill_seconds, 4),
+                "prefill_live_tokens_total": self._prefill_live_tokens,
+                "prefill_bucket_tokens_total": self._prefill_bucket_tokens,
                 "decode_seconds": round(self._decode_seconds, 4),
                 "layer_counts": dict(self._layer_counts),
             }
@@ -686,9 +695,12 @@ class GenerationEngine:
         t0 = time.monotonic()
         tp = bucket_for(max(r.n for r in joins), self._dec.prompt_ladder)
         bp = bucket_for(len(joins), self._dec.join_ladder)
+        live = sum(r.n for r in joins)
+        self._prefill_live_tokens += live
+        self._prefill_bucket_tokens += bp * tp
         with self._span("gen.prefill", kind="cold", joins=len(joins),
-                        prompt_bucket=tp, rows=bp,
-                        live_tokens=sum(r.n for r in joins),
+                        prompt_bucket=tp, rows=bp, live_tokens=live,
+                        bucket_tokens=bp * tp,
                         state_kinds=self._state_kinds):
             with self._span("gen.prefill.stage"):
                 self._grow_to(max(tp, self._S))
@@ -756,9 +768,12 @@ class GenerationEngine:
         # width per (ts, tpre, s) keeps the prefix warm set small, and
         # padding rows scatter out of bounds (dropped)
         bp = cfg.max_batch
+        live = sum(r.n - r.prefix_len for r in joins)
+        self._prefill_live_tokens += live
+        self._prefill_bucket_tokens += bp * ts
         with self._span("gen.prefill", kind="suffix", joins=len(joins),
-                        prompt_bucket=ts, rows=bp,
-                        live_tokens=sum(r.n - r.prefix_len for r in joins)):
+                        prompt_bucket=ts, rows=bp, live_tokens=live,
+                        bucket_tokens=bp * ts):
             with self._span("gen.prefill.stage"):
                 self._grow_to(max(max_m + ts, self._S))
                 suffix = np.full((bp, ts), self._dec.pad_id, np.int32)

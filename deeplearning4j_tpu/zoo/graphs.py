@@ -1100,12 +1100,19 @@ class HybridDecoderLM(GraphZooModel):
     neither rotating nor bounded: the two kinds pair the layer's two
     switches, ``window`` and ``rope_theta``, as the one family that uses
     them does; a full layer that rotates needs a third name here, the
-    layer itself keeps the switches apart). Scaled token embedding, then per
+    layer itself keeps the switches apart), ``"plain-attn"`` (grouped-query
+    attention with neither q/k norm, gate, rotation nor window,
+    ``GroupedAttentionLayer``) or ``"mamba"`` (a state-space mixer: a
+    selective scan behind a causal convolution, two kinds of per-row
+    state, ``conf.layers_ssm.MambaMixerLayer``, its sizes in ``mamba``).
+    Scaled token embedding, then per
     layer ``h = x + c Mixer(RMSNorm(x))``, ``x' = h + c FFN(RMSNorm(h))``
     with a gated feed-forward and ``c = scale_depth / sqrt(depth_for_scale)``,
-    a final RMS norm and an untied head whose logits are divided by
-    ``hidden / dim_model_base``. No position embedding: the lightning and
-    window layers rotate, the others take order from causality alone.
+    a final RMS norm and a head whose logits are divided by
+    ``hidden / dim_model_base``, untied unless ``tie_head`` (the head is
+    then the embedding's matrix). No position embedding: the lightning and
+    window layers rotate, the others take order from causality alone or
+    from the state-space layers around them.
 
     ``ffn_types[i]`` is ``"dense"`` (default) or ``"moe"``: dropless routed
     experts beside a shared one (``conf.layers_moe.RoutedExpertsLayer``,
@@ -1119,7 +1126,8 @@ class HybridDecoderLM(GraphZooModel):
     selection sizes. ``weight_dtype`` / ``cache_dtype`` are the matrices'
     and the KV caches' types; the recurrent state is float32."""
 
-    MIXERS = ("lightning-attn", "minicpm4", "window-attn", "full-attn")
+    MIXERS = ("lightning-attn", "minicpm4", "window-attn", "full-attn",
+              "plain-attn", "mamba")
 
     def __init__(self, vocab_size: int, hidden: int, ffn_dim: int,
                  mixer_types, n_heads: int, head_dim: int,
@@ -1133,7 +1141,8 @@ class HybridDecoderLM(GraphZooModel):
                  cache_dtype: str = "", seed: int = 123,
                  updater: IUpdater | None = None, window: int = 0,
                  ffn_types=None, moe: dict | None = None,
-                 post_norms: bool = False):
+                 post_norms: bool = False, mamba: dict | None = None,
+                 tie_head: bool = False):
         self.mixer_types = list(mixer_types)
         unknown = sorted(set(self.mixer_types) - set(self.MIXERS))
         if unknown:
@@ -1163,6 +1172,8 @@ class HybridDecoderLM(GraphZooModel):
                              "layer")
         self.moe = dict(moe or {})
         self.post_norms = post_norms
+        self.mamba = dict(mamba or {})
+        self.tie_head = tie_head
         self.max_len = max_len
         self.weight_dtype, self.cache_dtype = weight_dtype, cache_dtype
         self.seed = seed
@@ -1173,6 +1184,7 @@ class HybridDecoderLM(GraphZooModel):
             BlockSparseAttentionLayer,
             GatedAttentionLayer,
             GatedFeedForwardLayer,
+            GroupedAttentionLayer,
             LightningAttentionLayer,
             LMHeadLayer,
             ResidualAddVertex,
@@ -1180,6 +1192,7 @@ class HybridDecoderLM(GraphZooModel):
             ScaledEmbeddingLayer,
         )
         from deeplearning4j_tpu.conf.layers_moe import RoutedExpertsLayer
+        from deeplearning4j_tpu.conf.layers_ssm import MambaMixerLayer
 
         e, wd, c = self.hidden, self.weight_dtype, self.residual_scale
 
@@ -1217,6 +1230,15 @@ class HybridDecoderLM(GraphZooModel):
                     n_kv_heads=self.n_kv_heads, head_size=self.head_dim,
                     eps=self.eps, out_scale=c, weight_dtype=wd,
                     cache_dtype=self.cache_dtype, **self.sparse)
+            elif kind == "mamba":
+                mixer = MambaMixerLayer(n_out=e, eps=self.eps, out_scale=c,
+                                        weight_dtype=wd, **self.mamba)
+            elif kind == "plain-attn":
+                mixer = GroupedAttentionLayer(
+                    n_out=e, n_heads=self.n_heads,
+                    n_kv_heads=self.n_kv_heads, head_size=self.head_dim,
+                    eps=self.eps, out_scale=c, weight_dtype=wd,
+                    cache_dtype=self.cache_dtype)
             else:
                 windowed = kind == "window-attn"
                 mixer = GatedAttentionLayer(
@@ -1245,7 +1267,8 @@ class HybridDecoderLM(GraphZooModel):
         g.add_layer("output", LMHeadLayer(
             n_out=self.vocab_size, activation=Activation.SOFTMAX,
             loss_fn=LossMCXENT(), logit_scale=self.logit_scale,
-            weight_dtype=wd), "final_norm")
+            weight_dtype=wd, tied_to="embed" if self.tie_head else ""),
+            "final_norm")
         g.set_outputs("output")
         return g.build()
 

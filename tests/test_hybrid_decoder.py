@@ -938,4 +938,4 @@ def test_hybrid_conf_round_trips_through_json():
 
 def test_unknown_mixer_type_is_refused():
     with pytest.raises(ValueError, match="unknown mixer types"):
-        _zoo(_cfg("mixed"), mixer_types=["mamba"], layer_indices=[0])
+        _zoo(_cfg("mixed"), mixer_types=["hyena"], layer_indices=[0])

@@ -621,29 +621,13 @@ def _consumers(txt, shapes):
     return found
 
 
-@pytest.fixture(scope="module")
-def hybrid_programs(one_chip):
-    """Decode window, prompt and join of a two-layer ``HybridDecoderLM``
-    (one block-sparse layer, one lightning layer) compiled for the v5e
-    from avals: ``{program: (text, {kind of state: [shape, ...]})}``. The
-    caches' widths are the published ones per head (KV heads 2 x 128, a
-    recurrent state of 128 x 128 a head), so that they fill the (8, 128)
-    tiles as the cell's do (a toy width is relaid by the compiler whatever
-    the program says); the depth, the residual stream, the vocabulary and
-    the sparse sizes are small, and three rows make a cache's shape no
-    other array's."""
+def _compiled_programs(one_chip, zoo, b, s, tp):
+    """Decode window, prompt and join of ``zoo``'s decoder (``b`` rows, a
+    KV bucket of ``s``, a prompt bucket of ``tp``, one prompt a launch)
+    compiled for the v5e from avals: ``{program: (text, {kind of state:
+    {shape, ...}})}``."""
     from deeplearning4j_tpu.nn.graph import ComputationGraph
-    from deeplearning4j_tpu.zoo.graphs import HybridDecoderLM
 
-    b, s, tp = 3, 4096, 1024
-    zoo = HybridDecoderLM(
-        vocab_size=512, hidden=384, ffn_dim=768,
-        mixer_types=["minicpm4", "lightning-attn"], n_heads=4, head_dim=128,
-        n_kv_heads=2, lightning_heads=3, lightning_head_dim=128,
-        sparse={"kernel_size": 32, "kernel_stride": 16, "block_size": 64,
-                "window_size": 256, "init_blocks": 1, "topk": 4,
-                "dense_len": 512},
-        max_len=s, weight_dtype="bfloat16", cache_dtype="bfloat16")
     net = ComputationGraph(zoo.conf())
     net.params = jax.eval_shape(
         lambda: ComputationGraph(zoo.conf()).init().params)
@@ -679,6 +663,31 @@ def hybrid_programs(one_chip):
             lowering_platforms=("tpu",)).compile().as_text()
         out[program] = (_without_layout_constraints(txt), shapes)
     return out
+
+
+@pytest.fixture(scope="module")
+def hybrid_programs(one_chip):
+    """Decode window, prompt and join of a two-layer ``HybridDecoderLM``
+    (one block-sparse layer, one lightning layer) compiled for the v5e
+    from avals: ``{program: (text, {kind of state: [shape, ...]})}``. The
+    caches' widths are the published ones per head (KV heads 2 x 128, a
+    recurrent state of 128 x 128 a head), so that they fill the (8, 128)
+    tiles as the cell's do (a toy width is relaid by the compiler whatever
+    the program says); the depth, the residual stream, the vocabulary and
+    the sparse sizes are small, and three rows make a cache's shape no
+    other array's."""
+    from deeplearning4j_tpu.zoo.graphs import HybridDecoderLM
+
+    b, s, tp = 3, 4096, 1024
+    zoo = HybridDecoderLM(
+        vocab_size=512, hidden=384, ffn_dim=768,
+        mixer_types=["minicpm4", "lightning-attn"], n_heads=4, head_dim=128,
+        n_kv_heads=2, lightning_heads=3, lightning_head_dim=128,
+        sparse={"kernel_size": 32, "kernel_stride": 16, "block_size": 64,
+                "window_size": 256, "init_blocks": 1, "topk": 4,
+                "dense_len": 512},
+        max_len=s, weight_dtype="bfloat16", cache_dtype="bfloat16")
+    return _compiled_programs(one_chip, zoo, b, s, tp)
 
 
 # (program, kind of state) -> the operations that may take a whole array of
@@ -780,7 +789,6 @@ def routed_programs(one_chip):
     {kind of state: [shape, ...]})}``. KV heads 2 x 128 fill the tiles as
     the cell's 4 x 128 do; three rows make a cache's shape no other
     array's; the ring holds 256 slots, the bucket 4,096."""
-    from deeplearning4j_tpu.nn.graph import ComputationGraph
     from deeplearning4j_tpu.zoo.graphs import HybridDecoderLM
 
     b, s, tp = 3, 4096, _ROUTED["tokens"]
@@ -792,39 +800,7 @@ def routed_programs(one_chip):
              "n_shared_hidden": 128, "route_scale": 2.826},
         post_norms=True, n_heads=4, head_dim=128, n_kv_heads=2, window=256,
         max_len=s, weight_dtype="bfloat16", cache_dtype="bfloat16")
-    net = ComputationGraph(zoo.conf())
-    net.params = jax.eval_shape(
-        lambda: ComputationGraph(zoo.conf()).init().params)
-    net.state, net.opt_state = {}, {}
-    dec = zoo.decoder(net, max_batch=b, kv_bucket_min=s,
-                      prompt_bucket_min=tp, join_bucket_max=1)
-    on_chip = lambda tree: jax.tree_util.tree_map(  # noqa: E731
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
-        tree)
-    row = lambda dt, *tail: on_chip(  # noqa: E731
-        jax.ShapeDtypeStruct((1,) + tail, dt))
-    i32, req = row(jnp.int32), (row(jnp.int32), row(jnp.int32),
-                                row(jnp.float32), row(jnp.uint32, 2))
-    state, block = dec._struct_of(s), dec._kv_struct(1, tp)
-    table = {
-        "decode": (dec.decode_fn(s, 4), (net.params, state), state["caches"]),
-        "prompt": (dec.prompt_fn(tp, 1),
-                   (net.params, row(jnp.int32, tp), i32) + req, block),
-        "join": (dec.join_fn(s, tp, 1),
-                 (state, block, i32, i32, i32) + req + (row(jnp.bool_),),
-                 state["caches"]),
-    }
-    out = {}
-    for program, (step, args, leaves) in table.items():
-        shapes = {}
-        for name, layer_leaves in leaves.items():
-            for leaf, a in layer_leaves.items():
-                shapes.setdefault(dec._layer(name).cache_kinds[leaf], set()) \
-                    .add(f"bf16[{','.join(map(str, a.shape))}]")
-        txt = step.jit_fn.trace(*on_chip(args)).lower(
-            lowering_platforms=("tpu",)).compile().as_text()
-        out[program] = (_without_layout_constraints(txt), shapes)
-    return out
+    return _compiled_programs(one_chip, zoo, b, s, tp)
 
 
 _ROUTED_READERS = {
@@ -870,3 +846,90 @@ def test_routed_prompt_walk_holds_no_tokens_by_experts_by_hidden_array(
     decode, _ = routed_programs["decode"]
     assert re.search(rf"\[{e},3,{h}\]", decode)
     assert "ragged-dot" not in decode
+
+
+# --- the state-space hybrid: a scan's state, a convolution's window, a bucket
+
+_SSM = {"rows": 3, "tokens": 256, "channels": 1024, "state": 16}
+
+
+@pytest.fixture(scope="module")
+def ssm_programs(one_chip):
+    """Decode window, prompt and join of a two-layer ``HybridDecoderLM``
+    (a Mamba layer, a multi-query attention layer without positions, a
+    tied head) compiled for the v5e from avals: ``{program: (text, {kind
+    of state: [shape, ...]})}``. One block of 1,024 channels and a state
+    of 16 fill the tiles as the cell's 5,120 x 16 do, and the prompt
+    walk's scan is the kernel; three rows make a state's shape no other
+    array's."""
+    from deeplearning4j_tpu.zoo.graphs import HybridDecoderLM
+
+    b, s, tp = _SSM["rows"], 1024, _SSM["tokens"]
+    zoo = HybridDecoderLM(
+        vocab_size=512, hidden=384, ffn_dim=768,
+        mixer_types=["mamba", "plain-attn"],
+        mamba={"d_inner": _SSM["channels"], "d_state": _SSM["state"],
+               "d_conv": 4, "dt_rank": 24},
+        n_heads=3, head_dim=128, n_kv_heads=1, tie_head=True,
+        depth_for_scale=1, max_len=s, weight_dtype="bfloat16",
+        cache_dtype="bfloat16")
+    return _compiled_programs(one_chip, zoo, b, s, tp)
+
+
+_SSM_READERS = {
+    # h' = exp(dt A) h + dt x B is the whole state by nature, elementwise
+    # and in place; the read-out reduces it over the state's 16
+    ("decode", "recurrent"): {("multiply", "whole"), ("add", "whole"),
+                              ("exponential", "whole"), ("reduce", "part")},
+    # the ring: the taps weigh every slot where it lies (a product, its
+    # three lane slices summed), the token's input is selected into the
+    # oldest slot; nothing shifts
+    ("decode", "conv_window"): {("multiply", "whole"), ("add", "whole"),
+                                ("select", "whole"), ("slice", "whole")},
+    # the token's write, in place; the bucket is read by the paged kernel
+    ("decode", "kv"): {_WRITE, ("custom-call", "part")},
+    # a join writes the joining row alone (a third of a three-row state)
+    ("join", "recurrent"): {_WRITE, ("dynamic-slice", "whole")},
+    ("join", "conv_window"): {_WRITE, ("dynamic-slice", "whole")},
+    ("join", "kv"): {_WRITE, ("dynamic-slice", "whole")},
+}
+
+
+@pytest.mark.parametrize("program,kind", sorted(_SSM_READERS))
+def test_ssm_programs_touch_each_state_only_to_update_it_where_it_lies(
+        ssm_programs, program, kind):
+    """No ``copy``, ``pad``, ``concatenate`` or scatter's ``while`` has a
+    scan's state, a convolution's window or a KV bucket among its
+    operands, in the decode window or in the join (a window kept
+    oldest-first was copied whole every step to be shifted)."""
+    txt, shapes = ssm_programs[program]
+    rows, d, n = _SSM["rows"], _SSM["channels"], _SSM["state"]
+    assert shapes == {"recurrent": {f"f32[{rows},{n},{d}]"},
+                      "conv_window": {f"f32[{rows},{3 * d}]"},
+                      "kv": {f"bf16[{rows},1024,128]"}}
+    found = _consumers(txt, shapes[kind])
+    assert found <= _SSM_READERS[program, kind], sorted(
+        found - _SSM_READERS[program, kind])
+    assert found
+
+
+def test_ssm_prompt_walk_is_the_kernel_and_keeps_no_state_history(
+        ssm_programs):
+    """The prompt walk compiled for the v5e scans with the one Mosaic
+    kernel (``lax.scan`` over positions is what a CPU lowers to) and
+    nowhere holds a state a position; the decode window's one kernel is
+    the attention layer's paged read."""
+    t, d, n = _SSM["tokens"], _SSM["channels"], _SSM["state"]
+    prompt, _ = ssm_programs["prompt"]
+    kernels = [line for line in prompt.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 1 and "selective_scan" in kernels[0]
+    history = re.compile(
+        rf"\[(\d+,)?({t},{n},{d // 128},128|{t},{n},{d}|{n},{t},{d}|"
+        rf"{t},1,{n},{d})\]")
+    assert not history.search(prompt)
+    assert f"f32[1,{n},{d // 128},128]" in prompt    # the kernel's state block
+    decode, _ = ssm_programs["decode"]
+    kernels = [line for line in decode.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 1 and "selective_scan" not in kernels[0]
