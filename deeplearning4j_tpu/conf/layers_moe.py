@@ -41,14 +41,28 @@ from deeplearning4j_tpu.conf.layers_hybrid import _dot, _matrix, _wdtype
 #: MultiLayerNetwork/ComputationGraph ``_loss`` sums them into the score.
 AUX_LOSS_KEY = "__aux_loss__"
 ROUTED_ROWS_MAX = 4096      # tokens a RoutedExpertsLayer routes at a time
-# Where the slots (token x chosen expert) an expert held gets lie in this
-# range, a RoutedExpertsLayer puts every token through every expert held;
-# elsewhere the slots go grouped by expert. Fewer, and most experts are
-# chosen by nobody: the grouped product never reads them. More, and every
-# expert is ``held / top_k`` times the matrix unit's work. Between, the
-# plain batched product streams the matrices at the HBM's rate and the
-# grouped one at half of it. Read on the v5e at hidden 2048, 128 experts
-# of 1024, top 8 (tools/chip/moe_crossover.py; PERF.md section 6, PR 33).
+# Up to ``most`` slots (token x chosen expert) an expert held, a
+# RoutedExpertsLayer's rows are FEW: the time is the reading of the
+# matrices. A program lowered for a TPU then reads the touched experts'
+# matrices where they lie, in one Pallas kernel
+# (``ops.routed_experts.touched_experts_ffn``). Every other platform puts
+# every token through every expert held from ``fewest`` slots on (below,
+# most experts are chosen by nobody) and groups the slots by expert
+# elsewhere; beyond ``most`` every platform groups them: every expert
+# would be ``held / top_k`` times the matrix unit's work. Read on the v5e
+# at hidden 2048, 128 experts of 1024, top 8 (tools/chip/moe_crossover.py;
+# PERF.md section 6, PR 33 and PR 36), ms a layer:
+#   rows  slots  touched | every expert  grouped  the kernel
+#      8    0.5       51 |        2.290    1.046       0.868
+#     16    1         81 |        2.289    1.780       1.371
+#     32    2        106 |        2.293    2.768       1.787
+#     64    4        127 |        2.298    5.057       2.139
+#    128    8        128 |        2.306    5.171       2.170
+#    256   16        128 |        2.904    5.331       2.226
+#   1024   64        128 |       10.65     6.50    not measured
+# The kernel is bound by its copies (754 GB/s at 32 rows; its products
+# alone take 0.42 ms there and 2.15 at 256 rows, as long as the copies),
+# so one path serves the whole range on the TPU.
 EVERY_EXPERT_SLOTS = (2, 24)
 
 
@@ -185,21 +199,26 @@ class RoutedExpertsLayer(BaseLayer):
     add is another holder's. The shared expert is computed by every
     holder alike.
 
-    One sum, two products by the number of tokens: the live ``tokens *
-    top_k`` slots are sorted by expert and go through
-    ``jax.lax.ragged_dot`` (a grouped matrix product on the TPU: an
-    expert nobody chose is never read), a prompt's thousands of tokens;
-    a decode step's rows (``EVERY_EXPERT_SLOTS``) go through every expert
-    held with the weight zero where it was not chosen. Tokens that are
-    not live (idle rows, prompt padding) are routed nowhere and weigh
-    nothing. More than ``ROUTED_ROWS_MAX`` tokens go through in slices.
+    One sum, three products by the number of tokens and the platform
+    (``EVERY_EXPERT_SLOTS``): a prompt's thousands of tokens go through
+    ``jax.lax.ragged_dot``, their live ``tokens * top_k`` slots sorted by
+    expert (a grouped matrix product on the TPU: an expert nobody chose
+    is never read); a decode step's rows go, in a program lowered for a
+    TPU, through ``ops.routed_experts.touched_experts_ffn`` (one Pallas
+    kernel over the matrices of the experts the rows chose, read where
+    they lie), and elsewhere through every expert held with the weight
+    zero where it was not chosen. Tokens that are not live (idle rows,
+    prompt padding) are routed nowhere and weigh nothing. More than
+    ``ROUTED_ROWS_MAX`` tokens go through in slices.
 
     ``forward_live`` is what ``nn.decoding`` calls (the interface of a
     layer without state, ``conf/layers_hybrid.py``); its counts, one
     scalar a call: ``moe_routed_slots`` (live token x chosen expert held
     here), ``moe_experts_touched`` (experts held with at least one slot),
     ``moe_expert_layer_steps`` (1 where any token was live),
-    ``moe_max_load`` (the fullest expert's slots)."""
+    ``moe_max_load`` (the fullest expert's slots), ``moe_experts_read``
+    (experts whose matrices the product streamed: the touched ones by the
+    kernel and the grouped product, all those held by the batched one)."""
 
     n_out: int = 0
     n_experts: int = 8
@@ -214,7 +233,8 @@ class RoutedExpertsLayer(BaseLayer):
 
     uses_mask = True
     live_counters = ("moe_routed_slots", "moe_experts_touched",
-                     "moe_expert_layer_steps", "moe_max_load")
+                     "moe_expert_layer_steps", "moe_max_load",
+                     "moe_experts_read")
 
     def _held(self):
         first, count = self.experts_held
@@ -286,10 +306,10 @@ class RoutedExpertsLayer(BaseLayer):
         return ys[back].reshape(x.shape[0], self.top_k, -1).sum(axis=1)
 
     def _every_expert(self, params, x, w):
-        """The same sum for a decode step's rows (``EVERY_EXPERT_SLOTS``:
-        most experts are chosen by somebody anyway): every token through
-        every expert held, ``w: [n, held]`` zero where the expert was not
-        chosen."""
+        """The same sum for a decode step's rows off the TPU
+        (``EVERY_EXPERT_SLOTS``), and the kernel's reference: every token
+        through every expert held, ``w: [n, held]`` zero where the expert
+        was not chosen."""
         f32 = jnp.float32
         hidden = (jax.nn.silu(jnp.einsum("nd,edh->enh", x, params["Wg"],
                                          preferred_element_type=f32))
@@ -299,34 +319,79 @@ class RoutedExpertsLayer(BaseLayer):
                         preferred_element_type=f32)
         return jnp.einsum("end,ne->nd", ys, w)
 
+    def _held_slots(self, params, u, live):
+        """The slots of tokens ``u: [n, d]`` (``live: [n]`` bool):
+        ``(experts, mine, w [n, top_k], chosen [n, top_k, held], sizes
+        [held])``: a slot's expert, whether it is a live token's and held
+        here, its weight; the slot's expert among those held here as a
+        mask, and the slots each of them got."""
+        first, held = self._held()
+        experts, w = self.route(params, u)
+        mine = (live[:, None] & (experts >= first)
+                & (experts < first + held))
+        chosen = mine[:, :, None] & (
+            (experts - first)[:, :, None] == jnp.arange(held))
+        return (experts, mine, w, chosen,
+                jnp.sum(chosen, axis=(0, 1), dtype=jnp.int32))
+
+    @staticmethod
+    def _by_row(w, chosen):
+        """``[n, held]``: a row's weight for each expert held, zero where
+        it did not choose it."""
+        return jnp.sum(jnp.where(chosen, w[:, :, None], 0.0), axis=1)
+
+    def _experts(self, params, x, experts, mine, w, chosen, sizes):
+        """``(y [n, n_out], read)``: the routed sum of ``x: [n, d]`` over
+        the slots :meth:`_held_slots` gives, and how many experts'
+        matrices the product streamed. Which product is a matter of ``n *
+        top_k / held`` alone, and of the platform the program is lowered
+        for."""
+        from deeplearning4j_tpu.ops import routed_experts
+
+        first, held = self._held()
+        slots = experts.size
+        fewest, most = EVERY_EXPERT_SLOTS
+
+        def n_touched():
+            return jnp.sum(sizes > 0, dtype=jnp.int32)
+
+        def every():
+            return (self._every_expert(params, x, self._by_row(w, chosen)),
+                    jnp.int32(held))
+
+        def grouped():     # a slot not held here sorts behind every group
+            return (self._grouped(
+                params, x, jnp.where(mine, experts - first, held).reshape(-1),
+                w.reshape(-1), sizes), n_touched())
+
+        def touched():
+            return (routed_experts.touched_experts_ffn(
+                x, params["Wg"], params["Wu"], params["Wd"],
+                self._by_row(w, chosen), sizes, interpret=False),
+                n_touched())
+
+        if slots > most * held:
+            return grouped()
+        plain = every if slots >= fewest * held else grouped
+        if not routed_experts.touched_experts_applies(
+                params["Wg"].shape, params["Wd"].shape[-1]):
+            return plain()
+        return jax.lax.platform_dependent(tpu=touched, default=plain)
+
     def _slice(self, params, u, live):
         """``u: [n, d]`` float32, ``live: [n]`` bool -> ``(y [n, n_out],
-        sizes [held])``, ``sizes`` the slots each expert held here got."""
-        n, k = u.shape[0], self.top_k
-        first, held = self._held()
-        wd = params["Wg"].dtype
+        sizes [held], read)``: ``sizes`` the slots each expert held here
+        got, ``read`` the experts whose matrices the product streamed."""
         with jax.named_scope("moe.route"):
-            experts, w = self.route(params, u)
-            mine = (live[:, None] & (experts >= first)
-                    & (experts < first + held))
-            # [n, k, held]: the slot's expert, among those held here
-            chosen = mine[:, :, None] & (
-                (experts - first)[:, :, None] == jnp.arange(held))
-            sizes = jnp.sum(chosen, axis=(0, 1), dtype=jnp.int32)
+            *slots, sizes = self._held_slots(params, u, live)
         with jax.named_scope("moe.experts"):
-            fewest, most = EVERY_EXPERT_SLOTS
-            if fewest * held <= n * k <= most * held:
-                y = self._every_expert(params, u.astype(wd), jnp.sum(
-                    jnp.where(chosen, w[:, :, None], 0.0), axis=1))
-            else:
-                y = self._grouped(params, u.astype(wd), jnp.where(
-                    mine, experts - first, held).reshape(-1), w.reshape(-1),
-                    sizes)
+            y, read = self._experts(params, u.astype(params["Wg"].dtype),
+                                    *slots, sizes)
         if self.n_shared_hidden:
             with jax.named_scope("moe.shared"):
                 y = y + _dot(jax.nn.silu(_dot(u, params["Sg"]))
                              * _dot(u, params["Su"]), params["Sd"])
-        return y * self.out_scale, sizes
+        return y * self.out_scale, sizes, read
 
     def forward_live(self, params, x, live):
         """``x: [..., d]``, ``live: x.shape[:-1]`` (true where the token
@@ -336,17 +401,18 @@ class RoutedExpertsLayer(BaseLayer):
         n = flat.shape[0]
         if n > ROUTED_ROWS_MAX and n % ROUTED_ROWS_MAX == 0:
             m = n // ROUTED_ROWS_MAX
-            y, sizes = jax.lax.map(
+            y, sizes, read = jax.lax.map(
                 lambda a: self._slice(params, *a),
                 (flat.reshape(m, ROUTED_ROWS_MAX, -1),
                  live.reshape(m, ROUTED_ROWS_MAX)))
-            sizes = jnp.sum(sizes, axis=0)
+            sizes, read = jnp.sum(sizes, axis=0), jnp.sum(read)
         else:
-            y, sizes = self._slice(params, flat, live)
+            y, sizes, read = self._slice(params, flat, live)
         counts = {"moe_routed_slots": jnp.sum(sizes),
                   "moe_experts_touched": jnp.sum(sizes > 0, dtype=jnp.int32),
                   "moe_expert_layer_steps": jnp.any(live).astype(jnp.int32),
-                  "moe_max_load": jnp.max(sizes)}
+                  "moe_max_load": jnp.max(sizes),
+                  "moe_experts_read": read}
         return y.reshape(x.shape[:-1] + (-1,)), counts
 
     def forward(self, params, state, x, train=False, rng=None, mask=None):

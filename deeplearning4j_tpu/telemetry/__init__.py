@@ -526,9 +526,11 @@ def record_decode_layer_counts(counts: dict) -> None:
     cached positions streamed over the positions held (a bucket; for a
     window layer's ring the row's context); ``moe_routed_slots`` (live
     token x chosen expert), ``moe_experts_touched``,
-    ``moe_expert_layer_steps`` and ``moe_max_load`` per (expert layer,
-    step): experts with a live token, layer-steps with one, the fullest
-    expert's slots."""
+    ``moe_expert_layer_steps``, ``moe_max_load`` and ``moe_experts_read``
+    per (expert layer, step): experts with a live token, layer-steps with
+    one, the fullest expert's slots, experts whose matrices the step's
+    product streamed (the touched ones by the TPU's kernel, all those
+    held by the batched product of every other platform)."""
     for name, n in counts.items():
         REGISTRY.counter(f"dl4j_{name}_total",
                          help="summed in-graph by the decode window "

@@ -835,8 +835,9 @@ def test_routed_prompt_walk_holds_no_tokens_by_experts_by_hidden_array(
         routed_programs):
     """The prompt walk groups: nowhere in its compiled text is an array of
     tokens x experts x hidden (the dense form, every token through every
-    expert), while the decode window, whose three rows go through every
-    expert held, has its small one."""
+    expert); the decode window, whose three rows go through the touched
+    experts' kernel, holds neither that nor the batched product's small
+    one (PR 36: a CPU lowers to it, ``tests/test_routed_decoder.py``)."""
     t, e, h = (_ROUTED[k] for k in ("tokens", "experts", "expert_hidden"))
     dense = re.compile(
         rf"\[({t},{e},{h}|{e},{t},{h}|{2 * t},{e},{h}|{e},{2 * t},{h})\]")
@@ -844,8 +845,40 @@ def test_routed_prompt_walk_holds_no_tokens_by_experts_by_hidden_array(
     assert not dense.search(prompt)
     assert "ragged-dot" in prompt
     decode, _ = routed_programs["decode"]
-    assert re.search(rf"\[{e},3,{h}\]", decode)
+    assert not re.search(rf"\[{e},3,{h}\]", decode)
     assert "ragged-dot" not in decode
+
+
+def test_routed_decode_window_reads_the_expert_stacks_by_the_kernel_alone(
+        routed_programs):
+    """The decode window compiled for the v5e holds ONE more Mosaic kernel
+    beside the full layer's paged read: the touched experts' product, once
+    in the step's body, with the three stacks among its operands as they
+    lie: no ``copy``, ``gather``, ``dot`` or ``convolution`` takes a
+    stack, whole or in part. The prompt walk's stacks go to its grouped
+    products and nowhere else, as before, and neither the prompt nor the
+    join holds the kernel."""
+    e, d, h = _ROUTED["experts"], 384, _ROUTED["expert_hidden"]
+    stacks = {f"bf16[{e},{d},{h}]", f"bf16[{e},{h},{d}]"}
+    decode, _ = routed_programs["decode"]
+    assert _consumers(decode, stacks) == {("custom-call", "part")}
+    kernels = [line for line in decode.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    ours = [line for line in kernels if "touched_experts_ffn" in line]
+    assert len(kernels) == 2 and len(ours) == 1
+    types = {mo.group(1): mo.group(2) for mo in map(
+        _INSTRUCTION.match, decode.splitlines()) if mo}
+    taken = [types[a].split("{")[0] for a in re.findall(
+        r"%([\w.\-]+)", _INSTRUCTION.match(ours[0]).group(4))]
+    assert sorted(t for t in taken if t in stacks) == [
+        f"bf16[{e},{h},{d}]", f"bf16[{e},{d},{h}]", f"bf16[{e},{d},{h}]"]
+    prompt, _ = routed_programs["prompt"]
+    # (the compiler's own grouped kernels, three of them: ``ragged-dot-*``)
+    assert _consumers(prompt, stacks) == {("custom-call", "whole")}
+    assert len(re.findall(r"= f32\[\d+,\d+\]\S* custom-call\(.*"
+                          r"op_name=\"ragged-dot", prompt)) == 3
+    for program in ("prompt", "join"):
+        assert "touched_experts_ffn" not in routed_programs[program][0]
 
 
 # --- the state-space hybrid: a scan's state, a convolution's window, a bucket

@@ -238,8 +238,8 @@ def test_idle_rows_and_padding_touch_no_expert(served):
     _, _, net, dec = served
     names = dec.counter_names
     assert names == ["decode_kv_bucket_positions", "decode_kv_read_positions",
-                     "moe_expert_layer_steps", "moe_experts_touched",
-                     "moe_max_load", "moe_routed_slots"]
+                     "moe_expert_layer_steps", "moe_experts_read",
+                     "moe_experts_touched", "moe_max_load", "moe_routed_slots"]
 
     def window(active):
         state = dict(dec.new_state(128), active=jnp.asarray(active),
@@ -250,13 +250,16 @@ def test_idle_rows_and_padding_touch_no_expert(served):
     one = window([False, True])
     assert one["moe_routed_slots"] == 4 * 3 * 2          # K x layers x top_k
     assert one["moe_experts_touched"] == 4 * 3 * 2
+    # one row's 2 slots over 8 experts go grouped: the touched are read
+    assert one["moe_experts_read"] == one["moe_experts_touched"]
     assert one["moe_expert_layer_steps"] == 4 * 3
     assert one["moe_max_load"] == 4 * 3
     both = window([True, True])
     assert both["moe_routed_slots"] == 2 * one["moe_routed_slots"]
     none = window([False, False])
     assert (none["moe_routed_slots"] == none["moe_experts_touched"]
-            == none["moe_expert_layer_steps"] == none["moe_max_load"] == 0)
+            == none["moe_expert_layer_steps"] == none["moe_max_load"]
+            == none["moe_experts_read"] == 0)
     # the prompt walk: 9 real tokens in a bucket of 16
     tally = []
     prompts = np.zeros((1, 16), np.int32)
@@ -344,7 +347,9 @@ def test_routing_is_dropless_whatever_the_load(monkeypatch):
     y, counts = layer.forward_live(p, x, np.ones((4, 16), bool))
     assert {k: int(v) for k, v in counts.items()} == {
         "moe_routed_slots": 128, "moe_experts_touched": 2,
-        "moe_expert_layer_steps": 1, "moe_max_load": 64}
+        "moe_expert_layer_steps": 1, "moe_max_load": 64,
+        # four slices of 16 tokens, 4 slots an expert: every expert held
+        "moe_experts_read": 4 * 8}
     experts, w = layer.route(p, x.reshape(-1, 16))
     assert set(np.asarray(experts).ravel().tolist()) == {3, 5}
     np.testing.assert_allclose(np.asarray(w).sum(axis=1), 1.0, atol=1e-6)
@@ -352,6 +357,194 @@ def test_routing_is_dropless_whatever_the_load(monkeypatch):
     monkeypatch.undo()
     whole = layer.forward_live(p, x, np.ones((4, 16), bool))[0]
     np.testing.assert_allclose(y, whole, atol=1e-6)
+
+
+# --- the few rows' product as a TPU lowers it: the touched experts' kernel ---
+
+def _steer_to_the_kernel(monkeypatch):
+    """What lowering for a TPU chooses, chosen here for a CPU run: the
+    experts' ``tpu`` branch, its kernel through the Pallas interpreter,
+    at widths that fill no 128-lane tile (steered in the test, as
+    ``test_kv_cache_layout`` steers the paged read)."""
+    from deeplearning4j_tpu.ops import routed_experts
+
+    kernel, choose = routed_experts.touched_experts_ffn, \
+        jax.lax.platform_dependent
+    monkeypatch.setattr(routed_experts, "touched_experts_applies",
+                        lambda *a: True)
+    monkeypatch.setattr(
+        routed_experts, "touched_experts_ffn",
+        lambda *a, **kw: kernel(*a, **{**kw, "interpret": True}))
+    monkeypatch.setattr(
+        jax.lax, "platform_dependent",
+        lambda *a, default=None, **per: (
+            per["tpu"](*a) if getattr(per.get("tpu"), "__name__", "")
+            == "touched" else choose(*a, default=default, **per)))
+
+
+def _expert_layer(held=(0, 0)):
+    layer = RoutedExpertsLayer(
+        n_out=32, n_experts=16, n_hidden=16, top_k=2, n_shared_hidden=16,
+        route_scale=2.826, experts_held=held)
+    p = layer.init(jax.random.PRNGKey(4), type("T", (), {"size": 32})(),
+                   jnp.float32)
+    return layer, p
+
+
+def _step_operands(layer, p, n, live=None):
+    """What the layer hands the product for ``n`` seeded rows: ``(x, w
+    [n, held], sizes [held])``."""
+    x = jax.random.normal(jax.random.PRNGKey(n), (n, 32), jnp.float32)
+    live = jnp.ones((n,), bool) if live is None else jnp.asarray(live)
+    *_, w, chosen, sizes = layer._held_slots(p, x, live)
+    return x, layer._by_row(w, chosen), sizes
+
+
+@pytest.mark.parametrize("rows", [1, 8, 32])
+def test_kernel_reads_the_touched_experts_alone(rows):
+    """The kernel under the Pallas interpreter against ``_every_expert``:
+    the same sum, and with the matrices of every expert no row chose set
+    to NaN still the same and finite, which a product over all of them
+    cannot give (``0 * NaN``)."""
+    from deeplearning4j_tpu.ops.routed_experts import touched_experts_ffn
+
+    layer, p = _expert_layer()
+    x, w, sizes = _step_operands(layer, p, rows)
+    touched = np.asarray(sizes) > 0
+    assert touched.any() and (rows > 8 or not touched.all())
+    want = np.asarray(layer._every_expert(p, x, w))
+    hole = jnp.where(jnp.asarray(touched)[:, None, None], 0.0, jnp.nan)
+    stacks = [p[k] + hole for k in ("Wg", "Wu", "Wd")]
+    got = np.asarray(touched_experts_ffn(x, *stacks, w, sizes,
+                                         interpret=True))
+    assert got.shape == want.shape and np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    if not touched.all():
+        holed = dict(p, **dict(zip(("Wg", "Wu", "Wd"), stacks)))
+        assert np.isnan(np.asarray(layer._every_expert(holed, x, w))).all()
+
+
+@pytest.mark.parametrize("live", ["one_idle_row", "no_live_row"])
+def test_kernel_gives_idle_rows_and_an_empty_list_zeros(live):
+    """A row that is not live weighs nothing anywhere: its sum is zero
+    beside its live neighbours' unchanged one; with no live row the list
+    of touched experts is empty and the output is zeros whatever the
+    matrices hold (the one grid step there is reads no matrix into it)."""
+    from deeplearning4j_tpu.ops.routed_experts import (
+        touched_experts_ffn,
+        touched_list,
+    )
+
+    layer, p = _expert_layer()
+    mask = np.ones((8,), bool)
+    mask[[3] if live == "one_idle_row" else slice(None)] = False
+    x, w, sizes = _step_operands(layer, p, 8, mask)
+    stacks = [p[k] for k in ("Wg", "Wu", "Wd")]
+    if live == "no_live_row":
+        assert int(touched_list(sizes)[1]) == 0
+        stacks = [a + jnp.nan for a in stacks]
+    got = np.asarray(touched_experts_ffn(x, *stacks, w, sizes,
+                                         interpret=True))
+    assert (got[~mask] == 0).all()
+    if mask.any():
+        np.testing.assert_allclose(
+            got, np.asarray(layer._every_expert(p, x, w)), atol=2e-5)
+        assert np.abs(got[mask]).min(axis=1).max() > 0
+
+
+def test_touched_list_is_the_touched_experts_in_order():
+    from deeplearning4j_tpu.ops.routed_experts import touched_list
+
+    sizes = jnp.asarray([0, 3, 0, 0, 1, 9, 0, 2], jnp.int32)
+    ids, count = touched_list(sizes)
+    assert int(count) == 4
+    # the steps past the list's end (never run) name its last expert
+    assert np.asarray(ids).tolist() == [1, 4, 5, 7, 7, 7, 7, 7]
+    ids, count = touched_list(jnp.zeros((8,), jnp.int32))
+    assert int(count) == 0 and np.asarray(ids).tolist() == [0] * 8
+
+
+@pytest.mark.parametrize("rows", [1, 8, 32])
+def test_layer_through_the_kernel_gives_the_layers_sum(rows, monkeypatch):
+    """``forward_live`` as a TPU lowers it against itself as the CPU
+    does (grouped at 1 and 8 rows, every expert held at 32), one idle
+    row among them; ``moe_experts_read`` is the touched experts in the
+    kernel's branch and in the grouped product's, and the experts held in
+    the batched product's."""
+    layer, p = _expert_layer()
+    x = jax.random.normal(jax.random.PRNGKey(rows), (rows, 32), jnp.float32)
+    live = np.arange(rows) != 2
+    want, plain = layer.forward_live(p, x, live)
+    _steer_to_the_kernel(monkeypatch)
+    got, kernel = layer.forward_live(p, x, live)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    for name in ("moe_routed_slots", "moe_experts_touched", "moe_max_load",
+                 "moe_expert_layer_steps"):
+        assert int(kernel[name]) == int(plain[name]), name
+    assert int(kernel["moe_experts_read"]) == int(
+        kernel["moe_experts_touched"]) <= 16
+    assert int(plain["moe_experts_read"]) == (
+        16 if rows == 32 else int(plain["moe_experts_touched"]))
+
+
+def test_expert_shares_add_up_through_the_kernel(monkeypatch):
+    """``experts_held`` quarters through the kernel's branch: each holder
+    reads the touched ones of ITS four experts, and the parts, the shared
+    expert counted once, add up to what one holder of all sixteen gives
+    by the batched product."""
+    whole, p = _expert_layer()
+    u = jax.random.normal(jax.random.PRNGKey(1), (32, 32), jnp.float32)
+    live = np.ones((32,), bool)
+    want, counts = whole.forward_live(p, u, live)
+    assert int(counts["moe_experts_read"]) == 16        # every expert held
+    shared = np.asarray(ref.gated(u, p["Sg"], p["Su"], p["Sd"]))
+    _steer_to_the_kernel(monkeypatch)
+    total, read = np.zeros_like(shared), 0
+    for first in (0, 4, 8, 12):
+        layer, _ = _expert_layer(held=(first, 4))
+        part = {**p, **{k: p[k][first:first + 4] for k in ("Wg", "Wu", "Wd")}}
+        y, counts = layer.forward_live(part, u, live)
+        assert int(counts["moe_experts_read"]) == int(
+            counts["moe_experts_touched"]) <= 4
+        read += int(counts["moe_experts_read"])
+        total += np.asarray(y) - shared
+    np.testing.assert_allclose(total + shared, want, atol=2e-5)
+    assert read == 16       # 64 slots over 16 experts leave none untouched
+
+
+def test_decode_window_through_the_kernel_gives_the_same_logits(monkeypatch):
+    """One decode step of the whole stack as a TPU lowers its expert
+    layers (the kernel through the interpreter, inside the jitted walk)
+    against the CPU's: the same logits, the same routing counts, and
+    ``moe_experts_read`` the touched experts where the CPU's grouped
+    product read them too."""
+    cfg = _cfg()
+    zoo, net, _ = _net(cfg)
+    dec = zoo.decoder(net, max_batch=3, kv_bucket_min=128,
+                      prompt_bucket_min=16)
+    caches = jax.tree_util.tree_map(
+        lambda a: jax.random.normal(jax.random.PRNGKey(3), a.shape, a.dtype),
+        dec.new_state(128)["caches"])
+    t = np.asarray([5, 17, 60], np.int32)
+    pos = np.asarray([30, 41, 7], np.int32)
+    active = np.asarray([True, False, True])
+
+    def step():
+        logits, _, counts = jax.jit(
+            lambda: dec._run_token(net.params, t, pos, caches, active))()
+        return np.asarray(logits), {k: int(np.sum(np.where(
+            active, v, 0) if np.ndim(v) else v)) for k, v in counts.items()}
+
+    want, plain = step()
+    _steer_to_the_kernel(monkeypatch)
+    got, kernel = step()
+    np.testing.assert_allclose(got[active], want[active], atol=2e-5,
+                               rtol=2e-5)
+    assert kernel == plain
+    # three expert layers, two live rows of two slots each
+    assert kernel["moe_routed_slots"] == 3 * 2 * 2
+    assert 3 * 2 <= kernel["moe_experts_read"] == kernel[
+        "moe_experts_touched"] <= 3 * 4
 
 
 @pytest.mark.parametrize("dropless", [False, True])

@@ -1,16 +1,25 @@
-"""Chip timing of ``RoutedExpertsLayer``'s two expert products (PR 33;
-PERF.md §6) at Trinity-Mini's widths: hidden 2048, 128 experts of 1024,
-top 8, bfloat16 matrices. For a step of ``n`` live rows, the whole layer
-(route, experts, shared expert) with every token through every expert
-held and with the slots grouped by expert (``jax.lax.ragged_dot``): where
-the two cross is where ``conf.layers_moe.EVERY_EXPERT_SLOTS`` belongs.
-Times are the program's on the DEVICE (a trace with the host tracer off,
-as the harness traces), beside the host's clock over the same runs. Run
-it through the chip tool from the root of a checkout; it writes
-``chiprun_out/moe_crossover.json``. ``tiny`` rehearses on the CPU (no
-device plane there: the device time is left out).
+"""Chip timing of ``RoutedExpertsLayer``'s three expert products (PR 33,
+PR 36; PERF.md §6) at Trinity-Mini's widths: hidden 2048, 128 experts of
+1024, top 8, bfloat16 matrices. For a step of ``n`` live rows, the whole
+layer (route, experts, shared expert) with every token through every
+expert held, with the slots grouped by expert (``jax.lax.ragged_dot``)
+and with the touched experts read where they lie by the Pallas kernel
+(``ops.routed_experts.touched_experts_ffn``): where they cross is where
+``conf.layers_moe.EVERY_EXPERT_SLOTS``'s edges belong. Times are the
+program's on the DEVICE (a trace with the host tracer off, as the harness
+traces), beside the host's clock over the same runs. Run it through the
+chip tool from the root of a checkout; it writes
+``chiprun_out/moe_crossover.json`` (``_parts.json``). ``tiny`` rehearses on the CPU (no
+device plane there: the device time is left out, and the kernel's column
+is whatever the CPU lowers to).
 
-    python tools/chip/moe_crossover.py [rows=16,32,64,128,256] [tiny]
+``parts`` takes the kernel apart instead, alone in a program, at each
+``rows``: whole; its copies alone (the products left out); its products
+alone (every step the same expert: the pipeline fetches it once); and
+whole with an expert's hidden width in 2 and in 4 blocks a grid step
+(``ops.routed_experts.STEP_BYTES_MAX``).
+
+    python tools/chip/moe_crossover.py [rows=8,16,32,64,128,256] [parts] [tiny]
 """
 import glob
 import json
@@ -25,9 +34,12 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.conf import layers_moe
+from deeplearning4j_tpu.ops import routed_experts
+
+applies = routed_experts.touched_experts_applies
 
 TINY = "tiny" in sys.argv
-ROWS = [16, 32, 64, 128, 256]
+ROWS = [8, 16, 32, 64, 128, 256]
 for a in sys.argv[1:]:
     if a.startswith("rows="):
         ROWS = [int(x) for x in a[5:].split(",")]
@@ -57,40 +69,105 @@ def device_us(run, reps):
     return round(sum(b - a for a, b in runs) / len(runs) * 1e-3, 1)
 
 
+def time_both(rec, name, f, reps):
+    """``f()`` launched ``reps`` times in a row (the same operands each
+    time: the device runs its programs in turn), on both clocks."""
+    def runs():
+        for _ in range(reps):
+            y = f()
+        jax.block_until_ready(y)
+
+    runs()
+    t = time.perf_counter()
+    runs()
+    rec[name + "_host_us"] = round((time.perf_counter() - t) / reps * 1e6, 1)
+    rec[name + "_us"] = device_us(runs, reps)
+
+
+def kernel_parts(layer, params, d):
+    """The kernel alone in a program, whole and taken apart, a record a
+    row count."""
+    from jax.experimental import pallas as pl
+
+    re_ = routed_experts
+    whole_body, whole_list = re_._touched_experts_kernel, re_.touched_list
+    step_bytes = re_.STEP_BYTES_MAX
+
+    def copies_alone(ids_ref, count_ref, x_ref, w_ref, wg_ref, wu_ref,
+                     wd_ref, o_ref):
+        @pl.when((pl.program_id(0) == 0) & (pl.program_id(1) == 0))
+        def _zero():
+            o_ref[...] = jnp.zeros_like(o_ref)
+
+        o_ref[0:8, 0:128] += (wg_ref[0, 0:8, 0:128] + wu_ref[0, 0:8, 0:128]
+                              + wd_ref[0, 0:8, 0:128]).astype(jnp.float32)
+
+    def one_expert(sizes):
+        ids, count = whole_list(sizes)
+        return ids * 0, count
+
+    stacks = [params[k] for k in ("Wg", "Wu", "Wd")]
+    for n in ROWS:
+        x = jax.random.normal(jax.random.PRNGKey(n), (n, d), jnp.float32)
+        *_, w, chosen, sizes = layer._held_slots(params, x,
+                                                jnp.ones((n,), bool))
+        by_row, xb = layer._by_row(w, chosen), x.astype(stacks[0].dtype)
+        rec = {"rows": n, "experts_touched": int(jnp.sum(sizes > 0))}
+        for name, body, lister, limit in (
+                ("whole", whole_body, whole_list, step_bytes),
+                ("copies_alone", copies_alone, whole_list, step_bytes),
+                ("products_alone", whole_body, one_expert, step_bytes),
+                ("whole_2_blocks", whole_body, whole_list, step_bytes // 2),
+                ("whole_4_blocks", whole_body, whole_list, step_bytes // 4)):
+            if TINY and name == "copies_alone":
+                continue        # its slices want whole tiles
+            re_._touched_experts_kernel, re_.touched_list = body, lister
+            re_.STEP_BYTES_MAX = limit
+            f = jax.jit(lambda *a: re_.touched_experts_ffn.__wrapped__(
+                *a, interpret=None))
+            time_both(rec, name, lambda: f(xb, *stacks, by_row, sizes),
+                      2 if TINY else 20)
+        re_._touched_experts_kernel, re_.touched_list = whole_body, whole_list
+        re_.STEP_BYTES_MAX = step_bytes
+        yield rec
+
+
+def layer_rows(layer, params, d, e, k):
+    """The whole layer by each of its three products, a record a row
+    count."""
+    for n in ROWS:
+        x = jax.random.normal(jax.random.PRNGKey(n), (n, d), jnp.float32)
+        live = jnp.ones((n,), bool)
+        rec = {"rows": n, "slots_an_expert": n * k / e}
+        for name, slots, kernel in (("every_expert", (0, 1 << 30), False),
+                                    ("grouped", (1 << 30, 1 << 30), False),
+                                    ("kernel", (0, 1 << 30), True)):
+            layers_moe.EVERY_EXPERT_SLOTS = slots
+            routed_experts.touched_experts_applies = (
+                applies if kernel else lambda *a: False)
+            f = jax.jit(lambda p, x: layer.forward_live(p, x, live))
+            rec["experts_touched"] = int(
+                f(params, x)[1]["moe_experts_touched"])
+            time_both(rec, name, lambda: f(params, x)[0], 2 if TINY else 20)
+        yield rec
+
+
 def main():
     d, e, h, k = (64, 16, 32, 4) if TINY else (2048, 128, 1024, 8)
     layer = layers_moe.RoutedExpertsLayer(
         n_out=d, n_experts=e, n_hidden=h, top_k=k, n_shared_hidden=h,
         route_scale=2.826, weight_dtype="bfloat16")
     params = layer.init(jax.random.PRNGKey(33), type("T", (), {"size": d})())
+    parts = "parts" in sys.argv
     out = {"device": jax.devices()[0].device_kind, "hidden": d, "experts": e,
            "expert_hidden": h, "top_k": k, "rows": []}
-    for n in ROWS:
-        x = jax.random.normal(jax.random.PRNGKey(n), (n, d), jnp.float32)
-        live = jnp.ones((n,), bool)
-        rec = {"rows": n, "slots_an_expert": n * k / e}
-        for name, slots in (("every_expert", (0, 1 << 30)),
-                            ("grouped", (1, 0))):
-            layers_moe.EVERY_EXPERT_SLOTS = slots
-            f = jax.jit(lambda p, x: layer.forward_live(p, x, live))
-            rec["experts_touched"] = int(
-                f(params, x)[1]["moe_experts_touched"])
-            reps = 2 if TINY else 20
-
-            def runs():     # the same random rows each time: the device
-                for _ in range(reps):       # runs its programs in turn
-                    y = f(params, x)[0]
-                y.block_until_ready()
-
-            t = time.perf_counter()
-            runs()
-            rec[name + "_host_us"] = round(
-                (time.perf_counter() - t) / reps * 1e6, 1)
-            rec[name + "_us"] = device_us(runs, reps)
+    for rec in (kernel_parts(layer, params, d) if parts
+                else layer_rows(layer, params, d, e, k)):
         print(json.dumps(rec), flush=True)
         out["rows"].append(rec)
     os.makedirs("chiprun_out", exist_ok=True)
-    with open("chiprun_out/moe_crossover.json", "w") as f:
+    with open("chiprun_out/moe_crossover%s.json"
+              % ("_parts" if parts else ""), "w") as f:
         json.dump(out, f, indent=1)
 
 
