@@ -49,6 +49,10 @@ class GraphVertex:
 
     name: Optional[str] = None
 
+    # the decoder walk's scope for a vertex without a layer
+    # (``Layer.scope_class``): such a vertex combines activations
+    scope_class = "residual"
+
     def output_type(self, input_types: List[object]):
         return input_types[0]
 
@@ -77,6 +81,8 @@ class GraphVertex:
 class LayerVertex(GraphVertex):
     """Wraps a layer conf as a single-input vertex (reference
     ``LayerVertex`` = layer + optional InputPreProcessor)."""
+
+    scope_class = property(lambda self: self.layer.scope_class)
 
     layer: Optional[Layer] = None
     preprocessor: Optional[Layer] = None

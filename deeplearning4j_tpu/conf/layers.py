@@ -57,6 +57,11 @@ class Layer:
 
     name: Optional[str] = None
 
+    # what ``jax.named_scope`` the decoder's walk puts a vertex of this
+    # layer under (``telemetry.device_time.SCOPE_CLASSES``): a class
+    # attribute, no field, so no conf's repr or JSON holds it
+    scope_class = "ffn"
+
     # --- shape inference ---------------------------------------------------
     def output_type(self, input_type):
         return input_type
@@ -183,6 +188,8 @@ class OutputLayer(DenseLayer):
     The network computes score via ``score()`` on pre-activations so fused
     stable softmax/sigmoid CE forms apply."""
 
+    scope_class = "head"
+
     loss_fn: ILossFunction = dataclasses.field(default_factory=LossMCXENT)
     activation: Activation = Activation.SOFTMAX
 
@@ -274,6 +281,8 @@ class EmbeddingLayer(BaseLayer):
 class EmbeddingSequenceLayer(BaseLayer):
     """Reference ``EmbeddingSequenceLayer``: [batch, time] int ->
     [batch, time, nOut]."""
+
+    scope_class = "embed"
 
     n_in: int = 0
     n_out: int = 0

@@ -89,6 +89,8 @@ def _rnn_size(input_type) -> int:
 class SelfAttentionLayer(BaseLayer):
     """Self-attention over the sequence (reference ``SelfAttentionLayer``)."""
 
+    scope_class = "attn.mha"
+
     n_out: int = 0
     n_heads: int = 1
     head_size: int = 0  # 0 → nOut // nHeads

@@ -639,6 +639,8 @@ class LayerNormalization(BaseLayer):
     ``sd.nn.layerNorm``; a standalone conf layer makes Transformer blocks
     composable in the graph DSL)."""
 
+    scope_class = "norm"
+
     eps: float = 1e-5
 
     def output_type(self, input_type):
@@ -677,6 +679,8 @@ class PositionEmbeddingLayer(BaseLayer):
     SameDiff; kept here so TransformerEncoder is order-aware). Params
     ``P: [max_len, size]``; sequences longer than ``max_len`` are
     rejected at trace time."""
+
+    scope_class = "pos"
 
     max_len: int = 512
 

@@ -158,6 +158,8 @@ class ResidualAddVertex(GraphVertex):
 class RMSNormLayer(BaseLayer):
     """``x / rms(x) * gain`` over the feature axis, float32."""
 
+    scope_class = "norm"
+
     eps: float = 1e-6
 
     def init(self, key, input_type, dtype=jnp.float32):
@@ -334,6 +336,8 @@ class LightningAttentionLayer(_GatedMixer):
     sigmoid gate, ``Wo``. Its cache is the state alone, ``[rows, heads,
     d, d]`` in ``state_dtype``, whatever the bucket."""
 
+    scope_class = "attn.lightning"
+
     n_out: int = 0
     n_heads: int = 1
     head_size: int = 0
@@ -469,6 +473,8 @@ class BlockSparseAttentionLayer(_GatedMixer):
     bucket / stride, kv_heads * d]`` in float32 (they feed a discrete
     choice: ``ops/block_sparse``). How many compressed keys of a row are live follows
     from the row's position, so a row's next tenant inherits none."""
+
+    scope_class = "attn.sparse"
 
     n_out: int = 0
     n_heads: int = 1
@@ -634,6 +640,9 @@ class GatedAttentionLayer(_GatedMixer):
     ``cache_join`` writes the last ``window`` positions of a prompt, each
     in its slot, ``cache_grow`` has nothing to grow, and ``state_bytes``
     reports it under the kind ``kv_ring``."""
+
+    scope_class = property(
+        lambda self: "attn.window" if self.window else "attn.full")
 
     n_out: int = 0
     n_heads: int = 1

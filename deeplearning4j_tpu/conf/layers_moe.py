@@ -90,6 +90,8 @@ class MoELayer(BaseLayer):
     the same axis as the batch, the GShard layout — see
     ``param_shard_axes``); standalone, all experts run locally."""
 
+    scope_class = "moe"
+
     n_experts: int = 4
     d_hidden: int = 0          # 0 -> 4 * d_model
     top_k: int = 2
@@ -219,6 +221,8 @@ class RoutedExpertsLayer(BaseLayer):
     ``moe_max_load`` (the fullest expert's slots), ``moe_experts_read``
     (experts whose matrices the product streamed: the touched ones by the
     kernel and the grouped product, all those held by the batched one)."""
+
+    scope_class = "moe"
 
     n_out: int = 0
     n_experts: int = 8

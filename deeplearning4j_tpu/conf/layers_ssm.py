@@ -66,6 +66,8 @@ class MambaMixerLayer(BaseLayer):
     silu(z))``. The mask of a sequence is taken to be a RIGHT padding
     (each row's real positions first)."""
 
+    scope_class = "ssm"
+
     n_out: int = 0
     d_inner: int = 0
     d_state: int = 16
@@ -228,7 +230,8 @@ class MambaMixerLayer(BaseLayer):
                                  0.0) for j in range(k)) * ring
             xc = jax.nn.silu(params["conv_b"] + params["conv_w"][k] * xi + sum(
                 taps[:, j * d:(j + 1) * d] for j in range(k)))
-            ring = jnp.where(age == 0, jnp.tile(xi, (1, k)), ring)
+            with jax.named_scope("cache.write"):
+                ring = jnp.where(age == 0, jnp.tile(xi, (1, k)), ring)
             dt, b, c = self._scan_inputs(params, xc)
             y, state = selective_scan_step(
                 xc, dt, -jnp.exp(params["A_log"]), b, c, params["D"],

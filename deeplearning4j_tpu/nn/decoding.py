@@ -52,6 +52,7 @@ engine is pinned bit-identical against (greedy token ids).
 
 from __future__ import annotations
 
+import contextlib
 from typing import Dict, List, Optional
 
 import jax
@@ -132,16 +133,17 @@ def _seed_rows(state, caches, rows, tok, lengths, max_new, eos, temps, rng,
     arrays written at ``rows`` (slots >= ``max_batch`` are padding, dropped
     by the scatter)."""
     at = lambda a, v: a.at[rows].set(v, mode="drop")  # noqa: E731
-    return dict(
-        state, caches=caches,
-        tokens=at(state["tokens"], tok),
-        positions=at(state["positions"], lengths),
-        prompt_lens=at(state["prompt_lens"], jnp.maximum(lengths, 1)),
-        max_new=at(state["max_new"], max_new),
-        eos=at(state["eos"], eos),
-        temps=at(state["temps"], temps),
-        rng=at(state["rng"], rng),
-        active=at(state["active"], active))
+    with jax.named_scope("window.account"):
+        return dict(
+            state, caches=caches,
+            tokens=at(state["tokens"], tok),
+            positions=at(state["positions"], lengths),
+            prompt_lens=at(state["prompt_lens"], jnp.maximum(lengths, 1)),
+            max_new=at(state["max_new"], max_new),
+            eos=at(state["eos"], eos),
+            temps=at(state["temps"], temps),
+            rng=at(state["rng"], rng),
+            active=at(state["active"], active))
 
 
 def _reject_types():
@@ -394,29 +396,42 @@ class TransformerDecoder:
         logits = None
         for kind, name, spec in self._plan:
             xs = [acts[src] for src in spec.inputs]
-            if kind == "attn":
-                y = cached(name, params[name], xs[0])
-            elif kind == "pos" and positions is not None:
-                y = xs[0] + params[name]["P"][positions]
-            elif kind == "head":
-                x = xs[0]
-                if lengths is not None:
-                    idx = jnp.maximum(lengths - 1, 0)[:, None, None]
-                    x = jnp.take_along_axis(x, idx, axis=1)[:, 0]
-                logits = self._layer(name).pre_output(
-                    self._net._params_of(params, name), x)
-                continue
-            elif hasattr(self._layer(name), "forward_live"):
-                y, own = self._layer(name).forward_live(
-                    params[name], xs[0],
-                    jnp.ones(tokens.shape, bool) if live is None else live)
-                if tally is not None:
-                    tally(own)
-            else:
-                y, _ = spec.vertex.forward(params.get(name, {}), {}, xs,
-                                           train=False, rng=None)
+            with self._scope(name):
+                if kind == "attn":
+                    y = cached(name, params[name], xs[0])
+                elif kind == "pos" and positions is not None:
+                    y = xs[0] + params[name]["P"][positions]
+                elif kind == "head":
+                    x = xs[0]
+                    if lengths is not None:
+                        idx = jnp.maximum(lengths - 1, 0)[:, None, None]
+                        x = jnp.take_along_axis(x, idx, axis=1)[:, 0]
+                    logits = self._layer(name).pre_output(
+                        self._net._params_of(params, name), x)
+                    continue
+                elif hasattr(self._layer(name), "forward_live"):
+                    y, own = self._layer(name).forward_live(
+                        params[name], xs[0],
+                        jnp.ones(tokens.shape, bool) if live is None
+                        else live)
+                    if tally is not None:
+                        tally(own)
+                else:
+                    y, _ = spec.vertex.forward(params.get(name, {}), {}, xs,
+                                               train=False, rng=None)
             acts[name] = y
         return logits
+
+    @contextlib.contextmanager
+    def _scope(self, name):
+        """The two nested ``jax.named_scope``s of a plan entry, ``<class>``
+        then ``<vertex name>``: what ``telemetry.device_time`` files the
+        device's time under. The class is the layer type's ``scope_class``
+        (``telemetry.device_time.SCOPE_CLASSES``). Metadata of the lowered
+        program only: nothing at run time."""
+        with jax.named_scope(self._net._vmap[name].vertex.scope_class), \
+                jax.named_scope(name):
+            yield
 
     def _run_token(self, params, tokens, positions, caches, active=None):
         """One token through the graph against the caches:
@@ -444,8 +459,9 @@ class TransformerDecoder:
         """Whole-prompt prefill walk: ``prompts [Bp, Tp] int32`` →
         (last-valid-position logits ``[Bp, V]``, per-layer kv blocks in
         cache layout)."""
-        key_mask = (jnp.arange(prompts.shape[1])[None, :]
-                    < lengths[:, None]).astype(self._dtype)
+        with jax.named_scope("window.prepare"):
+            key_mask = (jnp.arange(prompts.shape[1])[None, :]
+                        < lengths[:, None]).astype(self._dtype)
         kv = {}
 
         def prefill(name, p, x):
@@ -470,10 +486,11 @@ class TransformerDecoder:
         self._need("prefill_suffix", "the prefix-cache suffix walk")
         ts = suffix.shape[1]
         tpre = next(iter(prefix_kv.values()))["k"].shape[1]
-        key_mask = (jnp.arange(ts)[None, :]
-                    < suf_lens[:, None]).astype(self._dtype)
-        prefix_mask = (jnp.arange(tpre)[None, :]
-                       < prefix_lens[:, None]).astype(self._dtype)
+        with jax.named_scope("window.prepare"):
+            key_mask = (jnp.arange(ts)[None, :]
+                        < suf_lens[:, None]).astype(self._dtype)
+            prefix_mask = (jnp.arange(tpre)[None, :]
+                           < prefix_lens[:, None]).astype(self._dtype)
         kv = {}
 
         def prefill(name, p, x):
@@ -483,8 +500,9 @@ class TransformerDecoder:
             kv[name] = {"k": k, "v": v}
             return y
 
-        at = jnp.clip(prefix_lens[:, None] + jnp.arange(ts),
-                      0, self.max_len - 1)
+        with jax.named_scope("window.prepare"):
+            at = jnp.clip(prefix_lens[:, None] + jnp.arange(ts),
+                          0, self.max_len - 1)
         return self._walk(params, suffix, prefill, positions=at,
                           lengths=suf_lens, live=key_mask), kv
 
@@ -498,6 +516,16 @@ class TransformerDecoder:
             self._fns[kind] = aot_cache.wrap(
                 jax.jit(fn, donate_argnums=donate), self._graph_key(), kind)
         return self._fns[kind]
+
+    def _each_cache(self, state, op):
+        """``{name: op(layer, name, cache)}`` over the state's caches,
+        each under its layer's scopes and ``cache.write``: a join, a grow
+        or a release is a write of the cache and nothing else."""
+        out = {}
+        for name, c in state["caches"].items():
+            with self._scope(name), jax.named_scope("cache.write"):
+                out[name] = op(self._layer(name), name, c)
+        return out
 
     def decode_fn(self, s: int, k: int):
         """K fused decode steps at KV bucket ``s``: ``lax.scan`` of the
@@ -513,8 +541,9 @@ class TransformerDecoder:
                 return st, toks, emitted
             # the layers' counters ride the window's own outputs: the
             # engine reads them with the tokens, no further sync
-            return st, toks, emitted, jnp.stack(
-                [counts[n] for n in self.counter_names])
+            with jax.named_scope("window.account"):
+                return st, toks, emitted, jnp.stack(
+                    [counts[n] for n in self.counter_names])
 
         return self._exe(f"decode_step:s{s}:k{k}", fn, donate=(1,))
 
@@ -527,25 +556,30 @@ class TransformerDecoder:
             logits, caches, counts = self._run_token(
                 params, st["tokens"], st["positions"], st["caches"],
                 active=active)
-            counts = {**{n: jnp.sum(jnp.where(active, counts[n], 0))
-                         for n in self._row_counters},
-                      **{n: counts[n] for n in self._step_counters}}
-            step_keys, rng_next = _advance_rng(st["rng"])
-            tok = _sample_tokens(logits, step_keys, st["temps"])
-            tok = jnp.where(active, tok, st["tokens"])
-            new_pos = st["positions"] + active.astype(jnp.int32)
-            gen = new_pos - st["prompt_lens"] + 1
-            nxt = active & (tok != st["eos"]) & (gen < st["max_new"])
-            st = dict(st, caches=caches, tokens=tok,
-                      positions=new_pos, active=nxt,
-                      rng=jnp.where(active[:, None], rng_next,
-                                    st["rng"]))
+            with jax.named_scope("window.account"):
+                counts = {**{n: jnp.sum(jnp.where(active, counts[n], 0))
+                             for n in self._row_counters},
+                          **{n: counts[n] for n in self._step_counters}}
+            with jax.named_scope("sample"):
+                step_keys, rng_next = _advance_rng(st["rng"])
+                tok = _sample_tokens(logits, step_keys, st["temps"])
+            with jax.named_scope("window.account"):
+                tok = jnp.where(active, tok, st["tokens"])
+                new_pos = st["positions"] + active.astype(jnp.int32)
+                gen = new_pos - st["prompt_lens"] + 1
+                nxt = active & (tok != st["eos"]) & (gen < st["max_new"])
+                st = dict(st, caches=caches, tokens=tok,
+                          positions=new_pos, active=nxt,
+                          rng=jnp.where(active[:, None], rng_next,
+                                        st["rng"]))
             return st, (tok, active, counts)
 
         st, (toks, emitted, counts) = jax.lax.scan(body, state, None,
                                                    length=k)
-        return st, toks, emitted, {n: jnp.sum(c, dtype=jnp.int32)
-                                   for n, c in counts.items()}
+        with jax.named_scope("window.account"):
+            counts = {n: jnp.sum(c, dtype=jnp.int32)
+                      for n, c in counts.items()}
+        return st, toks, emitted, counts
 
     def prompt_fn(self, tp: int, bp: int):
         """Prefill forward for a compact ``[bp, tp]`` group of joining
@@ -553,7 +587,9 @@ class TransformerDecoder:
         (:func:`_first_token`)."""
         def fn(params, prompts, lengths, max_new, eos, temps, rng):
             logits, kv = self._run_prompt(params, prompts, lengths)
-            return (kv,) + _first_token(logits, max_new, eos, temps, rng)
+            with jax.named_scope("sample"):
+                return (kv,) + _first_token(logits, max_new, eos, temps,
+                                            rng)
 
         return self._exe(f"gen_prompt:t{tp}:b{bp}", fn)
 
@@ -565,9 +601,9 @@ class TransformerDecoder:
         place."""
         def fn(state, kv, rows, tok, lengths, max_new, eos, temps,
                rng, active):
-            caches = {name: self._layer(name).cache_join(
-                c, kv[name], rows, s)
-                for name, c in state["caches"].items()}
+            caches = self._each_cache(
+                state, lambda layer, name, c: layer.cache_join(
+                    c, kv[name], rows, s))
             return _seed_rows(state, caches, rows, tok, lengths, max_new,
                               eos, temps, rng, active)
 
@@ -580,9 +616,8 @@ class TransformerDecoder:
         them anyway — the old buffers free by refcount when the engine
         swaps states."""
         def fn(state):
-            caches = {name: self._layer(name).cache_grow(c, s2)
-                      for name, c in state["caches"].items()}
-            return dict(state, caches=caches)
+            return dict(state, caches=self._each_cache(
+                state, lambda layer, name, c: layer.cache_grow(c, s2)))
 
         return self._exe(f"kv_grow:s{s}:{s2}", fn)
 
@@ -591,10 +626,11 @@ class TransformerDecoder:
         ``active &= keep``. State donated; everything else passes
         through aliased."""
         def fn(state, keep):
-            caches = {name: self._layer(name).cache_release(c, keep)
-                      for name, c in state["caches"].items()}
-            return dict(state, caches=caches,
-                        active=state["active"] & keep)
+            caches = self._each_cache(
+                state, lambda layer, name, c: layer.cache_release(c, keep))
+            with jax.named_scope("window.account"):
+                return dict(state, caches=caches,
+                            active=state["active"] & keep)
 
         return self._exe(f"gen_release:s{s}", fn, donate=(0,))
 
@@ -609,18 +645,17 @@ class TransformerDecoder:
         write that makes a hit O(pages copied), not O(prefix
         re-projected)."""
         def fn(state, prefix_kv, rows, prefix_lens):
-            caches = {}
-            for name, c in state["caches"].items():
-                caches[name] = {
-                    "k": c["k"].at[rows, :tpre].set(
-                        prefix_kv[name]["k"], mode="drop"),
-                    "v": c["v"].at[rows, :tpre].set(
-                        prefix_kv[name]["v"], mode="drop"),
-                }
-            return dict(
-                state, caches=caches,
-                positions=state["positions"].at[rows].set(
-                    prefix_lens, mode="drop"))
+            caches = self._each_cache(state, lambda layer, name, c: {
+                "k": c["k"].at[rows, :tpre].set(
+                    prefix_kv[name]["k"], mode="drop"),
+                "v": c["v"].at[rows, :tpre].set(
+                    prefix_kv[name]["v"], mode="drop"),
+            })
+            with jax.named_scope("window.account"):
+                return dict(
+                    state, caches=caches,
+                    positions=state["positions"].at[rows].set(
+                        prefix_lens, mode="drop"))
 
         return self._exe(f"prefix_attach:s{s}:t{tpre}:b{bp}", fn, donate=(0,))
 
@@ -634,7 +669,9 @@ class TransformerDecoder:
                max_new, eos, temps, rng):
             logits, kv = self._run_suffix(
                 params, suffix, suf_lens, prefix_kv, prefix_lens)
-            return (kv,) + _first_token(logits, max_new, eos, temps, rng)
+            with jax.named_scope("sample"):
+                return (kv,) + _first_token(logits, max_new, eos, temps,
+                                            rng)
 
         return self._exe(f"gen_prompt_sfx:t{ts}:p{tpre}:b{bp}", fn)
 
@@ -651,11 +688,12 @@ class TransformerDecoder:
         def fn(state, kv, rows, tok, prefix_lens, lengths, max_new,
                eos, temps, rng, active):
             b = self.max_batch
-            valid = rows < b
-            rc = jnp.minimum(rows, b - 1)
-            off = jnp.clip(prefix_lens, 0, s - ts)
-            caches = {}
-            for name, c in state["caches"].items():
+            with jax.named_scope("window.prepare"):
+                valid = rows < b
+                rc = jnp.minimum(rows, b - 1)
+                off = jnp.clip(prefix_lens, 0, s - ts)
+
+            def write(layer, name, c):
                 ck, cv = c["k"], c["v"]
                 for i in range(bp):
                     cur_k = jax.lax.dynamic_slice(
@@ -672,9 +710,11 @@ class TransformerDecoder:
                         ck, new_k, (rc[i], off[i], 0))
                     cv = jax.lax.dynamic_update_slice(
                         cv, new_v, (rc[i], off[i], 0))
-                caches[name] = {"k": ck, "v": cv}
-            return _seed_rows(state, caches, rows, tok, lengths, max_new,
-                              eos, temps, rng, active)
+                return {"k": ck, "v": cv}
+
+            return _seed_rows(state, self._each_cache(state, write), rows,
+                              tok, lengths, max_new, eos, temps, rng,
+                              active)
 
         return self._exe(f"prefix_join:s{s}:t{ts}:b{bp}", fn, donate=(0,))
 

@@ -188,31 +188,36 @@ class ComputationGraph(nn_io.LazyScoreMixin):
             kw = ({"mask": mask} if mask is not None
                   and isinstance(spec.vertex, LayerVertex) else {})
             routed = None
-            if getattr(self.conf, "use_kernels", False) \
-                    and (carries is None
-                         or not getattr(spec.vertex, "has_carry", False)):
-                # kernel-registry routing (conf.use_kernels): a TUNED
-                # Pallas kernel covering the wrapped layer's concrete
-                # shapes replaces the vertex forward; None = stock XLA
-                from deeplearning4j_tpu import kernels as _kernels
+            # one scope a vertex, its name: what the device's time is
+            # filed under (``telemetry.device_time``); the backward pass
+            # carries it as ``transpose(jvp(<name>))``
+            with jax.named_scope(name):
+                if getattr(self.conf, "use_kernels", False) \
+                        and (carries is None
+                             or not getattr(spec.vertex, "has_carry", False)):
+                    # kernel-registry routing (conf.use_kernels): a TUNED
+                    # Pallas kernel covering the wrapped layer's concrete
+                    # shapes replaces the vertex forward; None = stock XLA
+                    from deeplearning4j_tpu import kernels as _kernels
 
-                routed = _kernels.maybe_vertex_forward(
-                    spec.vertex, p, s, xs, train=train, rng=vrng, **kw)
-            if routed is not None:
-                y, s2 = routed
-            elif carries is not None \
-                    and getattr(spec.vertex, "has_carry", False) \
-                    and not _is_go_backwards(spec.vertex):
-                c = carries.get(name)
-                if c is None:
-                    c = spec.vertex.zero_carry(xs[0].shape[0], xs[0].dtype)
-                y, c2 = spec.vertex.forward_with_carry(
-                    p, c, xs, train=train, rng=vrng, **kw)
-                new_carries[name] = c2
-                s2 = s
-            else:
-                y, s2 = spec.vertex.forward(p, s, xs, train=train, rng=vrng,
-                                            **kw)
+                    routed = _kernels.maybe_vertex_forward(
+                        spec.vertex, p, s, xs, train=train, rng=vrng, **kw)
+                if routed is not None:
+                    y, s2 = routed
+                elif carries is not None \
+                        and getattr(spec.vertex, "has_carry", False) \
+                        and not _is_go_backwards(spec.vertex):
+                    c = carries.get(name)
+                    if c is None:
+                        c = spec.vertex.zero_carry(xs[0].shape[0],
+                                                   xs[0].dtype)
+                    y, c2 = spec.vertex.forward_with_carry(
+                        p, c, xs, train=train, rng=vrng, **kw)
+                    new_carries[name] = c2
+                    s2 = s
+                else:
+                    y, s2 = spec.vertex.forward(p, s, xs, train=train,
+                                                rng=vrng, **kw)
             acts[name] = y
             masks[name] = nn_io.propagate_mask(mask, y, spec.vertex)
             if name in state:
@@ -247,31 +252,37 @@ class ComputationGraph(nn_io.LazyScoreMixin):
     def _loss(self, params, state, features: Sequence, labels: Sequence,
               fmasks: Sequence, lmasks: Sequence, rng, train=True,
               carries=None):
-        features = tuple(self._dequant(f, i)
-                         for i, f in enumerate(features))
-        out_specs = self._output_specs()
-        fwd_params, features = self._fwd_cast(params, features)
-        if self._cdtype is not None and carries is not None:
-            carries = nn_io.cast_floats(carries, self._cdtype)
+        # the scopes (``cast``, one a vertex in ``_forward``, ``loss``) are
+        # what ``telemetry.device_time`` files the device's time under
+        with jax.named_scope("cast"):
+            features = tuple(self._dequant(f, i)
+                             for i, f in enumerate(features))
+            out_specs = self._output_specs()
+            fwd_params, features = self._fwd_cast(params, features)
+            if self._cdtype is not None and carries is not None:
+                carries = nn_io.cast_floats(carries, self._cdtype)
         acts, new_state, new_carries = self._forward(
             fwd_params, state, features, train, rng,
             skip={s.name for s in out_specs}, fmasks=fmasks,
             carries=carries)
-        loss = 0.0
-        for i, spec in enumerate(out_specs):
-            # output-vertex activation + loss in the storage dtype on the
-            # f32 master params (bf16 log-softmax loses gradient bits)
-            x = acts[spec.inputs[0]].astype(self._dtype)
-            loss = loss + spec.vertex.score(
-                self._params_of(params, spec.name), x, labels[i], lmasks[i])
-        loss = loss + self._regularization_score(params)
-        # auxiliary TRAIN-time loss terms layers stash in their state
-        # (MoE load-balance); eval scores must not pick up the stale
-        # last-training-step value
-        if train:
-            from deeplearning4j_tpu.conf.layers_moe import sum_aux_losses
+        with jax.named_scope("loss"):
+            loss = 0.0
+            for i, spec in enumerate(out_specs):
+                # output-vertex activation + loss in the storage dtype on
+                # the f32 master params (bf16 log-softmax loses gradient
+                # bits)
+                x = acts[spec.inputs[0]].astype(self._dtype)
+                loss = loss + spec.vertex.score(
+                    self._params_of(params, spec.name), x, labels[i],
+                    lmasks[i])
+            loss = loss + self._regularization_score(params)
+            # auxiliary TRAIN-time loss terms layers stash in their state
+            # (MoE load-balance); eval scores must not pick up the stale
+            # last-training-step value
+            if train:
+                from deeplearning4j_tpu.conf.layers_moe import sum_aux_losses
 
-            loss = loss + sum_aux_losses(new_state, self._dtype)
+                loss = loss + sum_aux_losses(new_state, self._dtype)
         return loss, (new_state, new_carries)
 
     def _regularization_score(self, params):
@@ -286,6 +297,22 @@ class ComputationGraph(nn_io.LazyScoreMixin):
                 for r in regs or ():
                     total = total + r.score_term(p)
         return total
+
+    def _apply_updaters(self, params, opt_state, grads, it, ep):
+        """Every vertex's updater over its gradients, under the scope
+        ``updater`` (``telemetry.device_time``): ``(new_params,
+        new_opt_state)``."""
+        new_params, new_opt = {}, {}
+        with jax.named_scope("updater"):
+            for k in params:
+                v = self._vmap[k].vertex
+                layer_conf = getattr(v, "layer", None) or v
+                upd = self._updater_for(k)
+                lr = upd.current_lr(it, ep)
+                g = solver.normalize_layer_gradients(layer_conf, grads[k])
+                new_params[k], new_opt[k] = solver.apply_updater_to_layer(
+                    layer_conf, upd, params[k], g, opt_state[k], lr, it, ep)
+        return new_params, new_opt
 
     def train_step_fn(self, guards: str = ""):
         """Raw (unjitted) pure train step for parallel wrappers (stage-7).
@@ -304,34 +331,30 @@ class ComputationGraph(nn_io.LazyScoreMixin):
 
             (loss, (new_state, new_carries)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params)
-            new_params, new_opt = {}, {}
-            for k in params:
-                v = self._vmap[k].vertex
-                layer_conf = getattr(v, "layer", None) or v
-                upd = self._updater_for(k)
-                lr = upd.current_lr(it, ep)
-                g = solver.normalize_layer_gradients(layer_conf, grads[k])
-                new_params[k], new_opt[k] = solver.apply_updater_to_layer(
-                    layer_conf, upd, params[k], g, opt_state[k], lr, it, ep)
+            new_params, new_opt = self._apply_updaters(
+                params, opt_state, grads, it, ep)
             if carries is not None:
                 # tBPTT: the next segment resumes from this segment's
                 # final RNN state, detached (gradients do not flow across
                 # segments — reference BackpropType.TruncatedBPTT)
                 new_carries = jax.lax.stop_gradient(new_carries)
             if guards:
-                vec = health.guard_vector(loss, grads, params=params,
-                                          new_params=new_params)
-                if guards == "skip":
-                    if carries is None:
-                        (new_params, new_state, new_opt) = health.apply_skip(
-                            vec, (new_params, new_state, new_opt),
-                            (params, state, opt_state))
-                    else:
-                        (new_params, new_state, new_opt,
-                         new_carries) = health.apply_skip(
-                            vec,
-                            (new_params, new_state, new_opt, new_carries),
-                            (params, state, opt_state, carries))
+                with jax.named_scope("guards"):
+                    vec = health.guard_vector(loss, grads, params=params,
+                                              new_params=new_params)
+                    if guards == "skip":
+                        if carries is None:
+                            (new_params, new_state,
+                             new_opt) = health.apply_skip(
+                                vec, (new_params, new_state, new_opt),
+                                (params, state, opt_state))
+                        else:
+                            (new_params, new_state, new_opt,
+                             new_carries) = health.apply_skip(
+                                vec,
+                                (new_params, new_state, new_opt,
+                                 new_carries),
+                                (params, state, opt_state, carries))
                 if carries is None:
                     return new_params, new_state, new_opt, loss, vec
                 return (new_params, new_state, new_opt, loss, new_carries,
@@ -367,19 +390,7 @@ class ComputationGraph(nn_io.LazyScoreMixin):
         """Updater half: (params, opt_state, grads, it, ep) ->
         (new_params, new_opt_state)."""
 
-        def afn(params, opt_state, grads, it, ep):
-            new_params, new_opt = {}, {}
-            for k in params:
-                v = self._vmap[k].vertex
-                layer_conf = getattr(v, "layer", None) or v
-                upd = self._updater_for(k)
-                lr = upd.current_lr(it, ep)
-                g = solver.normalize_layer_gradients(layer_conf, grads[k])
-                new_params[k], new_opt[k] = solver.apply_updater_to_layer(
-                    layer_conf, upd, params[k], g, opt_state[k], lr, it, ep)
-            return new_params, new_opt
-
-        return afn
+        return self._apply_updaters
 
     # --- training ----------------------------------------------------------
     def fit(self, data, labels=None, epochs: int = 1,
@@ -557,16 +568,20 @@ class ComputationGraph(nn_io.LazyScoreMixin):
             # dispatch round-trip (see nn_io device counters)
             def step(params, state, opt_state, features, labels, fmasks,
                      lmasks, itc, ep, base_key):
-                it, rng = nn_io.step_scalars(itc, base_key)
-                lmasks = tuple(
-                    jnp.ones((l.shape[0],), dtype) if m is None else m
-                    for m, l in zip(lmasks, labels))
+                with jax.named_scope("updater"):   # its clock, the rng
+                    it, rng = nn_io.step_scalars(itc, base_key)
+                with jax.named_scope("loss"):
+                    lmasks = tuple(
+                        jnp.ones((l.shape[0],), dtype) if m is None else m
+                        for m, l in zip(lmasks, labels))
                 out = raw(params, state, opt_state, features, labels,
                           fmasks, lmasks, it, ep, rng)
                 new_p, new_s, new_o, loss = out[:4]
+                with jax.named_scope("updater"):
+                    itc = itc + 1
                 if mode:
-                    return new_p, new_s, new_o, loss, itc + 1, out[4]
-                return new_p, new_s, new_o, loss, itc + 1
+                    return new_p, new_s, new_o, loss, itc, out[4]
+                return new_p, new_s, new_o, loss, itc
 
             self._train_step_ktag = self._ktag()
             self._train_step = aot_cache.wrap(

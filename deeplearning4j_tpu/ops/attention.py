@@ -383,10 +383,13 @@ def cache_update(cache, new, positions):
     stages each whole cache through VMEM and back every step to run it.
     Out-of-range positions clamp to the last slot (``dynamic_update_slice``
     semantics) — harmless by construction: only retired rows ever sit at
-    a position that high, and their slots are never attended."""
-    for i in range(cache.shape[0]):
-        cache = jax.lax.dynamic_update_slice(cache, new[i:i + 1],
-                                             (i, positions[i], 0))
+    a position that high, and their slots are never attended. The scope
+    ``cache.write`` is what ``telemetry.device_time`` files the write
+    under, beneath the layer's own."""
+    with jax.named_scope("cache.write"):
+        for i in range(cache.shape[0]):
+            cache = jax.lax.dynamic_update_slice(cache, new[i:i + 1],
+                                                 (i, positions[i], 0))
     return cache
 
 

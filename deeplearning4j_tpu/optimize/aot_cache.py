@@ -88,10 +88,18 @@ class AotCacheStats:
             self.fallbacks = 0
             self.overflows = 0
             self.last_miss_key = None
+            self.dispatches = {}      # executable key -> times dispatched
 
-    def record_hit(self):
+    def record_hit(self, key=None):
         with self._lock:
             self.hits += 1
+            if key is not None:
+                self.dispatches[key] = self.dispatches.get(key, 0) + 1
+
+    def record_first_dispatch(self, key):
+        """The dispatch that follows a miss: no hit, but a run."""
+        with self._lock:
+            self.dispatches[key] = self.dispatches.get(key, 0) + 1
 
     def record_miss(self, key, seconds: float):
         with self._lock:
@@ -139,11 +147,111 @@ def stats() -> dict:
 def clear():
     """Drop every cached executable (tests; a long-lived server swapping
     model families can call this to release device programs). Identity
-    pins are released with the entries they guarded."""
+    pins are released with the entries they guarded. What
+    :func:`programs` lists of the executables that were DISPATCHED stays
+    (their host-side HLO module, nothing that holds device memory), until
+    the next ``clear()`` replaces it: a trace taken while they ran can
+    still be read by scope. Fetching a module from the runtime costs 0.05
+    to 1.1 s an executable on the chip (14 s for the state-space
+    decoder's thirteen, my chip run, PR 37), so it is not done here: a
+    thread takes the dispatched executables, most dispatched first, and
+    lets each go as soon as it has its module; :func:`programs` waits for
+    it. ``clear()`` itself returns at once."""
+    global _KEPT, _KEEPER
     with _LOCK:
+        _KEPT = sorted((p for p in (Program(key, exe) for key, exe
+                                    in _EXECUTABLES.items())
+                        if p.dispatches), key=lambda p: -p.dispatches)
         _EXECUTABLES.clear()
         _ID_PINNED.clear()
+        _KEEPER = None
+        if _KEPT:
+            _KEEPER = threading.Thread(
+                target=lambda kept: [p._release() for p in kept],
+                args=(_KEPT,), name="aot-cache-keeper")
+            _KEEPER.start()
     STATS.reset()
+
+
+# --------------------------------------------------------------------------
+# the table of the loaded executables
+# --------------------------------------------------------------------------
+
+class Program:
+    """One executable of the cache, as the device's trace knows it.
+
+    ``kind`` is the step kind it was cached under (``fn_key``:
+    ``decode_step:s1024:k4``, ``gen_prompt:t128:b1``,
+    ``prefill_join:s1024:t128:b1``, ``train_step:d012+itc``),
+    ``module_name`` the HLO module's name (what the trace's ``XLA
+    Modules`` line prints before the brackets), ``dispatches`` how often
+    it ran, ``trace_id`` what that line prints INSIDE the brackets where
+    the runtime exposes it (``None`` on jax 0.9.0 / libtpu 0.0.34:
+    ``telemetry.device_time.match_program`` then joins by the
+    instructions' names and result types), ``scope_map()`` the
+    instructions of the compiled text with the ``jax.named_scope`` each
+    was written under. Nothing is read from the executable until one of
+    those is asked for."""
+
+    trace_id = None     # no runtime hands it out yet (the docstring)
+
+    def __init__(self, key, exe):
+        self.graph_key, self.kind, self.signature = key
+        self.dispatches = STATS.dispatches.get(key, 0)
+        self._exe = exe
+        self._hlo = None
+        self._map = None
+
+    def _module(self):
+        if self._hlo is None:
+            self._hlo = self._exe.runtime_executable().hlo_modules()[0]
+        return self._hlo
+
+    def _release(self):
+        """Keep the host-side HLO module, let the executable go (where
+        the runtime hands no module out, :func:`programs` drops the
+        entry)."""
+        try:
+            self._module()
+        except Exception:
+            pass
+        self._exe = None
+
+    @property
+    def module_name(self) -> str:
+        return self._module().name
+
+    def text(self) -> str:
+        """The compiled text, ``metadata={op_name=...}`` included."""
+        return self._module().to_string()
+
+    def scope_map(self) -> dict:
+        """``{instruction name: telemetry.device_time.Op}``, parsed once."""
+        if self._map is None:
+            from deeplearning4j_tpu.telemetry import device_time
+
+            self._map = device_time.parse_scopes(self.text())
+        return self._map
+
+    def __repr__(self):
+        return (f"Program({self.kind!r}, dispatches={self.dispatches}, "
+                f"loaded={self._exe is not None})")
+
+
+_KEPT: list = []     # the dispatched executables of the last clear()
+_KEEPER: Optional[threading.Thread] = None   # fetching their HLO modules
+
+
+def programs() -> list:
+    """The table: a :class:`Program` for every loaded executable, and for
+    those the last :func:`clear` dropped after they had run. Built on
+    demand; holds no device memory of its own."""
+    keeper = _KEEPER
+    if keeper is not None:
+        keeper.join()
+    with _LOCK:
+        return [Program(key, exe) for key, exe in _EXECUTABLES.items()] \
+            + [p for p in _KEPT if p._hlo is not None]
 
 
 _NAMED_SHARDING = None  # lazy: keep this module importable without jax
@@ -401,6 +509,7 @@ class AotStep:
                 exe, _ = self._compile_locked(key, args)
             if exe is None:
                 return self._jit(*args)
+            STATS.record_first_dispatch(key)
             return exe(*args)
         try:
             out = exe(*args)
@@ -412,7 +521,7 @@ class AotStep:
             # retrace as a hit.
             STATS.record_fallback()
             return self._jit(*args)
-        STATS.record_hit()
+        STATS.record_hit(key)
         return out
 
     def warm(self, *args) -> bool:

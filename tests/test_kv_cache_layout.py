@@ -966,3 +966,139 @@ def test_ssm_prompt_walk_is_the_kernel_and_keeps_no_state_history(
     kernels = [line for line in decode.splitlines()
                if 'custom_call_target="tpu_custom_call"' in line]
     assert len(kernels) == 1 and "selective_scan" not in kernels[0]
+
+
+# --- the scopes of the programs compiled for the v5e --------------------------
+# (``telemetry.device_time``: what the device's time is filed under)
+
+@pytest.fixture(scope="module")
+def gpt2_programs(one_chip):
+    """Decode window, prompt and join of a two-layer GPT-2-style decoder
+    (heads of 128, three rows) compiled for the v5e from avals."""
+    zoo = TransformerEncoder(vocab_size=512, embed_dim=256, n_heads=2,
+                             n_layers=2, max_len=1024, causal=True,
+                             lm_head=True, seed=0)
+    return _compiled_programs(one_chip, zoo, 3, 1024, 128)
+
+
+@pytest.fixture(scope="module")
+def train_programs(one_chip):
+    """The train step of a tiny residual ``ComputationGraph`` (two
+    convolutions with BatchNorm around a skip, bfloat16 compute, Adam)
+    compiled for the v5e from avals: ``{"train": (text, vertex names)}``."""
+    import dataclasses
+
+    from deeplearning4j_tpu.conf.activations import Activation
+    from deeplearning4j_tpu.conf.graph import ElementWiseOp, ElementWiseVertex
+    from deeplearning4j_tpu.conf.inputs import InputType
+    from deeplearning4j_tpu.conf.layers import OutputLayer
+    from deeplearning4j_tpu.conf.layers_cnn import (
+        BatchNormalization,
+        ConvolutionLayer,
+        GlobalPoolingLayer,
+    )
+    from deeplearning4j_tpu.conf.multilayer import NeuralNetConfiguration
+    from deeplearning4j_tpu.conf.updaters import Adam
+    from deeplearning4j_tpu.nn.graph import ComputationGraph
+
+    g = (NeuralNetConfiguration.builder().seed(3).updater(Adam(1e-3))
+         .graph_builder().add_inputs("in")
+         .set_input_types(InputType.convolutional(32, 32, 16)))
+    conv = lambda: ConvolutionLayer(  # noqa: E731
+        n_out=16, kernel_size=(3, 3), padding=(1, 1),
+        activation=Activation.IDENTITY)
+    g.add_layer("stem_conv", conv(), "in")
+    g.add_layer("stem_bn", BatchNormalization(activation=Activation.RELU),
+                "stem_conv")
+    g.add_layer("res_conv", conv(), "stem_bn")
+    g.add_layer("res_bn", BatchNormalization(), "res_conv")
+    g.add_vertex("res_add", ElementWiseVertex(op=ElementWiseOp.ADD),
+                 "stem_bn", "res_bn")
+    g.add_layer("pool", GlobalPoolingLayer(), "res_add")
+    g.add_layer("output", OutputLayer(n_out=10), "pool")
+    conf = dataclasses.replace(g.set_outputs("output").build(),
+                               compute_dtype="bfloat16")
+    net = ComputationGraph(conf)
+    params, state = jax.eval_shape(lambda: (lambda n: (n.params, n.state))(
+        ComputationGraph(conf).init()))
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    on_chip = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda x: sds(x.shape, x.dtype), tree)
+    opt = jax.tree_util.tree_map(lambda x: {"m": x, "v": x}, params)
+    raw = net.train_step_fn()
+
+    def step(params, state, opt, features, labels, it, ep, key):
+        ones = (jnp.ones((labels[0].shape[0],), jnp.float32),)
+        return raw(params, state, opt, features, labels, (None,), ones, it,
+                   ep, key)
+
+    txt = jax.jit(step, donate_argnums=(0, 1, 2)).trace(
+        on_chip(params), on_chip(state), on_chip(opt),
+        (sds((8, 32, 32, 16), jnp.float32),), (sds((8, 10), jnp.float32),),
+        sds((), jnp.int32), sds((), jnp.int32), sds((2,), jnp.uint32)).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
+    return {"train": (txt, set(net._topo))}
+
+
+_WALKED = {     # the classes of each decoder's plan entries
+    "gpt2_programs": {"embed", "pos", "norm", "attn.mha", "residual", "ffn",
+                      "head"},
+    "hybrid_programs": {"embed", "norm", "attn.sparse", "attn.lightning",
+                        "residual", "ffn", "head"},
+    "routed_programs": {"embed", "norm", "attn.window", "attn.full",
+                        "residual", "ffn", "moe", "head"},
+    "ssm_programs": {"embed", "norm", "ssm", "attn.full", "residual", "ffn",
+                     "head"},
+}
+
+
+@pytest.mark.parametrize("programs,program", [
+    (d, p) for d in sorted(_WALKED) for p in ("decode", "prompt", "join")
+] + [("train_programs", "train")])
+def test_compiled_programs_carry_a_scope_on_what_does_work(
+        request, programs, program):
+    """In the text the TPU's compiler makes of each program, at least 95%
+    of the instructions that run as operations and do work (no parameter,
+    constant, tuple or bitcast, no loop: its body's are counted) lie
+    under a scope of the vocabulary, their own or the one they take from
+    the instruction they feed (``device_time.parse_scopes``); and every
+    plan entry's class appears (a join holds the cached layers' alone; a
+    residual sum lives inside its neighbour's fusion, so the classes are
+    looked for in every ``op_name``)."""
+    from deeplearning4j_tpu.telemetry import device_time as dt
+
+    txt, vertices = request.getfixturevalue(programs)[program]
+    work = [op for op in dt.parse_scopes(txt).values()
+            if op.opcode not in dt.NO_WORK
+            and op.opcode not in ("while", "call", "conditional")]
+    if program == "train":
+        known = set(dt.TRAIN_SCOPES) | vertices
+        known |= {f"transpose({name})" for name in known}
+        wanted = {"cast", "loss", "updater", "stem_conv", "res_bn",
+                  "transpose(res_conv)", "transpose(stem_bn)"}
+    else:
+        known = set(dt.SCOPE_CLASSES + dt.PROGRAM_SCOPES)
+        wanted = _WALKED[programs]
+        if program == "join":
+            wanted = {c for c in wanted if c.startswith(("attn.", "ssm"))} \
+                | {"cache.write", "window.account"}
+        else:
+            wanted = wanted | {"sample"}
+    named = [op for op in work if dt.group_of(op) in known]
+    assert len(work) > 10 and len(named) >= 0.95 * len(work), (
+        len(named), len(work), sorted({
+            (op.opcode, op.scope) for op in work
+            if dt.group_of(op) not in known})[:20])
+    seen = set()
+    for name in set(re.findall(r'op_name="([^"]*)"', txt)):
+        scope, backward, _loop = dt.scope_of(name)
+        seen.update(scope)
+        if backward and scope:
+            seen.add(f"transpose({scope[0]})")
+    assert wanted <= seen, wanted - seen
+    if (programs, program) == ("gpt2_programs", "decode"):
+        # float32 matrices: the loop-invariant converts to bfloat16 stand
+        # before the while, once a window
+        assert any(op.opcode == "convert" and not op.in_while
+                   and dt.group_of(op) == "window.prepare" for op in work)
