@@ -86,8 +86,10 @@ def reference_attention(q, k, v, key_mask=None, causal=False, scale=None):
 #   q[g, d]`` where ``g == g'`` and 0 elsewhere, output ``P^T @ V`` of
 #   which each head keeps its own ``head_dim`` columns. The zeros add
 #   exact zeros. ``Precision.HIGHEST`` keeps float32 operands float32
-#   (the matrix unit's default rounds them to bfloat16); the step is
-#   bound by reading the cache, not by the matrix unit, either way.
+#   (the matrix unit's default rounds them to bfloat16). A bfloat16
+#   cache's paged read takes the same numbers in one bfloat16 pass
+#   (:func:`exact_parts_dot`): at ONE KV head, six float32 passes of 20
+#   rows were a third of the read's time (PERF.md §6, PR 38).
 
 def _block_diagonal(q):
     """``q: [batch, time, heads, head_dim]`` as the right-hand side of the
@@ -146,9 +148,34 @@ def decode_attention(q, k_cache, v_cache, positions, scale=None):
                                   scale)[:, 0]
 
 
+def exact_parts_dot(x, y, y_contract: int):
+    """``x @ y`` (``x``'s axis 1 against ``y``'s axis ``y_contract``) at
+    ``Precision.HIGHEST`` for a float32 ``x: [rows, k]`` and a bfloat16
+    ``y``, in ONE bfloat16 pass of the matrix unit. ``y`` is exact in one
+    bfloat16 part, so the float32 product has three partial products that
+    are not zero: each of ``x``'s three exact bfloat16 parts (``hi =
+    bf16(x)``, ``mid = bf16(x - hi)``, ``lo = bf16(x - hi - mid)``) times
+    ``y``. Stacked along the rows they are one product of ``3 * rows``
+    rows against ``y`` (which the matrix unit loads once), accumulated in
+    float32; the three row blocks summed are the HIGHEST product to float32
+    rounding. Nothing is rounded to one bfloat16 part. On the TPU ``rows``
+    is a multiple of 16, so that the parts stack on whole bfloat16 tiles.
+    Returns ``[rows, n]`` float32."""
+    n = x.shape[0]
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    z = jax.lax.dot_general(
+        jnp.concatenate([hi, mid, lo], axis=0), y,
+        (((1,), (y_contract,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return z[:n] + z[n:2 * n] + z[2 * n:]
+
+
 def _paged_decode_kernel(pos_ref, row_ref, page_ref, q_ref, own_ref, k_ref,
                          v_ref, o_ref, m_sc, l_sc, acc_sc, *, sm, page,
-                         grouped=False):
+                         grouped=False, split=False):
     """Online-softmax decode over the LIVE KV pages of every row. The grid
     is one flat list of (row, page) pairs, a row's pages in order and the
     rows one after another, as long as the rows' positions make it (its
@@ -168,7 +195,10 @@ def _paged_decode_kernel(pos_ref, row_ref, page_ref, q_ref, own_ref, k_ref,
     ``grouped`` (several query heads a KV head): a head's query sits in
     its KV GROUP's columns, the heads of a group share them, and the
     store keeps every head's row apart (``[heads, e]``, zeros outside the
-    head's own group)."""
+    head's own group). ``split`` (a bfloat16 cache): the page stays
+    bfloat16 and both products are :func:`exact_parts_dot`, the float32
+    operand's three exact parts in one pass, in place of a float32 page
+    at HIGHEST."""
     w = pl.program_id(0)
     j = page_ref[w]
     pos = pos_ref[row_ref[w]]
@@ -180,11 +210,14 @@ def _paged_decode_kernel(pos_ref, row_ref, page_ref, q_ref, own_ref, k_ref,
         l_sc[...] = jnp.zeros_like(l_sc)
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
-    k = k_ref[0].astype(jnp.float32)           # [page, e]
-    v = v_ref[0].astype(jnp.float32)           # [page, e]
-    s = jax.lax.dot_general(
-        q_ref[0].astype(jnp.float32), k, (((1,), (1,)), ((), ())),
-        precision=highest, preferred_element_type=jnp.float32) * sm  # [h, page]
+    if split:
+        s = exact_parts_dot(q_ref[0], k_ref[0], 1) * sm           # [h, page]
+    else:
+        k = k_ref[0].astype(jnp.float32)           # [page, e]
+        v = v_ref[0].astype(jnp.float32)           # [page, e]
+        s = jax.lax.dot_general(
+            q_ref[0].astype(jnp.float32), k, (((1,), (1,)), ((), ())),
+            precision=highest, preferred_element_type=jnp.float32) * sm
     # boundary page: slots past positions[b] masked exactly like the
     # masked full-cache read (exp underflows to 0.0 — garbage in
     # unwritten slots can never leak)
@@ -196,8 +229,9 @@ def _paged_decode_kernel(pos_ref, row_ref, page_ref, q_ref, own_ref, k_ref,
     alpha = jnp.exp(m_prev - m_next)
     m_sc[...] = m_next
     l_sc[...] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-    acc_sc[...] = acc_sc[...] * alpha + jnp.dot(
-        p, v, precision=highest, preferred_element_type=jnp.float32)  # [h, e]
+    acc_sc[...] = acc_sc[...] * alpha + (                           # [h, e]
+        exact_parts_dot(p, v_ref[0], 0) if split else jnp.dot(
+            p, v, precision=highest, preferred_element_type=jnp.float32))
 
     @pl.when(j == pos // page)
     def _store():
@@ -235,7 +269,9 @@ def paged_decode_attention(q, k_cache, v_cache, positions, scale=None,
     a divisor exists). ``interpret=None`` auto-enables the Pallas
     interpreter off-TPU. ``groups`` (0: one query head a KV head): the
     caches hold ``groups`` KV heads, ``heads / groups`` query heads
-    each."""
+    each. A bfloat16 cache is read as it lies and multiplied by
+    :func:`exact_parts_dot` (the heads padded to whole bfloat16 tiles); a
+    float32 one at HIGHEST."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, h, d = q.shape
@@ -247,6 +283,14 @@ def paged_decode_attention(q, k_cache, v_cache, positions, scale=None,
         raise ValueError(f"{h} heads of {d} must share {groups} KV heads of "
                          f"a {e}-wide cache")
     sm = _scale(q, scale)
+    split = k_cache.dtype == v_cache.dtype == jnp.bfloat16
+    rows = -(-h // 16) * 16 if split else h
+
+    def pad(x):     # [.., h, e] -> [.., rows, e], zeros below
+        if rows == h:
+            return x
+        return jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(0, rows - h), (0, 0)])
+
     pos = jnp.clip(positions.astype(jnp.int32), 0, s - 1)
     if groups:      # a head's query and output sit in its GROUP's columns
         from deeplearning4j_tpu.ops.block_sparse import (
@@ -277,30 +321,32 @@ def paged_decode_attention(q, k_cache, v_cache, positions, scale=None,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(ends[-1],),
-        in_specs=[pl.BlockSpec((1, h, e), row_map),
-                  pl.BlockSpec((h, e), lambda w, p, r, j: (0, 0)),
+        in_specs=[pl.BlockSpec((1, rows, e), row_map),
+                  pl.BlockSpec((rows, e), lambda w, p, r, j: (0, 0)),
                   pl.BlockSpec((1, page, e), kv_map),
                   pl.BlockSpec((1, page, e), kv_map)],
-        out_specs=pl.BlockSpec((1, h if groups else 1, e), row_map),
-        scratch_shapes=[pltpu.VMEM((h, 1), jnp.float32),
-                        pltpu.VMEM((h, 1), jnp.float32),
-                        pltpu.VMEM((h, e), jnp.float32)],
+        out_specs=pl.BlockSpec((1, rows if groups else 1, e), row_map),
+        scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32),
+                        pltpu.VMEM((rows, 1), jnp.float32),
+                        pltpu.VMEM((rows, e), jnp.float32)],
     )
     params = None
     if not interpret:
         params = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, sm=sm, page=page,
-                          grouped=bool(groups)),
+                          grouped=bool(groups), split=split),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h if groups else 1, e), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, rows if groups else 1, e),
+                                       q.dtype),
         compiler_params=params,
         interpret=interpret,
     )(pos, row_of, page_of,
-      q_rows if groups else jnp.swapaxes(_block_diagonal(q[:, None]), 1, 2),
-      own, k_cache, v_cache)
+      pad(q_rows if groups else jnp.swapaxes(_block_diagonal(q[:, None]), 1,
+                                             2)),
+      pad(own), k_cache, v_cache)
     if groups:      # the other groups' columns of a head's row are zeros
-        return out.reshape(b, h, groups, d).sum(axis=2)
+        return out[:, :h].reshape(b, h, groups, d).sum(axis=2)
     return out.reshape(b, h, d)
 
 
@@ -308,19 +354,33 @@ def paged_decode_attention(q, k_cache, v_cache, positions, scale=None,
 # grid step costs a fixed third of a microsecond beside its DMA: at
 # [8, 1024, 1280] float32 on the v5e, 128 reads a fifth-full bucket in
 # 40 us a layer (64: 42, 256: 45) and a full one in 117 (64: 145, 256:
-# 111) against the masked read's 113 (PERF.md §5).
+# 111) against the masked read's 113 (PERF.md §5). A narrow cache's page
+# is lengthened until its keys fill DECODE_PAGE_BYTES: a grid step costs
+# some 0.45 us beside its DMA whatever the page holds, and ONE bfloat16 KV
+# head of 128 holds 32 KB a page of 128. One such layer at 128 rows and
+# about 1,070 live positions a row read in 599 us at 128 a page, 360 at
+# 256, 231 at 512, 195 at 1,024; four KV heads at 32 rows and about 2,800
+# in 465, 332, 273, 278 (PERF.md §6, PR 38).
 DECODE_PAGE = 128
+DECODE_PAGE_BYTES = 1 << 18
 
 
-def decode_page(max_len: int, width: int) -> Optional[int]:
+def decode_page(max_len: int, width: int, itemsize: int) -> Optional[int]:
     """The page :func:`bounded_decode_attention` reads a ``[batch,
-    max_len, width]`` cache by on the TPU, from the shape alone, or
-    ``None`` where the kernel does not apply: the width must fill whole
-    128-lane tiles and the bucket must hold at least two pages (with one
-    there is nothing to skip)."""
+    max_len, width]`` cache of ``itemsize``-byte elements by on the TPU,
+    from the shape alone, or ``None`` where the kernel does not apply:
+    the width must fill whole 128-lane tiles and the bucket must hold at
+    least two pages (with one there is nothing to skip). The page is
+    ``DECODE_PAGE`` times the largest power of two that keeps a page of
+    keys within ``DECODE_PAGE_BYTES`` and still divides the bucket into
+    two pages or more."""
     if width % 128 or max_len % DECODE_PAGE or max_len < 2 * DECODE_PAGE:
         return None
-    return DECODE_PAGE
+    page = DECODE_PAGE
+    while (2 * page * width * itemsize <= DECODE_PAGE_BYTES
+           and max_len % (2 * page) == 0 and max_len >= 4 * page):
+        page *= 2
+    return page
 
 
 def bounded_decode_attention(q, k_cache, v_cache, positions, scale=None,
@@ -346,7 +406,7 @@ def bounded_decode_attention(q, k_cache, v_cache, positions, scale=None,
         raise ValueError("bounded_decode_attention: grouped KV heads take "
                          "the default scale")
     s, e = k_cache.shape[1:]
-    page = decode_page(s, e)
+    page = decode_page(s, e, k_cache.dtype.itemsize)
 
     def masked(q, k_cache, v_cache, positions):
         if groups:

@@ -24,11 +24,13 @@ import numpy as np
 import pytest
 
 from deeplearning4j_tpu.ops.attention import (
+    DECODE_PAGE_BYTES,
     bounded_decode_attention,
     cache_update,
     chunk_decode_attention,
     decode_attention,
     decode_page,
+    exact_parts_dot,
     paged_decode_attention,
     reference_attention,
 )
@@ -94,20 +96,36 @@ def _ragged(page, s):
     return np.asarray([0, page - 1, page, s - 1, 17, 2 * page + 5], np.int32)
 
 
-@pytest.mark.parametrize("page", [16, 128])
+def _cache(rng, shape, dtype):
+    """Seeded keys or values in the cache's dtype, and the float32 array
+    that holds the same numbers (a bfloat16 value is exact in float32)."""
+    c = rng.normal(size=shape).astype(np.float32)
+    if dtype == "bfloat16":
+        c = np.asarray(jnp.asarray(c, jnp.bfloat16).astype(jnp.float32))
+    return c
+
+
+# (page, cache dtype): a float32 cache at the pages it has run at since
+# PR 30, a bfloat16 one at the longer pages PR 38's decode_page gives it
+# (the page stays bfloat16, the products are exact_parts_dot)
+PAGED = [(16, "float32"), (128, "float32"), (256, "bfloat16"),
+         (512, "bfloat16")]
+
+
+@pytest.mark.parametrize("page,dtype", PAGED)
 @pytest.mark.parametrize("heads,hs", GEOMETRIES)
-def test_paged_read_matches_masked_read(heads, hs, page):
+def test_paged_read_matches_masked_read(heads, hs, page, dtype):
     """The kernel the decode step runs on the TPU (here through the
-    Pallas interpreter) against ``decode_attention``, rows at ragged
-    positions over caches FULL of a retired tenant's keys and values:
-    large, finite, and different beyond every row's position, so a page
-    or a slot read past the bound shows."""
+    Pallas interpreter) against ``decode_attention`` of the same numbers
+    in float32, rows at ragged positions over caches FULL of a retired
+    tenant's keys and values: large, finite, and different beyond every
+    row's position, so a page or a slot read past the bound shows."""
     s = 4 * page
     positions = _ragged(page, s)
     b, e = len(positions), heads * hs
     rng = np.random.default_rng(heads * 100 + hs + page)
-    k = rng.normal(size=(b, s, e)).astype(np.float32)
-    v = rng.normal(size=(b, s, e)).astype(np.float32)
+    k = _cache(rng, (b, s, e), dtype)
+    v = _cache(rng, (b, s, e), dtype)
     q = jnp.asarray(rng.normal(size=(b, heads, hs)).astype(np.float32))
     want = np.asarray(decode_attention(q, jnp.asarray(k), jnp.asarray(v),
                                        positions))
@@ -115,8 +133,7 @@ def test_paged_read_matches_masked_read(heads, hs, page):
     stale_k = np.where(beyond, 300.0 * rng.normal(size=k.shape), k)
     stale_v = np.where(beyond, 300.0 * rng.normal(size=v.shape), v)
     got = paged_decode_attention(
-        q, jnp.asarray(stale_k, jnp.float32), jnp.asarray(stale_v,
-                                                          jnp.float32),
+        q, jnp.asarray(stale_k, dtype), jnp.asarray(stale_v, dtype),
         positions, page=page, interpret=True)
     np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
 
@@ -127,8 +144,8 @@ def test_bounded_read_off_the_tpu_is_the_masked_read(heads, hs):
     says so: it read the whole bucket. A position past the bucket (a
     retired row's) reads as the last slot, as the write clamps it."""
     s, e = 256, heads * hs
-    assert decode_page(s, e) == 128 and decode_page(128, e) is None
-    assert decode_page(s, e + 64) is None
+    assert decode_page(s, e, 4) == 128 and decode_page(128, e, 4) is None
+    assert decode_page(s, e + 64, 4) is None
     positions = np.asarray([0, 127, 128, s - 1, s + 3], np.int32)
     b = len(positions)
     rng = np.random.default_rng(heads + hs)
@@ -146,24 +163,29 @@ def test_bounded_read_off_the_tpu_is_the_masked_read(heads, hs):
                                rtol=2e-5, atol=2e-5)
 
 
-# (query heads, KV heads, head size): the routed-experts cell's, a toy
-GROUPED = [(32, 4, 128), (8, 2, 16)]
+# (query heads, KV heads, head size): the routed-experts cell's, the
+# state-space cell's (ONE KV head), a toy
+GROUPED = [(32, 4, 128), (20, 1, 128), (8, 2, 16)]
 
 
-@pytest.mark.parametrize("page", [16, 128])
+@pytest.mark.parametrize("page,dtype", [(16, "float32"), (128, "float32"),
+                                        (256, "bfloat16"),
+                                        (1024, "bfloat16")])
 @pytest.mark.parametrize("heads,groups,hs", GROUPED)
-def test_grouped_paged_read_matches_masked_read(heads, groups, hs, page):
+def test_grouped_paged_read_matches_masked_read(heads, groups, hs, page,
+                                                dtype):
     """The same kernel with several query heads a KV head (through the
-    Pallas interpreter) against the masked read of grouped caches, rows
-    at ragged positions over a retired tenant's keys and values."""
+    Pallas interpreter) against the masked read of grouped caches holding
+    the same numbers in float32, rows at ragged positions over a retired
+    tenant's keys and values."""
     from deeplearning4j_tpu.ops.block_sparse import dense_decode_attention
 
     s = 4 * page
     positions = _ragged(page, s)
     b, e = len(positions), groups * hs
     rng = np.random.default_rng(heads * 100 + hs + page)
-    k = rng.normal(size=(b, s, e)).astype(np.float32)
-    v = rng.normal(size=(b, s, e)).astype(np.float32)
+    k = _cache(rng, (b, s, e), dtype)
+    v = _cache(rng, (b, s, e), dtype)
     q = jnp.asarray(rng.normal(size=(b, heads, hs)).astype(np.float32))
     want = np.asarray(dense_decode_attention(
         q, jnp.asarray(k), jnp.asarray(v), positions, groups))
@@ -171,19 +193,63 @@ def test_grouped_paged_read_matches_masked_read(heads, groups, hs, page):
     stale_k = np.where(beyond, 300.0 * rng.normal(size=k.shape), k)
     stale_v = np.where(beyond, 300.0 * rng.normal(size=v.shape), v)
     got = paged_decode_attention(
-        q, jnp.asarray(stale_k, jnp.float32),
-        jnp.asarray(stale_v, jnp.float32), positions, page=page,
-        interpret=True, groups=groups)
+        q, jnp.asarray(stale_k, dtype), jnp.asarray(stale_v, dtype),
+        positions, page=page, interpret=True, groups=groups)
     assert got.shape == (b, heads, hs)
     np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
     # off the TPU the bounded read is the masked one and says so
+    kc, vc = jnp.asarray(k, dtype), jnp.asarray(v, dtype)
     got, read = jax.jit(lambda *a: bounded_decode_attention(
-        *a, groups=groups))(q, jnp.asarray(k), jnp.asarray(v), positions)
-    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-6)
+        *a, groups=groups))(q, kc, vc, positions)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(jax.jit(
+            dense_decode_attention, static_argnums=4)(
+                q, kc, vc, positions, groups)), rtol=1e-5, atol=1e-6)
     assert np.asarray(read).tolist() == [s] * b
     with pytest.raises(ValueError, match="must share"):
         paged_decode_attention(q, jnp.asarray(k), jnp.asarray(v), positions,
                                page=page, interpret=True, groups=3)
+
+
+@pytest.mark.parametrize("y_contract", [0, 1])
+def test_exact_parts_product_is_the_highest_product(y_contract):
+    """The stacked-parts product of a float32 operand and a bfloat16 one
+    (both forms the kernel takes: ``P V`` contracts the page's rows, ``Q
+    K^T`` its lanes) is the HIGHEST product to float32 rounding: each
+    entry within 1e-6 of the sum of its terms' magnitudes (the two sum in
+    different orders); rounding the float32 operand to ONE bfloat16 part
+    misses by a hundred times that."""
+    rng = np.random.default_rng(38 + y_contract)
+    x = jnp.asarray(rng.normal(size=(32, 256)).astype(np.float32))
+    y = jnp.asarray(rng.normal(size=(256, 512) if y_contract == 0
+                               else (512, 256)), jnp.bfloat16)
+    dims = (((1,), (y_contract,)), ((), ()))
+    want = np.asarray(jax.lax.dot_general(
+        x, y.astype(jnp.float32), dims,
+        precision=jax.lax.Precision.HIGHEST))
+    got = np.asarray(exact_parts_dot(x, y, y_contract))
+    assert got.shape == want.shape == (32, 512)
+    y32 = np.asarray(y.astype(jnp.float32))
+    terms = np.abs(np.asarray(x)) @ np.abs(y32 if y_contract == 0 else y32.T)
+    assert np.max(np.abs(got - want) / terms) < 1e-6
+    one_part = np.asarray(jax.lax.dot_general(
+        x.astype(jnp.bfloat16), y, dims, preferred_element_type=jnp.float32))
+    assert np.max(np.abs(one_part - want) / terms) > 1e-4
+
+
+def test_decode_page_follows_the_cache_bytes_a_position():
+    """GPT-2-large's float32 cache of 1,280 lanes keeps pages of 128; a
+    bfloat16 cache of ONE KV head of 128 (the state-space cell's) takes
+    the page whose keys fill ``DECODE_PAGE_BYTES``, four KV heads
+    (the routed-experts cell's) a quarter of it; a small bucket still
+    holds two pages; a width off the lanes' tiles takes no kernel."""
+    assert decode_page(1024, 1280, 4) == 128
+    assert decode_page(8192, 128, 2) * 128 * 2 == DECODE_PAGE_BYTES
+    assert decode_page(16384, 512, 2) * 512 * 2 == DECODE_PAGE_BYTES
+    assert decode_page(8192, 128, 2) == 4 * decode_page(16384, 512, 2)
+    assert decode_page(512, 128, 2) == 256 and decode_page(256, 128, 2) == 128
+    assert decode_page(8192, 128, 4) == decode_page(8192, 128, 2) // 2
+    assert decode_page(8192, 192, 2) is None
 
 
 # --- what the engine says the step read ----------------------------------------
@@ -197,6 +263,7 @@ def _steer_to_the_tpu_branch(monkeypatch, page):
     kernel, choose = attention.paged_decode_attention, \
         jax.lax.platform_dependent
     monkeypatch.setattr(attention, "DECODE_PAGE", page)
+    monkeypatch.setattr(attention, "DECODE_PAGE_BYTES", 0)
     monkeypatch.setattr(
         attention, "paged_decode_attention",
         lambda *a, **kw: kernel(*a, **{**kw, "interpret": True}))
@@ -497,7 +564,7 @@ def test_compiled_decode_window_keeps_one_cache_layout(one_chip, heads, hs):
     benchmark's 36 layers)."""
     b, s, k = 8, 1024, 4
     layers = 2
-    assert decode_page(s, heads * hs) == 128
+    assert decode_page(s, heads * hs, 4) == 128
     m = TransformerEncoder(vocab_size=256, embed_dim=heads * hs,
                            n_heads=heads, n_layers=layers, max_len=s,
                            causal=True, lm_head=True, seed=0)

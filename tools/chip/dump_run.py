@@ -63,7 +63,7 @@ def by_scope(self):
         return
     table["by_scope_s"] = time.monotonic() - t0
     table["clear"] = dict(cleared)
-    os.makedirs("chiprun_out", exist_ok=True)
+    os.makedirs(os.path.dirname(f"chiprun_out/{label}"), exist_ok=True)
     with open(f"chiprun_out/{label}.scopes.json", "w") as f:
         json.dump(table, f)
     text = device_time.format_table(table)
