@@ -31,7 +31,9 @@ The per-layer cache interface (``nn.decoding`` walks it; ``docs/serving.md``):
 - ``cache_grow(cache, length)``, ``cache_release(cache, keep)``.
 - ``cache_kinds``: cache leaf -> the kind of state it is (``kv``,
   ``kv_ring``, ``compressed_keys``, ``recurrent``, ``conv_window``: a
-  state-space mixer's two, ``conf/layers_ssm.py``); ``cache_counters``:
+  state-space or delta-rule mixer's two, ``conf/layers_ssm.py``,
+  ``conf/layers_delta.py``; ``latent``: one latent vector a position,
+  ``conf/layers_delta.py``); ``cache_counters``:
   the names of the counts ``cache_step`` returns.
 
 A layer WITHOUT per-row state that still couples the rows of a batch (the
@@ -88,6 +90,17 @@ def rms_norm(x, gain, eps: float):
     x = x.astype(jnp.float32)
     return x * jax.lax.rsqrt(
         jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * gain
+
+
+def swiglu(g, up, limit: float = 0.0):
+    """``silu(g) * up()``; with ``limit`` the gate's input is clamped from
+    above and the other half into ``[-limit, limit]`` first. ``up`` is
+    called after the gate's activation, in the order the unclamped
+    expression has always been traced in."""
+    if limit:
+        return (jax.nn.silu(jnp.minimum(g, limit))
+                * jnp.clip(up(), -limit, limit))
+    return jax.nn.silu(g) * up()
 
 
 def rotate(x, positions, theta: float):
@@ -156,14 +169,18 @@ class ResidualAddVertex(GraphVertex):
 @serde.register
 @dataclasses.dataclass
 class RMSNormLayer(BaseLayer):
-    """``x / rms(x) * gain`` over the feature axis, float32."""
+    """``x / rms(x) * gain`` over the feature axis, float32.
+    ``zero_centred``: the parameter is ``w`` and the gain ``1 + w``."""
 
     scope_class = "norm"
 
     eps: float = 1e-6
+    zero_centred: bool = False
 
     def init(self, key, input_type, dtype=jnp.float32):
-        return {"gain": jnp.ones((_as_ff_size(input_type),), jnp.float32)}
+        n = _as_ff_size(input_type)
+        return {"gain": jnp.zeros((n,), jnp.float32) if self.zero_centred
+                else jnp.ones((n,), jnp.float32)}
 
     def param_order(self):
         return ["gain"]
@@ -172,7 +189,9 @@ class RMSNormLayer(BaseLayer):
         return []
 
     def forward(self, params, state, x, train=False, rng=None):
-        return rms_norm(x, params["gain"], self.eps), state
+        gain = params["gain"]
+        return rms_norm(x, 1.0 + gain if self.zero_centred else gain,
+                        self.eps), state
 
 
 @serde.register
@@ -196,15 +215,17 @@ class ScaledEmbeddingLayer(EmbeddingSequenceLayer):
 @serde.register
 @dataclasses.dataclass
 class GatedFeedForwardLayer(BaseLayer):
-    """``out_scale * Wd (silu(Wg x) * Wu x)``. More than ``rows_max``
-    tokens go through in slices of that many, so that a long prompt's
-    hidden activations are never all alive at once."""
+    """``out_scale * Wd (silu(Wg x) * Wu x)`` (:func:`swiglu`, clamped
+    where ``swiglu_limit``). More than ``rows_max`` tokens go through in
+    slices of that many, so that a long prompt's hidden activations are
+    never all alive at once."""
 
     n_out: int = 0
     n_hidden: int = 0
     out_scale: float = 1.0
     rows_max: int = 2048
     weight_dtype: str = ""
+    swiglu_limit: float = 0.0
 
     def output_type(self, input_type):
         if isinstance(input_type, it.Recurrent):
@@ -230,7 +251,8 @@ class GatedFeedForwardLayer(BaseLayer):
         x = self._dropout_input(x, train, rng)
 
         def ff(u):
-            hidden = jax.nn.silu(_dot(u, params["Wg"])) * _dot(u, params["Wu"])
+            hidden = swiglu(_dot(u, params["Wg"]),
+                            lambda: _dot(u, params["Wu"]), self.swiglu_limit)
             return _dot(hidden, params["Wd"]) * self.out_scale
 
         flat = x.reshape(-1, x.shape[-1])

@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from deeplearning4j_tpu import serde
 from deeplearning4j_tpu.conf import inputs as it
 from deeplearning4j_tpu.conf.layers import BaseLayer
-from deeplearning4j_tpu.conf.layers_hybrid import _dot, _matrix, _wdtype
+from deeplearning4j_tpu.conf.layers_hybrid import _dot, _matrix, _wdtype, swiglu
 
 #: Reserved state key: layers put auxiliary (train-time) loss terms here;
 #: MultiLayerNetwork/ComputationGraph ``_loss`` sums them into the score.
@@ -234,6 +234,7 @@ class RoutedExpertsLayer(BaseLayer):
     experts_held: tuple = (0, 0)    # (first, count); count 0: all
     out_scale: float = 1.0
     weight_dtype: str = ""
+    swiglu_limit: float = 0.0       # every SwiGLU clamped (layers_hybrid.swiglu)
 
     uses_mask = True
     live_counters = ("moe_routed_slots", "moe_experts_touched",
@@ -297,10 +298,12 @@ class RoutedExpertsLayer(BaseLayer):
         expert got. An expert nobody chose is never read."""
         order = jnp.argsort(group)
         xs = x[order // self.top_k]
-        hidden = (jax.nn.silu(jax.lax.ragged_dot(
-            xs, params["Wg"], sizes, preferred_element_type=jnp.float32))
-            * jax.lax.ragged_dot(xs, params["Wu"], sizes,
-                                 preferred_element_type=jnp.float32))
+        hidden = swiglu(
+            jax.lax.ragged_dot(xs, params["Wg"], sizes,
+                               preferred_element_type=jnp.float32),
+            lambda: jax.lax.ragged_dot(xs, params["Wu"], sizes,
+                                       preferred_element_type=jnp.float32),
+            self.swiglu_limit)
         ys = jax.lax.ragged_dot(hidden.astype(x.dtype), params["Wd"], sizes,
                                 preferred_element_type=jnp.float32)
         # rows behind the last group are whatever the product left
@@ -315,10 +318,11 @@ class RoutedExpertsLayer(BaseLayer):
         through every expert held, ``w: [n, held]`` zero where the expert
         was not chosen."""
         f32 = jnp.float32
-        hidden = (jax.nn.silu(jnp.einsum("nd,edh->enh", x, params["Wg"],
-                                         preferred_element_type=f32))
-                  * jnp.einsum("nd,edh->enh", x, params["Wu"],
-                               preferred_element_type=f32))
+        hidden = swiglu(jnp.einsum("nd,edh->enh", x, params["Wg"],
+                                   preferred_element_type=f32),
+                        lambda: jnp.einsum("nd,edh->enh", x, params["Wu"],
+                                           preferred_element_type=f32),
+                        self.swiglu_limit)
         ys = jnp.einsum("enh,ehd->end", hidden.astype(x.dtype), params["Wd"],
                         preferred_element_type=f32)
         return jnp.einsum("end,ne->nd", ys, w)
@@ -371,12 +375,16 @@ class RoutedExpertsLayer(BaseLayer):
         def touched():
             return (routed_experts.touched_experts_ffn(
                 x, params["Wg"], params["Wu"], params["Wd"],
-                self._by_row(w, chosen), sizes, interpret=False),
+                self._by_row(w, chosen), sizes, interpret=False,
+                **({"limit": self.swiglu_limit} if self.swiglu_limit
+                   else {})),
                 n_touched())
 
-        if slots > most * held:
+        # slots an expert expects, whatever share of the experts is held
+        # here: a holder of 16 of 256 gets a sixteenth of the slots
+        if slots > most * self.n_experts:
             return grouped()
-        plain = every if slots >= fewest * held else grouped
+        plain = every if slots >= fewest * self.n_experts else grouped
         if not routed_experts.touched_experts_applies(
                 params["Wg"].shape, params["Wd"].shape[-1]):
             return plain()
@@ -393,8 +401,9 @@ class RoutedExpertsLayer(BaseLayer):
                                     *slots, sizes)
         if self.n_shared_hidden:
             with jax.named_scope("moe.shared"):
-                y = y + _dot(jax.nn.silu(_dot(u, params["Sg"]))
-                             * _dot(u, params["Su"]), params["Sd"])
+                y = y + _dot(swiglu(_dot(u, params["Sg"]),
+                                    lambda: _dot(u, params["Su"]),
+                                    self.swiglu_limit), params["Sd"])
         return y * self.out_scale, sizes, read
 
     def forward_live(self, params, x, live):

@@ -56,6 +56,60 @@ from deeplearning4j_tpu.ops.selective_scan import (
 SSM_TOKEN_SPAN = 2048      # positions MambaMixerLayer projects and scans at a time
 
 
+# --- a causal depthwise convolution that keeps its last inputs in a ring ------
+# (the state-space mixer's and the delta-rule mixer's, conf/layers_delta.py)
+
+def conv_span(tail, x, taps, mask, bias=None):
+    """A span ``x: [batch, span, d]`` through ``K = taps.shape[0]`` causal
+    taps after the ``K - 1`` earlier inputs ``tail: [batch, K - 1, d]``,
+    oldest first. Returns ``(y, tail')``: ``y_t = silu((bias +) sum_j
+    taps[j] x_{t-K+1+j})``, and the last ``K - 1`` REAL inputs (a
+    right-padded row's real positions are the span's first
+    ``sum(mask)``)."""
+    k, span = taps.shape[0] - 1, x.shape[1]
+    padded = jnp.concatenate([tail, x], axis=1)          # [b, k + span, d]
+    y = sum(taps[j] * padded[:, j:j + span] for j in range(k + 1))
+    if bias is not None:
+        y = bias + y
+    y = jax.nn.silu(y)
+    real = jnp.sum(mask, axis=1).astype(jnp.int32)
+    return y, jnp.take_along_axis(
+        padded, (real[:, None] + jnp.arange(k))[:, :, None], axis=1)
+
+
+def tail_to_ring(tail, lengths):
+    """The last ``k`` inputs oldest first ``[batch, k, d]`` of rows of
+    ``lengths`` positions as the RING a decode step keeps, ``[batch, k *
+    d]``: slot ``s`` holds the input of the position ``p = s (mod k)`` among
+    the last ``k``, the tail's entry ``(s - length) mod k``."""
+    b, k, d = tail.shape
+    entry = (jnp.arange(k)[None, :] - lengths[:, None]) % k
+    return jnp.take_along_axis(tail, entry[:, :, None], axis=1).reshape(
+        b, k * d)
+
+
+def conv_ring_step(ring, x, taps, positions, bias=None):
+    """One token ``x: [batch, d]`` at ``positions`` through the taps, its
+    ``k = K - 1`` earlier inputs in ``ring: [batch, k * d]`` (the input of
+    position ``p`` in slot ``p mod k``): the taps weigh the ring where it
+    lies and the token's input overwrites the oldest slot, so nothing is
+    shifted. Returns ``(silu(y), ring')``, ``y`` as :func:`conv_span`'s."""
+    k, d = taps.shape[0] - 1, x.shape[-1]
+    # the ring's slot of each lane, and how many positions back from the
+    # oldest input (slot positions mod k) it lies
+    slot = jax.lax.broadcasted_iota(jnp.int32, ring.shape, 1) // d
+    age = (slot - (positions % k)[:, None]) % k
+    weighed = sum(jnp.where(age == j, jnp.tile(taps[j], k), 0.0)
+                  for j in range(k)) * ring
+    y = taps[k] * x
+    if bias is not None:
+        y = bias + y
+    y = jax.nn.silu(y + sum(weighed[:, j * d:(j + 1) * d] for j in range(k)))
+    with jax.named_scope("cache.write"):
+        ring = jnp.where(age == 0, jnp.tile(x, (1, k)), ring)
+    return y, ring
+
+
 @serde.register
 @dataclasses.dataclass
 class MambaMixerLayer(BaseLayer):
@@ -150,7 +204,6 @@ class MambaMixerLayer(BaseLayer):
         oldest first: a ``lax.scan`` over spans of ``SSM_TOKEN_SPAN``
         positions, both states its carry. Returns ``(y, state, conv)``."""
         b, t, _ = u.shape
-        k = self.d_conv - 1
         n, span = _token_spans(t, SSM_TOKEN_SPAN)
         mask = (jnp.ones((b, t), jnp.float32) if mask is None
                 else (jnp.asarray(mask) > 0).astype(jnp.float32))
@@ -161,17 +214,8 @@ class MambaMixerLayer(BaseLayer):
             uc, mc = xs
             x, z = self._project(params, uc)
             with jax.named_scope("ssm.conv"):
-                padded = jnp.concatenate([tail, x], axis=1)  # [b, k + span, d]
-                xc = params["conv_b"] + sum(
-                    params["conv_w"][j] * padded[:, j:j + span]
-                    for j in range(self.d_conv))
-                xc = jax.nn.silu(xc)
-                # the last k REAL inputs: a right-padded row's real
-                # positions are the span's first sum(mask)
-                real = jnp.sum(mc, axis=1).astype(jnp.int32)
-                tail = jnp.take_along_axis(
-                    padded, (real[:, None] + jnp.arange(k))[:, :, None],
-                    axis=1)
+                xc, tail = conv_span(tail, x, params["conv_w"], mc,
+                                     params["conv_b"])
             dt, bm, cm = self._scan_inputs(params, xc)
             with jax.named_scope("ssm.scan"):
                 y, h = scan(xc, dt, a, bm, cm, params["D"], mc, h)
@@ -202,16 +246,12 @@ class MambaMixerLayer(BaseLayer):
 
     def cache_prefill(self, params, x, key_mask=None, dtype=jnp.float32,
                       use_kernels=False):
-        b, k, d = x.shape[0], self.d_conv - 1, self.d_inner
+        b = x.shape[0]
         y, state, tail = self._sequence(
             params, x, key_mask, *self._zeros(b), selective_scan)
         lengths = (jnp.full((b,), x.shape[1], jnp.int32) if key_mask is None
                    else jnp.sum(key_mask > 0, axis=1).astype(jnp.int32))
-        # oldest-first -> the ring: slot s holds the input of the position
-        # p = s (mod k) among the last k, the tail's entry (s - length) mod k
-        entry = (jnp.arange(k)[None, :] - lengths[:, None]) % k
-        ring = jnp.take_along_axis(tail, entry[:, :, None], axis=1)
-        return y, {"state": state, "conv": ring.reshape(b, k * d)}
+        return y, {"state": state, "conv": tail_to_ring(tail, lengths)}
 
     def cache_join(self, cache, block, rows, length):
         return {n: _join_rows(cache[n], block[n], rows)
@@ -220,18 +260,8 @@ class MambaMixerLayer(BaseLayer):
     def cache_step(self, params, x, cache, positions, active=None):
         with jax.named_scope("ssm.step"):
             xi, z = self._project(params, x)
-            k, d = self.d_conv - 1, self.d_inner
-            ring = cache["conv"]
-            # the ring's slot of each lane, and how many positions back
-            # from the oldest input (slot positions mod k) it lies
-            slot = jax.lax.broadcasted_iota(jnp.int32, ring.shape, 1) // d
-            age = (slot - (positions % k)[:, None]) % k
-            taps = sum(jnp.where(age == j, jnp.tile(params["conv_w"][j], k),
-                                 0.0) for j in range(k)) * ring
-            xc = jax.nn.silu(params["conv_b"] + params["conv_w"][k] * xi + sum(
-                taps[:, j * d:(j + 1) * d] for j in range(k)))
-            with jax.named_scope("cache.write"):
-                ring = jnp.where(age == 0, jnp.tile(xi, (1, k)), ring)
+            xc, ring = conv_ring_step(cache["conv"], xi, params["conv_w"],
+                                      positions, params["conv_b"])
             dt, b, c = self._scan_inputs(params, xc)
             y, state = selective_scan_step(
                 xc, dt, -jnp.exp(params["A_log"]), b, c, params["D"],

@@ -38,8 +38,10 @@ cache interface of ``conf/layers_hybrid.py`` (``cache_init``,
 ``cache_release``) is served: KV buffers (``SelfAttentionLayer``), KV
 buffers beside compressed keys (``BlockSparseAttentionLayer``), a
 fixed-size recurrent state (``LightningAttentionLayer``), a scan's state
-beside a convolution's window (``conf.layers_ssm.MambaMixerLayer``), side
-by side in
+beside a convolution's window (``conf.layers_ssm.MambaMixerLayer``), a
+delta rule's state beside a convolution's window
+(``conf.layers_delta.GatedDeltaNetLayer``), one latent vector a position
+(``conf.layers_delta.LatentAttentionLayer``), side by side in
 the one donated state pytree, each in its own type. The suffix walk (the
 prefix cache) needs ``prefill_suffix`` on every such layer and refuses a
 graph that has a layer without it, by name.
@@ -325,8 +327,8 @@ class TransformerDecoder:
     def state_bytes(self, s: int) -> Dict[str, int]:
         """Bytes the caches hold at KV bucket ``s``, by kind of state
         (``kv``, ``kv_ring``, ``compressed_keys``, ``recurrent``,
-        ``conv_window``): what each layer's ``cache_kinds`` calls its
-        leaves."""
+        ``conv_window``, ``latent``): what each layer's ``cache_kinds``
+        calls its leaves."""
         out: Dict[str, int] = {}
         for name, leaves in self._kv_struct(self.max_batch, s).items():
             kinds = self._layer(name).cache_kinds

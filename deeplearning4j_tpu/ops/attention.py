@@ -175,7 +175,7 @@ def exact_parts_dot(x, y, y_contract: int):
 
 def _paged_decode_kernel(pos_ref, row_ref, page_ref, q_ref, own_ref, k_ref,
                          v_ref, o_ref, m_sc, l_sc, acc_sc, *, sm, page,
-                         grouped=False, split=False):
+                         grouped=False, split=False, value_width=0):
     """Online-softmax decode over the LIVE KV pages of every row. The grid
     is one flat list of (row, page) pairs, a row's pages in order and the
     rows one after another, as long as the rows' positions make it (its
@@ -198,7 +198,8 @@ def _paged_decode_kernel(pos_ref, row_ref, page_ref, q_ref, own_ref, k_ref,
     head's own group). ``split`` (a bfloat16 cache): the page stays
     bfloat16 and both products are :func:`exact_parts_dot`, the float32
     operand's three exact parts in one pass, in place of a float32 page
-    at HIGHEST."""
+    at HIGHEST. ``v_ref`` None: the values are the first ``value_width``
+    columns of the keys' own page (a latent cache: one array, one DMA)."""
     w = pl.program_id(0)
     j = page_ref[w]
     pos = pos_ref[row_ref[w]]
@@ -210,11 +211,14 @@ def _paged_decode_kernel(pos_ref, row_ref, page_ref, q_ref, own_ref, k_ref,
         l_sc[...] = jnp.zeros_like(l_sc)
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
+    def values():                                  # [page, e or value_width]
+        return v_ref[0] if v_ref is not None else k_ref[0][:, :value_width]
+
     if split:
         s = exact_parts_dot(q_ref[0], k_ref[0], 1) * sm           # [h, page]
     else:
         k = k_ref[0].astype(jnp.float32)           # [page, e]
-        v = v_ref[0].astype(jnp.float32)           # [page, e]
+        v = values().astype(jnp.float32)
         s = jax.lax.dot_general(
             q_ref[0].astype(jnp.float32), k, (((1,), (1,)), ((), ())),
             precision=highest, preferred_element_type=jnp.float32) * sm
@@ -230,7 +234,7 @@ def _paged_decode_kernel(pos_ref, row_ref, page_ref, q_ref, own_ref, k_ref,
     m_sc[...] = m_next
     l_sc[...] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
     acc_sc[...] = acc_sc[...] * alpha + (                           # [h, e]
-        exact_parts_dot(p, v_ref[0], 0) if split else jnp.dot(
+        exact_parts_dot(p, values(), 0) if split else jnp.dot(
             p, v, precision=highest, preferred_element_type=jnp.float32))
 
     @pl.when(j == pos // page)
@@ -244,15 +248,23 @@ def _paged_decode_kernel(pos_ref, row_ref, page_ref, q_ref, own_ref, k_ref,
                                keepdims=True).astype(o_ref.dtype)   # [1, e]
 
 
+def _latent_page_kernel(kernel, value_width, pos_ref, row_ref, page_ref,
+                        q_ref, own_ref, kv_ref, *rest):
+    """:func:`_paged_decode_kernel` over a latent cache: one page operand,
+    the values its first ``value_width`` columns."""
+    kernel(pos_ref, row_ref, page_ref, q_ref, own_ref, kv_ref, None, *rest,
+           value_width=value_width)
+
+
 # jitted so that a decoder's layers share ONE trace and one lowered body of
 # the kernel: 36 layers each tracing and lowering their own cost the decode
 # window 1.7 s more at every start, warm or cold, and a third more StableHLO
 @functools.partial(jax.jit, static_argnames=("scale", "page", "interpret",
-                                             "groups"))
+                                             "groups", "value_width"))
 def paged_decode_attention(q, k_cache, v_cache, positions, scale=None,
                            page: int = 64,
                            interpret: Optional[bool] = None,
-                           groups: int = 0):
+                           groups: int = 0, value_width: int = 0):
     """:func:`decode_attention` as a Pallas kernel gathering KV **pages**
     in-kernel: ``page``-slot blocks of the cache stream HBM→VMEM one DMA
     per page, and only the pages that hold a position up to
@@ -271,7 +283,12 @@ def paged_decode_attention(q, k_cache, v_cache, positions, scale=None,
     caches hold ``groups`` KV heads, ``heads / groups`` query heads
     each. A bfloat16 cache is read as it lies and multiplied by
     :func:`exact_parts_dot` (the heads padded to whole bfloat16 tiles); a
-    float32 one at HIGHEST."""
+    float32 one at HIGHEST.
+
+    ``v_cache`` None (a LATENT cache, ``groups`` 1): the values are the
+    first ``value_width`` columns of the keys themselves, read from the
+    same page; the key width ``d`` is the cache's and the result is
+    ``[batch, heads, value_width]``."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, h, d = q.shape
@@ -283,7 +300,13 @@ def paged_decode_attention(q, k_cache, v_cache, positions, scale=None,
         raise ValueError(f"{h} heads of {d} must share {groups} KV heads of "
                          f"a {e}-wide cache")
     sm = _scale(q, scale)
-    split = k_cache.dtype == v_cache.dtype == jnp.bfloat16
+    latent = v_cache is None
+    if latent and (groups != 1 or not 0 < value_width <= e):
+        raise ValueError("a latent cache takes groups=1 and 0 < value_width "
+                         f"<= {e}")
+    ev = value_width if latent else e           # the values' width
+    split = k_cache.dtype == (k_cache if latent else v_cache).dtype \
+        == jnp.bfloat16
     rows = -(-h // 16) * 16 if split else h
 
     def pad(x):     # [.., h, e] -> [.., rows, e], zeros below
@@ -302,6 +325,8 @@ def paged_decode_attention(q, k_cache, v_cache, positions, scale=None,
             q[:, None].astype(jnp.float32), groups), 1, 2)
     else:
         own = jnp.repeat(jnp.eye(h, dtype=jnp.float32), d, axis=1)  # [h, e]
+    if latent:
+        own = own[:, :ev]
     # the flat list of live pages: step w reads page page_of[w] of row
     # row_of[w]; steps past the list's end (never run) name the last pair
     pages = pos // page + 1
@@ -322,29 +347,34 @@ def paged_decode_attention(q, k_cache, v_cache, positions, scale=None,
         num_scalar_prefetch=3,
         grid=(ends[-1],),
         in_specs=[pl.BlockSpec((1, rows, e), row_map),
-                  pl.BlockSpec((rows, e), lambda w, p, r, j: (0, 0)),
-                  pl.BlockSpec((1, page, e), kv_map),
-                  pl.BlockSpec((1, page, e), kv_map)],
-        out_specs=pl.BlockSpec((1, rows if groups else 1, e), row_map),
+                  pl.BlockSpec((rows, ev), lambda w, p, r, j: (0, 0)),
+                  pl.BlockSpec((1, page, e), kv_map)]
+        + ([] if latent else [pl.BlockSpec((1, page, e), kv_map)]),
+        out_specs=pl.BlockSpec((1, rows if groups else 1, ev), row_map),
         scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32),
                         pltpu.VMEM((rows, 1), jnp.float32),
-                        pltpu.VMEM((rows, e), jnp.float32)],
+                        pltpu.VMEM((rows, ev), jnp.float32)],
     )
     params = None
     if not interpret:
         params = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
+    kernel = functools.partial(_paged_decode_kernel, sm=sm, page=page,
+                               grouped=bool(groups), split=split)
+    if latent:
+        kernel = functools.partial(_latent_page_kernel, kernel, ev)
     out = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, sm=sm, page=page,
-                          grouped=bool(groups), split=split),
+        kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, rows if groups else 1, e),
+        out_shape=jax.ShapeDtypeStruct((b, rows if groups else 1, ev),
                                        q.dtype),
         compiler_params=params,
         interpret=interpret,
     )(pos, row_of, page_of,
       pad(q_rows if groups else jnp.swapaxes(_block_diagonal(q[:, None]), 1,
                                              2)),
-      pad(own), k_cache, v_cache)
+      pad(own), k_cache, *([] if latent else [v_cache]))
+    if latent:
+        return out[:, :h]
     if groups:      # the other groups' columns of a head's row are zeros
         return out[:, :h].reshape(b, h, groups, d).sum(axis=2)
     return out.reshape(b, h, d)
@@ -429,6 +459,43 @@ def bounded_decode_attention(q, k_cache, v_cache, positions, scale=None,
         return masked(q, k_cache, v_cache, positions)
     return jax.lax.platform_dependent(q, k_cache, v_cache, positions,
                                       tpu=paged, default=masked)
+
+
+def latent_decode_attention(q, cache, positions, value_width: int,
+                            scale: float):
+    """One token of ABSORBED latent attention: every query head ``q:
+    [batch, heads, e]`` against ONE latent head ``cache: [batch, max_len,
+    e]`` whose first ``value_width`` columns are also its values (a latent
+    vector beside a shared rotated key). Bounded per row as
+    :func:`bounded_decode_attention`: on the TPU the paged kernel reads a
+    page once for both products (:func:`paged_decode_attention` with no
+    ``v_cache``) at :func:`decode_page` of the cache's width in whole
+    128-lane tiles; elsewhere the masked read of the bucket. Returns ``(out
+    [batch, heads, value_width] float32, read [batch] int32)``."""
+    s, e = cache.shape[1:]
+    page = decode_page(s, -(-e // 128) * 128, cache.dtype.itemsize)
+
+    def masked(q, cache, positions):
+        f32, hi = jnp.float32, jax.lax.Precision.HIGHEST
+        c = cache.astype(f32)
+        scores = jnp.einsum("bhe,bse->bhs", q.astype(f32), c,
+                            precision=hi) * scale
+        live = jnp.arange(s)[None, None, :] <= positions[:, None, None]
+        p = jax.nn.softmax(jnp.where(live, scores, NEG_INF), axis=-1)
+        out = jnp.einsum("bhs,bsv->bhv", p, c[..., :value_width],
+                         precision=hi)
+        return out, jnp.full(positions.shape, s, jnp.int32)
+
+    def paged(q, cache, positions):
+        out = paged_decode_attention(q, cache, None, positions, scale,
+                                     page=page, interpret=False, groups=1,
+                                     value_width=value_width)
+        return out, (jnp.clip(positions, 0, s - 1) // page + 1) * page
+
+    if page is None:
+        return masked(q, cache, positions)
+    return jax.lax.platform_dependent(q, cache, positions, tpu=paged,
+                                      default=masked)
 
 
 def cache_update(cache, new, positions):

@@ -84,13 +84,14 @@ def touched_list(sizes):
 
 
 def _touched_experts_kernel(ids_ref, count_ref, x_ref, w_ref, wg_ref, wu_ref,
-                            wd_ref, o_ref):
+                            wd_ref, o_ref, limit=0.0):
     """Grid step ``(t, j)``: block ``j`` of the hidden width of expert
     ``ids_ref[t]``. ``x_ref: [rows, d]``, ``w_ref: [rows, held]`` float32,
     ``wg_ref, wu_ref: [1, d, hb]``, ``wd_ref: [1, hb, n_out]``, ``o_ref:
     [rows, n_out]`` float32, the same block at every step. The grid has
     one step even where the list is empty: that step zeroes the sum and
-    reads no matrix into it."""
+    reads no matrix into it. ``limit``: the SwiGLU's clamp
+    (``conf.layers_hybrid.swiglu``)."""
     t, j = pl.program_id(0), pl.program_id(1)
     f32 = jnp.float32
 
@@ -101,9 +102,12 @@ def _touched_experts_kernel(ids_ref, count_ref, x_ref, w_ref, wg_ref, wu_ref,
     @pl.when(t < count_ref[0])
     def _expert():
         x = x_ref[...]
-        hidden = (jax.nn.silu(jnp.dot(x, wg_ref[0],
-                                      preferred_element_type=f32))
-                  * jnp.dot(x, wu_ref[0], preferred_element_type=f32))
+        g = jnp.dot(x, wg_ref[0], preferred_element_type=f32)
+        if limit:
+            g = jnp.minimum(g, limit)
+        up = jax.nn.silu(g)
+        u = jnp.dot(x, wu_ref[0], preferred_element_type=f32)
+        hidden = up * (jnp.clip(u, -limit, limit) if limit else u)
         y = jnp.dot(hidden.astype(x.dtype), wd_ref[0],
                     preferred_element_type=f32)
         lane = jax.lax.broadcasted_iota(jnp.int32, w_ref.shape, 1)
@@ -114,9 +118,9 @@ def _touched_experts_kernel(ids_ref, count_ref, x_ref, w_ref, wg_ref, wu_ref,
 
 # jitted so that a decoder's expert layers share one trace and one lowered
 # body of the kernel (``ops.attention.paged_decode_attention``)
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "limit"))
 def touched_experts_ffn(x, Wg, Wu, Wd, w, sizes,
-                        interpret: Optional[bool] = None):
+                        interpret: Optional[bool] = None, limit: float = 0.0):
     """``sum_e w[:, e, None] * (silu(x Wg_e) * (x Wu_e)) Wd_e`` over the
     experts with ``sizes[e] > 0`` alone: ``x: [rows, d]`` in the stacks'
     type, ``Wg, Wu: [held, d, h]``, ``Wd: [held, h, n_out]``, ``w: [rows,
@@ -125,7 +129,8 @@ def touched_experts_ffn(x, Wg, Wu, Wd, w, sizes,
     float32; zeros where ``sizes`` is zero everywhere. The matrices of an
     expert with no slot are never read: they may hold anything.
     ``interpret=None`` runs the Pallas interpreter off the TPU; a TPU
-    needs :func:`touched_experts_applies` besides."""
+    needs :func:`touched_experts_applies` besides. ``limit``: every
+    SwiGLU clamped (``conf.layers_hybrid.swiglu``)."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     n, d = x.shape
@@ -164,8 +169,11 @@ def touched_experts_ffn(x, Wg, Wu, Wd, w, sizes,
         params = pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=int(2 * step + 2 * rows_in + body + (8 << 20)))
+    kernel = _touched_experts_kernel
+    if limit:
+        kernel = functools.partial(_touched_experts_kernel, limit=limit)
     y = pl.pallas_call(
-        _touched_experts_kernel, grid_spec=grid_spec,
+        kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, n_out), jnp.float32),
         compiler_params=params, interpret=interpret,
     )(ids, count.reshape(1), x, w, Wg, Wu, Wd)

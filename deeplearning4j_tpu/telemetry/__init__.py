@@ -505,8 +505,8 @@ def record_decode_first_token(seconds: float) -> None:
 def record_decode_state_bytes(by_kind: dict) -> None:
     """Bytes of per-row state a generation engine's caches hold, by kind
     (``kv``, ``kv_ring``, ``compressed_keys``, ``recurrent``,
-    ``conv_window``): set at engine build and at every hop to a wider KV
-    bucket."""
+    ``conv_window``, ``latent``): set at engine build and at every hop to a
+    wider KV bucket."""
     for kind, n in by_kind.items():
         REGISTRY.gauge("dl4j_gen_state_bytes",
                        help="bytes of per-row decode state by kind",
@@ -521,8 +521,10 @@ def record_decode_layer_counts(counts: dict) -> None:
     ``sparse_read_positions`` (what the read streamed to attend that) per
     (sparse-layer query, KV head), ``sparse_dense_fallback_queries``,
     ``recurrent_state_updates``, ``ssm_state_updates`` (active row x
-    state-space layer a step); ``decode_kv_read_positions`` /
-    ``decode_kv_bucket_positions`` per (attention layer, row, step): the
+    state-space layer a step), ``delta_state_updates`` (active row x
+    delta-rule layer a step); ``decode_kv_read_positions`` /
+    ``decode_kv_bucket_positions`` per (attention layer, row, step; a
+    latent layer's too): the
     cached positions streamed over the positions held (a bucket; for a
     window layer's ring the row's context); ``moe_routed_slots`` (live
     token x chosen expert), ``moe_experts_touched``,

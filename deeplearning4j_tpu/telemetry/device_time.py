@@ -40,7 +40,8 @@ from typing import Dict, Iterable, List, NamedTuple, Optional, Tuple
 # a plan entry's class: ``conf`` layer types carry one as ``scope_class``
 SCOPE_CLASSES = (
     "embed", "pos", "norm", "attn.mha", "attn.full", "attn.window",
-    "attn.sparse", "attn.lightning", "ssm", "moe", "ffn", "residual", "head")
+    "attn.sparse", "attn.lightning", "attn.delta", "attn.latent", "ssm",
+    "moe", "ffn", "residual", "head")
 # what a decoder program runs outside the walk. ``window.prepare`` is also
 # given by :func:`group_of` to every operation the compiler hoisted out of
 # the decode window's ``while`` (the float32 -> bfloat16 converts of the

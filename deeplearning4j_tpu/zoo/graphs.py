@@ -1102,9 +1102,14 @@ class HybridDecoderLM(GraphZooModel):
     them does; a full layer that rotates needs a third name here, the
     layer itself keeps the switches apart), ``"plain-attn"`` (grouped-query
     attention with neither q/k norm, gate, rotation nor window,
-    ``GroupedAttentionLayer``) or ``"mamba"`` (a state-space mixer: a
+    ``GroupedAttentionLayer``), ``"mamba"`` (a state-space mixer: a
     selective scan behind a causal convolution, two kinds of per-row
-    state, ``conf.layers_ssm.MambaMixerLayer``, its sizes in ``mamba``).
+    state, ``conf.layers_ssm.MambaMixerLayer``, its sizes in ``mamba``),
+    ``"gated-deltanet"`` (gated delta-rule linear attention behind a
+    causal convolution, two kinds of per-row state,
+    ``conf.layers_delta.GatedDeltaNetLayer``, its sizes in ``delta``) or
+    ``"mla"`` (latent attention: one latent vector a position cached,
+    ``conf.layers_delta.LatentAttentionLayer``, its sizes in ``mla``).
     Scaled token embedding, then per
     layer ``h = x + c Mixer(RMSNorm(x))``, ``x' = h + c FFN(RMSNorm(h))``
     with a gated feed-forward and ``c = scale_depth / sqrt(depth_for_scale)``,
@@ -1118,7 +1123,9 @@ class HybridDecoderLM(GraphZooModel):
     experts beside a shared one (``conf.layers_moe.RoutedExpertsLayer``,
     its sizes in ``moe``). ``post_norms`` puts an RMS norm on each
     branch's OUTPUT as well: ``h = x + c Norm(Mixer(Norm(x)))``, four
-    norms a layer.
+    norms a layer. ``zero_centred_norms``: every norm's gain is ``1 + w``
+    (``RMSNormLayer(zero_centred=True)``); ``swiglu_limit``: every SwiGLU
+    clamped (``conf.layers_hybrid.swiglu``).
 
     ``layer_indices`` gives each built layer its index among
     ``n_layers_total`` (a served slice of a deeper model keeps its
@@ -1127,7 +1134,7 @@ class HybridDecoderLM(GraphZooModel):
     and the KV caches' types; the recurrent state is float32."""
 
     MIXERS = ("lightning-attn", "minicpm4", "window-attn", "full-attn",
-              "plain-attn", "mamba")
+              "plain-attn", "mamba", "gated-deltanet", "mla")
 
     def __init__(self, vocab_size: int, hidden: int, ffn_dim: int,
                  mixer_types, n_heads: int, head_dim: int,
@@ -1142,7 +1149,9 @@ class HybridDecoderLM(GraphZooModel):
                  updater: IUpdater | None = None, window: int = 0,
                  ffn_types=None, moe: dict | None = None,
                  post_norms: bool = False, mamba: dict | None = None,
-                 tie_head: bool = False):
+                 tie_head: bool = False, delta: dict | None = None,
+                 mla: dict | None = None, zero_centred_norms: bool = False,
+                 swiglu_limit: float = 0.0):
         self.mixer_types = list(mixer_types)
         unknown = sorted(set(self.mixer_types) - set(self.MIXERS))
         if unknown:
@@ -1173,6 +1182,9 @@ class HybridDecoderLM(GraphZooModel):
         self.moe = dict(moe or {})
         self.post_norms = post_norms
         self.mamba = dict(mamba or {})
+        self.delta, self.mla = dict(delta or {}), dict(mla or {})
+        self.zero_centred_norms = zero_centred_norms
+        self.swiglu_limit = swiglu_limit
         self.tie_head = tie_head
         self.max_len = max_len
         self.weight_dtype, self.cache_dtype = weight_dtype, cache_dtype
@@ -1191,17 +1203,30 @@ class HybridDecoderLM(GraphZooModel):
             RMSNormLayer,
             ScaledEmbeddingLayer,
         )
+        from deeplearning4j_tpu.conf.layers_delta import (
+            GatedDeltaNetLayer,
+            LatentAttentionLayer,
+        )
         from deeplearning4j_tpu.conf.layers_moe import RoutedExpertsLayer
         from deeplearning4j_tpu.conf.layers_ssm import MambaMixerLayer
 
         e, wd, c = self.hidden, self.weight_dtype, self.residual_scale
+        # the clamp and the zero-centred gain only where asked for: every
+        # other model's layers keep their defaults
+        limit = {"swiglu_limit": self.swiglu_limit} if self.swiglu_limit \
+            else {}
+
+        def norm():
+            if self.zero_centred_norms:
+                return RMSNormLayer(eps=self.eps, zero_centred=True)
+            return RMSNormLayer(eps=self.eps)
 
         def branch(name):
             """The vertex a residual sum takes: the branch's own output,
             or its RMS norm."""
             if not self.post_norms:
                 return name
-            g.add_layer(f"{name}_norm", RMSNormLayer(eps=self.eps), name)
+            g.add_layer(f"{name}_norm", norm(), name)
             return f"{name}_norm"
 
         g = (NeuralNetConfiguration.builder()
@@ -1215,7 +1240,7 @@ class HybridDecoderLM(GraphZooModel):
             weight_dtype=wd), "input")
         prev = "embed"
         for i, kind in enumerate(self.mixer_types):
-            g.add_layer(f"b{i}_norm1", RMSNormLayer(eps=self.eps), prev)
+            g.add_layer(f"b{i}_norm1", norm(), prev)
             if kind == "lightning-attn":
                 mixer = LightningAttentionLayer(
                     n_out=e, n_heads=self.lightning_heads,
@@ -1233,6 +1258,13 @@ class HybridDecoderLM(GraphZooModel):
             elif kind == "mamba":
                 mixer = MambaMixerLayer(n_out=e, eps=self.eps, out_scale=c,
                                         weight_dtype=wd, **self.mamba)
+            elif kind == "gated-deltanet":
+                mixer = GatedDeltaNetLayer(n_out=e, eps=self.eps, out_scale=c,
+                                           weight_dtype=wd, **self.delta)
+            elif kind == "mla":
+                mixer = LatentAttentionLayer(
+                    n_out=e, eps=self.eps, out_scale=c, weight_dtype=wd,
+                    cache_dtype=self.cache_dtype, **self.mla)
             elif kind == "plain-attn":
                 mixer = GroupedAttentionLayer(
                     n_out=e, n_heads=self.n_heads,
@@ -1251,19 +1283,19 @@ class HybridDecoderLM(GraphZooModel):
             g.add_layer(f"b{i}_mix", mixer, f"b{i}_norm1")
             g.add_vertex(f"b{i}_res1", ResidualAddVertex(),
                          prev, branch(f"b{i}_mix"))
-            g.add_layer(f"b{i}_norm2", RMSNormLayer(eps=self.eps),
-                        f"b{i}_res1")
+            g.add_layer(f"b{i}_norm2", norm(), f"b{i}_res1")
             if self.ffn_types[i] == "moe":
                 ffn = RoutedExpertsLayer(n_out=e, out_scale=c,
-                                         weight_dtype=wd, **self.moe)
+                                         weight_dtype=wd, **self.moe, **limit)
             else:
                 ffn = GatedFeedForwardLayer(n_out=e, n_hidden=self.ffn_dim,
-                                            out_scale=c, weight_dtype=wd)
+                                            out_scale=c, weight_dtype=wd,
+                                            **limit)
             g.add_layer(f"b{i}_ffn", ffn, f"b{i}_norm2")
             g.add_vertex(f"b{i}_res2", ResidualAddVertex(),
                          f"b{i}_res1", branch(f"b{i}_ffn"))
             prev = f"b{i}_res2"
-        g.add_layer("final_norm", RMSNormLayer(eps=self.eps), prev)
+        g.add_layer("final_norm", norm(), prev)
         g.add_layer("output", LMHeadLayer(
             n_out=self.vocab_size, activation=Activation.SOFTMAX,
             loss_fn=LossMCXENT(), logit_scale=self.logit_scale,

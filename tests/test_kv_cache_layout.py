@@ -1035,6 +1035,98 @@ def test_ssm_prompt_walk_is_the_kernel_and_keeps_no_state_history(
     assert len(kernels) == 1 and "selective_scan" not in kernels[0]
 
 
+# --- the delta-rule / latent hybrid: a delta state, a ring, a latent cache ----
+
+_DELTA = {"rows": 3, "tokens": 256, "value_heads": 2, "head": 128,
+          "latent": 384}
+
+
+@pytest.fixture(scope="module")
+def delta_programs(one_chip):
+    """Decode window, prompt and join of a two-layer ``HybridDecoderLM`` (a
+    gated delta-rule layer over a dense feed-forward, a latent attention
+    layer over routed experts, zero-centred norms, clamped SwiGLUs)
+    compiled for the v5e from avals: ``{program: (text, {kind of state:
+    [shape, ...]})}``. Heads of 128 fill the tiles as the cell's do; the
+    latent row of 256 + 64 lies in 384 lanes as the cell's 576 in 640;
+    three rows make a state's shape no other array's."""
+    from deeplearning4j_tpu.zoo.graphs import HybridDecoderLM
+
+    b, s, tp = _DELTA["rows"], 1024, _DELTA["tokens"]
+    h, hv = _DELTA["head"], _DELTA["value_heads"]
+    zoo = HybridDecoderLM(
+        vocab_size=512, hidden=384, ffn_dim=768,
+        mixer_types=["gated-deltanet", "mla"], ffn_types=["dense", "moe"],
+        delta={"key_heads": 1, "value_heads": hv, "key_dim": h,
+               "value_dim": h, "gate_scale": 2.0},
+        mla={"n_heads": 2, "q_rank": 128, "kv_rank": 256, "nope_dim": 128,
+             "rope_dim": 64, "value_dim": 128, "rope_theta": 100000.0,
+             "yarn_factor": 8.0, "yarn_original": 32768},
+        moe={"n_experts": 3, "n_hidden": 128, "top_k": 2,
+             "n_shared_hidden": 128, "route_scale": 2.5},
+        post_norms=True, zero_centred_norms=True, swiglu_limit=10.0,
+        n_heads=2, head_dim=128, n_kv_heads=2, depth_for_scale=1, max_len=s,
+        weight_dtype="bfloat16", cache_dtype="bfloat16")
+    return _compiled_programs(one_chip, zoo, b, s, tp)
+
+
+_DELTA_READERS = {
+    # the delta rule's state goes whole into its one kernel and comes out
+    # of it in place: the kernel reads and writes it, nothing else does
+    ("decode", "recurrent"): {("custom-call", "whole")},
+    # the ring, as the state-space layer's: the taps weigh every slot
+    # where it lies, the token's input is selected into the oldest slot
+    ("decode", "conv_window"): {("multiply", "whole"), ("add", "whole"),
+                                ("select", "whole"), ("slice", "whole")},
+    # the token's write, in place; the bucket is read by the paged kernel
+    # (keys and values from one page)
+    ("decode", "latent"): {_WRITE, ("custom-call", "part")},
+    # a join writes the joining row alone (a third of a three-row state)
+    ("join", "recurrent"): {_WRITE, ("dynamic-slice", "whole")},
+    ("join", "conv_window"): {_WRITE, ("dynamic-slice", "whole")},
+    ("join", "latent"): {_WRITE, ("dynamic-slice", "whole")},
+}
+
+
+@pytest.mark.parametrize("program,kind", sorted(_DELTA_READERS))
+def test_delta_programs_touch_each_state_only_to_update_it_where_it_lies(
+        delta_programs, program, kind):
+    """No ``copy``, ``pad``, ``transpose`` or scatter's ``while`` has the
+    delta rule's state, the convolution's ring or the latent cache among
+    its operands, in the decode window or in the join (a latent row of 576
+    lanes was kept in another layout inside the loop and copied whole in
+    and out of every window: the cache's row fills whole lane tiles)."""
+    txt, shapes = delta_programs[program]
+    rows, hv, h = _DELTA["rows"], _DELTA["value_heads"], _DELTA["head"]
+    assert shapes == {"recurrent": {f"f32[{rows},{hv},{h},{h}]"},
+                      "conv_window": {f"f32[{rows},{3 * 4 * h}]"},
+                      "latent": {f"bf16[{rows},1024,{_DELTA['latent']}]"}}
+    found = _consumers(txt, shapes[kind])
+    assert found <= _DELTA_READERS[program, kind], sorted(
+        found - _DELTA_READERS[program, kind])
+    assert found
+
+
+def test_delta_decode_window_holds_the_three_kernels_once_each(
+        delta_programs):
+    """The decode window compiled for the v5e updates the delta rule's
+    state by its kernel, reads the latent cache by the paged kernel and the
+    experts by theirs, once each in the step's body; the prompt walk is the
+    chunked form (no kernel of the delta rule) and holds no state a
+    position."""
+    decode, _ = delta_programs["decode"]
+    kernels = [line for line in decode.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    names = sorted(re.search(r"%(\w+?)(\.\d+)? =", k).group(1)
+                   for k in kernels)
+    assert names == ["delta_rule_step_kernel", "paged_decode_attention",
+                     "touched_experts_ffn"], names
+    prompt, _ = delta_programs["prompt"]
+    assert "delta_rule_step_kernel" not in prompt
+    t, hv, h = _DELTA["tokens"], _DELTA["value_heads"], _DELTA["head"]
+    assert not re.search(rf"\[\d*,?{t},{hv},{h},{h}\]", prompt)
+
+
 # --- the scopes of the programs compiled for the v5e --------------------------
 # (``telemetry.device_time``: what the device's time is filed under)
 
@@ -1117,6 +1209,8 @@ _WALKED = {     # the classes of each decoder's plan entries
                         "residual", "ffn", "moe", "head"},
     "ssm_programs": {"embed", "norm", "ssm", "attn.full", "residual", "ffn",
                      "head"},
+    "delta_programs": {"embed", "norm", "attn.delta", "attn.latent",
+                       "residual", "ffn", "moe", "head"},
 }
 
 

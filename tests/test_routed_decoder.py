@@ -305,29 +305,35 @@ def test_prefix_walk_refuses_the_ring_layers_by_name(served):
 
 # --- the expert layer ---------------------------------------------------------
 
-def test_expert_shares_add_up_to_the_uncut_reference_layer():
-    """``experts_held`` = each quarter of the experts: every holder routes
-    over all eight and computes its own two experts' part and the shared
-    expert; the parts, the shared expert counted once, add up to what the
-    reference gives for the whole layer."""
-    cfg = _cfg("window")
+@pytest.mark.parametrize("experts,top_k,holders", [
+    (8, 2, 4),          # each quarter of the experts
+    (256, 8, 16),       # one chip's sixteenth of 256, a deployment's share
+])
+def test_expert_shares_add_up_to_the_uncut_reference_layer(experts, top_k,
+                                                           holders):
+    """``experts_held`` = each holder's ``experts / holders``: every holder
+    routes over all the experts and computes its own experts' part and the
+    shared expert; the parts, the shared expert counted once, add up to
+    what the reference gives for the whole layer."""
+    cfg = _cfg("window", num_experts=experts, num_experts_per_tok=top_k)
     _, _, w = _net(cfg)
     p = w["b1_ffn"]
+    n = experts // holders
     u = jax.random.normal(jax.random.PRNGKey(1), (40, 32), jnp.float32)
     with jax.default_matmul_precision("highest"):
         want = np.asarray(ref.routed_experts(cfg, u, p))
         shared = np.asarray(ref.gated(u, p["Sg"], p["Su"], p["Sd"]))
     total = np.zeros_like(want)
-    for first in (0, 2, 4, 6):
+    for first in range(0, experts, n):
         layer = RoutedExpertsLayer(
-            n_out=32, n_experts=8, n_hidden=16, top_k=2, n_shared_hidden=16,
-            route_scale=2.826, experts_held=(first, 2))
-        part = {**p, **{k: p[k][first:first + 2] for k in ("Wg", "Wu", "Wd")}}
+            n_out=32, n_experts=experts, n_hidden=16, top_k=top_k,
+            n_shared_hidden=16, route_scale=2.826, experts_held=(first, n))
+        part = {**p, **{k: p[k][first:first + n] for k in ("Wg", "Wu", "Wd")}}
         y, counts = layer.forward_live(part, u, np.ones((40,), bool))
-        held = dict(cfg, experts_held=(first, 2))
+        held = dict(cfg, experts_held=(first, n))
         np.testing.assert_allclose(
             y, ref.routed_experts(held, u, part), atol=1e-5)
-        assert int(counts["moe_experts_touched"]) <= 2
+        assert int(counts["moe_experts_touched"]) <= n
         total += np.asarray(y) - shared
     np.testing.assert_allclose(total + shared, want, atol=2e-5)
 
