@@ -75,6 +75,7 @@ from deeplearning4j_tpu.ops.linear_attention import (
     linear_attention_chunked,
     linear_attention_step,
 )
+from deeplearning4j_tpu.ops.routed_experts import swiglu
 
 
 def _wdtype(name: str, default):
@@ -90,17 +91,6 @@ def rms_norm(x, gain, eps: float):
     x = x.astype(jnp.float32)
     return x * jax.lax.rsqrt(
         jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * gain
-
-
-def swiglu(g, up, limit: float = 0.0):
-    """``silu(g) * up()``; with ``limit`` the gate's input is clamped from
-    above and the other half into ``[-limit, limit]`` first. ``up`` is
-    called after the gate's activation, in the order the unclamped
-    expression has always been traced in."""
-    if limit:
-        return (jax.nn.silu(jnp.minimum(g, limit))
-                * jnp.clip(up(), -limit, limit))
-    return jax.nn.silu(g) * up()
 
 
 def rotate(x, positions, theta: float):

@@ -21,7 +21,8 @@ score. In eval/``output()`` the state entry is ignored.
 :class:`RoutedExpertsLayer` is the DROPLESS layer serving runs
 (``nn.decoding`` refuses :class:`MoELayer`, whose capacity couples the
 rows of a batch): sigmoid scores, a top-k choice, no capacity, a grouped
-matrix product over the token slots sorted by expert.
+matrix product over the token slots sorted by expert (on a TPU a Pallas
+kernel, ``ops.routed_experts``).
 """
 
 from __future__ import annotations
@@ -202,14 +203,17 @@ class RoutedExpertsLayer(BaseLayer):
     holder alike.
 
     One sum, three products by the number of tokens and the platform
-    (``EVERY_EXPERT_SLOTS``): a prompt's thousands of tokens go through
-    ``jax.lax.ragged_dot``, their live ``tokens * top_k`` slots sorted by
-    expert (a grouped matrix product on the TPU: an expert nobody chose
-    is never read); a decode step's rows go, in a program lowered for a
-    TPU, through ``ops.routed_experts.touched_experts_ffn`` (one Pallas
-    kernel over the matrices of the experts the rows chose, read where
-    they lie), and elsewhere through every expert held with the weight
-    zero where it was not chosen. Tokens that are not live (idle rows,
+    (``EVERY_EXPERT_SLOTS``): a prompt's thousands of tokens go grouped,
+    their live ``tokens * top_k`` slots sorted by expert (an expert nobody
+    chose is never read), in a program lowered for a TPU through
+    ``ops.routed_experts.grouped_experts_ffn`` (one Pallas kernel, a tile
+    of rows of one expert a grid step) and elsewhere through
+    ``jax.lax.ragged_dot`` (whose gradient the kernel's is); a decode
+    step's rows go, in a program lowered for a TPU, through
+    ``ops.routed_experts.touched_experts_ffn`` (one Pallas kernel over the
+    matrices of the experts the rows chose, read where they lie), and
+    elsewhere through every expert held with the weight zero where it was
+    not chosen. Tokens that are not live (idle rows,
     prompt padding) are routed nowhere and weigh nothing. More than
     ``ROUTED_ROWS_MAX`` tokens go through in slices.
 
@@ -291,27 +295,6 @@ class RoutedExpertsLayer(BaseLayer):
             w = w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20)
         return experts.astype(jnp.int32), w * self.route_scale
 
-    def _grouped(self, params, x, group, w, sizes):
-        """The grouped product: ``x: [n, d]``, each of its ``n * top_k``
-        slots with its expert ``group`` (``held``: not this holder's; it
-        sorts behind every group) and weight ``w``; ``sizes`` the slots an
-        expert got. An expert nobody chose is never read."""
-        order = jnp.argsort(group)
-        xs = x[order // self.top_k]
-        hidden = swiglu(
-            jax.lax.ragged_dot(xs, params["Wg"], sizes,
-                               preferred_element_type=jnp.float32),
-            lambda: jax.lax.ragged_dot(xs, params["Wu"], sizes,
-                                       preferred_element_type=jnp.float32),
-            self.swiglu_limit)
-        ys = jax.lax.ragged_dot(hidden.astype(x.dtype), params["Wd"], sizes,
-                                preferred_element_type=jnp.float32)
-        # rows behind the last group are whatever the product left
-        ys = jnp.where((jnp.arange(order.size) < jnp.sum(sizes))[:, None],
-                       ys * w[order][:, None], 0.0)
-        back = jnp.zeros_like(order).at[order].set(jnp.arange(order.size))
-        return ys[back].reshape(x.shape[0], self.top_k, -1).sum(axis=1)
-
     def _every_expert(self, params, x, w):
         """The same sum for a decode step's rows off the TPU
         (``EVERY_EXPERT_SLOTS``), and the kernel's reference: every token
@@ -353,7 +336,9 @@ class RoutedExpertsLayer(BaseLayer):
         the slots :meth:`_held_slots` gives, and how many experts'
         matrices the product streamed. Which product is a matter of ``n *
         top_k / held`` alone, and of the platform the program is lowered
-        for."""
+        for. Each is the scope ``moe.experts``, but a prompt's kernel on a
+        TPU: that is ``moe.grouped`` and the sort and the gathers around it
+        the layer's own."""
         from deeplearning4j_tpu.ops import routed_experts
 
         first, held = self._held()
@@ -363,30 +348,50 @@ class RoutedExpertsLayer(BaseLayer):
         def n_touched():
             return jnp.sum(sizes > 0, dtype=jnp.int32)
 
-        def every():
-            return (self._every_expert(params, x, self._by_row(w, chosen)),
-                    jnp.int32(held))
+        def grouped_by(product, **kw):
+            """The grouped product: each of the ``n * top_k`` slots with its
+            expert (``held``: not this holder's; it sorts behind every
+            group) and weight; ``ragged_dot``, or the kernel over tiles of
+            one expert (a program lowered for a TPU)."""
+            return product(x, params["Wg"], params["Wu"], params["Wd"],
+                           jnp.where(mine, experts - first, held).reshape(-1),
+                           w.reshape(-1), sizes, self.top_k,
+                           self.swiglu_limit, **kw)
 
-        def grouped():     # a slot not held here sorts behind every group
-            return (self._grouped(
-                params, x, jnp.where(mine, experts - first, held).reshape(-1),
-                w.reshape(-1), sizes), n_touched())
+        def every():
+            with jax.named_scope("moe.experts"):
+                return (self._every_expert(params, x,
+                                           self._by_row(w, chosen)),
+                        jnp.int32(held))
+
+        def grouped():
+            with jax.named_scope("moe.experts"):
+                return (grouped_by(routed_experts.grouped_experts_ragged),
+                        n_touched())
 
         def touched():
-            return (routed_experts.touched_experts_ffn(
-                x, params["Wg"], params["Wu"], params["Wd"],
-                self._by_row(w, chosen), sizes, interpret=False,
-                **({"limit": self.swiglu_limit} if self.swiglu_limit
-                   else {})),
-                n_touched())
+            with jax.named_scope("moe.experts"):
+                return (routed_experts.touched_experts_ffn(
+                    x, params["Wg"], params["Wu"], params["Wd"],
+                    self._by_row(w, chosen), sizes, interpret=False,
+                    **({"limit": self.swiglu_limit} if self.swiglu_limit
+                       else {})),
+                    n_touched())
 
+        def tiled():
+            return (grouped_by(routed_experts.grouped_experts_ffn,
+                               interpret=False), n_touched())
+
+        applies = routed_experts.touched_experts_applies(
+            params["Wg"].shape, params["Wd"].shape[-1])
         # slots an expert expects, whatever share of the experts is held
         # here: a holder of 16 of 256 gets a sixteenth of the slots
         if slots > most * self.n_experts:
-            return grouped()
+            if not applies:
+                return grouped()
+            return jax.lax.platform_dependent(tpu=tiled, default=grouped)
         plain = every if slots >= fewest * self.n_experts else grouped
-        if not routed_experts.touched_experts_applies(
-                params["Wg"].shape, params["Wd"].shape[-1]):
+        if not applies:
             return plain()
         return jax.lax.platform_dependent(tpu=touched, default=plain)
 
@@ -397,8 +402,8 @@ class RoutedExpertsLayer(BaseLayer):
         with jax.named_scope("moe.route"):
             *slots, sizes = self._held_slots(params, u, live)
         with jax.named_scope("moe.experts"):
-            y, read = self._experts(params, u.astype(params["Wg"].dtype),
-                                    *slots, sizes)
+            x = u.astype(params["Wg"].dtype)
+        y, read = self._experts(params, x, *slots, sizes)
         if self.n_shared_hidden:
             with jax.named_scope("moe.shared"):
                 y = y + _dot(swiglu(_dot(u, params["Sg"]),

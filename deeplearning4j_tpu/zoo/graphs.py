@@ -1125,7 +1125,7 @@ class HybridDecoderLM(GraphZooModel):
     branch's OUTPUT as well: ``h = x + c Norm(Mixer(Norm(x)))``, four
     norms a layer. ``zero_centred_norms``: every norm's gain is ``1 + w``
     (``RMSNormLayer(zero_centred=True)``); ``swiglu_limit``: every SwiGLU
-    clamped (``conf.layers_hybrid.swiglu``).
+    clamped (``ops.routed_experts.swiglu``).
 
     ``layer_indices`` gives each built layer its index among
     ``n_layers_total`` (a served slice of a deeper model keeps its

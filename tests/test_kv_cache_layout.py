@@ -902,15 +902,28 @@ def test_routed_prompt_walk_holds_no_tokens_by_experts_by_hidden_array(
         routed_programs):
     """The prompt walk groups: nowhere in its compiled text is an array of
     tokens x experts x hidden (the dense form, every token through every
-    expert); the decode window, whose three rows go through the touched
+    expert), nor a ``ragged-dot`` (what every other platform lowers a
+    prompt's product to): its product is ONE Mosaic kernel over the slots
+    in tiles of one expert, the three stacks among its operands as they
+    lie; the decode window, whose three rows go through the touched
     experts' kernel, holds neither that nor the batched product's small
     one (PR 36: a CPU lowers to it, ``tests/test_routed_decoder.py``)."""
     t, e, h = (_ROUTED[k] for k in ("tokens", "experts", "expert_hidden"))
+    d = 384
     dense = re.compile(
         rf"\[({t},{e},{h}|{e},{t},{h}|{2 * t},{e},{h}|{e},{2 * t},{h})\]")
     prompt, _ = routed_programs["prompt"]
     assert not dense.search(prompt)
-    assert "ragged-dot" in prompt
+    assert "ragged-dot" not in prompt
+    kernels = [line for line in prompt.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 1 and "grouped_experts_ffn" in kernels[0]
+    types = {mo.group(1): mo.group(2) for mo in map(
+        _INSTRUCTION.match, prompt.splitlines()) if mo}
+    taken = [types[a].split("{")[0] for a in re.findall(
+        r"%([\w.\-]+)", _INSTRUCTION.match(kernels[0]).group(4))]
+    assert sorted(s for s in taken if s.startswith(f"bf16[{e},")) == [
+        f"bf16[{e},{h},{d}]", f"bf16[{e},{d},{h}]", f"bf16[{e},{d},{h}]"]
     decode, _ = routed_programs["decode"]
     assert not re.search(rf"\[{e},3,{h}\]", decode)
     assert "ragged-dot" not in decode
@@ -923,8 +936,8 @@ def test_routed_decode_window_reads_the_expert_stacks_by_the_kernel_alone(
     in the step's body, with the three stacks among its operands as they
     lie: no ``copy``, ``gather``, ``dot`` or ``convolution`` takes a
     stack, whole or in part. The prompt walk's stacks go to its grouped
-    products and nowhere else, as before, and neither the prompt nor the
-    join holds the kernel."""
+    kernel and nowhere else, and neither the prompt nor the join holds
+    the touched experts' kernel."""
     e, d, h = _ROUTED["experts"], 384, _ROUTED["expert_hidden"]
     stacks = {f"bf16[{e},{d},{h}]", f"bf16[{e},{h},{d}]"}
     decode, _ = routed_programs["decode"]
@@ -940,10 +953,10 @@ def test_routed_decode_window_reads_the_expert_stacks_by_the_kernel_alone(
     assert sorted(t for t in taken if t in stacks) == [
         f"bf16[{e},{h},{d}]", f"bf16[{e},{d},{h}]", f"bf16[{e},{d},{h}]"]
     prompt, _ = routed_programs["prompt"]
-    # (the compiler's own grouped kernels, three of them: ``ragged-dot-*``)
+    # (the grouped kernel, ``ops.routed_experts.grouped_experts_ffn``)
     assert _consumers(prompt, stacks) == {("custom-call", "whole")}
     assert len(re.findall(r"= f32\[\d+,\d+\]\S* custom-call\(.*"
-                          r"op_name=\"ragged-dot", prompt)) == 3
+                          r"op_name=\"[^\"]*/grouped_experts_ffn/", prompt)) == 1
     for program in ("prompt", "join"):
         assert "touched_experts_ffn" not in routed_programs[program][0]
 

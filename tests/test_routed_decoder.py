@@ -371,21 +371,28 @@ def _steer_to_the_kernel(monkeypatch):
     """What lowering for a TPU chooses, chosen here for a CPU run: the
     experts' ``tpu`` branch, its kernel through the Pallas interpreter,
     at widths that fill no 128-lane tile (steered in the test, as
-    ``test_kv_cache_layout`` steers the paged read)."""
+    ``test_kv_cache_layout`` steers the paged read): the touched experts'
+    for a step's rows, the one over tiles of 8 rows of one expert for a
+    prompt's."""
     from deeplearning4j_tpu.ops import routed_experts
 
     kernel, choose = routed_experts.touched_experts_ffn, \
         jax.lax.platform_dependent
+    tiles = routed_experts.grouped_experts_ffn
     monkeypatch.setattr(routed_experts, "touched_experts_applies",
                         lambda *a: True)
     monkeypatch.setattr(
         routed_experts, "touched_experts_ffn",
         lambda *a, **kw: kernel(*a, **{**kw, "interpret": True}))
     monkeypatch.setattr(
+        routed_experts, "grouped_experts_ffn",
+        lambda *a, **kw: tiles(*a, **{**kw, "interpret": True}))
+    monkeypatch.setattr(routed_experts, "ROW_TILE", 8)
+    monkeypatch.setattr(
         jax.lax, "platform_dependent",
         lambda *a, default=None, **per: (
             per["tpu"](*a) if getattr(per.get("tpu"), "__name__", "")
-            == "touched" else choose(*a, default=default, **per)))
+            in ("touched", "tiled") else choose(*a, default=default, **per)))
 
 
 def _expert_layer(held=(0, 0)):
@@ -551,6 +558,129 @@ def test_decode_window_through_the_kernel_gives_the_same_logits(monkeypatch):
     assert kernel["moe_routed_slots"] == 3 * 2 * 2
     assert 3 * 2 <= kernel["moe_experts_read"] == kernel[
         "moe_experts_touched"] <= 3 * 4
+
+
+# --- a prompt's product as a TPU lowers it: tiles of one expert -------------
+
+# slots an expert held, slots of other holders (sorted behind every
+# group, no tile), the SwiGLU's clamp and the experts' hidden width, in
+# tiles of 8 rows; at 256 the hidden width goes through in two blocks
+GROUPED_CASES = {
+    "skewed_with_empty_experts": ([0, 17, 0, 3, 0, 0, 1, 9], 0, 0.0, 16),
+    "a_group_over_three_tiles": ([2, 20, 1, 1], 0, 0.0, 16),
+    "held_subset_others_behind": ([5, 0, 9, 2], 40, 0.0, 16),
+    "swiglu_limit": ([6, 11, 3, 12], 0, 0.5, 16),
+    "slots_no_multiple_of_tm": ([7, 6, 13], 0, 0.0, 16),
+    "hidden_in_two_blocks": ([4, 9, 0, 6], 13, 0.5, 256),
+}
+
+
+def _grouped_operands(sizes, others, top_k=2, d=32, h=16, n_out=24):
+    """Seeded operands of the grouped product whose experts get exactly
+    ``sizes`` slots, beside ``others`` slots of other holders, shuffled:
+    ``(x, Wg, Wu, Wd, group, w, sizes)``."""
+    held = len(sizes)
+    group = np.repeat(np.arange(held + 1), list(sizes) + [others])
+    rng = np.random.default_rng(sum(sizes) + others)
+    group = rng.permutation(group).astype(np.int32)
+    assert group.size % top_k == 0
+    k = jax.random.split(jax.random.PRNGKey(group.size), 5)
+    return (jax.random.normal(k[0], (group.size // top_k, d), jnp.float32),
+            0.3 * jax.random.normal(k[1], (held, d, h), jnp.float32),
+            0.3 * jax.random.normal(k[2], (held, d, h), jnp.float32),
+            0.3 * jax.random.normal(k[3], (held, h, n_out), jnp.float32),
+            jnp.asarray(group),
+            jax.random.uniform(k[4], (group.size,), jnp.float32),
+            jnp.asarray(sizes, jnp.int32))
+
+
+@pytest.mark.parametrize("case", sorted(GROUPED_CASES))
+def test_grouped_kernel_gives_the_ragged_product(case, monkeypatch):
+    """The kernel over tiles of one expert, under the Pallas interpreter,
+    against ``grouped_experts_ragged`` (``jax.lax.ragged_dot``): the same
+    sums to float32 rounding; with the matrices of every expert nobody
+    chose set to NaN still the same and finite (those are never read)."""
+    from deeplearning4j_tpu.ops import routed_experts
+
+    monkeypatch.setattr(routed_experts, "ROW_TILE", 8)
+    sizes, others, limit, h = GROUPED_CASES[case]
+    # a step's matrices may hold one byte: as many blocks as whole tiles
+    monkeypatch.setattr(routed_experts, "STEP_BYTES_MAX", 1)
+    assert routed_experts.hidden_blocks(32, h, 24, 4) == h // 128 or h < 256
+    x, Wg, Wu, Wd, group, w, sz = _grouped_operands(sizes, others, h=h)
+    want = np.asarray(routed_experts.grouped_experts_ragged(
+        x, Wg, Wu, Wd, group, w, sz, 2, limit))
+    hole = jnp.where((sz > 0)[:, None, None], 0.0, jnp.nan)
+    got = np.asarray(routed_experts.grouped_experts_ffn(
+        x, Wg + hole, Wu + hole, Wd + hole, group, w, sz, 2, limit,
+        interpret=True))
+    assert got.shape == want.shape and np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    # a token whose slots are all other holders' sums to zero
+    mine = np.asarray(group).reshape(-1, 2) < len(sizes)
+    assert (got[~mine.any(axis=1)] == 0).all()
+
+
+def test_tile_layout_starts_each_expert_on_a_tile_of_its_own():
+    from deeplearning4j_tpu.ops.routed_experts import tile_layout
+
+    sizes = jnp.asarray([0, 10, 0, 3, 8], jnp.int32)
+    group = jnp.asarray([4] * 8 + [1] * 10 + [3] * 3 + [5] * 3, jnp.int32)
+    ids, count, slot, valid, row = tile_layout(group, sizes, 4)
+    # 24 slots in tiles of 4: the bound is 24 // 4 + 5 tiles
+    assert ids.shape == (11,) and slot.shape == valid.shape == (44,)
+    assert int(count) == 3 + 1 + 2
+    assert np.asarray(ids)[:6].tolist() == [1, 1, 1, 3, 4, 4]
+    valid = np.asarray(valid)
+    assert valid.sum() == 21 and not valid[24:].any()
+    assert valid[:12].tolist() == [True] * 10 + [False] * 2
+    # each held slot's row holds that slot; other holders' slots have none
+    row, slot = np.asarray(row), np.asarray(slot)
+    held = np.asarray(group) < 5
+    assert (slot[row[held]] == np.flatnonzero(held)).all()
+    assert valid[row[held]].all() and (row[~held] == 0).all()
+
+
+@pytest.mark.parametrize("limit", [0.0, 0.5])
+def test_grouped_kernel_gradient_is_the_ragged_products(limit, monkeypatch):
+    """The kernel's ``custom_vjp``: its gradient with respect to the
+    tokens, the three stacks and the slots' weights is ``ragged_dot``'s."""
+    from deeplearning4j_tpu.ops import routed_experts
+
+    monkeypatch.setattr(routed_experts, "ROW_TILE", 8)
+    x, Wg, Wu, Wd, group, w, sizes = _grouped_operands([5, 0, 9, 2], 16)
+
+    def grads(product, **kw):
+        return jax.grad(lambda *a: jnp.sum(jnp.sin(product(
+            a[0], a[1], a[2], a[3], group, a[4], sizes, 2, limit, **kw))),
+            argnums=(0, 1, 2, 3, 4))(x, Wg, Wu, Wd, w)
+
+    want = grads(routed_experts.grouped_experts_ragged)
+    got = grads(routed_experts.grouped_experts_ffn, interpret=True)
+    for name, a, b in zip(("x", "Wg", "Wu", "Wd", "w"), got, want):
+        assert np.abs(np.asarray(b)).max() > 0, name
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("held", [(0, 0), (4, 4)])
+def test_prompt_through_the_grouped_kernel_gives_the_layers_sum(
+        held, monkeypatch):
+    """``forward_live`` of a prompt's 200 tokens (400 slots: beyond 24 an
+    expert, so every platform groups them) as a TPU lowers it, through
+    the kernel over tiles of 8 rows, against the CPU's ``ragged_dot``:
+    the same sum, the same counts; a padding token routes nowhere."""
+    layer, p = _expert_layer(held)
+    x = jax.random.normal(jax.random.PRNGKey(41), (200, 32), jnp.float32)
+    live = np.arange(200) % 17 != 3
+    want, plain = layer.forward_live(p, x, live)
+    _steer_to_the_kernel(monkeypatch)
+    got, kernel = layer.forward_live(p, x, live)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    assert {k: int(v) for k, v in kernel.items()} == {
+        k: int(v) for k, v in plain.items()}
+    assert int(kernel["moe_experts_read"]) == int(
+        kernel["moe_experts_touched"])
+    assert int(kernel["moe_routed_slots"]) <= 2 * int(live.sum())
 
 
 @pytest.mark.parametrize("dropless", [False, True])

@@ -19,8 +19,22 @@ alone (every step the same expert: the pipeline fetches it once); and
 whole with an expert's hidden width in 2 and in 4 blocks a grid step
 (``ops.routed_experts.STEP_BYTES_MAX``).
 
+``prompt`` times a PROMPT's grouped product alone in a program, at
+``tokens`` (default 512, 1,024, 2,048, 4,096: ``conf.layers_moe
+.ROUTED_ROWS_MAX`` is the largest slice) at Trinity-Mini's widths and at
+GigaChat3.5's (hidden 7168, 16 of 256 experts of 2048 held, top 8, the
+SwiGLU clamped at 10): ``jax.lax.ragged_dot`` (``ops.routed_experts
+.grouped_experts_ragged``) against the Pallas kernel over tiles of one
+expert (``grouped_experts_ffn``) at each row tile of ``tm`` (default 128,
+256, 512; the kernel takes ``ops.routed_experts.ROW_TILE``), with the
+tiles' padding (the list's length over ``ceil(slots held / tm)``), the
+products' TFLOP/s and the largest gap between the two sums. It writes
+``chiprun_out/moe_crossover_prompt.json``.
+
     python tools/chip/moe_crossover.py [rows=8,16,32,64,128,256] [parts] [tiny]
+    python tools/chip/moe_crossover.py prompt [tokens=...] [tm=...] [tiny]
 """
+import functools
 import glob
 import json
 import os
@@ -40,9 +54,15 @@ applies = routed_experts.touched_experts_applies
 
 TINY = "tiny" in sys.argv
 ROWS = [8, 16, 32, 64, 128, 256]
+TOKENS = [512, 1024, 2048, 4096]
+TILES = [128, 256, 512]
 for a in sys.argv[1:]:
     if a.startswith("rows="):
         ROWS = [int(x) for x in a[5:].split(",")]
+    if a.startswith("tokens="):
+        TOKENS = [int(x) for x in a[7:].split(",")]
+    if a.startswith("tm="):
+        TILES = [int(x) for x in a[3:].split(",")]
 
 
 def device_us(run, reps):
@@ -152,7 +172,75 @@ def layer_rows(layer, params, d, e, k):
         yield rec
 
 
+def prompt_products():
+    """A prompt slice's grouped product alone in a program, by
+    ``ragged_dot`` and by the kernel at each row tile, a record a
+    configuration and token count."""
+    re_ = routed_experts
+    widths = {"trinity-mini": (2048, 128, 1024, 8, (0, 0), 0.0),
+              "gigachat35": (7168, 256, 2048, 8, (0, 16), 10.0)}
+    if TINY:
+        widths = {"tiny": (64, 16, 32, 4, (0, 0), 0.0),
+                  "tiny-held": (64, 32, 32, 4, (0, 4), 10.0)}
+    for name, (d, e, h, k, held, limit) in widths.items():
+        layer = layers_moe.RoutedExpertsLayer(
+            n_out=d, n_experts=e, n_hidden=h, top_k=k, route_scale=2.826,
+            weight_dtype="bfloat16", experts_held=held, swiglu_limit=limit)
+        params = layer.init(jax.random.PRNGKey(41),
+                            type("T", (), {"size": d})())
+        stacks = [params[key] for key in ("Wg", "Wu", "Wd")]
+        first, n_held = layer._held()
+        for n in TOKENS:
+            x = jax.random.normal(jax.random.PRNGKey(n), (n, d), jnp.float32)
+            experts, mine, w, _, sizes = layer._held_slots(
+                params, x, jnp.ones((n,), bool))
+            group = jnp.where(mine, experts - first, n_held).reshape(-1)
+            operands = (x.astype(stacks[0].dtype), *stacks, group,
+                        w.reshape(-1), sizes)
+            held_slots = int(jnp.sum(sizes))
+            flop = 2.0 * held_slots * h * 3 * d
+            rec = {"config": name, "tokens": n, "slots_held": held_slots,
+                   "experts_touched": int(jnp.sum(sizes > 0)),
+                   "tm_chosen": re_.ROW_TILE, "gflop": flop * 1e-9}
+            reps = 2 if TINY else 10
+            ragged = jax.jit(lambda *a: re_.grouped_experts_ragged(
+                *a, k, limit))
+            want = ragged(*operands)
+            time_both(rec, "ragged", lambda: ragged(*operands), reps)
+            interpret = jax.default_backend() != "tpu"
+            for tm in TILES:
+                kernel = functools.partial(
+                    re_._grouped_tiles, top_k=k, tm=tm, limit=limit,
+                    interpret=interpret)
+                got = kernel(*operands)
+                ids, count, slot, valid, _ = re_.tile_layout(group, sizes, tm)
+                rec[f"tm{tm}_padding"] = int(count) / max(
+                    1, -(-held_slots // tm))
+                rec[f"tm{tm}_gap"] = float(jnp.max(jnp.abs(got - want)))
+                time_both(rec, f"tm{tm}", lambda: kernel(*operands), reps)
+                # the kernel alone, its rows laid out beforehand
+                xp = operands[0][slot // k]
+                wp = jnp.where(valid, w.reshape(-1)[slot], 0.0)[:, None]
+                alone = jax.jit(lambda *a: re_.tiles_ffn(
+                    *a, limit=limit, interpret=interpret))
+                time_both(rec, f"tm{tm}_kernel_alone",
+                          lambda: alone(xp, wp, *stacks, ids, count), reps)
+            for key in ["ragged"] + [f"tm{tm}" for tm in TILES]:
+                if rec.get(key + "_us"):
+                    rec[key + "_tflops"] = flop / rec[key + "_us"] * 1e-6
+            yield rec
+
+
 def main():
+    if "prompt" in sys.argv:
+        out = {"device": jax.devices()[0].device_kind, "rows": []}
+        for rec in prompt_products():
+            print(json.dumps(rec), flush=True)
+            out["rows"].append(rec)
+        os.makedirs("chiprun_out", exist_ok=True)
+        with open("chiprun_out/moe_crossover_prompt.json", "w") as f:
+            json.dump(out, f, indent=1)
+        return
     d, e, h, k = (64, 16, 32, 4) if TINY else (2048, 128, 1024, 8)
     layer = layers_moe.RoutedExpertsLayer(
         n_out=d, n_experts=e, n_hidden=h, top_k=k, n_shared_hidden=h,
