@@ -43,8 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deeplearning4j_tpu import serde
-from deeplearning4j_tpu.conf import inputs as it
-from deeplearning4j_tpu.conf.layers import BaseLayer, _as_ff_size
+from deeplearning4j_tpu.conf.layers import _as_ff_size
 from deeplearning4j_tpu.conf.layers_hybrid import (
     _dot,
     _join_rows,
@@ -56,6 +55,7 @@ from deeplearning4j_tpu.conf.layers_hybrid import (
     rms_norm,
 )
 from deeplearning4j_tpu.conf.layers_ssm import (
+    _SequenceMixer,
     conv_ring_step,
     conv_span,
     tail_to_ring,
@@ -70,16 +70,6 @@ from deeplearning4j_tpu.ops.delta_rule import (
 
 DELTA_TOKEN_SPAN = 2048     # positions GatedDeltaNetLayer projects at a time
 LATENT_QUERY_CHUNK = 128    # a prompt's queries LatentAttentionLayer attends at a time
-
-
-class _SequenceMixer(BaseLayer):
-    def output_type(self, input_type):
-        ts = (input_type.timesteps if isinstance(input_type, it.Recurrent)
-              else -1)
-        return it.Recurrent(size=self.n_out, timesteps=ts)
-
-    def streaming_safe(self) -> bool:
-        return False
 
 
 @serde.register

@@ -31,9 +31,9 @@ The per-layer cache interface (``nn.decoding`` walks it; ``docs/serving.md``):
 - ``cache_grow(cache, length)``, ``cache_release(cache, keep)``.
 - ``cache_kinds``: cache leaf -> the kind of state it is (``kv``,
   ``kv_ring``, ``compressed_keys``, ``recurrent``, ``conv_window``: a
-  state-space or delta-rule mixer's two, ``conf/layers_ssm.py``,
-  ``conf/layers_delta.py``; ``latent``: one latent vector a position,
-  ``conf/layers_delta.py``); ``cache_counters``:
+  state-space or delta-rule mixer's two, a short convolution's one,
+  ``conf/layers_ssm.py``, ``conf/layers_delta.py``; ``latent``: one latent
+  vector a position, ``conf/layers_delta.py``); ``cache_counters``:
   the names of the counts ``cache_step`` returns.
 
 A layer WITHOUT per-row state that still couples the rows of a batch (the
@@ -822,3 +822,23 @@ class GroupedAttentionLayer(GatedAttentionLayer):
     def _finish(self, params, u, o):
         o = o.reshape(o.shape[:-2] + (-1,))
         return self.activation.apply(_dot(o, params["Wo"]) * self.out_scale)
+
+
+@serde.register
+@dataclasses.dataclass
+class NormedAttentionLayer(GroupedAttentionLayer):
+    """:class:`GroupedAttentionLayer` with the q/k RMS norm of
+    :class:`GatedAttentionLayer` (a gain over each head's width) and no
+    output gate: ``q = RMSNorm(Wq u)``, ``k = RMSNorm(Wk u)``, rotated at
+    ``rope_theta`` where it is set, ``v = Wv u``, causal softmax attention
+    over grouped KV heads, ``Wo``."""
+
+    _heads = _GatedMixer._heads
+
+    def init(self, key, input_type, dtype=jnp.float32):
+        gain = jnp.ones((self.head_size,), jnp.float32)
+        return {**super().init(key, input_type, dtype), "q_norm": gain,
+                "k_norm": gain}
+
+    def param_order(self):
+        return super().param_order() + ["q_norm", "k_norm"]

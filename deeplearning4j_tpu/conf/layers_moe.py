@@ -189,7 +189,7 @@ class RoutedExpertsLayer(BaseLayer):
 
     ``s = sigmoid(Wr u)`` over ``n_experts`` in float32; the ``top_k``
     largest of ``s + b`` are chosen (the bias ``b`` enters the choice
-    only); ``w_e = route_scale * s_e / (sum of the chosen s + 1e-20)``
+    only); ``w_e = route_scale * s_e / (sum of the chosen s + route_eps)``
     (``route_norm``; else ``route_scale * s_e``); the output is
     ``Shared(u) + sum_e w_e Expert_e(u)``, every expert ``Wd (silu(Wg u)
     * Wu u)``. No capacity: every chosen (token, expert) slot is
@@ -235,6 +235,7 @@ class RoutedExpertsLayer(BaseLayer):
     n_shared_hidden: int = 0        # 0: no shared expert
     route_norm: bool = True
     route_scale: float = 1.0
+    route_eps: float = 1e-20        # added to the chosen scores' sum
     experts_held: tuple = (0, 0)    # (first, count); count 0: all
     out_scale: float = 1.0
     weight_dtype: str = ""
@@ -292,7 +293,7 @@ class RoutedExpertsLayer(BaseLayer):
         _, experts = jax.lax.top_k(s + params["b"], self.top_k)
         w = jnp.take_along_axis(s, experts, axis=1)
         if self.route_norm:
-            w = w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20)
+            w = w / (jnp.sum(w, axis=1, keepdims=True) + self.route_eps)
         return experts.astype(jnp.int32), w * self.route_scale
 
     def _every_expert(self, params, x, w):

@@ -1,22 +1,29 @@
-"""A state-space ("Mamba") mixer on the per-layer cache interface of
-``conf/layers_hybrid.py``: an input projection into ``x`` and a gate ``z``,
-a depthwise causal convolution over ``x`` that keeps its last
-``d_conv - 1`` inputs, a selective scan (``ops/selective_scan``) whose
-step, ``B`` and ``C`` come from the convolved input, the gate, an output
-projection.
+"""Two convolution mixers on the per-layer cache interface of
+``conf/layers_hybrid.py``:
 
-TWO kinds of per-row state, both float32 and neither depending on the
-bucket: the scan's state ``[rows, d_state, d_inner]`` (kind
-``recurrent``) and the convolution's last ``d_conv - 1`` inputs (kind
-``conv_window``), a RING ``[rows, (d_conv - 1) * d_inner]``: the input of
-position ``p`` lies in slot ``p mod (d_conv - 1)``, so a decode step
-overwrites the oldest input where it lies and moves nothing (a window
-kept oldest-first is shifted every step: the compiler copied every
-layer's whole window a step to do it; and flat, so that no dimension of 3
-meets the TPU's tiles of 8 x 128). What ``cache_prefill`` owes a
-RIGHT-padded row (``docs/serving.md``): the scan's state after the row's
-last REAL token (a padded position has a step of 0: the state stands
-still) and the row's last ``d_conv - 1`` REAL inputs, each in its
+- :class:`MambaMixerLayer`, a state-space ("Mamba") mixer: an input
+  projection into ``x`` and a gate ``z``, a depthwise causal convolution
+  over ``x`` that keeps its last ``d_conv - 1`` inputs, a selective scan
+  (``ops/selective_scan``) whose step, ``B`` and ``C`` come from the
+  convolved input, the gate, an output projection;
+- :class:`ShortConvLayer`, a gated short convolution: an input projection
+  into two gates ``B``, ``C`` and ``x``, a depthwise causal convolution of
+  a few taps over ``B * x`` with no activation, ``C`` on its output, an
+  output projection. ONE kind of per-row state, the convolution's last
+  ``d_conv - 1`` inputs (kind ``conv_window``, the same ring as below).
+
+The Mamba mixer keeps TWO kinds of per-row state, both float32 and
+neither depending on the bucket: the scan's state ``[rows, d_state,
+d_inner]`` (kind ``recurrent``) and the convolution's last ``d_conv - 1``
+inputs (kind ``conv_window``), a RING ``[rows, (d_conv - 1) * d_inner]``:
+the input of position ``p`` lies in slot ``p mod (d_conv - 1)``, so a
+decode step overwrites the oldest input where it lies and moves nothing
+(a window kept oldest-first is shifted every step: the compiler copied
+every layer's whole window a step to do it; and flat, so that no
+dimension of 3 meets the TPU's tiles of 8 x 128). What ``cache_prefill``
+owes a RIGHT-padded row (``docs/serving.md``): the scan's state after the
+row's last REAL token (a padded position has a step of 0: the state
+stands still) and the row's last ``d_conv - 1`` REAL inputs, each in its
 position's slot (zeros where the prompt is shorter), not the bucket's
 tail.
 
@@ -54,24 +61,40 @@ from deeplearning4j_tpu.ops.selective_scan import (
 
 
 SSM_TOKEN_SPAN = 2048      # positions MambaMixerLayer projects and scans at a time
+SHORTCONV_TOKEN_SPAN = 2048     # positions ShortConvLayer projects at a time
+
+
+class _SequenceMixer(BaseLayer):
+    """What a sequence mixer of the cache interface shares: a recurrent
+    output of ``n_out`` features, and no streaming."""
+
+    def output_type(self, input_type):
+        ts = (input_type.timesteps if isinstance(input_type, it.Recurrent)
+              else -1)
+        return it.Recurrent(size=self.n_out, timesteps=ts)
+
+    def streaming_safe(self) -> bool:
+        return False
 
 
 # --- a causal depthwise convolution that keeps its last inputs in a ring ------
-# (the state-space mixer's and the delta-rule mixer's, conf/layers_delta.py)
+# (the state-space mixer's, the delta-rule mixer's, conf/layers_delta.py,
+# and the short convolution's, which takes it without the activation)
 
-def conv_span(tail, x, taps, mask, bias=None):
+def conv_span(tail, x, taps, mask, bias=None, silu=True):
     """A span ``x: [batch, span, d]`` through ``K = taps.shape[0]`` causal
     taps after the ``K - 1`` earlier inputs ``tail: [batch, K - 1, d]``,
     oldest first. Returns ``(y, tail')``: ``y_t = silu((bias +) sum_j
-    taps[j] x_{t-K+1+j})``, and the last ``K - 1`` REAL inputs (a
-    right-padded row's real positions are the span's first
-    ``sum(mask)``)."""
+    taps[j] x_{t-K+1+j})`` (``silu`` False: the sum alone), and the last
+    ``K - 1`` REAL inputs (a right-padded row's real positions are the
+    span's first ``sum(mask)``)."""
     k, span = taps.shape[0] - 1, x.shape[1]
     padded = jnp.concatenate([tail, x], axis=1)          # [b, k + span, d]
     y = sum(taps[j] * padded[:, j:j + span] for j in range(k + 1))
     if bias is not None:
         y = bias + y
-    y = jax.nn.silu(y)
+    if silu:
+        y = jax.nn.silu(y)
     real = jnp.sum(mask, axis=1).astype(jnp.int32)
     return y, jnp.take_along_axis(
         padded, (real[:, None] + jnp.arange(k))[:, :, None], axis=1)
@@ -88,12 +111,13 @@ def tail_to_ring(tail, lengths):
         b, k * d)
 
 
-def conv_ring_step(ring, x, taps, positions, bias=None):
+def conv_ring_step(ring, x, taps, positions, bias=None, silu=True):
     """One token ``x: [batch, d]`` at ``positions`` through the taps, its
     ``k = K - 1`` earlier inputs in ``ring: [batch, k * d]`` (the input of
     position ``p`` in slot ``p mod k``): the taps weigh the ring where it
     lies and the token's input overwrites the oldest slot, so nothing is
-    shifted. Returns ``(silu(y), ring')``, ``y`` as :func:`conv_span`'s."""
+    shifted. Returns ``(silu(y), ring')`` (``silu`` False: ``(y,
+    ring')``), ``y`` as :func:`conv_span`'s."""
     k, d = taps.shape[0] - 1, x.shape[-1]
     # the ring's slot of each lane, and how many positions back from the
     # oldest input (slot positions mod k) it lies
@@ -104,7 +128,9 @@ def conv_ring_step(ring, x, taps, positions, bias=None):
     y = taps[k] * x
     if bias is not None:
         y = bias + y
-    y = jax.nn.silu(y + sum(weighed[:, j * d:(j + 1) * d] for j in range(k)))
+    y = y + sum(weighed[:, j * d:(j + 1) * d] for j in range(k))
+    if silu:
+        y = jax.nn.silu(y)
     with jax.named_scope("cache.write"):
         ring = jnp.where(age == 0, jnp.tile(x, (1, k)), ring)
     return y, ring
@@ -112,7 +138,7 @@ def conv_ring_step(ring, x, taps, positions, bias=None):
 
 @serde.register
 @dataclasses.dataclass
-class MambaMixerLayer(BaseLayer):
+class MambaMixerLayer(_SequenceMixer):
     """``[x ; z] = W_in u``; ``x~_t = silu(b_c + sum_j w_c[j] x_{t-K+1+j})``
     over ``K = d_conv`` taps; ``[delta ; B ; C] = W_x x~``, each RMS-normed
     with a gain; ``dt = softplus(W_dt delta +
@@ -134,14 +160,6 @@ class MambaMixerLayer(BaseLayer):
     uses_mask = True
     cache_kinds = {"state": "recurrent", "conv": "conv_window"}
     cache_counters = ("ssm_state_updates",)
-
-    def output_type(self, input_type):
-        ts = (input_type.timesteps if isinstance(input_type, it.Recurrent)
-              else -1)
-        return it.Recurrent(size=self.n_out, timesteps=ts)
-
-    def streaming_safe(self) -> bool:
-        return False
 
     def init(self, key, input_type, dtype=jnp.float32):
         """Matrices by the layer's initializer; what decides how long the
@@ -276,3 +294,120 @@ class MambaMixerLayer(BaseLayer):
     def cache_release(self, cache, keep):
         return {"state": jnp.where(keep[:, None, None], cache["state"], 0),
                 "conv": jnp.where(keep[:, None], cache["conv"], 0)}
+
+
+@serde.register
+@dataclasses.dataclass
+class ShortConvLayer(_SequenceMixer):
+    """A gated short convolution: ``[B | C | x] = W_in u`` (three
+    ``n_out``-wide chunks in that order), ``v = B * x``, ``z_t = sum_j w[j]
+    v_{t-K+1+j}`` over ``K = d_conv`` depthwise causal taps with no bias
+    and no activation, ``W_out (C * z)``. Its per-row state is the last
+    ``d_conv - 1`` values of ``v``, float32, a RING ``[rows, (d_conv - 1) *
+    n_out]`` whatever the bucket (kind ``conv_window``): what
+    ``cache_prefill`` owes a right-padded row is its last ``d_conv - 1``
+    REAL values, each in its position's slot. The mask of a sequence is
+    taken to be a RIGHT padding."""
+
+    scope_class = "mixer.shortconv"
+
+    n_out: int = 0
+    d_conv: int = 3
+    out_scale: float = 1.0
+    weight_dtype: str = ""
+
+    uses_mask = True
+    cache_kinds = {"conv": "conv_window"}
+    cache_counters = ("shortconv_state_updates",)
+
+    def init(self, key, input_type, dtype=jnp.float32):
+        """Matrices by the layer's initializer; the taps uniform in
+        +-1/sqrt(d_conv), float32."""
+        n_in, e = _as_ff_size(input_type), self.n_out
+        wd = _wdtype(self.weight_dtype, dtype)
+        ks = jax.random.split(key, 3)
+        return {"W_in": _matrix(self, ks[0], (n_in, 3 * e), wd),
+                "conv_w": jax.random.uniform(
+                    ks[1], (self.d_conv, e), jnp.float32, -1.0, 1.0)
+                / self.d_conv ** 0.5,
+                "W_out": _matrix(self, ks[2], (e, self.n_out), wd)}
+
+    def param_order(self):
+        return ["W_in", "conv_w", "W_out"]
+
+    def regularized_param_keys(self):
+        return ["W_in", "W_out"]
+
+    # --- the mathematics ----------------------------------------------------
+    def _project(self, params, u):
+        """``u`` -> ``(v, C)``: the convolution's input ``B * x`` and the
+        output gate."""
+        e = self.n_out
+        with jax.named_scope("shortconv.in_proj"):
+            bcx = _dot(u, params["W_in"])
+            return bcx[..., :e] * bcx[..., 2 * e:], bcx[..., e:2 * e]
+
+    def _finish(self, params, z, c):
+        with jax.named_scope("shortconv.out_proj"):
+            return self.activation.apply(
+                _dot(c * z, params["W_out"]) * self.out_scale)
+
+    def _sequence(self, params, u, mask, conv):
+        """``u: [batch, time, features]`` after the convolution's last
+        inputs ``conv: [batch, d_conv - 1, n_out]``, oldest first: a
+        ``lax.scan`` over spans of ``SHORTCONV_TOKEN_SPAN`` positions, the
+        inputs its carry. Returns ``(y, conv)``."""
+        b, t, _ = u.shape
+        n, span = _token_spans(t, SHORTCONV_TOKEN_SPAN)
+        mask = (jnp.ones((b, t), jnp.float32) if mask is None
+                else (jnp.asarray(mask) > 0).astype(jnp.float32))
+
+        def body(tail, xs):
+            uc, mc = xs
+            v, c = self._project(params, uc)
+            with jax.named_scope("shortconv.conv"):
+                z, tail = conv_span(tail, v, params["conv_w"], mc,
+                                    silu=False)
+            return tail, self._finish(params, z, c) * mc[:, :, None]
+
+        conv, y = jax.lax.scan(
+            body, conv,
+            (_split_spans(u, n, span), _split_spans(mask, n, span)))
+        return _merge_spans(y), conv
+
+    def _zeros(self, batch):
+        return jnp.zeros((batch, self.d_conv - 1, self.n_out), jnp.float32)
+
+    def forward(self, params, state, x, train=False, rng=None, mask=None):
+        x = self._dropout_input(x, train, rng)
+        y, _ = self._sequence(params, x, mask, self._zeros(x.shape[0]))
+        return y, state
+
+    # --- the cache interface ------------------------------------------------
+    def cache_init(self, batch, length, n_in, dtype=jnp.float32):
+        return {"conv": self._zeros(batch).reshape(batch, -1)}
+
+    def cache_prefill(self, params, x, key_mask=None, dtype=jnp.float32,
+                      use_kernels=False):
+        b = x.shape[0]
+        y, tail = self._sequence(params, x, key_mask, self._zeros(b))
+        lengths = (jnp.full((b,), x.shape[1], jnp.int32) if key_mask is None
+                   else jnp.sum(key_mask > 0, axis=1).astype(jnp.int32))
+        return y, {"conv": tail_to_ring(tail, lengths)}
+
+    def cache_join(self, cache, block, rows, length):
+        return {"conv": _join_rows(cache["conv"], block["conv"], rows)}
+
+    def cache_step(self, params, x, cache, positions, active=None):
+        with jax.named_scope("shortconv.step"):
+            v, c = self._project(params, x)
+            z, ring = conv_ring_step(cache["conv"], v, params["conv_w"],
+                                     positions, silu=False)
+        counts = {"shortconv_state_updates": jnp.ones_like(positions)}
+        return self._finish(params, z, c), {"conv": ring}, counts
+
+    def cache_grow(self, cache, length):
+        return cache
+
+    def cache_release(self, cache, keep):
+        return {"conv": jnp.where(keep[:, None], cache["conv"], 0)}

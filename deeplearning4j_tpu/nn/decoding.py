@@ -41,7 +41,8 @@ fixed-size recurrent state (``LightningAttentionLayer``), a scan's state
 beside a convolution's window (``conf.layers_ssm.MambaMixerLayer``), a
 delta rule's state beside a convolution's window
 (``conf.layers_delta.GatedDeltaNetLayer``), one latent vector a position
-(``conf.layers_delta.LatentAttentionLayer``), side by side in
+(``conf.layers_delta.LatentAttentionLayer``), a short convolution's window
+alone (``conf.layers_ssm.ShortConvLayer``), side by side in
 the one donated state pytree, each in its own type. The suffix walk (the
 prefix cache) needs ``prefill_suffix`` on every such layer and refuses a
 graph that has a layer without it, by name.

@@ -522,7 +522,8 @@ def record_decode_layer_counts(counts: dict) -> None:
     (sparse-layer query, KV head), ``sparse_dense_fallback_queries``,
     ``recurrent_state_updates``, ``ssm_state_updates`` (active row x
     state-space layer a step), ``delta_state_updates`` (active row x
-    delta-rule layer a step); ``decode_kv_read_positions`` /
+    delta-rule layer a step), ``shortconv_state_updates`` (active row x
+    short-convolution layer a step); ``decode_kv_read_positions`` /
     ``decode_kv_bucket_positions`` per (attention layer, row, step; a
     latent layer's too): the
     cached positions streamed over the positions held (a bucket; for a

@@ -41,7 +41,7 @@ from typing import Dict, Iterable, List, NamedTuple, Optional, Tuple
 SCOPE_CLASSES = (
     "embed", "pos", "norm", "attn.mha", "attn.full", "attn.window",
     "attn.sparse", "attn.lightning", "attn.delta", "attn.latent", "ssm",
-    "moe", "ffn", "residual", "head")
+    "mixer.shortconv", "moe", "ffn", "residual", "head")
 # what a decoder program runs outside the walk. ``window.prepare`` is also
 # given by :func:`group_of` to every operation the compiler hoisted out of
 # the decode window's ``while`` (the float32 -> bfloat16 converts of the

@@ -1099,12 +1099,16 @@ class HybridDecoderLM(GraphZooModel):
     cache, ``GatedAttentionLayer``) or ``"full-attn"`` (the same layer,
     neither rotating nor bounded: the two kinds pair the layer's two
     switches, ``window`` and ``rope_theta``, as the one family that uses
-    them does; a full layer that rotates needs a third name here, the
-    layer itself keeps the switches apart), ``"plain-attn"`` (grouped-query
+    them does), ``"rope-attn"`` (grouped-query attention with the q/k
+    norm, rotating at ``rope_theta``, neither gated nor bounded,
+    ``NormedAttentionLayer``), ``"plain-attn"`` (grouped-query
     attention with neither q/k norm, gate, rotation nor window,
     ``GroupedAttentionLayer``), ``"mamba"`` (a state-space mixer: a
     selective scan behind a causal convolution, two kinds of per-row
     state, ``conf.layers_ssm.MambaMixerLayer``, its sizes in ``mamba``),
+    ``"short-conv"`` (a gated short convolution, one kind of per-row
+    state, ``conf.layers_ssm.ShortConvLayer``, its sizes in
+    ``shortconv``),
     ``"gated-deltanet"`` (gated delta-rule linear attention behind a
     causal convolution, two kinds of per-row state,
     ``conf.layers_delta.GatedDeltaNetLayer``, its sizes in ``delta``) or
@@ -1134,7 +1138,8 @@ class HybridDecoderLM(GraphZooModel):
     and the KV caches' types; the recurrent state is float32."""
 
     MIXERS = ("lightning-attn", "minicpm4", "window-attn", "full-attn",
-              "plain-attn", "mamba", "gated-deltanet", "mla")
+              "plain-attn", "mamba", "gated-deltanet", "mla", "rope-attn",
+              "short-conv")
 
     def __init__(self, vocab_size: int, hidden: int, ffn_dim: int,
                  mixer_types, n_heads: int, head_dim: int,
@@ -1151,7 +1156,7 @@ class HybridDecoderLM(GraphZooModel):
                  post_norms: bool = False, mamba: dict | None = None,
                  tie_head: bool = False, delta: dict | None = None,
                  mla: dict | None = None, zero_centred_norms: bool = False,
-                 swiglu_limit: float = 0.0):
+                 swiglu_limit: float = 0.0, shortconv: dict | None = None):
         self.mixer_types = list(mixer_types)
         unknown = sorted(set(self.mixer_types) - set(self.MIXERS))
         if unknown:
@@ -1183,6 +1188,7 @@ class HybridDecoderLM(GraphZooModel):
         self.post_norms = post_norms
         self.mamba = dict(mamba or {})
         self.delta, self.mla = dict(delta or {}), dict(mla or {})
+        self.shortconv = dict(shortconv or {})
         self.zero_centred_norms = zero_centred_norms
         self.swiglu_limit = swiglu_limit
         self.tie_head = tie_head
@@ -1199,6 +1205,7 @@ class HybridDecoderLM(GraphZooModel):
             GroupedAttentionLayer,
             LightningAttentionLayer,
             LMHeadLayer,
+            NormedAttentionLayer,
             ResidualAddVertex,
             RMSNormLayer,
             ScaledEmbeddingLayer,
@@ -1208,7 +1215,10 @@ class HybridDecoderLM(GraphZooModel):
             LatentAttentionLayer,
         )
         from deeplearning4j_tpu.conf.layers_moe import RoutedExpertsLayer
-        from deeplearning4j_tpu.conf.layers_ssm import MambaMixerLayer
+        from deeplearning4j_tpu.conf.layers_ssm import (
+            MambaMixerLayer,
+            ShortConvLayer,
+        )
 
         e, wd, c = self.hidden, self.weight_dtype, self.residual_scale
         # the clamp and the zero-centred gain only where asked for: every
@@ -1265,6 +1275,15 @@ class HybridDecoderLM(GraphZooModel):
                 mixer = LatentAttentionLayer(
                     n_out=e, eps=self.eps, out_scale=c, weight_dtype=wd,
                     cache_dtype=self.cache_dtype, **self.mla)
+            elif kind == "short-conv":
+                mixer = ShortConvLayer(n_out=e, out_scale=c, weight_dtype=wd,
+                                       **self.shortconv)
+            elif kind == "rope-attn":
+                mixer = NormedAttentionLayer(
+                    n_out=e, n_heads=self.n_heads,
+                    n_kv_heads=self.n_kv_heads, head_size=self.head_dim,
+                    rope_theta=self.rope_theta, eps=self.eps, out_scale=c,
+                    weight_dtype=wd, cache_dtype=self.cache_dtype)
             elif kind == "plain-attn":
                 mixer = GroupedAttentionLayer(
                     n_out=e, n_heads=self.n_heads,

@@ -1140,6 +1140,64 @@ def test_delta_decode_window_holds_the_three_kernels_once_each(
     assert not re.search(rf"\[\d*,?{t},{hv},{h},{h}\]", prompt)
 
 
+# --- the short-convolution hybrid: a ring of two inputs beside a KV cache ---
+
+_SHORTCONV = {"rows": 3, "tokens": 256, "hidden": 384}
+
+
+@pytest.fixture(scope="module")
+def shortconv_programs(one_chip):
+    """Decode window, prompt and join of a two-layer ``HybridDecoderLM`` (a
+    gated short convolution over a dense feed-forward, q/k-normed rotating
+    attention over routed experts, a tied head) compiled for the v5e from
+    avals: ``{program: (text, {kind of state: [shape, ...]})}``. A hidden
+    width of 384 lays the ring's two inputs in whole lane tiles as the
+    cell's 2 x 2,048 do; three rows and a feed-forward of 640 make a
+    state's shape no other array's."""
+    from deeplearning4j_tpu.zoo.graphs import HybridDecoderLM
+
+    b, s, tp = _SHORTCONV["rows"], 1024, _SHORTCONV["tokens"]
+    zoo = HybridDecoderLM(
+        vocab_size=512, hidden=_SHORTCONV["hidden"], ffn_dim=640,
+        mixer_types=["short-conv", "rope-attn"], ffn_types=["dense", "moe"],
+        shortconv={"d_conv": 3},
+        moe={"n_experts": 4, "n_hidden": 128, "top_k": 2,
+             "route_eps": 1e-6},
+        n_heads=6, head_dim=64, n_kv_heads=2, rope_theta=1e6, tie_head=True,
+        depth_for_scale=1, max_len=s, weight_dtype="bfloat16",
+        cache_dtype="bfloat16")
+    return _compiled_programs(one_chip, zoo, b, s, tp)
+
+
+_SHORTCONV_READERS = {
+    # the ring, as the state-space layer's: the taps weigh every slot
+    # where it lies, the token's input is selected into the oldest slot
+    ("decode", "conv_window"): {("multiply", "whole"), ("add", "whole"),
+                                ("select", "whole"), ("slice", "whole")},
+    # the token's write, in place; the bucket is read by the paged kernel
+    ("decode", "kv"): {_WRITE, ("custom-call", "part")},
+    # a join writes the joining row alone (a third of a three-row state)
+    ("join", "conv_window"): {_WRITE, ("dynamic-slice", "whole")},
+    ("join", "kv"): {_WRITE, ("dynamic-slice", "whole")},
+}
+
+
+@pytest.mark.parametrize("program,kind", sorted(_SHORTCONV_READERS))
+def test_shortconv_programs_touch_each_state_only_to_update_it_where_it_lies(
+        shortconv_programs, program, kind):
+    """No ``copy``, ``pad``, ``concatenate`` or scatter's ``while`` has the
+    short convolution's ring or a KV bucket among its operands, in the
+    decode window or in the join."""
+    txt, shapes = shortconv_programs[program]
+    rows, e = _SHORTCONV["rows"], _SHORTCONV["hidden"]
+    assert shapes == {"conv_window": {f"f32[{rows},{2 * e}]"},
+                      "kv": {f"bf16[{rows},1024,128]"}}
+    found = _consumers(txt, shapes[kind])
+    assert found <= _SHORTCONV_READERS[program, kind], sorted(
+        found - _SHORTCONV_READERS[program, kind])
+    assert found
+
+
 # --- the scopes of the programs compiled for the v5e --------------------------
 # (``telemetry.device_time``: what the device's time is filed under)
 
@@ -1224,6 +1282,8 @@ _WALKED = {     # the classes of each decoder's plan entries
                      "head"},
     "delta_programs": {"embed", "norm", "attn.delta", "attn.latent",
                        "residual", "ffn", "moe", "head"},
+    "shortconv_programs": {"embed", "norm", "mixer.shortconv", "attn.full",
+                           "residual", "ffn", "moe", "head"},
 }
 
 

@@ -90,7 +90,8 @@ def main(argv):
     sys.path.insert(0, root)
     os.chdir(root)
     for name in ("gpt2-large-serve", "minicpm-sala-serve",
-                 "trinity-mini-serve", "jamba2-3b-serve", "gigachat35-serve"):
+                 "trinity-mini-serve", "jamba2-3b-serve", "gigachat35-serve",
+                 "lfm2-8b-a1b-serve"):
         path = os.path.join(root, "benchmarks", "configs", name + ".json")
         if not os.path.exists(path):    # a parent checkout lacks the newest
             print(name, "not in this checkout", flush=True)
